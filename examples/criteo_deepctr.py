@@ -110,6 +110,8 @@ def main():
                          "print the end-of-run hot-id + shard-balance "
                          "tables beside the trace dump")
     args = ap.parse_args()
+    from openembedding_tpu.utils import compile_cache
+    compile_cache.enable()
     if args.flight_recorder > 0:
         from openembedding_tpu.utils import trace as T
         T.configure(args.flight_recorder)

@@ -24,7 +24,6 @@ Public API (the reference's 3-line conversion, `openembedding/tensorflow/exb.py`
 
 __version__ = "0.1.0"
 
-from . import _jax_compat  # noqa: F401  (installs jax.shard_map/enable_x64 aliases)
 from . import meta
 from . import config
 from . import initializers

@@ -3,14 +3,19 @@
 The reference ships native code for its data path (`test/criteo_preprocess.cpp`) and
 runtime (pico-core); here the TSV parse/hash/batch producer is C++
 (`oetpu_data.cpp`) bound with ctypes (no pybind11 in this image). The library is
-built on demand with g++ (cached next to the source, keyed by source mtime);
-everything degrades gracefully to the pure-Python reader when no compiler is
-available (`data/criteo.py` falls back automatically).
+built on demand with g++ and cached next to the source under a name that is a
+hash of the source and the compile command (`liboetpu_data.<hash>.so`) — a
+binary that arrives with a copied tree is used only if it was built from this
+source with these flags, never because its mtime looks new. No `-march=native`:
+the tree may be copied to a host with another CPU. Everything degrades
+gracefully to the pure-Python reader when no compiler is available
+(`data/criteo.py` falls back automatically).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,7 +25,10 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "oetpu_data.cpp")
-_LIB = os.path.join(_DIR, "liboetpu_data.so")
+_CXX = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread"]
+# tried in order: hosts without zlib dev libs keep every plain-file path with
+# the gzip support compiled out (.gz opens then fail loudly at read time)
+_VARIANTS = (["-lz"], ["-DOETPU_NO_ZLIB"])
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -30,26 +38,34 @@ NUM_DENSE = 13
 NUM_SPARSE = 26
 
 
+def _lib_paths() -> list:
+    """One library path per entry of `_VARIANTS`, each named by a hash of the
+    source and that variant's compile command."""
+    with open(_SRC, "rb") as f:
+        src = f.read()
+    return [os.path.join(_DIR, "liboetpu_data.%s.so" % hashlib.sha256(
+        src + " ".join(_CXX + extra).encode()).hexdigest()[:16])
+        for extra in _VARIANTS]
+
+
 def build(force: bool = False) -> str:
-    """Compile the shared library if missing/stale; returns its path."""
+    """Compile the shared library unless the one for this source + command
+    is already there; returns its path."""
     with _lock:
-        if (not force and os.path.exists(_LIB)
-                and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)):
-            return _LIB
-        tmp = f"{_LIB}.tmp.{os.getpid()}"  # unique per builder: concurrent
-        # processes (multi-host launch, pytest-xdist) must not share a tmp
-        base = ["g++", "-O3", "-march=native", "-std=c++17", "-shared",
-                "-fPIC", "-pthread", _SRC, "-o", tmp]
-        proc = subprocess.run(base + ["-lz"], capture_output=True, text=True)
-        if proc.returncode != 0:
-            # hosts without zlib dev libs keep every plain-file path: compile
-            # the gzip support out (.gz opens then fail loudly at read time)
-            proc = subprocess.run(base + ["-DOETPU_NO_ZLIB"],
+        paths = _lib_paths()
+        if not force:
+            for path in paths:
+                if os.path.exists(path):
+                    return path
+        for path, extra in zip(paths, _VARIANTS):
+            tmp = f"{path}.tmp.{os.getpid()}"  # unique per builder: concurrent
+            # processes (multi-host launch, pytest-xdist) must not share a tmp
+            proc = subprocess.run(_CXX + [_SRC, "-o", tmp] + extra,
                                   capture_output=True, text=True)
-        if proc.returncode != 0:
-            raise RuntimeError(f"native build failed:\n{proc.stderr}")
-        os.replace(tmp, _LIB)
-        return _LIB
+            if proc.returncode == 0:
+                os.replace(tmp, path)
+                return path
+        raise RuntimeError(f"native build failed:\n{proc.stderr}")
 
 
 def load() -> ctypes.CDLL:

@@ -84,6 +84,11 @@ def _resolve() -> Tuple[bool, bool]:
     if _MODE in ("off", "auto"):  # auto == off: XLA measured faster (module doc)
         return False, False
     if _MODE == "interpret":
+        # oelint: disable=trace-hazard -- default_backend() is a host string, not a tracer
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "OETPU_PALLAS=interpret on a TPU backend: the interpreter is "
+                "the CPU test mode. Use 'on' (compiled kernels) or 'off'.")
         return True, True
     return True, False
 
@@ -163,22 +168,28 @@ def gather_rows(weights: jax.Array, rows: jax.Array,
     return jnp.where(in_range[:, None], out, jnp.zeros_like(out))
 
 
-def _lane_aligned(*widths: int) -> bool:
+def _require_lane_aligned(kernel: str, rows: int, *widths: int) -> None:
     """Mosaic constraint: per-row HBM DMA slices must cover whole 128-lane tiles, so
-    the kernels only run on hardware when every row width is a multiple of 128.
-    (Unaligned dims — the reference's 9/64 benchmarks — stay on the XLA path, whose
-    native gather already runs at HBM bandwidth; measured in
-    `tools/pallas_microbench.py`.)"""
-    return all(w % 128 == 0 for w in widths)
+    the compiled kernels only take row widths that are multiples of 128. "on"
+    means on: an unaligned table (the reference's dim 9/64 benchmarks) is an
+    error, never a silent switch to the XLA path."""
+    if any(w % 128 for w in widths):
+        raise ValueError(
+            f"OETPU_PALLAS=on: {kernel} got a {rows}-row table with row "
+            f"width(s) {list(widths)}; the compiled kernels need every width "
+            "to be a multiple of 128 lanes. Set OETPU_PALLAS=off (the XLA "
+            "path, the default) for this model.")
 
 
 def maybe_gather_rows(weights, rows, valid=None):
-    """Dispatch hook for `ops.sparse.lookup_rows`; None = use the XLA path."""
+    """Dispatch hook for `ops.sparse.lookup_rows`; None = use the XLA path
+    (mode off only)."""
     use, interpret = _resolve()
-    if not use or weights.ndim != 2:
+    if not use:
         return None
-    if not interpret and not _lane_aligned(weights.shape[1]):
-        return None
+    if not interpret:
+        _require_lane_aligned("gather_rows", weights.shape[0],
+                              *weights.shape[1:])
     return gather_rows(weights, rows, valid, interpret=interpret)
 
 
@@ -491,13 +502,15 @@ def fused_sparse_apply(optimizer, weights: jax.Array, slots: Dict[str, jax.Array
 
 
 def maybe_fused_apply(optimizer, weights, slots, rows, grads, counts):
-    """Dispatch hook for `ops.sparse.sparse_apply_dense_table`; None = XLA path."""
+    """Dispatch hook for `ops.sparse.sparse_apply_dense_table`; None = XLA path
+    (mode off only)."""
     use, interpret = _resolve()
     if not use:
         return None
-    if not interpret and not _lane_aligned(
-            weights.shape[1], *(s.shape[1] for s in slots.values())):
-        # e.g. Adam's per-row beta^t slots are width 1 -> XLA path on hardware
-        return None
+    if not interpret:
+        # e.g. Adam's per-row beta^t slots are width 1: not runnable compiled
+        _require_lane_aligned("fused_sparse_apply", weights.shape[0],
+                              weights.shape[1],
+                              *(s.shape[1] for s in slots.values()))
     return fused_sparse_apply(optimizer, weights, slots, rows, grads, counts,
                               interpret=interpret)

@@ -1462,6 +1462,8 @@ def main(argv=None) -> int:
                          "(flight tail + history rings + memory ledger) "
                          "here; render with tools/capsule_report.py")
     args = ap.parse_args(argv)
+    from .utils import compile_cache
+    compile_cache.enable()  # a restarted node reloads its gather/predict programs
     if args.flight_recorder > 0:
         trace.configure(args.flight_recorder)
     if args.capsule_dir:

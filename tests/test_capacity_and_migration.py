@@ -210,7 +210,7 @@ def test_zipfian_f1_drop_rate_and_auc_vs_exact():
 
 def test_num_shards_mismatch_warns():
     """A num_shards value that cannot be honored must warn, not lie
-    (VERDICT r2 weak #5)."""
+    (round-2 review finding)."""
     model = make_deepfm(vocabulary=VOCAB, dim=4, hidden=(16,), num_shards=3)
     tr = MeshTrainer(model, embed.Adagrad(learning_rate=0.1),
                      mesh=make_mesh())

@@ -308,13 +308,13 @@ def test_packed_scan_compiles_one_scatter_per_table():
 
 
 def test_packed_scan_dim64_split_first_order_one_scatter_each():
-    """The dim-64 benchmark configuration (VERDICT r3 weak #4): split
+    """The dim-64 benchmark configuration: split
     first-order auto-engages at lane-multiple dims, so train_many packs BOTH
     tables — categorical 64+64 -> (V, 128) lane-exact, first_order 1+1 ->
     (V, 2) sublane — and each updates through ONE packed scatter with no
     split-shape scatters left. The on-chip HBM claim (no 128-lane-padded temp
-    copy of the table at width 128) is probed by `tools/dim64_probe.py` on
-    real TPU; this pins the program STRUCTURE on any backend."""
+    copy of the table at width 128) needs a chip run (`bench.py` dim64);
+    this pins the program STRUCTURE on any backend."""
     V = 1 << 14
     model = make_deepfm(vocabulary=V, dim=64)
     assert set(model.specs) == {"categorical", "first_order"}

@@ -218,7 +218,7 @@ def test_incremental_restore_equals_live_state(setup, tmp_path):
 
 
 def test_incremental_bytes_proportional_to_touched(tmp_path):
-    """The VERDICT's acceptance: delta bytes scale with TOUCHED rows, not the
+    """The round-4 review's acceptance: delta bytes scale with TOUCHED rows, not the
     table. A 2^16-row table trained on batches touching ~64 ids must produce
     deltas orders of magnitude smaller than the full base persist."""
     from openembedding_tpu.persist import IncrementalPersister, list_deltas

@@ -60,8 +60,8 @@ def _train_and_score(model, heldout, epochs=EPOCHS):
     return auc(labels, scores)
 
 
-# Tolerances are measured-margin + ~0.005 drift slack, not guesses (VERDICT r4
-# weak #6 called the old uniform 0.03 loose). Every seed below is fixed, so on
+# Tolerances are measured-margin + ~0.005 drift slack, not guesses (the round-4
+# review called the old uniform 0.03 loose). Every seed below is fixed, so on
 # one platform the achieved AUC is deterministic; measured r5 on the CPU suite
 # (oracle 0.8298): lr margin +0.0183, wdl +0.0196, deepfm +0.0308. The slack
 # absorbs cross-version/XLA numeric drift (~1e-3), not regressions.

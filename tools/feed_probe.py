@@ -1,6 +1,6 @@
 """Input-path headroom: reader -> batcher -> prefetch_to_device, NO train step.
 
-VERDICT r4 item 8: at the 1M-examples/s north star each of 16 hosts must
+Round-4 review item 8: at the 1M-examples/s north star each of 16 hosts must
 parse ~62.5k rows/s; the native readers were measured in isolation (169k
 rows/s TFRecord @4 threads) but the end-to-end feed — parse + batch +
 device placement + the prefetch queue — was never pinned. This probe:

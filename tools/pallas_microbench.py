@@ -1,7 +1,7 @@
 """Micro-benchmark: Pallas kernels vs the XLA fallback on the current backend.
 
 The Pallas kernels only engage for 128-lane-aligned row widths (Mosaic DMA slice
-constraint, see `ops/pallas_sparse.py::_lane_aligned`), so this measures:
+constraint, see `ops/pallas_sparse.py::_require_lane_aligned`), so this measures:
 - dim 64 (reference benchmark shape): XLA path only (what production uses there);
 - dim 128 (aligned): XLA vs Pallas gather and fused-apply head to head;
 - a full single-chip DeepFM train step at the reference dims, Pallas auto vs off.
