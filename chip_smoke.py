@@ -36,6 +36,12 @@ is not a TPU. A CPU rehearsal is something the CALLER asks for by name —
 sizes (`make chip-smoke-cpu`) — and it prints `"platform": "cpu"`; the
 program never chooses it. Timings printed here are set-up facts about one
 run, not benchmark metrics.
+
+`--profile DIR` (off by default; no effect on pass/fail) records the train and
+the mesh stage's scan window under `jax.profiler.trace` into `DIR/train` and
+`DIR/mesh`, each with the compiled scan's HLO text beside the xplane — what
+`python tools/trace_report.py --xplane DIR/train --steps 16` reduces to device
+time per `trace.scope` stage.
 """
 
 import argparse
@@ -138,17 +144,50 @@ def make_batches(batch_size, vocabulary, scan_steps):
     return first, jax.tree_util.tree_map(lambda *xs: np.stack(xs), *rest)
 
 
-def drive(state, step, many, first, stacked):
+def fresh_hlo_text(jitted, *args):
+    """The compiled program's HLO text with THIS build's `op_name` metadata.
+    The persistent cache's key leaves metadata out
+    (`jax_compilation_cache_include_metadata_in_key`), so an executable
+    loaded from it carries the stage names of whichever build wrote the
+    entry: compile once past the cache (and past the in-memory one, which
+    would hand back an executable this process already loaded). The fresh
+    executable is what the next call of `jitted` runs."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    jax.clear_caches()
+    try:
+        return jitted.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def drive(state, step, many, first, stacked, profile=None):
     """The step/scan sequence both trainers run: FIXED_STEPS steps on the
     fixed batch, then ONE scan window over the stacked fresh batches.
-    -> (state, losses)."""
+    `profile`: a directory that gets the scan window's profiler trace and
+    the scan's HLO text (the names `trace.scope_map` joins the trace's ops to
+    their stages by; one program a trace, since two programs' instruction
+    names collide). -> (state, losses)."""
+    import jax
     fixed = []
     for _ in range(FIXED_STEPS):
         state, metrics = step(state, first)
         fixed.append(float(metrics["loss"]))
+    session = contextlib.nullcontext()
+    if profile:
+        os.makedirs(profile, exist_ok=True)
+        with open(os.path.join(profile, "train_many.hlo.txt"), "w") as f:
+            f.write(fresh_hlo_text(many, state, stacked))
+        session = jax.profiler.trace(profile)
+    with session:
+        state, window = many(state, stacked)
+        jax.block_until_ready(state)
     check(np.isfinite(fixed).all(), f"non-finite step loss: {fixed}")
     check(fixed[-1] < fixed[0], f"loss did not fall on a fixed batch: {fixed}")
-    state, window = many(state, stacked)
     scan = np.asarray(window["loss"], np.float64)
     check(scan.shape == stacked["label"].shape[:1], f"scan losses {scan.shape}")
     check(np.isfinite(scan).all(), f"non-finite scan loss: {scan.tolist()}")
@@ -168,7 +207,9 @@ def stage_train(args, result):
     state = trainer.init(first)
     packed = trainer._packed_layouts(state)
     state, losses = drive(state, trainer.jit_train_step(),
-                          trainer.jit_train_many(), first, stacked)
+                          trainer.jit_train_many(), first, stacked,
+                          profile=args.profile
+                          and os.path.join(args.profile, "train"))
     result.update(losses=[round(x, 6) for x in losses],
                   packed={k: list(map(list, v)) for k, v in packed.items()})
     return model, trainer, state, first, losses
@@ -254,7 +295,8 @@ def stage_mesh(args, train_losses, result):
     state = trainer.init(first)
     state, losses = drive(state, trainer.jit_train_step(first, state),
                           trainer.jit_train_many(stacked, state), first,
-                          stacked)
+                          stacked, profile=args.profile
+                          and os.path.join(args.profile, "mesh"))
 
     shard_rows = {}
     for name, spec in model.ps_specs().items():
@@ -299,6 +341,10 @@ def main(argv=None):
                     help=f"examples per chip per step (default {FULL_BATCH}; "
                          "must be given explicitly for a CPU rehearsal)")
     ap.add_argument("--scan-steps", type=int, default=16)
+    ap.add_argument("--profile", metavar="DIR", default=None,
+                    help="record the train and mesh scan windows' profiler "
+                         "trace and HLO text under DIR (read with "
+                         "tools/trace_report.py --xplane DIR/train)")
     args = ap.parse_args(argv)
     faulthandler.dump_traceback_later(DEADLINE_S, exit=True)
 

@@ -31,6 +31,7 @@ from .embedding import (Embedding, EmbeddingSpec, EmbeddingTableState,
                         apply_gradients, combine, init_table_state, lookup,
                         lookup_train)
 from .optimizers import Adagrad, SparseOptimizer
+from .utils import metrics as _metrics
 from .utils import trace as _trace
 
 
@@ -572,14 +573,17 @@ class Trainer:
         the packed weights+slots array (only inside `train_many`'s scan; see
         `ops/sparse.packed_layout`).
 
-        The step phases carry `trainer.{pull,compute,apply}` spans
-        (`utils/trace.py` -> `oetpu_trainer_*_ms` histograms). Under jit they
-        fire at TRACE time — once per compile, measuring how long each phase
-        takes to trace/build, not per-step device time (per-step wall time is
-        the CALLER's span around the jitted fn, e.g. `vtimer("train",
-        "step")`). Run the step eagerly (no jit) and the same spans measure
-        real per-phase execution.
+        The step's stages carry `trace.scope` names (`sparse.pull`,
+        `dense.tower`, `dense.reduce`, `dense.update`, `sparse.apply`, ...;
+        `utils/trace.py`): HLO metadata that a device profile reads
+        (`tools/trace_report.py --xplane`), never a /metrics series — this
+        body runs once per compile, so a clock read here would time tracing.
+        Per-step wall time is the CALLER's span around the jitted fn
+        (`vtimer("train", "step")`, `measure_every`). `trainer.traces{fn=}`
+        counts the times this body ran, i.e. the traces.
         """
+        _metrics.observe("trainer.traces", 1, "sum",
+                         labels={"fn": "train_step"})
         model = self.model
         if model.batch_transform is not None:
             batch = model.batch_transform(batch)
@@ -603,9 +607,8 @@ class Trainer:
         # Hash tables insert unseen ids here, so pull threads the table state.
         # MeshTrainer overrides tables_pull/tables_apply with the fused
         # multi-table exchange (3 all_to_alls per dim-group, not per table).
-        with _trace.span("trainer", "pull"):
-            pulled_tables, pulled, stats, pull_plans = self.tables_pull(
-                state.tables, batch, ps_specs, packed)
+        pulled_tables, pulled, stats, pull_plans = self.tables_pull(
+            state.tables, batch, ps_specs, packed)
 
         return self._train_step_tail(state, batch, ps_specs, sad_specs,
                                      packed, tr0, fr0, pulled_tables, pulled,
@@ -650,23 +653,26 @@ class Trainer:
                 fr_new = None
             return self._loss(logits, batch), (logits, fr_new)
 
-        with _trace.span("trainer", "compute"):
+        # forward, combine, loss and backward: one stage (the backward's ops
+        # read `transpose(jvp(dense.tower))`, which still holds the name)
+        with _trace.scope("dense", "tower"):
             (loss, (logits, fr_new)), (dense_grads, row_grads) = \
                 jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)(
                     tr0, pulled)
 
-            # sentinel reads the PRE-reduction dense grads: per-shard local
-            # sumsq psums (via reduce_metrics) to one well-defined global
-            # quantity in both the allreduce and the ZeRO (unreduced-here)
-            # paths
-            raw_dense_grads = dense_grads if self.sentinel else None
+        # sentinel reads the PRE-reduction dense grads: per-shard local
+        # sumsq psums (via reduce_metrics) to one well-defined global
+        # quantity in both the allreduce and the ZeRO (unreduced-here)
+        # paths
+        raw_dense_grads = dense_grads if self.sentinel else None
+        with _trace.scope("dense", "reduce"):
             stats.update(self.dense_grad_stats(dense_grads))
             dense_grads = self.reduce_dense_grads(dense_grads)
 
-        with _trace.span("trainer", "apply"):
-            # DENSE apply (reference: Keras optimizer after Horovod allreduce;
-            # MeshTrainer(dense_shard=True) overrides with the ZeRO-sharded
-            # reduce_scatter -> chunk update -> all_gather path)
+        # DENSE apply (reference: Keras optimizer after Horovod allreduce;
+        # MeshTrainer(dense_shard=True) overrides with the ZeRO-sharded
+        # reduce_scatter -> chunk update -> all_gather path)
+        with _trace.scope("dense", "update"):
             new_params, new_slots = self.dense_update(
                 tr0, state.dense_slots, dense_grads)
             if split is not None:
@@ -674,16 +680,16 @@ class Trainer:
                 new_params = model.module.merge_params(
                     new_params, self.reduce_module_state(fr))
 
-            # SPARSE push+update (reference: PushGradients + UpdateWeights
-            # store op)
-            new_tables = dict(state.tables)
-            applied, push_stats = self.tables_apply(
-                ps_specs, pulled_tables, batch, row_grads, packed, pull_plans)
-            new_tables.update(applied)
-            stats.update(push_stats)
-            if self.sentinel:
-                stats.update(self._sentinel_stats(
-                    loss, raw_dense_grads, row_grads, new_tables))
+        # SPARSE push+update (reference: PushGradients + UpdateWeights
+        # store op)
+        new_tables = dict(state.tables)
+        applied, push_stats = self.tables_apply(
+            ps_specs, pulled_tables, batch, row_grads, packed, pull_plans)
+        new_tables.update(applied)
+        stats.update(push_stats)
+        if self.sentinel:
+            stats.update(self._sentinel_stats(
+                loss, raw_dense_grads, row_grads, new_tables))
 
         new_state = TrainState(
             step=state.step + 1,
@@ -770,46 +776,47 @@ class Trainer:
         abs-sum + element counts, and the wire-quantization error sumsq
         (fp32-vs-roundtrip through `ops.wire.pack_inband`, skipped when the
         exchange ships fp32 or there is no exchange at all)."""
-        f32 = jnp.float32
-        out: Dict[str, jax.Array] = {}
-        loss_arr = jnp.asarray(loss, f32)
-        out["health/loss_nonfinite"] = jnp.sum(
-            ~jnp.isfinite(loss_arr)).astype(f32)
-        sumsq = jnp.zeros((), f32)
-        nonfin = jnp.zeros((), f32)
-        for leaf in jax.tree_util.tree_leaves(dense_grads):
-            g = jnp.asarray(leaf, f32)
-            sumsq = sumsq + jnp.sum(jnp.square(g))
-            nonfin = nonfin + jnp.sum(~jnp.isfinite(g)).astype(f32)
-        out["health/dense_grad_sumsq"] = sumsq
-        out["health/dense_grad_nonfinite"] = nonfin
-        fmt = None
-        if self.num_shards > 1:
-            from .ops.wire import wire_format
-            fmt = wire_format(getattr(self, "wire", None))
-            if fmt == "fp32":
-                fmt = None
-        for name, g in (row_grads or {}).items():
-            g = jnp.asarray(g, f32)
-            out[f"{name}/grad_sumsq"] = jnp.sum(jnp.square(g))
-            out[f"{name}/grad_nonfinite"] = jnp.sum(
-                ~jnp.isfinite(g)).astype(f32)
-            if fmt is not None and g.ndim >= 2 and g.shape[-1] > 0:
-                from .ops.wire import pack_inband, unpack_inband
-                rows = g.reshape(-1, g.shape[-1])
-                back = unpack_inband(pack_inband(rows, fmt),
-                                     rows.shape[-1], fmt)
-                out[f"{name}/quant_err_sumsq"] = jnp.sum(
-                    jnp.square(back - rows))
-        for name, ts in tables.items():
-            ef = getattr(ts, "ef", None)
-            if ef is None:
-                continue
-            out[f"{name}/ef_abs_sum"] = jnp.sum(jnp.abs(jnp.asarray(ef, f32)))
-            # a trace-time constant, shipped as a stat so the host-side mean
-            # divides by the GLOBAL (psum'd) element count
-            out[f"{name}/ef_elems"] = jnp.asarray(float(ef.size), f32)
-        return out
+        with _trace.scope("trainer", "sentinel"):
+            f32 = jnp.float32
+            out: Dict[str, jax.Array] = {}
+            loss_arr = jnp.asarray(loss, f32)
+            out["health/loss_nonfinite"] = jnp.sum(
+                ~jnp.isfinite(loss_arr)).astype(f32)
+            sumsq = jnp.zeros((), f32)
+            nonfin = jnp.zeros((), f32)
+            for leaf in jax.tree_util.tree_leaves(dense_grads):
+                g = jnp.asarray(leaf, f32)
+                sumsq = sumsq + jnp.sum(jnp.square(g))
+                nonfin = nonfin + jnp.sum(~jnp.isfinite(g)).astype(f32)
+            out["health/dense_grad_sumsq"] = sumsq
+            out["health/dense_grad_nonfinite"] = nonfin
+            fmt = None
+            if self.num_shards > 1:
+                from .ops.wire import wire_format
+                fmt = wire_format(getattr(self, "wire", None))
+                if fmt == "fp32":
+                    fmt = None
+            for name, g in (row_grads or {}).items():
+                g = jnp.asarray(g, f32)
+                out[f"{name}/grad_sumsq"] = jnp.sum(jnp.square(g))
+                out[f"{name}/grad_nonfinite"] = jnp.sum(
+                    ~jnp.isfinite(g)).astype(f32)
+                if fmt is not None and g.ndim >= 2 and g.shape[-1] > 0:
+                    from .ops.wire import pack_inband, unpack_inband
+                    rows = g.reshape(-1, g.shape[-1])
+                    back = unpack_inband(pack_inband(rows, fmt),
+                                         rows.shape[-1], fmt)
+                    out[f"{name}/quant_err_sumsq"] = jnp.sum(
+                        jnp.square(back - rows))
+            for name, ts in tables.items():
+                ef = getattr(ts, "ef", None)
+                if ef is None:
+                    continue
+                out[f"{name}/ef_abs_sum"] = jnp.sum(jnp.abs(jnp.asarray(ef, f32)))
+                # a trace-time constant, shipped as a stat so the host-side mean
+                # divides by the GLOBAL (psum'd) element count
+                out[f"{name}/ef_elems"] = jnp.asarray(float(ef.size), f32)
+            return out
 
     def record_step_stats(self, step_metrics):
         """Fold one step's metrics through the spine
@@ -818,7 +825,6 @@ class Trainer:
         `metrics.NonFiniteError` naming the offending table/phase when the
         sentinel saw a non-finite loss or gradient. Returns the health
         summary dict."""
-        from .utils import metrics as _metrics
         stats = step_metrics
         if isinstance(step_metrics, dict) and "stats" in step_metrics:
             stats = step_metrics["stats"]
@@ -846,8 +852,7 @@ class Trainer:
     def _wrap_measured(self, fn):
         """Wrap a jitted step with the sampled measurement mode
         (`measure_every` > 0): one call in N is bracketed host-side with
-        `block_until_ready` into `trainer.step_ms` + HLO-byte attribution +
-        `exchange.cost_drift`. The watch is cached so repeated
+        `block_until_ready` into `trainer.step_ms` + `exchange.cost_drift`. The watch is cached so repeated
         `jit_train_step()` calls share one sample counter/baseline."""
         watch = self._ensure_stepwatch()
         return fn if watch is None else watch.wrap(fn)
@@ -869,12 +874,15 @@ class Trainer:
     def table_pull(self, spec, table, ids):
         """-> (new_table, rows, stats, plan). The plan (routing/dedup state) is handed
         back to table_apply so push reuses pull's work; None on single device."""
-        table, rows = lookup_train(spec, table, ids)
+        with _trace.scope("sparse", "pull"):
+            table, rows = lookup_train(spec, table, ids)
         return table, rows, {}, None
 
     def table_apply(self, spec, table, ids, grads, plan=None):
         """-> (new_table, stats)."""
-        return apply_gradients(spec, table, self.opt_for(spec), ids, grads), {}
+        with _trace.scope("sparse", "apply"):
+            return apply_gradients(spec, table, self.opt_for(spec), ids,
+                                   grads), {}
 
     def table_lookup(self, spec, table, ids):
         return lookup(spec, table, ids)
@@ -927,32 +935,34 @@ class Trainer:
         latency-bound, the extra slot bytes ride free) and slice the weight
         columns. Hash tables keep their normal probe/insert (keys are a
         separate array either way)."""
-        from .embedding import _flat_ids
-        from .ops.sparse import lookup_rows
-        flat, out_shape = _flat_ids(spec, ids)
-        if spec.use_hash_table:
-            from .tables.hash_table import hash_lookup_train
-            table, rows = hash_lookup_train(table, flat,
-                                            out_dim=spec.output_dim)
-        else:
-            rows = lookup_rows(table.weights, flat)[:, :spec.output_dim]
-        rows = rows.astype(spec.dtype).reshape(out_shape + (spec.output_dim,))
-        return table, rows, {}, None
+        with _trace.scope("sparse", "pull"):
+            from .embedding import _flat_ids
+            from .ops.sparse import lookup_rows
+            flat, out_shape = _flat_ids(spec, ids)
+            if spec.use_hash_table:
+                from .tables.hash_table import hash_lookup_train
+                table, rows = hash_lookup_train(table, flat,
+                                                out_dim=spec.output_dim)
+            else:
+                rows = lookup_rows(table.weights, flat)[:, :spec.output_dim]
+            rows = rows.astype(spec.dtype).reshape(out_shape + (spec.output_dim,))
+            return table, rows, {}, None
 
     def _packed_apply(self, spec, table, ids, grads, layout, plan=None):
-        from .embedding import _flat_ids
-        from .ops.sparse import sparse_apply_packed_table
-        flat_ids, _ = _flat_ids(spec, ids)
-        flat_grads = grads.reshape(-1, spec.output_dim)
-        if spec.use_hash_table:
-            from .tables.hash_table import hash_apply_gradients_packed
-            return hash_apply_gradients_packed(
-                table, self.opt_for(spec), flat_ids, flat_grads, layout,
-                spec.output_dim), {}
-        packed = sparse_apply_packed_table(
-            self.opt_for(spec), table.weights, layout, spec.output_dim,
-            flat_ids, flat_grads)
-        return table.replace(weights=packed), {}
+        with _trace.scope("sparse", "apply"):
+            from .embedding import _flat_ids
+            from .ops.sparse import sparse_apply_packed_table
+            flat_ids, _ = _flat_ids(spec, ids)
+            flat_grads = grads.reshape(-1, spec.output_dim)
+            if spec.use_hash_table:
+                from .tables.hash_table import hash_apply_gradients_packed
+                return hash_apply_gradients_packed(
+                    table, self.opt_for(spec), flat_ids, flat_grads, layout,
+                    spec.output_dim), {}
+            packed = sparse_apply_packed_table(
+                self.opt_for(spec), table.weights, layout, spec.output_dim,
+                flat_ids, flat_grads)
+            return table.replace(weights=packed), {}
 
     def train_many(self, state: TrainState, batches) -> Tuple[TrainState, Dict]:
         """K steps in ONE compiled program via lax.scan over stacked batches
@@ -973,6 +983,8 @@ class Trainer:
         admission, so an unprepared cache would silently train initializer
         rows where the host store holds trained ones). Use
         `offload_train_many`, which drives prepare -> scan -> adopt."""
+        _metrics.observe("trainer.traces", 1, "sum",
+                         labels={"fn": "train_many"})
         if self.offload and not getattr(self, "_offload_prepared", False):
             # trace-time fail-fast for the old misuse (an unprepared cache
             # trains initializer rows over the store's trained ones); repeat

@@ -18,6 +18,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..utils import trace as _trace
+
 
 class UniqueResult(NamedTuple):
     unique_ids: jax.Array   # (n,) — first num_unique slots are the sorted unique ids
@@ -50,31 +52,32 @@ def unique_with_counts(ids: jax.Array) -> UniqueResult:
     ((n, 2) uint32, `ops/id64.py`): pairs sort lexicographically with a
     two-key `lax.sort`, everything downstream is lane-count agnostic.
     """
-    n = ids.shape[0]
-    if ids.ndim == 2:  # split-pair layout
-        iota = jnp.arange(n, dtype=jnp.int32)
-        s_hi, s_lo, order = jax.lax.sort(
-            (ids[:, 0], ids[:, 1], iota), num_keys=2)
-        sorted_ids = jnp.stack([s_hi, s_lo], axis=-1)
-        is_new = jnp.concatenate(
-            [jnp.ones((1,), dtype=bool),
-             (s_hi[1:] != s_hi[:-1]) | (s_lo[1:] != s_lo[:-1])])
-    else:
-        order = jnp.argsort(ids).astype(jnp.int32)
-        sorted_ids = ids[order]
-        is_new = jnp.concatenate(
-            [jnp.ones((1,), dtype=bool), sorted_ids[1:] != sorted_ids[:-1]])
-    seg = (jnp.cumsum(is_new) - 1).astype(jnp.int32)  # ascending segment ids
-    num_unique = seg[-1] + 1
-    # duplicate writes to one segment all carry the same value, so .set is deterministic
-    unique_ids = jnp.zeros(sorted_ids.shape, ids.dtype).at[seg].set(
-        sorted_ids, mode="drop", indices_are_sorted=True)
-    counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), seg, num_segments=n,
-                                 indices_are_sorted=True)
-    inverse = jnp.zeros((n,), jnp.int32).at[order].set(seg)
-    return UniqueResult(unique_ids, inverse, counts.astype(jnp.int32),
-                        num_unique.astype(jnp.int32), order.astype(jnp.int32),
-                        seg)
+    with _trace.scope("sparse", "dedup"):
+        n = ids.shape[0]
+        if ids.ndim == 2:  # split-pair layout
+            iota = jnp.arange(n, dtype=jnp.int32)
+            s_hi, s_lo, order = jax.lax.sort(
+                (ids[:, 0], ids[:, 1], iota), num_keys=2)
+            sorted_ids = jnp.stack([s_hi, s_lo], axis=-1)
+            is_new = jnp.concatenate(
+                [jnp.ones((1,), dtype=bool),
+                 (s_hi[1:] != s_hi[:-1]) | (s_lo[1:] != s_lo[:-1])])
+        else:
+            order = jnp.argsort(ids).astype(jnp.int32)
+            sorted_ids = ids[order]
+            is_new = jnp.concatenate(
+                [jnp.ones((1,), dtype=bool), sorted_ids[1:] != sorted_ids[:-1]])
+        seg = (jnp.cumsum(is_new) - 1).astype(jnp.int32)  # ascending segment ids
+        num_unique = seg[-1] + 1
+        # duplicate writes to one segment all carry the same value, so .set is deterministic
+        unique_ids = jnp.zeros(sorted_ids.shape, ids.dtype).at[seg].set(
+            sorted_ids, mode="drop", indices_are_sorted=True)
+        counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), seg, num_segments=n,
+                                     indices_are_sorted=True)
+        inverse = jnp.zeros((n,), jnp.int32).at[order].set(seg)
+        return UniqueResult(unique_ids, inverse, counts.astype(jnp.int32),
+                            num_unique.astype(jnp.int32), order.astype(jnp.int32),
+                            seg)
 
 
 def carry_to_unique(uniq: UniqueResult, values: jax.Array,
@@ -89,10 +92,11 @@ def carry_to_unique(uniq: UniqueResult, values: jax.Array,
     The hot-row membership probe (`parallel/sharded.py`) uses this to turn a
     per-position hot-slot probe into a per-unique-slot one without a second
     probe or sort."""
-    n = uniq.order.shape[0]
-    out = jnp.full((n,), fill, values.dtype)
-    return out.at[uniq.seg].set(values[uniq.order], mode="drop",
-                                indices_are_sorted=True)
+    with _trace.scope("sparse", "dedup"):
+        n = uniq.order.shape[0]
+        out = jnp.full((n,), fill, values.dtype)
+        return out.at[uniq.seg].set(values[uniq.order], mode="drop",
+                                    indices_are_sorted=True)
 
 
 class BucketResult(NamedTuple):
@@ -119,37 +123,38 @@ def bucket_by_owner(ids: jax.Array, valid: jax.Array, num_shards: int,
     validity is derivable from the ids alone — do not apply `bucket_validity`
     to THIS function's output.
     """
-    n = ids.shape[0]
-    if ids.ndim == 2:  # split-pair layout: owner via modular pair arithmetic
-        from .id64 import pair_mod
-        owner = jnp.where(valid, pair_mod(ids, num_shards).astype(jnp.int32),
-                          num_shards)
-    else:
-        owner = jnp.where(valid, (ids % num_shards).astype(jnp.int32),
-                          num_shards)
-    # stable sort by owner so each bucket preserves input order
-    order = jnp.argsort(owner, stable=True)
-    sorted_owner = owner[order]
-    # index within the owner group = position - start of that owner's run
-    group_start = jnp.searchsorted(sorted_owner, sorted_owner, side="left")
-    idx_in_group = jnp.arange(n, dtype=jnp.int32) - group_start.astype(jnp.int32)
-    slot_sorted = idx_in_group
-    in_cap = (slot_sorted < capacity) & (sorted_owner < num_shards)
-    overflow = jnp.sum((~in_cap) & (sorted_owner < num_shards)).astype(jnp.int32)
-    # scatter (owner, slot) -> id; out-of-capacity and invalid entries drop
-    flat_pos = jnp.where(in_cap, sorted_owner * capacity + slot_sorted,
-                         num_shards * capacity)
-    lanes = ids.shape[1:]  # () single-lane, (2,) split-pair
-    bucket_ids = jnp.zeros((num_shards * capacity,) + lanes,
-                           ids.dtype).at[flat_pos].set(
-        ids[order], mode="drop").reshape((num_shards, capacity) + lanes)
-    bucket_valid = jnp.zeros((num_shards * capacity,), bool).at[flat_pos].set(
-        True, mode="drop").reshape(num_shards, capacity)
-    # per-input-element position (for unbucketing responses)
-    owner_out = jnp.zeros((n,), jnp.int32).at[order].set(sorted_owner)
-    slot_out = jnp.zeros((n,), jnp.int32).at[order].set(
-        jnp.where(in_cap, slot_sorted, capacity))
-    return BucketResult(bucket_ids, bucket_valid, owner_out, slot_out, overflow)
+    with _trace.scope("exchange", "route"):
+        n = ids.shape[0]
+        if ids.ndim == 2:  # split-pair layout: owner via modular pair arithmetic
+            from .id64 import pair_mod
+            owner = jnp.where(valid, pair_mod(ids, num_shards).astype(jnp.int32),
+                              num_shards)
+        else:
+            owner = jnp.where(valid, (ids % num_shards).astype(jnp.int32),
+                              num_shards)
+        # stable sort by owner so each bucket preserves input order
+        order = jnp.argsort(owner, stable=True)
+        sorted_owner = owner[order]
+        # index within the owner group = position - start of that owner's run
+        group_start = jnp.searchsorted(sorted_owner, sorted_owner, side="left")
+        idx_in_group = jnp.arange(n, dtype=jnp.int32) - group_start.astype(jnp.int32)
+        slot_sorted = idx_in_group
+        in_cap = (slot_sorted < capacity) & (sorted_owner < num_shards)
+        overflow = jnp.sum((~in_cap) & (sorted_owner < num_shards)).astype(jnp.int32)
+        # scatter (owner, slot) -> id; out-of-capacity and invalid entries drop
+        flat_pos = jnp.where(in_cap, sorted_owner * capacity + slot_sorted,
+                             num_shards * capacity)
+        lanes = ids.shape[1:]  # () single-lane, (2,) split-pair
+        bucket_ids = jnp.zeros((num_shards * capacity,) + lanes,
+                               ids.dtype).at[flat_pos].set(
+            ids[order], mode="drop").reshape((num_shards, capacity) + lanes)
+        bucket_valid = jnp.zeros((num_shards * capacity,), bool).at[flat_pos].set(
+            True, mode="drop").reshape(num_shards, capacity)
+        # per-input-element position (for unbucketing responses)
+        owner_out = jnp.zeros((n,), jnp.int32).at[order].set(sorted_owner)
+        slot_out = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.where(in_cap, slot_sorted, capacity))
+        return BucketResult(bucket_ids, bucket_valid, owner_out, slot_out, overflow)
 
 
 def unique_and_route(ids: jax.Array, valid: jax.Array, num_shards: int,
@@ -175,69 +180,70 @@ def unique_and_route(ids: jax.Array, valid: jax.Array, num_shards: int,
     assignment INDIRECTION of cold-tail re-sharding, `parallel/sharded.py`
     "COLD-TAIL RE-SHARDING"). A passed owner must be a pure function of the
     id (duplicates of one id must agree) and is still masked by `valid`."""
-    n = ids.shape[0]
-    S = num_shards
-    iota = jnp.arange(n, dtype=jnp.int32)
-    if ids.ndim == 2:  # split-pair layout
-        from .id64 import pair_mod
-        owner_in = (pair_mod(ids, S).astype(jnp.int32) if owner is None
-                    else owner.astype(jnp.int32))
-        owner_in = jnp.where(valid, owner_in, S)
-        so, s_hi, s_lo, order = jax.lax.sort(
-            (owner_in, ids[:, 0], ids[:, 1], iota), num_keys=3)
-        sorted_ids = jnp.stack([s_hi, s_lo], axis=-1)
-        id_change = (s_hi[1:] != s_hi[:-1]) | (s_lo[1:] != s_lo[:-1])
-    else:
-        owner_in = ((ids % S).astype(jnp.int32) if owner is None
-                    else owner.astype(jnp.int32))
-        owner_in = jnp.where(valid, owner_in, S)
-        so, sorted_ids, order = jax.lax.sort((owner_in, ids, iota), num_keys=2)
-        id_change = sorted_ids[1:] != sorted_ids[:-1]
-    is_new = jnp.concatenate(
-        [jnp.ones((1,), bool), (so[1:] != so[:-1]) | id_change])
-    seg = (jnp.cumsum(is_new) - 1).astype(jnp.int32)
-    num_unique = seg[-1] + 1
-    unique_ids = jnp.zeros(sorted_ids.shape, ids.dtype).at[seg].set(
-        sorted_ids, mode="drop", indices_are_sorted=True)
-    counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), seg, num_segments=n,
-                                 indices_are_sorted=True)
-    inverse = jnp.zeros((n,), jnp.int32).at[order].set(seg)
-    uniq = UniqueResult(unique_ids, inverse, counts.astype(jnp.int32),
-                        num_unique.astype(jnp.int32), order.astype(jnp.int32),
-                        seg)
+    with _trace.scope("exchange", "route"):
+        n = ids.shape[0]
+        S = num_shards
+        iota = jnp.arange(n, dtype=jnp.int32)
+        if ids.ndim == 2:  # split-pair layout
+            from .id64 import pair_mod
+            owner_in = (pair_mod(ids, S).astype(jnp.int32) if owner is None
+                        else owner.astype(jnp.int32))
+            owner_in = jnp.where(valid, owner_in, S)
+            so, s_hi, s_lo, order = jax.lax.sort(
+                (owner_in, ids[:, 0], ids[:, 1], iota), num_keys=3)
+            sorted_ids = jnp.stack([s_hi, s_lo], axis=-1)
+            id_change = (s_hi[1:] != s_hi[:-1]) | (s_lo[1:] != s_lo[:-1])
+        else:
+            owner_in = ((ids % S).astype(jnp.int32) if owner is None
+                        else owner.astype(jnp.int32))
+            owner_in = jnp.where(valid, owner_in, S)
+            so, sorted_ids, order = jax.lax.sort((owner_in, ids, iota), num_keys=2)
+            id_change = sorted_ids[1:] != sorted_ids[:-1]
+        is_new = jnp.concatenate(
+            [jnp.ones((1,), bool), (so[1:] != so[:-1]) | id_change])
+        seg = (jnp.cumsum(is_new) - 1).astype(jnp.int32)
+        num_unique = seg[-1] + 1
+        unique_ids = jnp.zeros(sorted_ids.shape, ids.dtype).at[seg].set(
+            sorted_ids, mode="drop", indices_are_sorted=True)
+        counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), seg, num_segments=n,
+                                     indices_are_sorted=True)
+        inverse = jnp.zeros((n,), jnp.int32).at[order].set(seg)
+        uniq = UniqueResult(unique_ids, inverse, counts.astype(jnp.int32),
+                            num_unique.astype(jnp.int32), order.astype(jnp.int32),
+                            seg)
 
-    # owner per UNIQUE slot: scatter the sorted owners through seg (padding
-    # slots >= num_unique keep the invalid pseudo-owner S)
-    u_owner = jnp.full((n,), S, jnp.int32).at[seg].set(
-        so, mode="drop", indices_are_sorted=True)
-    # bucket slot = unique rank within the owner group (seg is owner-major)
-    per_owner = jax.ops.segment_sum(is_new.astype(jnp.int32), so,
-                                    num_segments=S + 1)
-    start = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32),
-         jnp.cumsum(per_owner)[:-1].astype(jnp.int32)])
-    slot_u = jnp.where(u_owner < S,
-                       iota - start[jnp.clip(u_owner, 0, S - 1)], capacity)
-    in_cap = (u_owner < S) & (slot_u < capacity)
-    overflow = jnp.sum((u_owner < S) & (slot_u >= capacity)).astype(jnp.int32)
-    flat_pos = jnp.where(in_cap, u_owner * capacity + slot_u, S * capacity)
-    lanes = ids.shape[1:]
-    # empty bucket slots hold the EMPTY sentinel, NOT zero (id 0 is a real
-    # id): validity is then a pure function of the id payload, so the
-    # exchange ships ONE all_to_all of ids instead of ids + a bool mask
-    # (`bucket_validity`), and the mask scatter disappears
-    if ids.ndim == 2:
-        from .id64 import PAIR_EMPTY
-        empty = jnp.full((S * capacity,) + lanes, PAIR_EMPTY, ids.dtype)
-    else:
-        empty = jnp.full((S * capacity,) + lanes, -1, ids.dtype)
-    bucket_ids = empty.at[flat_pos].set(
-        unique_ids, mode="drop").reshape((S, capacity) + lanes)
-    bucket_valid = bucket_validity(bucket_ids)
-    slot_out = jnp.where(in_cap, slot_u, capacity)
-    buckets = BucketResult(bucket_ids, bucket_valid, u_owner, slot_out,
-                           overflow)
-    return uniq, buckets
+        # owner per UNIQUE slot: scatter the sorted owners through seg (padding
+        # slots >= num_unique keep the invalid pseudo-owner S)
+        u_owner = jnp.full((n,), S, jnp.int32).at[seg].set(
+            so, mode="drop", indices_are_sorted=True)
+        # bucket slot = unique rank within the owner group (seg is owner-major)
+        per_owner = jax.ops.segment_sum(is_new.astype(jnp.int32), so,
+                                        num_segments=S + 1)
+        start = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32),
+             jnp.cumsum(per_owner)[:-1].astype(jnp.int32)])
+        slot_u = jnp.where(u_owner < S,
+                           iota - start[jnp.clip(u_owner, 0, S - 1)], capacity)
+        in_cap = (u_owner < S) & (slot_u < capacity)
+        overflow = jnp.sum((u_owner < S) & (slot_u >= capacity)).astype(jnp.int32)
+        flat_pos = jnp.where(in_cap, u_owner * capacity + slot_u, S * capacity)
+        lanes = ids.shape[1:]
+        # empty bucket slots hold the EMPTY sentinel, NOT zero (id 0 is a real
+        # id): validity is then a pure function of the id payload, so the
+        # exchange ships ONE all_to_all of ids instead of ids + a bool mask
+        # (`bucket_validity`), and the mask scatter disappears
+        if ids.ndim == 2:
+            from .id64 import PAIR_EMPTY
+            empty = jnp.full((S * capacity,) + lanes, PAIR_EMPTY, ids.dtype)
+        else:
+            empty = jnp.full((S * capacity,) + lanes, -1, ids.dtype)
+        bucket_ids = empty.at[flat_pos].set(
+            unique_ids, mode="drop").reshape((S, capacity) + lanes)
+        bucket_valid = bucket_validity(bucket_ids)
+        slot_out = jnp.where(in_cap, slot_u, capacity)
+        buckets = BucketResult(bucket_ids, bucket_valid, u_owner, slot_out,
+                               overflow)
+        return uniq, buckets
 
 
 # ---------------------------------------------------------------------------
@@ -375,10 +381,11 @@ def compact_member_slots(member: jax.Array, pcap: int):
 def unbucket(bucket_rows: jax.Array, owner: jax.Array, slot: jax.Array) -> jax.Array:
     """Inverse of bucket_by_owner for per-id payloads: read back each input element's
     row from its (owner, slot) position. bucket_rows: (num_shards, capacity, ...)."""
-    num_shards, capacity = bucket_rows.shape[:2]
-    flat = bucket_rows.reshape((num_shards * capacity,) + bucket_rows.shape[2:])
-    pos = jnp.clip(owner * capacity + slot, 0, num_shards * capacity - 1)
-    oob = (owner >= num_shards) | (slot >= capacity)
-    out = flat[pos]
-    return jnp.where(oob.reshape((-1,) + (1,) * (out.ndim - 1)),
-                     jnp.zeros_like(out), out)
+    with _trace.scope("exchange", "reassemble"):
+        num_shards, capacity = bucket_rows.shape[:2]
+        flat = bucket_rows.reshape((num_shards * capacity,) + bucket_rows.shape[2:])
+        pos = jnp.clip(owner * capacity + slot, 0, num_shards * capacity - 1)
+        oob = (owner >= num_shards) | (slot >= capacity)
+        out = flat[pos]
+        return jnp.where(oob.reshape((-1,) + (1,) * (out.ndim - 1)),
+                         jnp.zeros_like(out), out)

@@ -18,6 +18,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..utils import trace as _trace
 from .dedup import unique_with_counts
 
 
@@ -30,6 +31,13 @@ def lookup_rows(weights: jax.Array, rows: jax.Array,
 
     `sorted_unique`: caller guarantees `rows` is ascending with no in-range
     duplicates (the dedup output) — lets XLA use the vectorized gather path."""
+    with _trace.scope("sparse", "pull"):
+        return _gather_rows(weights, rows, valid, sorted_unique=sorted_unique)
+
+
+def _gather_rows(weights, rows, valid=None, *, sorted_unique=False):
+    """`lookup_rows` without its stage name: the fused applies read the rows
+    they update through this, so that read counts under `sparse.apply`."""
     if weights.ndim == 2 and rows.ndim == 1:
         from .pallas_sparse import maybe_gather_rows
         out = maybe_gather_rows(weights, rows, valid)
@@ -129,20 +137,22 @@ def packed_layout(dim: int, slots: Dict[str, jax.Array],
 def pack_table(weights: jax.Array, slots: Dict[str, jax.Array],
                layout) -> jax.Array:
     """-> (rows, dim+Σwidths) f32; column order: weights, then layout order."""
-    return jnp.concatenate(
-        [weights.astype(jnp.float32)] + [slots[name] for name, _ in layout],
-        axis=1)
+    with _trace.scope("sparse", "pack"):
+        return jnp.concatenate(
+            [weights.astype(jnp.float32)] + [slots[name] for name, _ in layout],
+            axis=1)
 
 
 def unpack_table(packed: jax.Array, layout, dim: int, weights_dtype
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    weights = packed[:, :dim].astype(weights_dtype)
-    slots = {}
-    off = dim
-    for name, w in layout:
-        slots[name] = packed[:, off:off + w]
-        off += w
-    return weights, slots
+    with _trace.scope("sparse", "unpack"):
+        weights = packed[:, :dim].astype(weights_dtype)
+        slots = {}
+        off = dim
+        for name, w in layout:
+            slots[name] = packed[:, off:off + w]
+            off += w
+        return weights, slots
 
 
 def _dedup_routed(n_rows: int, row_ids: jax.Array, grads: jax.Array,
@@ -165,11 +175,14 @@ def _dedup_routed(n_rows: int, row_ids: jax.Array, grads: jax.Array,
         pre_counts = jnp.ones((n,), jnp.int32)
     uniq = unique_with_counts(jnp.where((pre_counts > 0) & (row_ids >= 0),
                                         row_ids, n_rows))
-    g = uniq.segment_reduce(grads)
-    counts = uniq.segment_reduce(pre_counts)
-    counts = jnp.where(uniq.unique_ids < n_rows, counts, 0)
-    idx = jnp.where(counts > 0, uniq.unique_ids,
-                    n_rows + jnp.arange(n, dtype=uniq.unique_ids.dtype))
+    # the sums over duplicates get a name of their own: on the owner side of
+    # the exchange they run over S times the positions
+    with _trace.scope("sparse", "reduce"):
+        g = uniq.segment_reduce(grads)
+        counts = uniq.segment_reduce(pre_counts)
+        counts = jnp.where(uniq.unique_ids < n_rows, counts, 0)
+        idx = jnp.where(counts > 0, uniq.unique_ids,
+                        n_rows + jnp.arange(n, dtype=uniq.unique_ids.dtype))
     return g, counts, idx
 
 
@@ -184,19 +197,20 @@ def sparse_apply_packed_table(
 ) -> jax.Array:
     """`sparse_apply_dense_table` over the packed layout: identical dedup and
     optimizer math, ONE gather + ONE scatter instead of one pair per array."""
-    g, counts, idx = _dedup_routed(packed.shape[0], row_ids, grads, pre_counts)
-    rows = lookup_rows(packed, idx, sorted_unique=True)  # (n, W) f32
-    s_rows = {}
-    off = dim
-    for name, w in layout:
-        s_rows[name] = rows[:, off:off + w]
-        off += w
-    new_w, new_s = optimizer.apply(rows[:, :dim], s_rows,
-                                   g.astype(jnp.float32), counts)
-    new_rows = jnp.concatenate(
-        [new_w] + [new_s[name] for name, _ in layout], axis=1)
-    return scatter_rows(packed, idx, new_rows.astype(packed.dtype),
-                        sorted_unique=True)
+    with _trace.scope("sparse", "apply"):
+        g, counts, idx = _dedup_routed(packed.shape[0], row_ids, grads, pre_counts)
+        rows = _gather_rows(packed, idx, sorted_unique=True)  # (n, W) f32
+        s_rows = {}
+        off = dim
+        for name, w in layout:
+            s_rows[name] = rows[:, off:off + w]
+            off += w
+        new_w, new_s = optimizer.apply(rows[:, :dim], s_rows,
+                                       g.astype(jnp.float32), counts)
+        new_rows = jnp.concatenate(
+            [new_w] + [new_s[name] for name, _ in layout], axis=1)
+        return scatter_rows(packed, idx, new_rows.astype(packed.dtype),
+                            sorted_unique=True)
 
 
 def sparse_apply_dense_table(
@@ -217,26 +231,27 @@ def sparse_apply_dense_table(
     dedup -> sum gradients/counts over duplicates -> gather rows+slots -> fused
     optimizer apply -> scatter back. Rows not touched stay bit-identical.
     """
-    g, counts, idx = _dedup_routed(weights.shape[0], row_ids, grads, pre_counts)
+    with _trace.scope("sparse", "apply"):
+        g, counts, idx = _dedup_routed(weights.shape[0], row_ids, grads, pre_counts)
 
-    from .pallas_sparse import maybe_fused_apply
-    fused = maybe_fused_apply(optimizer, weights, slots, idx, g, counts)
-    if fused is not None:
-        return fused
+        from .pallas_sparse import maybe_fused_apply
+        fused = maybe_fused_apply(optimizer, weights, slots, idx, g, counts)
+        if fused is not None:
+            return fused
 
-    # Optimizer math always runs in float32, whatever the table dtype: in bf16,
-    # beta_2^t rounds to 1.0 (killing Adam's lr_t) and g^2 accumulators lose most of
-    # their mantissa. Slots are stored f32 (`SparseOptimizer.init_slots`); weights are
-    # upcast for the update and cast back on scatter (TPU-idiomatic mixed precision).
-    w_rows = lookup_rows(weights, idx, sorted_unique=True).astype(jnp.float32)
-    s_rows = {k: lookup_rows(v, idx, sorted_unique=True)
-              for k, v in slots.items()}
-    new_w, new_s = optimizer.apply(w_rows, s_rows, g.astype(jnp.float32), counts)
-    # idx is fully routed (invalid -> distinct OOB rows): valid=None
-    weights = scatter_rows(weights, idx, new_w.astype(weights.dtype),
-                           sorted_unique=True)
-    slots = {k: scatter_rows(slots[k], idx,
-                             new_s[k].astype(slots[k].dtype),
-                             sorted_unique=True)
-             for k in slots}
-    return weights, slots
+        # Optimizer math always runs in float32, whatever the table dtype: in bf16,
+        # beta_2^t rounds to 1.0 (killing Adam's lr_t) and g^2 accumulators lose most of
+        # their mantissa. Slots are stored f32 (`SparseOptimizer.init_slots`); weights are
+        # upcast for the update and cast back on scatter (TPU-idiomatic mixed precision).
+        w_rows = _gather_rows(weights, idx, sorted_unique=True).astype(jnp.float32)
+        s_rows = {k: _gather_rows(v, idx, sorted_unique=True)
+                  for k, v in slots.items()}
+        new_w, new_s = optimizer.apply(w_rows, s_rows, g.astype(jnp.float32), counts)
+        # idx is fully routed (invalid -> distinct OOB rows): valid=None
+        weights = scatter_rows(weights, idx, new_w.astype(weights.dtype),
+                               sorted_unique=True)
+        slots = {k: scatter_rows(slots[k], idx,
+                                 new_s[k].astype(slots[k].dtype),
+                                 sorted_unique=True)
+                 for k in slots}
+        return weights, slots

@@ -51,6 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import trace as _trace
+
 WIRE_ENV = "OETPU_WIRE"
 DEFAULT_WIRE = "bf16"
 FORMATS = ("fp32", "bf16", "int8")
@@ -195,24 +197,26 @@ def pack_inband(rows: jax.Array, fmt: str, *,
     any scales packed in-band. Static shapes in (d, fmt): switching the wire
     format never re-jits a fixed-format program. `stochastic` selects
     hash-dithered stochastic rounding (int8 only; fp32/bf16 ignore it)."""
-    if fmt == "fp32":
-        return rows
-    if fmt == "bf16":
-        # uint16 carrier — see wire_carrier_dtype for why not bf16 itself
-        return jax.lax.bitcast_convert_type(
-            rows.astype(jnp.bfloat16), jnp.uint16)
-    return _quantize_int8(rows.astype(jnp.float32), stochastic=stochastic)
+    with _trace.scope("exchange", "wire"):
+        if fmt == "fp32":
+            return rows
+        if fmt == "bf16":
+            # uint16 carrier — see wire_carrier_dtype for why not bf16 itself
+            return jax.lax.bitcast_convert_type(
+                rows.astype(jnp.bfloat16), jnp.uint16)
+        return _quantize_int8(rows.astype(jnp.float32), stochastic=stochastic)
 
 
 def unpack_inband(wire: jax.Array, dim: int, fmt: str) -> jax.Array:
     """Inverse of pack_inband -> (n, d) float32 (callers cast to their
     compute/table dtype — exact for bf16-kept tables)."""
-    if fmt == "int8":
-        return _dequantize_int8(wire, dim)
-    if fmt == "bf16":
-        return jax.lax.bitcast_convert_type(
-            wire, jnp.bfloat16).astype(jnp.float32)
-    return wire.astype(jnp.float32)
+    with _trace.scope("exchange", "wire"):
+        if fmt == "int8":
+            return _dequantize_int8(wire, dim)
+        if fmt == "bf16":
+            return jax.lax.bitcast_convert_type(
+                wire, jnp.bfloat16).astype(jnp.float32)
+        return wire.astype(jnp.float32)
 
 
 def encode_rows(rows: jax.Array, fmt: str) -> jax.Array:
@@ -330,16 +334,18 @@ def encode_grads(grads: jax.Array, counts: jax.Array, fmt: str, *,
     """(n, d) float grads + (n,) int32 counts -> (n, grads_wire_width) wire
     rows. Counts ride bit-exact; grads quantize like rows (`stochastic`
     selects the int8 hash-dither rounding the training push uses)."""
-    g = pack_inband(grads.astype(jnp.float32) if fmt != "bf16" else grads,
-                    fmt, stochastic=stochastic)
-    return jnp.concatenate([g, counts_to_lanes(counts, fmt)], axis=1)
+    with _trace.scope("exchange", "wire"):
+        g = pack_inband(grads.astype(jnp.float32) if fmt != "bf16" else grads,
+                        fmt, stochastic=stochastic)
+        return jnp.concatenate([g, counts_to_lanes(counts, fmt)], axis=1)
 
 
 def decode_grads(wire: jax.Array, dim: int, fmt: str):
     """-> ((n, d) float32 grads, (n,) int32 counts)."""
-    body = rows_wire_width(dim, fmt)
-    return unpack_inband(wire[:, :body], dim, fmt), lanes_to_counts(
-        wire[:, body:])
+    with _trace.scope("exchange", "wire"):
+        body = rows_wire_width(dim, fmt)
+        return unpack_inband(wire[:, :body], dim, fmt), lanes_to_counts(
+            wire[:, body:])
 
 
 # ---------------------------------------------------------------------------

@@ -137,6 +137,7 @@ from ..ops.dedup import (BucketResult, UniqueResult, bucket_by_owner,
                          bucket_validity, carry_to_unique, unbucket,
                          unique_and_route, unique_with_counts)
 from ..ops.sparse import lookup_rows, sparse_apply_dense_table
+from ..utils import trace as _trace
 from .mesh import DATA_AXIS
 
 # probe budget of the hot-set membership table (C = 2H slots -> load factor
@@ -169,6 +170,14 @@ class ExchangePlan(NamedTuple):
     # the wire — that `grouped_conflict_patch` replays so the patched rows
     # AND the post-patch residuals are bit-identical to the serial schedule
     ef_stash: Optional[jax.Array] = None
+
+
+def _a2a(what: str, x: jax.Array, axis) -> jax.Array:
+    """The exchange's `all_to_all` under its own stage name
+    (`exchange.a2a_ids` / `a2a_rows` / `a2a_grads`), so a device profile
+    tells the three wires apart."""
+    with _trace.scope("exchange", "a2a_" + what):
+        return jax.lax.all_to_all(x, axis, 0, 0)
 
 
 def _bucket_capacity(n: int, num_shards: int, capacity_factor: float) -> int:
@@ -298,41 +307,42 @@ def make_plan(spec: EmbeddingSpec, ids: jax.Array, *, axis: str = DATA_AXIS,
     their per-unique-slot cache rows in `hot_slot`. `mig`: the table's
     migration directory — cold positions route to their ASSIGNED owner
     instead of the `id % S` home (module doc "COLD-TAIL RE-SHARDING")."""
-    S = jax.lax.axis_size(axis)
-    flat = flatten_ids(spec, ids)
-    n = flat.shape[0]
-    if S == 1:
-        if hot is not None:
-            raise ValueError(
-                "hot-row replication needs S >= 2: on a 1-device mesh the "
-                "shard and the cache are the same memory, and two copies of "
-                "a row can only diverge (MeshTrainer disables hot_rows at "
-                "mesh size 1)")
-        if mig is not None:
-            raise ValueError(
-                "cold-tail re-sharding needs S >= 2: on a 1-device mesh "
-                "there is nowhere to migrate a row to (MeshTrainer disables "
-                "mig_rows at mesh size 1)")
-        uniq = unique_with_counts(flat)
-        valid = (uniq.counts > 0) & _id_valid(spec, uniq.unique_ids)
-        recv_ids = uniq.unique_ids[None]
-        recv_valid = valid[None]
-        buckets = BucketResult(
-            bucket_ids=recv_ids, bucket_valid=recv_valid,
-            owner=jnp.zeros((n,), jnp.int32),
-            slot=jnp.arange(n, dtype=jnp.int32),
-            overflow=jnp.zeros((), jnp.int32))
-        return ExchangePlan(uniq, buckets, recv_ids, recv_valid, n)
-    uniq, buckets, cap, hot_slot, moved = _client_route(spec, flat, S,
-                                                        capacity_factor, hot,
-                                                        mig)
-    # [BOUNDARY: was one RPC per owning server; now ONE ICI all_to_all —
-    # empty bucket slots carry the EMPTY sentinel, so the receive side
-    # derives validity from the ids and no bool mask rides the wire]
-    recv_ids = jax.lax.all_to_all(buckets.bucket_ids, axis, 0, 0)
-    recv_valid = bucket_validity(recv_ids)
-    return ExchangePlan(uniq, buckets, recv_ids, recv_valid, cap, hot_slot,
-                        0 if hot is None else hot.weights.shape[0], moved)
+    with _trace.scope("exchange", "route"):
+        S = jax.lax.axis_size(axis)
+        flat = flatten_ids(spec, ids)
+        n = flat.shape[0]
+        if S == 1:
+            if hot is not None:
+                raise ValueError(
+                    "hot-row replication needs S >= 2: on a 1-device mesh the "
+                    "shard and the cache are the same memory, and two copies of "
+                    "a row can only diverge (MeshTrainer disables hot_rows at "
+                    "mesh size 1)")
+            if mig is not None:
+                raise ValueError(
+                    "cold-tail re-sharding needs S >= 2: on a 1-device mesh "
+                    "there is nowhere to migrate a row to (MeshTrainer disables "
+                    "mig_rows at mesh size 1)")
+            uniq = unique_with_counts(flat)
+            valid = (uniq.counts > 0) & _id_valid(spec, uniq.unique_ids)
+            recv_ids = uniq.unique_ids[None]
+            recv_valid = valid[None]
+            buckets = BucketResult(
+                bucket_ids=recv_ids, bucket_valid=recv_valid,
+                owner=jnp.zeros((n,), jnp.int32),
+                slot=jnp.arange(n, dtype=jnp.int32),
+                overflow=jnp.zeros((), jnp.int32))
+            return ExchangePlan(uniq, buckets, recv_ids, recv_valid, n)
+        uniq, buckets, cap, hot_slot, moved = _client_route(spec, flat, S,
+                                                            capacity_factor, hot,
+                                                            mig)
+        # [BOUNDARY: was one RPC per owning server; now ONE ICI all_to_all —
+        # empty bucket slots carry the EMPTY sentinel, so the receive side
+        # derives validity from the ids and no bool mask rides the wire]
+        recv_ids = _a2a("ids", buckets.bucket_ids, axis)
+        recv_valid = bucket_validity(recv_ids)
+        return ExchangePlan(uniq, buckets, recv_ids, recv_valid, cap, hot_slot,
+                            0 if hot is None else hot.weights.shape[0], moved)
 
 
 def _client_route(spec: EmbeddingSpec, flat: jax.Array, S: int,
@@ -341,32 +351,33 @@ def _client_route(spec: EmbeddingSpec, flat: jax.Array, S: int,
     """Per-table client-side dedup + owner routing: the plan minus its id
     exchange (shared by `make_plan` and the grouped fused exchange).
     -> (uniq, buckets, cap, hot_slot-or-None, mig_moved-or-None)."""
-    n = flat.shape[0]
-    valid = _id_valid(spec, flat)
-    cap = _bucket_capacity(n, S, capacity_factor)
-    if hot is None and mig is None:
-        uniq, buckets = unique_and_route(flat, valid, S, cap)
-        return uniq, buckets, cap, None, None
-    # owner-assignment indirection (None keeps the plain `id % S` routing so
-    # the mig-off program stays byte-identical to the pre-feature trace)
-    owner = moved = None
-    if mig is not None:
-        owner, moved = _route_owner(mig, flat, valid, S)
-    if hot is None:
-        uniq, buckets = unique_and_route(flat, valid, S, cap, owner=owner)
-        return uniq, buckets, cap, None, \
-            carry_to_unique(uniq, moved.astype(jnp.int32), 0)
-    H = hot.weights.shape[0]
-    hr = _hot_probe(hot, flat, valid)
-    # hot positions leave the exchange entirely: they route like invalid ids
-    # (pseudo-owner S — no bucket slot, no wire bytes, no owner-shard load)
-    # but keep their unique slots/counts for the local gather + reduced push
-    uniq, buckets = unique_and_route(flat, valid & (hr >= H), S, cap,
-                                     owner=owner)
-    hot_slot = carry_to_unique(uniq, hr, H)
-    mig_moved = None if moved is None else \
-        carry_to_unique(uniq, (moved & (hr >= H)).astype(jnp.int32), 0)
-    return uniq, buckets, cap, hot_slot, mig_moved
+    with _trace.scope("exchange", "route"):
+        n = flat.shape[0]
+        valid = _id_valid(spec, flat)
+        cap = _bucket_capacity(n, S, capacity_factor)
+        if hot is None and mig is None:
+            uniq, buckets = unique_and_route(flat, valid, S, cap)
+            return uniq, buckets, cap, None, None
+        # owner-assignment indirection (None keeps the plain `id % S` routing so
+        # the mig-off program stays byte-identical to the pre-feature trace)
+        owner = moved = None
+        if mig is not None:
+            owner, moved = _route_owner(mig, flat, valid, S)
+        if hot is None:
+            uniq, buckets = unique_and_route(flat, valid, S, cap, owner=owner)
+            return uniq, buckets, cap, None, \
+                carry_to_unique(uniq, moved.astype(jnp.int32), 0)
+        H = hot.weights.shape[0]
+        hr = _hot_probe(hot, flat, valid)
+        # hot positions leave the exchange entirely: they route like invalid ids
+        # (pseudo-owner S — no bucket slot, no wire bytes, no owner-shard load)
+        # but keep their unique slots/counts for the local gather + reduced push
+        uniq, buckets = unique_and_route(flat, valid & (hr >= H), S, cap,
+                                         owner=owner)
+        hot_slot = carry_to_unique(uniq, hr, H)
+        mig_moved = None if moved is None else \
+            carry_to_unique(uniq, (moved & (hr >= H)).astype(jnp.int32), 0)
+        return uniq, buckets, cap, hot_slot, mig_moved
 
 
 def grouped_make_plans(specs, ids_list, *, axis: str = DATA_AXIS,
@@ -381,29 +392,30 @@ def grouped_make_plans(specs, ids_list, *, axis: str = DATA_AXIS,
     Optional[HotRows] per table (hot ids skip the fused wire exactly like the
     per-table path). `migs`: one Optional[MigRows] per table (the owner-
     assignment indirection rides each table's own route)."""
-    S = jax.lax.axis_size(axis)
-    if hots is None:
-        hots = [None] * len(specs)
-    if migs is None:
-        migs = [None] * len(specs)
-    if S == 1:
-        return [make_plan(spec, ids, axis=axis,
-                          capacity_factor=capacity_factor, hot=hot, mig=mig)
-                for spec, ids, hot, mig in zip(specs, ids_list, hots, migs)]
-    from ..ops.dedup import concat_owner_buckets, split_owner_buckets
-    parts = []
-    for spec, ids, hot, mig in zip(specs, ids_list, hots, migs):
-        flat = flatten_ids(spec, ids)
-        parts.append(_client_route(spec, flat, S, capacity_factor, hot, mig))
-    wire_ids = concat_owner_buckets([b.bucket_ids for _, b, _, _, _ in parts])
-    recv = jax.lax.all_to_all(wire_ids, axis, 0, 0)
-    templates = [(cap, b.bucket_ids.ndim == 3, b.bucket_ids.dtype)
-                 for _, b, cap, _, _ in parts]
-    segs = split_owner_buckets(recv, templates)
-    return [ExchangePlan(uniq, buckets, seg, bucket_validity(seg), cap, hs,
-                         0 if hot is None else hot.weights.shape[0], mv)
-            for (uniq, buckets, cap, hs, mv), seg, hot
-            in zip(parts, segs, hots)]
+    with _trace.scope("exchange", "route"):
+        S = jax.lax.axis_size(axis)
+        if hots is None:
+            hots = [None] * len(specs)
+        if migs is None:
+            migs = [None] * len(specs)
+        if S == 1:
+            return [make_plan(spec, ids, axis=axis,
+                              capacity_factor=capacity_factor, hot=hot, mig=mig)
+                    for spec, ids, hot, mig in zip(specs, ids_list, hots, migs)]
+        from ..ops.dedup import concat_owner_buckets, split_owner_buckets
+        parts = []
+        for spec, ids, hot, mig in zip(specs, ids_list, hots, migs):
+            flat = flatten_ids(spec, ids)
+            parts.append(_client_route(spec, flat, S, capacity_factor, hot, mig))
+        wire_ids = concat_owner_buckets([b.bucket_ids for _, b, _, _, _ in parts])
+        recv = _a2a("ids", wire_ids, axis)
+        templates = [(cap, b.bucket_ids.ndim == 3, b.bucket_ids.dtype)
+                     for _, b, cap, _, _ in parts]
+        segs = split_owner_buckets(recv, templates)
+        return [ExchangePlan(uniq, buckets, seg, bucket_validity(seg), cap, hs,
+                             0 if hot is None else hot.weights.shape[0], mv)
+                for (uniq, buckets, cap, hs, mv), seg, hot
+                in zip(parts, segs, hots)]
 
 
 def _flat_axis_index(axis) -> jax.Array:
@@ -441,22 +453,23 @@ def exchange_load_stats(plan: ExchangePlan, *, axis: str = DATA_AXIS
     `metrics.record_step_stats` folds these into labeled gauges
     (`exchange.shard_rows{table=,shard=}`) and the derived
     `exchange.shard_imbalance{table=}` histogram."""
-    S = jax.lax.axis_size(axis)
-    routed = jnp.sum(plan.buckets.bucket_valid, axis=1).astype(jnp.int32)
-    # duplicate-weighted positions per destination: sum each unique slot's
-    # count into its owner segment. `buckets.owner` is ASCENDING (the
-    # owner-major sort in `unique_and_route`; zeros at S == 1), so this is
-    # the vectorized sorted-segment path — an unsorted scatter-add
-    # serializes (the ops/dedup.py lesson). Invalid/padding slots carry
-    # owner == S at S > 1 and count 0 at S == 1 — either way they drop out.
-    w = jnp.where(plan.uniq.counts > 0, plan.uniq.counts, 0).astype(jnp.int32)
-    positions = jax.ops.segment_sum(
-        w, plan.buckets.owner, num_segments=S + 1,
-        indices_are_sorted=True)[:S].astype(jnp.int32)
-    occ = routed.max().astype(jnp.float32) / float(max(plan.cap, 1))
-    fill = jnp.zeros((S,), jnp.float32).at[_flat_axis_index(axis)].set(occ)
-    return {"shard_rows": routed, "shard_positions": positions,
-            "bucket_fill": fill}
+    with _trace.scope("exchange", "stats"):
+        S = jax.lax.axis_size(axis)
+        routed = jnp.sum(plan.buckets.bucket_valid, axis=1).astype(jnp.int32)
+        # duplicate-weighted positions per destination: sum each unique slot's
+        # count into its owner segment. `buckets.owner` is ASCENDING (the
+        # owner-major sort in `unique_and_route`; zeros at S == 1), so this is
+        # the vectorized sorted-segment path — an unsorted scatter-add
+        # serializes (the ops/dedup.py lesson). Invalid/padding slots carry
+        # owner == S at S > 1 and count 0 at S == 1 — either way they drop out.
+        w = jnp.where(plan.uniq.counts > 0, plan.uniq.counts, 0).astype(jnp.int32)
+        positions = jax.ops.segment_sum(
+            w, plan.buckets.owner, num_segments=S + 1,
+            indices_are_sorted=True)[:S].astype(jnp.int32)
+        occ = routed.max().astype(jnp.float32) / float(max(plan.cap, 1))
+        fill = jnp.zeros((S,), jnp.float32).at[_flat_axis_index(axis)].set(occ)
+        return {"shard_rows": routed, "shard_positions": positions,
+                "bucket_fill": fill}
 
 
 def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
@@ -483,86 +496,87 @@ def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
     PRE-serve residual gathered per recv slot ((S, cap, dim) f32; None when
     no EF ran) — `grouped_conflict_patch` replays it against the post-apply
     weights to reproduce exactly what a serial serve would have shipped."""
-    S = jax.lax.axis_size(axis)
-    pair = plan.recv_ids.ndim == 3  # (S, cap, 2) split-pair buckets
-    flat_recv = (plan.recv_ids.reshape(-1, 2) if pair
-                 else plan.recv_ids.reshape(-1))
-    flat_valid = plan.recv_valid.reshape(-1)
-    need_ef = train and fmt != "fp32" and state.ef is not None
-    ef_idx = None
-    mig = state.mig
-    m_found = None
-    if mig is not None:
-        m_found, m_rank, _ = _mig_find(mig, flat_recv, flat_valid)
-        main_valid = flat_valid & ~m_found
-    else:
-        main_valid = flat_valid
-    if spec.use_hash_table:
-        if pair:
-            from ..ops.id64 import PAIR_EMPTY
-            probe = jnp.where(main_valid[:, None], flat_recv, PAIR_EMPTY)
+    with _trace.scope("exchange", "owner_serve"):
+        S = jax.lax.axis_size(axis)
+        pair = plan.recv_ids.ndim == 3  # (S, cap, 2) split-pair buckets
+        flat_recv = (plan.recv_ids.reshape(-1, 2) if pair
+                     else plan.recv_ids.reshape(-1))
+        flat_valid = plan.recv_valid.reshape(-1)
+        need_ef = train and fmt != "fp32" and state.ef is not None
+        ef_idx = None
+        mig = state.mig
+        m_found = None
+        if mig is not None:
+            m_found, m_rank, _ = _mig_find(mig, flat_recv, flat_valid)
+            main_valid = flat_valid & ~m_found
         else:
-            probe = jnp.where(main_valid, flat_recv, -1)
-        if train:
-            from ..tables.hash_table import hash_lookup_train
-            old_overflow = state.overflow
-            state, rows = hash_lookup_train(state, probe,
-                                            out_dim=spec.output_dim)
-            # overflow is replicated table-level state: psum the per-shard increment
-            delta = jax.lax.psum(state.overflow - old_overflow, axis)
-            state = state.replace(overflow=old_overflow + delta)
+            main_valid = flat_valid
+        if spec.use_hash_table:
+            if pair:
+                from ..ops.id64 import PAIR_EMPTY
+                probe = jnp.where(main_valid[:, None], flat_recv, PAIR_EMPTY)
+            else:
+                probe = jnp.where(main_valid, flat_recv, -1)
+            if train:
+                from ..tables.hash_table import hash_lookup_train
+                old_overflow = state.overflow
+                state, rows = hash_lookup_train(state, probe,
+                                                out_dim=spec.output_dim)
+                # overflow is replicated table-level state: psum the per-shard increment
+                delta = jax.lax.psum(state.overflow - old_overflow, axis)
+                state = state.replace(overflow=old_overflow + delta)
+                if need_ef:
+                    # post-insert probe: the residual lives at the row's slot
+                    # (invalid/annex positions probe EMPTY -> miss -> OOB index)
+                    from ..tables.hash_table import hash_find
+                    capacity = state.keys.shape[0]
+                    slot = hash_find(state.keys, probe)
+                    ef_idx = jnp.where(slot < capacity, slot, capacity)
+            else:
+                from ..tables.hash_table import hash_lookup
+                rows = hash_lookup(state, probe)
+        else:
+            local_rows = jnp.where(main_valid, flat_recv // S, -1)
+            rows = lookup_rows(state.weights, local_rows)
+            if rows.shape[1] != spec.output_dim:
+                # packed weights+slots layout inside train_many's scan
+                # (`ops/sparse.packed_layout`): slice the weight columns out of
+                # the gathered packed rows — the gather is latency-bound, the
+                # slot bytes ride free
+                rows = rows[:, :spec.output_dim]
             if need_ef:
-                # post-insert probe: the residual lives at the row's slot
-                # (invalid/annex positions probe EMPTY -> miss -> OOB index)
-                from ..tables.hash_table import hash_find
-                capacity = state.keys.shape[0]
-                slot = hash_find(state.keys, probe)
-                ef_idx = jnp.where(slot < capacity, slot, capacity)
-        else:
-            from ..tables.hash_table import hash_lookup
-            rows = hash_lookup(state, probe)
-    else:
-        local_rows = jnp.where(main_valid, flat_recv // S, -1)
-        rows = lookup_rows(state.weights, local_rows)
-        if rows.shape[1] != spec.output_dim:
-            # packed weights+slots layout inside train_many's scan
-            # (`ops/sparse.packed_layout`): slice the weight columns out of
-            # the gathered packed rows — the gather is latency-bound, the
-            # slot bytes ride free
-            rows = rows[:, :spec.output_dim]
+                ef_idx = jnp.where(main_valid, flat_recv // S,
+                                   state.ef.shape[0]).astype(jnp.int32)
+        if m_found is not None:
+            M = mig.weights.shape[0]
+            arows = lookup_rows(mig.weights, jnp.where(m_found, m_rank, M))
+            rows = jnp.where(m_found[:, None], arows.astype(rows.dtype), rows)
+        stash = None
+        if fmt == "fp32":
+            if return_stash:
+                return state, rows.reshape(S, plan.cap, spec.output_dim), None
+            return state, rows.reshape(S, plan.cap, spec.output_dim)
+        # owner-edge encode: the pull a2a operand is already int8/bf16
+        from ..ops import wire as wire_mod
+        x = rows.astype(jnp.float32)
         if need_ef:
-            ef_idx = jnp.where(main_valid, flat_recv // S,
-                               state.ef.shape[0]).astype(jnp.int32)
-    if m_found is not None:
-        M = mig.weights.shape[0]
-        arows = lookup_rows(mig.weights, jnp.where(m_found, m_rank, M))
-        rows = jnp.where(m_found[:, None], arows.astype(rows.dtype), rows)
-    stash = None
-    if fmt == "fp32":
+            # invalid/annex slots index OOB: the gather fills 0, the scatter
+            # drops. Duplicate recv slots (one id requested by several sources)
+            # gather the same w+ef and write the same residual — deterministic.
+            ef_prev = state.ef.at[ef_idx].get(mode="fill", fill_value=0) \
+                .astype(jnp.float32)
+            x = x + ef_prev
+            enc = wire_mod.pack_inband(x, fmt)
+            ef_new = x - wire_mod.unpack_inband(enc, spec.output_dim, fmt)
+            state = state.replace(ef=state.ef.at[ef_idx].set(
+                ef_new.astype(state.ef.dtype), mode="drop"))
+            if return_stash:
+                stash = ef_prev.reshape(S, plan.cap, spec.output_dim)
+        else:
+            enc = wire_mod.pack_inband(x, fmt)
         if return_stash:
-            return state, rows.reshape(S, plan.cap, spec.output_dim), None
-        return state, rows.reshape(S, plan.cap, spec.output_dim)
-    # owner-edge encode: the pull a2a operand is already int8/bf16
-    from ..ops import wire as wire_mod
-    x = rows.astype(jnp.float32)
-    if need_ef:
-        # invalid/annex slots index OOB: the gather fills 0, the scatter
-        # drops. Duplicate recv slots (one id requested by several sources)
-        # gather the same w+ef and write the same residual — deterministic.
-        ef_prev = state.ef.at[ef_idx].get(mode="fill", fill_value=0) \
-            .astype(jnp.float32)
-        x = x + ef_prev
-        enc = wire_mod.pack_inband(x, fmt)
-        ef_new = x - wire_mod.unpack_inband(enc, spec.output_dim, fmt)
-        state = state.replace(ef=state.ef.at[ef_idx].set(
-            ef_new.astype(state.ef.dtype), mode="drop"))
-        if return_stash:
-            stash = ef_prev.reshape(S, plan.cap, spec.output_dim)
-    else:
-        enc = wire_mod.pack_inband(x, fmt)
-    if return_stash:
-        return state, enc.reshape(S, plan.cap, -1), stash
-    return state, enc.reshape(S, plan.cap, -1)
+            return state, enc.reshape(S, plan.cap, -1), stash
+        return state, enc.reshape(S, plan.cap, -1)
 
 
 def _merge_hot_rows(plan: ExchangePlan, uniq_rows: jax.Array,
@@ -585,19 +599,20 @@ def _hot_pull_stats(spec: EmbeddingSpec, plan: ExchangePlan, flat: jax.Array,
     skipped the wire), and `hot_bytes_saved` — unique rows x the static
     per-row wire cost (id lanes + pulled row + pushed grad+counts) the 3-a2a
     round trip would have charged for them."""
-    from ..ops import wire as wire_mod
-    H = plan.hot_rows
-    hm = (plan.hot_slot < H) & (plan.uniq.counts > 0)
-    hot_unique = jnp.sum(hm).astype(jnp.int32)
-    hot_hits = jnp.sum(jnp.where(hm, plan.uniq.counts, 0)).astype(jnp.int32)
-    w = jnp.dtype(wire_mod.wire_dtype(fmt)).itemsize
-    pair = flat.ndim == 2
-    per_row = (wire_mod.id_wire_itemsize(pair, jnp.dtype(flat.dtype).itemsize)
-               + wire_mod.rows_wire_width(spec.output_dim, fmt) * w
-               + wire_mod.grads_wire_width(spec.output_dim, fmt) * w)
-    return {"hot_unique": hot_unique, "hot_hits": hot_hits,
-            "hot_bytes_saved": hot_unique.astype(jnp.float32)
-            * float(per_row)}
+    with _trace.scope("exchange", "stats"):
+        from ..ops import wire as wire_mod
+        H = plan.hot_rows
+        hm = (plan.hot_slot < H) & (plan.uniq.counts > 0)
+        hot_unique = jnp.sum(hm).astype(jnp.int32)
+        hot_hits = jnp.sum(jnp.where(hm, plan.uniq.counts, 0)).astype(jnp.int32)
+        w = jnp.dtype(wire_mod.wire_dtype(fmt)).itemsize
+        pair = flat.ndim == 2
+        per_row = (wire_mod.id_wire_itemsize(pair, jnp.dtype(flat.dtype).itemsize)
+                   + wire_mod.rows_wire_width(spec.output_dim, fmt) * w
+                   + wire_mod.grads_wire_width(spec.output_dim, fmt) * w)
+        return {"hot_unique": hot_unique, "hot_hits": hot_hits,
+                "hot_bytes_saved": hot_unique.astype(jnp.float32)
+                * float(per_row)}
 
 
 def _mig_pull_stats(plan: ExchangePlan) -> Dict[str, jax.Array]:
@@ -605,10 +620,11 @@ def _mig_pull_stats(plan: ExchangePlan) -> Dict[str, jax.Array]:
     (rows the directory routed off their hash home this step) and `mig_hits`
     (duplicate-weighted positions those rows absorbed) —
     `metrics.record_step_stats` derives `placement.moved_ratio{table=}`."""
-    mm = (plan.mig_moved > 0) & (plan.uniq.counts > 0)
-    return {"mig_unique": jnp.sum(mm).astype(jnp.int32),
-            "mig_hits": jnp.sum(jnp.where(mm, plan.uniq.counts, 0))
-            .astype(jnp.int32)}
+    with _trace.scope("exchange", "stats"):
+        mm = (plan.mig_moved > 0) & (plan.uniq.counts > 0)
+        return {"mig_unique": jnp.sum(mm).astype(jnp.int32),
+                "mig_hits": jnp.sum(jnp.where(mm, plan.uniq.counts, 0))
+                .astype(jnp.int32)}
 
 
 # oelint: hot-path device_get=0
@@ -641,40 +657,41 @@ def _hot_apply(spec: EmbeddingSpec, optimizer, hot: HotRows,
     sums, all_gather(tiled) the (Hp/S, W) results back to everyone. Every
     replica decodes the SAME gathered bits, so the replicated slots still
     never diverge. Counts stay an exact int32 psum in every format."""
-    H = hot.weights.shape[0]
-    hm = plan.hot_slot < H
-    tgt = jnp.where(hm, plan.hot_slot, H)
-    hg = jnp.zeros((H, spec.output_dim), jnp.float32).at[tgt].set(
-        g.astype(jnp.float32), mode="drop", unique_indices=True)
-    hc = jnp.zeros((H,), jnp.int32).at[tgt].set(
-        jnp.where(hm, plan.uniq.counts, 0).astype(jnp.int32),
-        mode="drop", unique_indices=True)
-    if fmt == "fp32":
-        tg = jax.lax.psum(hg, axis)
-    elif fmt == "bf16":
-        tg = jax.lax.psum(hg.astype(jnp.bfloat16), axis).astype(jnp.float32)
-    else:
-        from ..ops import wire as wire_mod
-        S = jax.lax.axis_size(axis)
-        Hp = -(-H // S) * S
-        hp = (jnp.zeros((Hp, spec.output_dim), jnp.float32).at[:H].set(hg)
-              if Hp != H else hg)
-        enc = wire_mod.pack_inband(hp, "int8")              # (Hp, W)
-        W = enc.shape[1]
-        parts = jax.lax.all_to_all(enc.reshape(S, Hp // S, W), axis, 0, 0)
-        dec = wire_mod.unpack_inband(
-            parts.reshape(-1, W), spec.output_dim,
-            "int8").reshape(S, Hp // S, spec.output_dim)
-        partial = jnp.sum(dec, axis=0)                      # this shard's rows
-        enc2 = wire_mod.pack_inband(partial, "int8")        # (Hp/S, W)
-        full = jax.lax.all_gather(enc2, axis, tiled=True)   # (Hp, W)
-        tg = wire_mod.unpack_inband(full, spec.output_dim, "int8")[:H]
-    tc = jax.lax.psum(hc, axis)
-    new_w, new_s = optimizer.apply(hot.weights.astype(jnp.float32),
-                                   hot.slots, tg, tc)
-    return hot.replace(
-        weights=new_w.astype(hot.weights.dtype),
-        slots={k: new_s[k].astype(hot.slots[k].dtype) for k in hot.slots})
+    with _trace.scope("exchange", "owner_apply"):
+        H = hot.weights.shape[0]
+        hm = plan.hot_slot < H
+        tgt = jnp.where(hm, plan.hot_slot, H)
+        hg = jnp.zeros((H, spec.output_dim), jnp.float32).at[tgt].set(
+            g.astype(jnp.float32), mode="drop", unique_indices=True)
+        hc = jnp.zeros((H,), jnp.int32).at[tgt].set(
+            jnp.where(hm, plan.uniq.counts, 0).astype(jnp.int32),
+            mode="drop", unique_indices=True)
+        if fmt == "fp32":
+            tg = jax.lax.psum(hg, axis)
+        elif fmt == "bf16":
+            tg = jax.lax.psum(hg.astype(jnp.bfloat16), axis).astype(jnp.float32)
+        else:
+            from ..ops import wire as wire_mod
+            S = jax.lax.axis_size(axis)
+            Hp = -(-H // S) * S
+            hp = (jnp.zeros((Hp, spec.output_dim), jnp.float32).at[:H].set(hg)
+                  if Hp != H else hg)
+            enc = wire_mod.pack_inband(hp, "int8")              # (Hp, W)
+            W = enc.shape[1]
+            parts = _a2a("grads", enc.reshape(S, Hp // S, W), axis)
+            dec = wire_mod.unpack_inband(
+                parts.reshape(-1, W), spec.output_dim,
+                "int8").reshape(S, Hp // S, spec.output_dim)
+            partial = jnp.sum(dec, axis=0)                      # this shard's rows
+            enc2 = wire_mod.pack_inband(partial, "int8")        # (Hp/S, W)
+            full = jax.lax.all_gather(enc2, axis, tiled=True)   # (Hp, W)
+            tg = wire_mod.unpack_inband(full, spec.output_dim, "int8")[:H]
+        tc = jax.lax.psum(hc, axis)
+        new_w, new_s = optimizer.apply(hot.weights.astype(jnp.float32),
+                                       hot.slots, tg, tc)
+        return hot.replace(
+            weights=new_w.astype(hot.weights.dtype),
+            slots={k: new_s[k].astype(hot.slots[k].dtype) for k in hot.slots})
 
 
 def _reassemble(plan: ExchangePlan, rows: jax.Array, out_shape,
@@ -687,19 +704,20 @@ def _reassemble(plan: ExchangePlan, rows: jax.Array, out_shape,
     `fmt` means `rows` is the owner-edge ENCODED buffer (`_serve_rows`): the
     all_to_all moves it as-is — int8/bf16 through the collective — and the
     decode runs here, at the client edge."""
-    if jax.lax.axis_size(axis) == 1:
-        uniq_rows = rows[0]
-    else:
-        back = jax.lax.all_to_all(rows, axis, 0, 0)
-        if fmt != "fp32":
-            from ..ops import wire as wire_mod
-            back = wire_mod.unpack_inband(
-                back.reshape(-1, back.shape[-1]), dim,
-                fmt).reshape(back.shape[0], -1, dim)
-        uniq_rows = unbucket(back, plan.buckets.owner, plan.buckets.slot)
-    uniq_rows = _merge_hot_rows(plan, uniq_rows, hot)
-    out = jnp.take(uniq_rows, plan.uniq.inverse, axis=0)
-    return out.reshape(out_shape + (dim,))
+    with _trace.scope("exchange", "reassemble"):
+        if jax.lax.axis_size(axis) == 1:
+            uniq_rows = rows[0]
+        else:
+            back = _a2a("rows", rows, axis)
+            if fmt != "fp32":
+                from ..ops import wire as wire_mod
+                back = wire_mod.unpack_inband(
+                    back.reshape(-1, back.shape[-1]), dim,
+                    fmt).reshape(back.shape[0], -1, dim)
+            uniq_rows = unbucket(back, plan.buckets.owner, plan.buckets.slot)
+        uniq_rows = _merge_hot_rows(plan, uniq_rows, hot)
+        out = jnp.take(uniq_rows, plan.uniq.inverse, axis=0)
+        return out.reshape(out_shape + (dim,))
 
 
 # `# oelint: hot-path device_get=0` marks pure jit-side protocol code for the
@@ -811,8 +829,9 @@ def sharded_apply_gradients(
     uniq, buckets, cap = plan.uniq, plan.buckets, plan.cap
     # client-side pre-sum over local duplicates (`EmbeddingPushOperator.cpp:29-62`);
     # sorted-segment path (see UniqueResult.segment_reduce)
-    g = uniq.segment_reduce(gflat)
-    valid = (uniq.counts > 0) & _id_valid(spec, uniq.unique_ids)
+    with _trace.scope("exchange", "route"):
+        g = uniq.segment_reduce(gflat)
+        valid = (uniq.counts > 0) & _id_valid(spec, uniq.unique_ids)
     new_hot = (None if plan.hot_slot is None or state.hot is None
                else _hot_apply(spec, optimizer, state.hot, plan, g, axis,
                                fmt=hot_fmt))
@@ -836,7 +855,7 @@ def sharded_apply_gradients(
         width = spec.output_dim + lanes
         g_buckets = _scatter_buckets(payload, buckets, S, cap)
 
-        recv = jax.lax.all_to_all(g_buckets, axis, 0, 0)
+        recv = _a2a("grads", g_buckets, axis)
 
         # server side: cross-source re-dedup + fused optimizer (MPSC reduce
         # + update)
@@ -857,7 +876,7 @@ def sharded_apply_gradients(
         payload = wire_mod.encode_grads(g, counts_i32, fmt,
                                         stochastic=(fmt == "int8"))
         g_buckets = _scatter_buckets(payload, buckets, S, cap)
-        recv = jax.lax.all_to_all(g_buckets, axis, 0, 0)
+        recv = _a2a("grads", g_buckets, axis)
         rids = (plan.recv_ids.reshape(-1, 2) if plan.recv_ids.ndim == 3
                 else plan.recv_ids.reshape(-1))
         rg32, rc = wire_mod.decode_grads(
@@ -875,11 +894,12 @@ def _scatter_buckets(payload: jax.Array, buckets: BucketResult, S: int,
                      cap: int) -> jax.Array:
     """Scatter per-unique-slot payload rows (n, W) into their (owner, slot)
     bucket positions -> (S, cap, W); invalid/overflowed slots drop."""
-    width = payload.shape[1]
-    flat_pos = jnp.where((buckets.owner < S) & (buckets.slot < cap),
-                         buckets.owner * cap + buckets.slot, S * cap)
-    return jnp.zeros((S * cap, width), payload.dtype).at[flat_pos].set(
-        payload, mode="drop").reshape(S, cap, width)
+    with _trace.scope("exchange", "route"):
+        width = payload.shape[1]
+        flat_pos = jnp.where((buckets.owner < S) & (buckets.slot < cap),
+                             buckets.owner * cap + buckets.slot, S * cap)
+        return jnp.zeros((S * cap, width), payload.dtype).at[flat_pos].set(
+            payload, mode="drop").reshape(S, cap, width)
 
 
 def _apply_unique(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
@@ -892,44 +912,45 @@ def _apply_unique(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
     the annex (this shard is their assigned owner) through the identical
     sparse-apply machinery — the received buffer keeps its source-major
     order, so the per-row reduction is bit-identical to the home shard's."""
-    mig = state.mig
-    if mig is not None:
-        m_found, m_rank, _ = _mig_find(mig, rids, rc > 0)
-        M = mig.weights.shape[0]
-        mweights, mslots = sparse_apply_dense_table(
-            optimizer, mig.weights, mig.slots,
-            jnp.where(m_found, m_rank, M), rg,
-            pre_counts=jnp.where(m_found, rc, 0))
-        state = state.replace(mig=mig.replace(weights=mweights,
-                                              slots=mslots))
-        # migrated ids are ANNEX rows: drop them from the main-table apply
-        # (count 0 leaves a row bit-identical — SparseOptimizer.apply) so an
-        # array table never scatters into the alien row `id // S` points at
-        rc = jnp.where(m_found, 0, rc)
-    pair = rids.ndim == 2
-    if spec.use_hash_table:
-        from ..tables.hash_table import hash_find
-        if pair:
-            from ..ops.id64 import PAIR_EMPTY
-            probe = jnp.where((rc > 0)[:, None], rids, PAIR_EMPTY)
+    with _trace.scope("exchange", "owner_apply"):
+        mig = state.mig
+        if mig is not None:
+            m_found, m_rank, _ = _mig_find(mig, rids, rc > 0)
+            M = mig.weights.shape[0]
+            mweights, mslots = sparse_apply_dense_table(
+                optimizer, mig.weights, mig.slots,
+                jnp.where(m_found, m_rank, M), rg,
+                pre_counts=jnp.where(m_found, rc, 0))
+            state = state.replace(mig=mig.replace(weights=mweights,
+                                                  slots=mslots))
+            # migrated ids are ANNEX rows: drop them from the main-table apply
+            # (count 0 leaves a row bit-identical — SparseOptimizer.apply) so an
+            # array table never scatters into the alien row `id // S` points at
+            rc = jnp.where(m_found, 0, rc)
+        pair = rids.ndim == 2
+        if spec.use_hash_table:
+            from ..tables.hash_table import hash_find
+            if pair:
+                from ..ops.id64 import PAIR_EMPTY
+                probe = jnp.where((rc > 0)[:, None], rids, PAIR_EMPTY)
+            else:
+                probe = jnp.where(rc > 0, rids, -1).astype(state.keys.dtype)
+            slot = hash_find(state.keys, probe)
+            capacity = state.keys.shape[0]
+            pre_counts = jnp.where((slot < capacity) & (rc > 0), rc, 0)
+            rows, counts = jnp.clip(slot, 0, capacity), pre_counts
         else:
-            probe = jnp.where(rc > 0, rids, -1).astype(state.keys.dtype)
-        slot = hash_find(state.keys, probe)
-        capacity = state.keys.shape[0]
-        pre_counts = jnp.where((slot < capacity) & (rc > 0), rc, 0)
-        rows, counts = jnp.clip(slot, 0, capacity), pre_counts
-    else:
-        rows = jnp.where(rc > 0, rids // S, state.weights.shape[0])
-        counts = rc
-    if packed is not None:
-        from ..ops.sparse import sparse_apply_packed_table
-        new_packed = sparse_apply_packed_table(
-            optimizer, state.weights, packed, spec.output_dim, rows, rg,
-            pre_counts=counts)
-        return state.replace(weights=new_packed)
-    weights, slots = sparse_apply_dense_table(
-        optimizer, state.weights, state.slots, rows, rg, pre_counts=counts)
-    return state.replace(weights=weights, slots=slots)
+            rows = jnp.where(rc > 0, rids // S, state.weights.shape[0])
+            counts = rc
+        if packed is not None:
+            from ..ops.sparse import sparse_apply_packed_table
+            new_packed = sparse_apply_packed_table(
+                optimizer, state.weights, packed, spec.output_dim, rows, rg,
+                pre_counts=counts)
+            return state.replace(weights=new_packed)
+        weights, slots = sparse_apply_dense_table(
+            optimizer, state.weights, state.slots, rows, rg, pre_counts=counts)
+        return state.replace(weights=weights, slots=slots)
 
 
 # ---------------------------------------------------------------------------
@@ -1014,12 +1035,11 @@ def grouped_lookup_train(
         stacked = jnp.concatenate(rows_list, axis=1)
         if fmt == "fp32":
             enc = wire_mod.encode_rows(stacked.reshape(-1, dim), fmt)
-            back = jax.lax.all_to_all(
-                enc.reshape(S, -1, enc.shape[-1]), axis, 0, 0)
+            back = _a2a("rows", enc.reshape(S, -1, enc.shape[-1]), axis)
             dec = wire_mod.decode_rows(
                 back.reshape(-1, enc.shape[-1]), dim, fmt).reshape(S, -1, dim)
         else:
-            back = jax.lax.all_to_all(stacked, axis, 0, 0)
+            back = _a2a("rows", stacked, axis)
             dec = wire_mod.unpack_inband(
                 back.reshape(-1, stacked.shape[-1]), dim,
                 fmt).reshape(S, -1, dim)
@@ -1027,11 +1047,13 @@ def grouped_lookup_train(
         for spec, ids, plan, hot in zip(specs, ids_list, plans, hots):
             seg = dec[:, off:off + plan.cap]
             off += plan.cap
-            uniq_rows = unbucket(seg, plan.buckets.owner, plan.buckets.slot)
-            uniq_rows = _merge_hot_rows(plan, uniq_rows, hot)
-            out = jnp.take(uniq_rows, plan.uniq.inverse, axis=0)
-            outs.append(out.astype(spec.dtype).reshape(
-                _out_shape(spec, ids) + (spec.output_dim,)))
+            with _trace.scope("exchange", "reassemble"):
+                uniq_rows = unbucket(seg, plan.buckets.owner,
+                                     plan.buckets.slot)
+                uniq_rows = _merge_hot_rows(plan, uniq_rows, hot)
+                out = jnp.take(uniq_rows, plan.uniq.inverse, axis=0)
+                outs.append(out.astype(spec.dtype).reshape(
+                    _out_shape(spec, ids) + (spec.output_dim,)))
     stats_list = []
     for spec, ids, plan in zip(specs, ids_list, plans):
         st = {
@@ -1085,12 +1107,13 @@ def grouped_apply_gradients(
     # client side: per-table duplicate pre-sum into the unique slots
     gs, counts_list = [], []
     for spec, plan, grads in zip(specs, plans, grads_list):
-        g = plan.uniq.segment_reduce(grads.reshape(-1, dim))
-        valid = (plan.uniq.counts > 0) & _id_valid(spec,
-                                                   plan.uniq.unique_ids)
-        gs.append(g)
-        counts_list.append(jnp.where(valid, plan.uniq.counts, 0)
-                           .astype(jnp.int32))
+        with _trace.scope("exchange", "route"):
+            g = plan.uniq.segment_reduce(grads.reshape(-1, dim))
+            valid = (plan.uniq.counts > 0) & _id_valid(spec,
+                                                       plan.uniq.unique_ids)
+            gs.append(g)
+            counts_list.append(jnp.where(valid, plan.uniq.counts, 0)
+                               .astype(jnp.int32))
     # hot sets: reduced data-parallel, never on the fused wire (_hot_apply)
     hot_list = [
         (None if plan.hot_slot is None or state.hot is None
@@ -1113,7 +1136,7 @@ def grouped_apply_gradients(
         wire_mod.encode_grads(g, rc, fmt, stochastic=(fmt == "int8")),
         plan.buckets, S, plan.cap)
                 for plan, g, rc in zip(plans, gs, counts_list)]
-    recv = jax.lax.all_to_all(jnp.concatenate(payloads, axis=1), axis, 0, 0)
+    recv = _a2a("grads", jnp.concatenate(payloads, axis=1), axis)
     width = recv.shape[-1]
     off = 0
     for spec, state, opt, plan, g, packed in zip(
@@ -1237,12 +1260,11 @@ def grouped_prefetch(
     stacked = jnp.concatenate(rows_list, axis=1)
     if fmt == "fp32":
         enc = wire_mod.encode_rows(stacked.reshape(-1, dim), fmt)
-        back = jax.lax.all_to_all(
-            enc.reshape(S, -1, enc.shape[-1]), axis, 0, 0)
+        back = _a2a("rows", enc.reshape(S, -1, enc.shape[-1]), axis)
         dec = wire_mod.decode_rows(
             back.reshape(-1, enc.shape[-1]), dim, fmt).reshape(S, -1, dim)
     else:
-        back = jax.lax.all_to_all(stacked, axis, 0, 0)
+        back = _a2a("rows", stacked, axis)
         dec = wire_mod.unpack_inband(
             back.reshape(-1, stacked.shape[-1]), dim,
             fmt).reshape(S, -1, dim)
@@ -1279,15 +1301,16 @@ def grouped_finalize_pull(specs, states, ids_list, plans, uniq_rows_list):
     speculative unique rows hold zeros there, and the fresh overlay is what
     keeps hot rows exact under pipelining). Pure local math, no collective.
     Returns per-table batch-shaped rows in each table's dtype."""
-    outs = []
-    for spec, state, ids, plan, uniq_rows in zip(specs, states, ids_list,
-                                                 plans, uniq_rows_list):
-        ids = adapt_batch_ids(spec, state, ids)
-        ur = _merge_hot_rows(plan, uniq_rows, state.hot)
-        out = jnp.take(ur, plan.uniq.inverse, axis=0)
-        outs.append(out.astype(spec.dtype).reshape(
-            _out_shape(spec, ids) + (spec.output_dim,)))
-    return outs
+    with _trace.scope("exchange", "reassemble"):
+        outs = []
+        for spec, state, ids, plan, uniq_rows in zip(specs, states, ids_list,
+                                                     plans, uniq_rows_list):
+            ids = adapt_batch_ids(spec, state, ids)
+            ur = _merge_hot_rows(plan, uniq_rows, state.hot)
+            out = jnp.take(ur, plan.uniq.inverse, axis=0)
+            outs.append(out.astype(spec.dtype).reshape(
+                _out_shape(spec, ids) + (spec.output_dim,)))
+        return outs
 
 
 def _gather_rows_readonly(spec: EmbeddingSpec, state: EmbeddingTableState,
@@ -1301,47 +1324,48 @@ def _gather_rows_readonly(spec: EmbeddingSpec, state: EmbeddingTableState,
     into `state.ef` — the SAME index `_serve_rows` computes (OOB for
     invalid/annex rows), so the conflict patch's replay writes exactly the
     slots the speculative serve wrote."""
-    mig = state.mig
-    ef_idx = None
-    if mig is not None:
-        m_found, m_rank, _ = _mig_find(mig, flat_recv, flat_valid)
-        main_valid = flat_valid & ~m_found
-    else:
-        m_found = None
-        main_valid = flat_valid
-    if spec.use_hash_table:
-        from ..tables.hash_table import hash_find
-        if flat_recv.ndim == 2:
-            from ..ops.id64 import PAIR_EMPTY
-            probe = jnp.where(main_valid[:, None], flat_recv, PAIR_EMPTY)
+    with _trace.scope("exchange", "owner_serve"):
+        mig = state.mig
+        ef_idx = None
+        if mig is not None:
+            m_found, m_rank, _ = _mig_find(mig, flat_recv, flat_valid)
+            main_valid = flat_valid & ~m_found
         else:
-            probe = jnp.where(main_valid, flat_recv, -1)
-        capacity = state.keys.shape[0]
-        slot = hash_find(state.keys, probe)
-        idx = jnp.where((slot < capacity) & main_valid, slot, capacity)
-        rows = lookup_rows(state.weights, idx)
+            m_found = None
+            main_valid = flat_valid
+        if spec.use_hash_table:
+            from ..tables.hash_table import hash_find
+            if flat_recv.ndim == 2:
+                from ..ops.id64 import PAIR_EMPTY
+                probe = jnp.where(main_valid[:, None], flat_recv, PAIR_EMPTY)
+            else:
+                probe = jnp.where(main_valid, flat_recv, -1)
+            capacity = state.keys.shape[0]
+            slot = hash_find(state.keys, probe)
+            idx = jnp.where((slot < capacity) & main_valid, slot, capacity)
+            rows = lookup_rows(state.weights, idx)
+            if want_ef_idx:
+                ef_idx = idx
+        else:
+            idx = jnp.where(main_valid, flat_recv // S, -1)
+            rows = lookup_rows(state.weights, idx)
+            if want_ef_idx:
+                N = state.ef.shape[0] if state.ef is not None \
+                    else state.weights.shape[0]
+                ef_idx = jnp.where(main_valid, flat_recv // S,
+                                   N).astype(jnp.int32)
+        if rows.shape[1] != spec.output_dim:
+            # packed weights+slots layout inside train_many's scan
+            rows = rows[:, :spec.output_dim]
+        if m_found is not None:
+            M = mig.weights.shape[0]
+            arows = lookup_rows(mig.weights, jnp.where(m_found, m_rank, M))
+            if arows.shape[1] != spec.output_dim:
+                arows = arows[:, :spec.output_dim]
+            rows = jnp.where(m_found[:, None], arows.astype(rows.dtype), rows)
         if want_ef_idx:
-            ef_idx = idx
-    else:
-        idx = jnp.where(main_valid, flat_recv // S, -1)
-        rows = lookup_rows(state.weights, idx)
-        if want_ef_idx:
-            N = state.ef.shape[0] if state.ef is not None \
-                else state.weights.shape[0]
-            ef_idx = jnp.where(main_valid, flat_recv // S,
-                               N).astype(jnp.int32)
-    if rows.shape[1] != spec.output_dim:
-        # packed weights+slots layout inside train_many's scan
-        rows = rows[:, :spec.output_dim]
-    if m_found is not None:
-        M = mig.weights.shape[0]
-        arows = lookup_rows(mig.weights, jnp.where(m_found, m_rank, M))
-        if arows.shape[1] != spec.output_dim:
-            arows = arows[:, :spec.output_dim]
-        rows = jnp.where(m_found[:, None], arows.astype(rows.dtype), rows)
-    if want_ef_idx:
-        return rows, ef_idx
-    return rows
+            return rows, ef_idx
+        return rows
 
 
 # oelint: jit-entry
@@ -1424,7 +1448,7 @@ def grouped_conflict_patch(
         new_states.append(state)
         payloads.append(payload.reshape(S, pcap, -1))
         metas.append((pcap, member, oflow))
-    recv = jax.lax.all_to_all(jnp.concatenate(payloads, axis=1), axis, 0, 0)
+    recv = _a2a("rows", jnp.concatenate(payloads, axis=1), axis)
     width = recv.shape[-1]
     patched, stats_list, off = [], [], 0
     for spec, plan, uniq_rows, (pcap, member, oflow) in zip(

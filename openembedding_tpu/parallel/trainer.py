@@ -26,6 +26,7 @@ from ..embedding import EmbeddingSpec, EmbeddingTableState, HotRows, MigRows
 from ..model import EmbeddingModel, TrainState, Trainer, init_dense_slots
 from ..optimizers import SparseOptimizer
 from ..utils import metrics as _metrics
+from ..utils import trace as _trace
 from .mesh import DATA_AXIS, make_mesh
 from .sharded import (build_hot_identity, build_mig_identity, hot_gather,
                       hot_writeback, mig_gather, mig_writeback,
@@ -1257,7 +1258,6 @@ class MeshTrainer(Trainer):
         partials in fp32 and the untransmitted mass feeds the residual."""
         if not self.zero_enabled:
             return super().dense_update(params, slots, grads)
-        from ..utils import trace as _trace
         from . import zero
         plan = self._zero_plan_for(params)
         if plan.total == 0:
@@ -1313,15 +1313,13 @@ class MeshTrainer(Trainer):
         S, chunk = plan.num_shards, plan.chunk
         new_ef = None
         if not fmt:
-            with _trace.span("trainer", "dense_reduce_scatter",
-                             bytes=dcost["rs_bytes"]):
+            with _trace.scope("dense", "reduce"):
                 flat_g = zero.flatten_tree(plan, grads)
                 g_local = jax.lax.psum_scatter(flat_g, self.axis,
                                                scatter_dimension=0,
                                                tiled=True)
         elif fmt == "sparse_topk":
-            with _trace.span("trainer", "dense_grad_exchange",
-                             bytes=dcost["a2a_bytes"], k=int(k)):
+            with _trace.scope("dense", "reduce"):
                 flat_g = zero.flatten_tree(plan, grads) \
                     + flat_slots[zero.DENSE_EF_KEY].reshape(-1)
                 x = flat_g.reshape(S, chunk)  # destination-major partials
@@ -1338,8 +1336,7 @@ class MeshTrainer(Trainer):
                 g_local = zero.decode_flat_topk(
                     recv.reshape(S, -1), k, chunk).sum(axis=0)
         else:
-            with _trace.span("trainer", "dense_grad_exchange",
-                             bytes=dcost["a2a_bytes"]):
+            with _trace.scope("dense", "reduce"):
                 flat_g = zero.flatten_tree(plan, grads)
                 if fmt == "int8":
                     flat_g = flat_g \
@@ -1356,7 +1353,7 @@ class MeshTrainer(Trainer):
                 # lossy step per gradient, never a chain of S roundings
                 g_local = zero.decode_flat(recv.reshape(-1, W), fmt) \
                     .reshape(S, chunk).sum(axis=0)
-        with _trace.span("trainer", "dense_update", elems=chunk):
+        with _trace.scope("dense", "update"):
             if fmt:
                 # this replica's fp32 masters live in the flat slot — the
                 # replicated `params` only hold the rounded bf16 carrier
@@ -1373,7 +1370,7 @@ class MeshTrainer(Trainer):
             new_w_local, new_flat_slots = self.optimizer.apply(
                 w_local.reshape(1, -1), opt_slots,
                 g_local.reshape(1, -1), jnp.ones((1,), jnp.int32))
-        with _trace.span("trainer", "dense_gather", bytes=dcost["ag_bytes"]):
+        with _trace.scope("dense", "gather"):
             w_flat = new_w_local.reshape(-1)
             if fmt:
                 carrier = jax.lax.bitcast_convert_type(
@@ -1395,11 +1392,12 @@ class MeshTrainer(Trainer):
         return jax.lax.pmean(loss, self.axis)
 
     def reduce_metrics(self, metrics):
-        out = dict(metrics)
-        out["loss"] = self._reduce_loss(metrics["loss"])
-        out["stats"] = {k: jax.lax.psum(v, self.axis)
-                        for k, v in metrics.get("stats", {}).items()}
-        return out
+        with _trace.scope("trainer", "metrics"):
+            out = dict(metrics)
+            out["loss"] = self._reduce_loss(metrics["loss"])
+            out["stats"] = {k: jax.lax.psum(v, self.axis)
+                            for k, v in metrics.get("stats", {}).items()}
+            return out
 
     # -- fused multi-table exchange ------------------------------------------
 
@@ -1424,25 +1422,22 @@ class MeshTrainer(Trainer):
         self._observe_wire_cost(ps_specs, batch)
         if not self.group_exchange:
             return super().tables_pull(tables, batch, ps_specs, packed)
-        from ..utils import trace as _trace
         from .sharded import grouped_lookup_train
         pulled_tables, pulled, stats, plans = {}, {}, {}, {}
-        with _trace.span("trainer", "exchange",
-                         groups=len(self._exchange_groups(ps_specs))):
-            for names in self._exchange_groups(ps_specs):
-                specs = [ps_specs[n] for n in names]
-                ids_list = [jnp.asarray(batch["sparse"][s.feature_name])
-                            for s in specs]
-                new_states, outs, stats_list, plan_list = grouped_lookup_train(
-                    specs, [tables[n] for n in names], ids_list,
-                    axis=self.axis, capacity_factor=self.capacity_factor,
-                    wire=self.wire_for(names[0]),
-                    load_stats=self.shard_stats)
-                for n, ts, out, st, pl in zip(names, new_states, outs,
-                                              stats_list, plan_list):
-                    pulled_tables[n], pulled[n], plans[n] = ts, out, pl
-                    for k, v in st.items():
-                        stats[f"{n}/{k}"] = v
+        for names in self._exchange_groups(ps_specs):
+            specs = [ps_specs[n] for n in names]
+            ids_list = [jnp.asarray(batch["sparse"][s.feature_name])
+                        for s in specs]
+            new_states, outs, stats_list, plan_list = grouped_lookup_train(
+                specs, [tables[n] for n in names], ids_list,
+                axis=self.axis, capacity_factor=self.capacity_factor,
+                wire=self.wire_for(names[0]),
+                load_stats=self.shard_stats)
+            for n, ts, out, st, pl in zip(names, new_states, outs,
+                                          stats_list, plan_list):
+                pulled_tables[n], pulled[n], plans[n] = ts, out, pl
+                for k, v in st.items():
+                    stats[f"{n}/{k}"] = v
         return pulled_tables, pulled, stats, plans
 
     # oelint: hot-path device_get=0
@@ -1498,13 +1493,12 @@ class MeshTrainer(Trainer):
         route + id a2a) and the speculative row gather
         (`sharded.grouped_prefetch`). Returns (new_tables, plans, rows,
         stats) keyed by table, stats prefixed like tables_pull's."""
-        from ..utils import trace as _trace
         from .sharded import grouped_prefetch
         self._observe_wire_cost(ps_specs, batch, pipelined=True)
         new_tables = dict(tables)
         plans, rows, stats = {}, {}, {}
         groups = self._pipeline_groups(ps_specs)
-        with _trace.span("trainer", "prefetch", groups=len(groups)):
+        with _trace.scope("trainer", "prefetch"):
             for names in groups:
                 specs = [ps_specs[n] for n in names]
                 ids_list = [jnp.asarray(batch["sparse"][s.feature_name])
@@ -1526,19 +1520,17 @@ class MeshTrainer(Trainer):
         """Client tail of the carried prefetch — hot-cache overlay +
         duplicate expansion at CONSUME time (`sharded.grouped_finalize_pull`;
         pure local math, no collective)."""
-        from ..utils import trace as _trace
         from .sharded import grouped_finalize_pull
         pulled = {}
-        with _trace.span("trainer", "pull"):
-            for names in self._pipeline_groups(ps_specs):
-                specs = [ps_specs[n] for n in names]
-                ids_list = [jnp.asarray(batch["sparse"][s.feature_name])
-                            for s in specs]
-                outs = grouped_finalize_pull(
-                    specs, [tables[n] for n in names], ids_list,
-                    [plans[n] for n in names], [rows[n] for n in names])
-                for n, out in zip(names, outs):
-                    pulled[n] = out
+        for names in self._pipeline_groups(ps_specs):
+            specs = [ps_specs[n] for n in names]
+            ids_list = [jnp.asarray(batch["sparse"][s.feature_name])
+                        for s in specs]
+            outs = grouped_finalize_pull(
+                specs, [tables[n] for n in names], ids_list,
+                [plans[n] for n in names], [rows[n] for n in names])
+            for n, out in zip(names, outs):
+                pulled[n] = out
         return pulled
 
     # oelint: hot-path device_get=0
@@ -1549,12 +1541,11 @@ class MeshTrainer(Trainer):
         conflict_overflow psum) — `new_tables` carries the replayed
         error-feedback residuals on narrow-wire tables (unchanged
         otherwise)."""
-        from ..utils import trace as _trace
         from .sharded import grouped_conflict_patch
         patched, conflict = {}, {}
         new_tables = dict(tables)
         coflow = jnp.zeros((), jnp.int32)
-        with _trace.span("trainer", "conflict_patch"):
+        with _trace.scope("trainer", "conflict_patch"):
             for names in self._pipeline_groups(ps_specs):
                 specs = [ps_specs[n] for n in names]
                 outs, stats_list, states = grouped_conflict_patch(
@@ -1605,6 +1596,8 @@ class MeshTrainer(Trainer):
         patch re-encodes the patched rows with the same codec and rewrites
         the residual slots, so pipelined int8 windows match serial int8
         bit-for-bit."""
+        _metrics.observe("trainer.traces", 1, "sum",
+                         labels={"fn": "train_many"})
         if self.offload and not getattr(self, "_offload_prepared", False):
             raise ValueError(
                 "train_many on storage='host_cached' tables needs the union "
@@ -1982,9 +1975,11 @@ class MeshTrainer(Trainer):
             if many is None:
                 many = self.jit_train_many(w, state)
             t0 = _time.perf_counter()
-            state, m = many(state, w)
-            if block:
-                jax.block_until_ready(state)
+            # one profiler step per window, so a profile has steps
+            with jax.profiler.StepTraceAnnotation("train", step_num=n):
+                state, m = many(state, w)
+                if block:
+                    jax.block_until_ready(state)
             _metrics.observe("trainer.window_ms",
                              (_time.perf_counter() - t0) * 1e3, "hist")
             self.record_window_stats(m)

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from ..ops.id64 import (HI_INVALID, PAIR_EMPTY, is_pair, np_join_ids,
                         np_split_ids, pair_valid)
+from ..utils import trace as _trace
 
 EMPTY = -1
 DEFAULT_NUM_PROBES = 64
@@ -332,12 +333,13 @@ def hash_find(keys: jax.Array, ids: jax.Array,
 
 def hash_lookup(state, ids: jax.Array) -> jax.Array:
     """Read-only pull: absent ids return zero rows."""
-    ids = adapt_ids(state.keys, ids)
-    slot = hash_find(state.keys, ids)
-    capacity, dim = state.weights.shape
-    hit = slot < capacity
-    rows = jnp.take(state.weights, jnp.clip(slot, 0, capacity - 1), axis=0)
-    return jnp.where(hit[:, None], rows, jnp.zeros_like(rows))
+    with _trace.scope("sparse", "pull"):
+        ids = adapt_ids(state.keys, ids)
+        slot = hash_find(state.keys, ids)
+        capacity, dim = state.weights.shape
+        hit = slot < capacity
+        rows = jnp.take(state.weights, jnp.clip(slot, 0, capacity - 1), axis=0)
+        return jnp.where(hit[:, None], rows, jnp.zeros_like(rows))
 
 
 def hash_lookup_train(state, ids: jax.Array, out_dim: int = None):
@@ -349,38 +351,40 @@ def hash_lookup_train(state, ids: jax.Array, out_dim: int = None):
     (`ops/sparse.packed_layout`, inside `Trainer.train_many`'s scan), slice
     the weight columns out of the gathered packed rows — the gather is
     latency-bound, the slot bytes ride free."""
-    from ..ops.dedup import unique_with_counts
+    with _trace.scope("sparse", "pull"):
+        from ..ops.dedup import unique_with_counts
 
-    ids = adapt_ids(state.keys, ids)
-    uniq = unique_with_counts(ids)
-    # only insert real (count>0) unique ids; padding probes for EMPTY and is dropped
-    if state.keys.ndim == 2:
-        probe_ids = jnp.where((uniq.counts > 0)[:, None], uniq.unique_ids,
-                              PAIR_EMPTY)
-    else:
-        probe_ids = jnp.where(uniq.counts > 0, uniq.unique_ids, EMPTY)
-    new_keys, uslot, overflow = hash_find_or_insert(state.keys, probe_ids)
-    slot = uslot[uniq.inverse]
-    capacity = state.keys.shape[0]
-    hit = slot < capacity
-    rows = jnp.take(state.weights, jnp.clip(slot, 0, capacity - 1), axis=0)
-    if out_dim is not None and rows.shape[1] != out_dim:
-        rows = rows[:, :out_dim]
-    rows = jnp.where(hit[:, None], rows, jnp.zeros_like(rows))
-    new_overflow = (state.overflow + overflow if state.overflow is not None
-                    else overflow)
-    return state.replace(keys=new_keys, overflow=new_overflow), rows
+        ids = adapt_ids(state.keys, ids)
+        uniq = unique_with_counts(ids)
+        # only insert real (count>0) unique ids; padding probes for EMPTY and is dropped
+        if state.keys.ndim == 2:
+            probe_ids = jnp.where((uniq.counts > 0)[:, None], uniq.unique_ids,
+                                  PAIR_EMPTY)
+        else:
+            probe_ids = jnp.where(uniq.counts > 0, uniq.unique_ids, EMPTY)
+        new_keys, uslot, overflow = hash_find_or_insert(state.keys, probe_ids)
+        slot = uslot[uniq.inverse]
+        capacity = state.keys.shape[0]
+        hit = slot < capacity
+        rows = jnp.take(state.weights, jnp.clip(slot, 0, capacity - 1), axis=0)
+        if out_dim is not None and rows.shape[1] != out_dim:
+            rows = rows[:, :out_dim]
+        rows = jnp.where(hit[:, None], rows, jnp.zeros_like(rows))
+        new_overflow = (state.overflow + overflow if state.overflow is not None
+                        else overflow)
+        return state.replace(keys=new_keys, overflow=new_overflow), rows
 
 
 def _grad_slots_and_counts(state, ids: jax.Array):
     """ids -> (clipped slot indices, pre_counts) for the push+update: absent
     ids (overflowed at pull time) drop their gradients via count 0, like the
     reference dropping pushes for ids a dead shard lost."""
-    ids = adapt_ids(state.keys, ids)
-    slot = hash_find(state.keys, ids)
-    capacity = state.keys.shape[0]
-    pre_counts = jnp.where(slot < capacity, 1, 0).astype(jnp.int32)
-    return jnp.clip(slot, 0, capacity), pre_counts
+    with _trace.scope("sparse", "apply"):
+        ids = adapt_ids(state.keys, ids)
+        slot = hash_find(state.keys, ids)
+        capacity = state.keys.shape[0]
+        pre_counts = jnp.where(slot < capacity, 1, 0).astype(jnp.int32)
+        return jnp.clip(slot, 0, capacity), pre_counts
 
 
 def hash_apply_gradients(state, optimizer, ids: jax.Array, grads: jax.Array):

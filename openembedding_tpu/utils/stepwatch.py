@@ -1,23 +1,16 @@
-"""Measured step timing: sampled `block_until_ready` brackets + HLO-byte
-attribution + the `exchange.cost_drift` gauge.
+"""Measured step timing: sampled `block_until_ready` brackets and the
+`exchange.cost_drift` gauge.
 
-The trainer's phase spans (`trainer.pull/compute/apply`) fire at TRACE time
-— once per compile — so until now the tree had zero MEASURED device timing
-(`utils/trace.py` module doc says so explicitly). This module closes that
-gap without touching the hot path's one-device_get rule: the jitted step
-stays untouched; every Nth CALL is bracketed host-side with
+Code under jit runs its Python once per compile, so nothing inside the step
+can time it (`utils/trace.py`, "Two clocks"). This module measures the step
+from outside without touching the hot path's one-device_get rule: the jitted
+step stays untouched; every Nth CALL is bracketed host-side with
 `jax.block_until_ready` (the "caller's timing wrapper" the oelint host-sync
 pass points at) and lands in the `trainer.step_ms` histogram. All other
-calls pay one integer increment.
-
-Attribution: the first sampled call extracts the compiled HLO once
-(`fn.lower(*args).compile().as_text()` — a one-time cost of the opt-in
-measurement mode) and prices each collective kind's result-buffer bytes with
-the same regex family the oelint hlo-budget pass uses (reimplemented here in
-~30 lines: the package must not import `tools/`). Each sample then splits
-its measured wall time over collective kinds IN PROPORTION TO BYTES
-(`trainer.attrib_ms{kind=}` gauges) — an attribution MODEL over a measured
-total, honest about being byte-proportional, not a per-op profile.
+calls pay one integer increment. Time per STAGE of the step (and the exposed
+part of the exchange) is measured on the device's clock, not modelled here:
+profile a few windows (`chip_smoke.py --profile DIR`, `jax.profiler.trace`)
+and read `tools/trace_report.py --xplane DIR`.
 
 Cost drift: with the analytic wire model attached
 (`MeshTrainer.last_wire_cost` → `bytes_per_step`), each sample derives
@@ -31,60 +24,18 @@ Overlap awareness (round 18): software-pipelined windows move the prefetched
 exchange off the critical path — the wire model marks those bytes
 `overlapped_bytes`. Charging them to the sampled wall time would understate
 µs/byte while pipelined and read as phantom drift the moment pipelining
-toggles; instead only the EXPOSED bytes (`bytes_per_step − overlapped_bytes`)
-price the baseline, and `trainer.overlap_ms` gauges the modeled time the
-hidden bytes would have cost at the baseline rate — the sum-of-parts minus
-measured-wall evidence that the hiding is real.
+toggles; so only the EXPOSED bytes (`bytes_per_step − overlapped_bytes`)
+price the baseline.
 """
 
 from __future__ import annotations
 
-import re
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable, Optional
 
 from . import metrics
 
-# collective-definition lines in optimized HLO text, e.g.
-# `%all-to-all.1 = s8[8,56,16]{2,1,0} all-to-all(...)`
-_COLLECTIVES = {
-    "all_to_all": r" all-to-all(?:-start)?\(",
-    "all_reduce": r" all-reduce(?:-start)?\(",
-    "all_gather": r" all-gather(?:-start)?\(",
-    "reduce_scatter": r" reduce-scatter(?:-start)?\(",
-    "collective_permute": r" collective-permute(?:-start)?\(",
-}
-_TYPE_RE = re.compile(
-    r"(pred|bf16|f16|f32|f64|s8|u8|s16|u16|s32|u32|s64|u64)\[([0-9,]*)\]")
-_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
-             "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
-             "u64": 8}
-
 BASELINE_SAMPLES = 3
-
-
-def collective_bytes(hlo_text: str) -> Dict[str, int]:
-    """{collective kind: summed result-buffer bytes} read off compiled HLO
-    text (first tensor type on each collective's definition line — the same
-    counting rule as the hlo-budget pass, so measured attribution and the
-    pinned byte budgets speak the same unit)."""
-    out: Dict[str, int] = {}
-    patterns = {k: re.compile(v) for k, v in _COLLECTIVES.items()}
-    for line in hlo_text.splitlines():
-        for kind, pat in patterns.items():
-            if not pat.search(line):
-                continue
-            m = _TYPE_RE.search(line)
-            if m is None:
-                continue
-            dtype, dims = m.groups()
-            n = 1
-            for d in dims.split(","):
-                if d:
-                    n *= int(d)
-            out[kind] = out.get(kind, 0) + n * _ITEMSIZE[dtype]
-            break
-    return out
 
 
 class StepWatch:
@@ -109,38 +60,14 @@ class StepWatch:
         self.calls = 0
         self.samples = 0
         self.input_waits = 0
-        self._hlo_bytes: Optional[Dict[str, int]] = None
-        self._hlo_failed = False
         self._baseline_us_per_byte: Optional[float] = None
         self._baseline_n = 0
-
-    # -- HLO extraction (once, on the first sampled call) ---------------------
-
-    def _extract_hlo(self, fn, args, kwargs) -> None:
-        if self._hlo_bytes is not None or self._hlo_failed:
-            return
-        try:
-            text = fn.lower(*args, **kwargs).compile().as_text()
-            self._hlo_bytes = collective_bytes(text)
-        except Exception:  # noqa: BLE001 — measurement must never break the
-            # loop; attribution just stays empty (step_ms still records)
-            self._hlo_failed = True
-            metrics.observe("trainer.hlo_extract_errors", 1)
 
     # -- per-sample folding ---------------------------------------------------
 
     def _observe_sample(self, ms: float) -> None:
         self.samples += 1
         metrics.observe("trainer.step_ms", ms, "hist")
-        if self._hlo_bytes:
-            total = sum(self._hlo_bytes.values())
-            for kind, b in self._hlo_bytes.items():
-                metrics.observe("trainer.hlo_bytes", float(b), "gauge",
-                                labels={"kind": kind})
-                if total > 0:
-                    # byte-proportional share of the measured wall time
-                    metrics.observe("trainer.attrib_ms", ms * b / total,
-                                    "gauge", labels={"kind": kind})
         cost = self.wire_cost() if self.wire_cost is not None else None
         bytes_per_step = int((cost or {}).get("bytes_per_step", 0) or 0)
         overlapped = int((cost or {}).get("overlapped_bytes", 0) or 0)
@@ -161,12 +88,6 @@ class StepWatch:
                 metrics.observe(
                     "exchange.cost_drift",
                     us_per_byte / self._baseline_us_per_byte - 1.0, "gauge")
-        if overlapped > 0 and self._baseline_us_per_byte:
-            # modeled time the hidden collectives would have added had they
-            # stayed on the critical path (sum-of-parts − measured wall)
-            metrics.observe("trainer.overlap_ms",
-                            overlapped * self._baseline_us_per_byte / 1e3,
-                            "gauge")
 
     def observe_input_wait(self, ms: float) -> None:
         """The input-wait attribution lane (round 20): time the TRAIN LOOP
@@ -202,7 +123,6 @@ class _MeasuredStep:
         w.calls += 1
         if w.calls % w.every:
             return self._fn(*args, **kwargs)
-        w._extract_hlo(self._fn, args, kwargs)
         t0 = time.perf_counter()
         out = self._fn(*args, **kwargs)
         jax.block_until_ready(out)

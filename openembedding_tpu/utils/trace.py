@@ -35,12 +35,29 @@ or a DEGRADED transition after the fact.
   ui.perfetto.dev; `tools/trace_report.py` turns a dump into a latency table.
 
 Spans cost two clock reads, a histogram observe, and a deque append — cheap
-enough to stay always-on, like the accumulators. NOTE on jitted code: a span
-around traced (jit/shard_map/scan) Python measures TRACE time, once per
-compile — honest for compile structure, not per-step execution. Put spans
-around the jitted CALL (dispatch+wall) or host-side stages for runtime
-numbers; `model.Trainer.train_step`'s phase spans are the trace-time kind
-and say so.
+enough to stay always-on, like the accumulators.
+
+Two clocks, two tools:
+
+- HOST stages (`span`): besides the recorder and the histogram, every span is
+  a `jax.profiler.TraceAnnotation("oetpu.<group>.<name>")`, so whenever a
+  profiler session is open (`jax.profiler.trace`, `chip_smoke.py --profile`)
+  the span lands in the xplane's host plane on the SAME clock as the device
+  ops, and an idle gap of the device can be put down to it by name. With no
+  session open the annotation is one flag test.
+- IN-JIT stages (`scope`): code under jit/scan/shard_map runs its Python once
+  per compile, so a clock read there times tracing, never a step. `scope` is
+  `jax.named_scope` and nothing else: HLO metadata, no instruction, no flag,
+  seen in a profile and never on /metrics. `scope_map` reads the stage of
+  every instruction off compiled HLO text; `device_report`
+  (`utils/devtrace.py`, `tools/trace_report.py --xplane`) reduces a device
+  trace to time per stage.
+
+The stage vocabulary (`<layer>.<stage>`; README "Observability"):
+`sparse.{dedup,pull,reduce,apply,pack,unpack}`, `exchange.{route,wire,
+a2a_ids,a2a_rows,a2a_grads,owner_serve,owner_apply,reassemble,stats}`,
+`dense.{tower,reduce,update,gather}`, `trainer.{metrics,prefetch,
+conflict_patch,sentinel}`.
 """
 
 from __future__ import annotations
@@ -56,7 +73,10 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, Iterable, List, Optional
 
+import jax
+
 from . import metrics
+from .devtrace import device_report, scope_map  # noqa: F401  (trace.* names)
 
 REQUEST_ID_HEADER = "X-OETPU-Request-Id"
 TRACE_HEADER = "X-OETPU-Trace"
@@ -308,7 +328,9 @@ def span(group: str, name: str, *, labels: Optional[Dict[str, str]] = None,
     token = _current_span.set(s)
     t0 = s.start
     try:
-        yield s
+        # the same span on the profiler's clock (module doc "Two clocks")
+        with jax.profiler.TraceAnnotation(f"oetpu.{group}.{name}"):
+            yield s
     except BaseException as e:
         s.attrs.setdefault("error", f"{type(e).__name__}: {e}")
         # explicit status + a discrete flight-recorder event: a span that
@@ -324,6 +346,15 @@ def span(group: str, name: str, *, labels: Optional[Dict[str, str]] = None,
         RECORDER.record(s)
         metrics.observe(f"{group}.{name}.ms", ms, "hist", labels=labels)
         metrics.observe(f"{group}.{name}.max_ms", ms, "max", labels=labels)
+
+
+def scope(group: str, name: str):
+    """Stage name for TRACED code (jit/scan/shard_map bodies): every op built
+    inside carries `<group>.<name>` in its HLO `op_name` metadata, which is
+    where a device profile reads it. `jax.named_scope` and nothing else — no
+    clock, no recorder entry, no histogram (module doc "Two clocks"). Works
+    as a context manager and as a function decorator; scopes nest."""
+    return jax.named_scope(f"{group}.{name}")
 
 
 def current_span() -> Optional[Span]:

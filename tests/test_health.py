@@ -2,8 +2,8 @@
 sentinel (clean run -> health.* gauges populated, nonfinite_total == 0;
 planted NaN -> `NonFiniteError` naming the table + the `health/nonfinite`
 flight-recorder event + the numerics SLO flipping to BREACHED on a live
-`GET /sloz`), the sampled step-time watch (`trainer.step_ms`, HLO-byte
-attribution, `exchange.cost_drift`), sentinel-off stat hygiene, the mesh
+`GET /sloz`), the sampled step-time watch (`trainer.step_ms`,
+`exchange.cost_drift`), sentinel-off stat hygiene, the mesh
 additive-stats path, and the PeriodicReporter JSONL sink."""
 
 import json
@@ -173,24 +173,14 @@ def test_mesh_sentinel_grad_norms_and_quant_err():
 
 
 def test_stepwatch_cadence_attribution_and_cost_drift():
-    from openembedding_tpu.utils.stepwatch import StepWatch, collective_bytes
-
-    hlo = "\n".join([
-        "  %a2a = f32[8,16]{1,0} all-to-all(%x)",
-        "  %ar = bf16[4]{0} all-reduce(%y)",
-        "  %other = f32[2,2]{1,0} add(%x, %x)",
-    ])
-    assert collective_bytes(hlo) == {"all_to_all": 8 * 16 * 4,
-                                     "all_reduce": 4 * 2}
+    from openembedding_tpu.utils.stepwatch import StepWatch
 
     watch = StepWatch(every=2, wire_cost=lambda: {"bytes_per_step": 1024})
-    wrapped = watch.wrap(lambda x: x)  # no .lower: extraction error path
+    wrapped = watch.wrap(lambda x: x)  # any callable: nothing is lowered
     for i in range(8):
         assert wrapped(i) == i
     assert watch.calls == 8 and watch.samples == 4
     assert metrics.Accumulator.get("trainer.step_ms", "hist").count == 4
-    # HLO extraction failed once, loudly, and sampling carried on
-    assert metrics.Accumulator.get("trainer.hlo_extract_errors").value() == 1
     # baseline = first 3 samples; drift gauged from sample 1 on, finite
     drift = metrics.Accumulator.get("exchange.cost_drift", "gauge").value()
     assert np.isfinite(drift)
@@ -198,7 +188,7 @@ def test_stepwatch_cadence_attribution_and_cost_drift():
                                    "gauge").value() > 0.0
 
 
-def test_stepwatch_jit_attribution_populates_hlo_bytes():
+def test_stepwatch_jit_proxy_and_no_modelled_series():
     import jax
     import jax.numpy as jnp
 
@@ -212,12 +202,14 @@ def test_stepwatch_jit_attribution_populates_hlo_bytes():
     # proxied attributes still reach the jit fn (recompile guards use this)
     assert hasattr(wrapped, "lower")
     assert watch.samples == 1
-    # no collectives on one CPU device: attribution is empty but step_ms
-    # still measured, and nothing errored
+    # step_ms is measured; the byte-model series (time split over collective
+    # kinds by HLO bytes, modelled overlap) are gone: per-stage time comes
+    # from a device profile (tools/trace_report.py --xplane)
     assert metrics.Accumulator.get("trainer.step_ms", "hist").count == 1
     with metrics._LOCK:
         names = {a.name for a in metrics._REGISTRY.values()}
-    assert "trainer.hlo_extract_errors" not in names
+    assert not names & {"trainer.attrib_ms", "trainer.overlap_ms",
+                        "trainer.hlo_bytes", "trainer.hlo_extract_errors"}
 
 
 def test_stepwatch_rejects_bad_every():

@@ -4,6 +4,7 @@ export, request-id propagation through a live serving request, /statusz and
 /tracez, and the tools/trace_report.py smoke."""
 
 import contextvars
+import functools
 import json
 import os
 import threading
@@ -295,9 +296,12 @@ def test_statusz_and_tracez_surfaces(served_model):
         time.sleep(0.01)
 
 
-def test_trainer_phase_histograms_on_metrics(served_model):
-    """An (eager) train step records trainer.{pull,compute,apply} phase
-    spans; /metrics then exposes them as histogram series."""
+def test_trainer_stages_are_scopes_not_metrics_series(served_model):
+    """The train step's stages are `trace.scope`s (HLO metadata, read from a
+    device profile): neither an eager nor a jitted step leaves a
+    `trainer.{pull,compute,apply}.ms` series — those timed Python tracing —
+    while `trainer.traces{fn=}` counts each TRACE of the entry point: 1 after
+    the first jitted call and still 1 after the second."""
     import openembedding_tpu as embed
     from openembedding_tpu.data import synthetic_criteo
     from openembedding_tpu.model import Trainer
@@ -308,9 +312,227 @@ def test_trainer_phase_histograms_on_metrics(served_model):
     trainer = Trainer(model, embed.Adagrad(learning_rate=0.05))
     batch = next(iter(synthetic_criteo(4, id_space=128, steps=1, seed=2)))
     state = trainer.init(batch)
-    trainer.train_step(state, batch)  # eager: spans time real execution
+    step = trainer.jit_train_step()
+    state, _ = step(state, batch)
+    key = 'trainer.traces{fn="train_step"}'
+    assert metrics.report()[key] == 1
+    state, _ = step(state, batch)
+    rep = metrics.report()
+    assert rep[key] == 1
+    assert not [k for k in rep if k.startswith(
+        ("trainer.pull.", "trainer.compute.", "trainer.apply."))]
     with urllib.request.urlopen(f"{base}/metrics") as resp:
         text = resp.read().decode()
+    assert 'oetpu_trainer_traces_total{fn="train_step"} 1' in text
     for phase in ("pull", "compute", "apply"):
-        assert f"# TYPE oetpu_trainer_{phase}_ms histogram" in text
-        assert f"oetpu_trainer_{phase}_ms_count 1" in text
+        assert f"oetpu_trainer_{phase}_ms" not in text
+
+
+# -- stage scopes in the compiled scan, the profiler's clock, the reducer -----
+
+_HEAVY = ("gather", "scatter", "sort", "dot", "convolution", "all-to-all",
+          "all-reduce", "all-gather", "reduce-scatter", "collective-permute")
+_SINGLE = {"sparse.dedup", "sparse.pull", "sparse.apply", "sparse.pack",
+           "sparse.unpack", "dense.tower", "dense.update"}
+_MESH = _SINGLE | {"exchange.route", "exchange.a2a_ids", "exchange.a2a_rows",
+                   "exchange.a2a_grads", "exchange.owner_serve",
+                   "exchange.owner_apply", "exchange.reassemble",
+                   "dense.reduce"}
+
+
+@functools.lru_cache(maxsize=None)  # two tests read the same two texts
+def _compiled_scan_text(kind):
+    """`train_many` of a tiny DeepFM, compiled: Trainer, or MeshTrainer on 4
+    of the suite's virtual devices."""
+    import jax
+
+    import openembedding_tpu as embed
+    from openembedding_tpu.models import make_deepfm
+
+    rng = np.random.default_rng(0)
+    K, B, V = 2, 32, 512
+    stacked = {
+        "sparse": {"categorical":
+                   rng.integers(0, V, (K, B, 26)).astype(np.int32)},
+        "dense": rng.normal(size=(K, B, 13)).astype(np.float32),
+        "label": rng.integers(0, 2, (K, B)).astype(np.float32)}
+    one = jax.tree_util.tree_map(lambda x: x[0], stacked)
+    model = make_deepfm(vocabulary=V, dim=4, hidden=(8,))
+    opt = embed.Adagrad(learning_rate=0.05)
+    if kind == "mesh":
+        from openembedding_tpu.parallel import MeshTrainer, make_mesh
+        trainer = MeshTrainer(model, opt, mesh=make_mesh(jax.devices()[:4]))
+        state = trainer.init(one)
+        many = trainer.jit_train_many(stacked, state)
+    else:
+        trainer = embed.Trainer(model, opt)
+        state = trainer.init(one)
+        many = trainer.jit_train_many()
+    return many.lower(state, stacked).compile().as_text()
+
+
+@pytest.mark.parametrize("kind", ["single", "mesh"])
+def test_compiled_scan_carries_every_stage_scope(kind):
+    from openembedding_tpu.utils import devtrace
+
+    text = _compiled_scan_text(kind)
+    scopes = trace.scope_map(text)
+    inner = {path.rsplit("/", 1)[-1] for path in scopes.values() if path}
+    want = _MESH if kind == "mesh" else _SINGLE
+    assert want <= inner, sorted(want - inner)
+    opcode = {}
+    for line in text.splitlines():
+        m = devtrace._INSTR.match(line)
+        if m:
+            opcode[m.group(1)] = devtrace.opcode_of(line)
+    heavy = {n: op for n, op in opcode.items()
+             if op.removesuffix("-start").removesuffix("-done") in _HEAVY}
+    assert heavy
+    assert not {n: op for n, op in heavy.items() if not scopes[n]}
+    a2a = [scopes[n].rsplit("/", 1)[-1] for n, op in heavy.items()
+           if op.startswith("all-to-all")]
+    if kind == "mesh":
+        # ids, rows, grads: each all-to-all under its own name
+        assert sorted(a2a) == ["exchange.a2a_grads", "exchange.a2a_ids",
+                               "exchange.a2a_rows"]
+    else:
+        assert a2a == []
+
+
+@pytest.mark.parametrize("kind", ["single", "mesh"])
+def test_scopes_add_no_instruction(kind, monkeypatch):
+    """The compiled scan with scopes == the one without, metadata stripped,
+    byte for byte: a scope is a name and nothing else."""
+    import contextlib
+    import re
+
+    def strip(text):
+        # metadata = each instruction's `metadata={op_name=... stack_frame_id}`
+        # and the module's source-location tables those ids point into
+        blocks = [b for b in text.split("\n\n") if b.split("\n", 1)[0] not in
+                  ("FileNames", "FunctionNames", "FileLocations",
+                   "StackFrames")]
+        return re.sub(r",? ?metadata=\{[^}]*\}", "", "\n\n".join(blocks))
+
+    with_scopes = _compiled_scan_text(kind)
+    assert "sparse.apply" in with_scopes
+    monkeypatch.setattr(trace, "scope",
+                        lambda group, name: contextlib.nullcontext())
+    without = _compiled_scan_text.__wrapped__(kind)
+    assert "sparse.apply" not in without
+    assert strip(with_scopes) == strip(without)
+
+
+def test_span_is_a_profiler_annotation(tmp_path):
+    """A host span lands in an open profiler session as
+    `oetpu.<group>.<name>` (the clock the device ops are on) and still in the
+    flight recorder and its histogram."""
+    import jax
+
+    from openembedding_tpu.utils import devtrace
+
+    with jax.profiler.trace(str(tmp_path)):
+        with trace.span("ingest", "parse_block", rows=3):
+            time.sleep(0.002)
+    events = devtrace.load_events(devtrace.find_xplane(str(tmp_path)))
+    mine = [e for e in events["host"] if e[0] == "oetpu.ingest.parse_block"]
+    assert len(mine) == 1 and mine[0][2] >= 2e6  # ns
+    (s,) = trace.RECORDER.spans()
+    assert (s.group, s.name, s.attrs) == ("ingest", "parse_block",
+                                          {"rows": 3})
+    assert metrics.Accumulator.get("ingest.parse_block.ms", "hist").count == 1
+
+
+def test_scope_map_reads_nested_and_wrapped_names():
+    text = "\n".join([
+        '  %copy.9 = f32[8,4]{0,1} copy(%param.1), metadata={op_name="w"}',
+        '  %fusion.7 = f32[8,4]{1,0} fusion(%copy.9), kind=kLoop, calls=%fc, '
+        'metadata={op_name="jit(train_many)/while/body/exchange.owner_apply/'
+        'sparse.apply/sparse.apply/scatter-add" stack_frame_id=3}',
+        '  ROOT %dot.2 = f32[8,8]{1,0} dot(%a, %b), metadata={op_name='
+        '"jit(f)/transpose(jvp(dense.tower))/Dense_0/dot_general"}',
+        "  %copy.1 = f32[8]{0} copy(%x)",
+        '  %add.3 = f32[] add(%x, %y), metadata={op_name="jit(f)/my.dense.x"}',
+    ])
+    assert trace.scope_map(text) == {
+        "fusion.7": "exchange.owner_apply/sparse.apply",
+        # a layout copy the compiler put in takes its consumer's scope
+        "copy.9": "exchange.owner_apply/sparse.apply",
+        "dot.2": "dense.tower", "copy.1": "", "add.3": ""}
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """A v5e trace cut to a few hundred events (see its `recorded` key)."""
+    path = os.path.join(os.path.dirname(__file__), "fixtures",
+                        "devtrace_small.json")
+    with open(path) as f:
+        return json.load(f)
+
+
+def test_device_report_partitions_busy_time(recorded):
+    from openembedding_tpu.utils import devtrace
+
+    rep = devtrace.reduce_events(recorded, scopes=recorded["scopes"],
+                                 steps=recorded["steps_in_cut"])
+    (dev,) = rep["devices"].values()
+    total = sum(dev["scope_s"].values()) + dev["unscoped_s"]
+    assert abs(total - dev["busy_s"]) < 1e-9
+    assert abs(sum(dev["path_s"].values()) - sum(dev["scope_s"].values())) \
+        < 1e-9
+    assert abs(sum(dev["rollup_s"].values())
+               - sum(dev["scope_s"].values())) < 1e-9
+    assert 0.5 < dev["scoped_share"] <= 1.0
+    assert {"sparse.apply", "sparse.pull", "sparse.dedup",
+            "dense.tower"} <= set(dev["scope_s"])
+    assert dev["idle_s"] == pytest.approx(dev["span_s"] - dev["busy_s"])
+    per_step = dev["scope_ms_per_step"]["sparse.apply"]
+    assert per_step == pytest.approx(
+        dev["scope_s"]["sparse.apply"] / recorded["steps_in_cut"] * 1e3)
+
+
+def test_device_report_leaves_containers_out(recorded):
+    from openembedding_tpu.utils import devtrace
+
+    (name,) = recorded["devices"]
+    ops = recorded["devices"][name]["ops"]
+    loops = [e for e in ops if devtrace.opcode_of(e[0]) in
+             devtrace.CONTAINERS]
+    assert loops, "the cut keeps the scan's while op"
+    with_loops = devtrace.reduce_device(recorded["devices"][name],
+                                        recorded["scopes"])
+    without = devtrace.reduce_device(
+        {"ops": [e for e in ops if e not in loops],
+         "async": recorded["devices"][name]["async"]}, recorded["scopes"])
+    assert with_loops["busy_ns"] == without["busy_ns"]
+    assert with_loops["path_ns"] == without["path_ns"]
+    # a container spans its body: counted, each scan would be charged twice
+    assert sum(e[2] for e in loops) > with_loops["busy_ns"] / 2
+
+
+def test_device_report_names_idle_gaps(recorded):
+    from openembedding_tpu.utils import devtrace
+
+    rep = devtrace.reduce_events(recorded, scopes=recorded["scopes"])
+    (dev,) = rep["devices"].values()
+    gaps = dev["idle_gaps"]
+    assert gaps and gaps == sorted(gaps, key=lambda g: -g[1])
+    assert gaps[0][0] == recorded["longest_gap_under"]
+    # with no host plane the same gap is there, unattributed
+    bare = dict(recorded, host=[])
+    (dev2,) = devtrace.reduce_events(
+        bare, scopes=recorded["scopes"])["devices"].values()
+    assert dev2["idle_gaps"][0] == ["unattributed", gaps[0][1]]
+
+
+def test_trace_report_xplane_smoke(tmp_path, capsys):
+    """tools/trace_report.py --xplane on a CPU profile of a jitted fn: no
+    device plane there, and the tool says so instead of failing."""
+    import jax
+    import jax.numpy as jnp
+
+    trace_report = _load_tool("trace_report")
+    with jax.profiler.trace(str(tmp_path)):
+        jax.jit(lambda x: x * 2)(jnp.ones(4)).block_until_ready()
+    assert trace_report.main(["--xplane", str(tmp_path)]) == 0
+    assert "no device ops" in capsys.readouterr().out

@@ -189,10 +189,15 @@ def count_collectives(hlo_text: str) -> Dict[str, int]:
 # (resharding between mismatched in/out shardings) carry no such traced-op
 # tail: that absence is the detection signal for the silent-all-gather class.
 _OPNAME_RE = re.compile(r'op_name="([^"]+)"')
+# (a `trace.scope` lengthens the path in the MIDDLE —
+# `.../shard_map/trainer.metrics/psum` — the tail stays the primitive.)
+# The set is this JAX's collective primitives (`jax._src.lax.parallel`):
+# under shard_map's varying-axes check a psum lowers as `psum_invariant`.
 _EXPLICIT_TAILS = {
     "psum", "psum2", "pmean", "pmax", "pmin", "ppermute", "pbroadcast",
     "all_to_all", "all_gather", "all_gather_invariant", "reduce_scatter",
-    "psum_scatter",
+    "psum_scatter", "psum_invariant", "all_gather_reduced", "unreduced_psum",
+    "unreduced_reduce_scatter", "ragged_all_to_all", "pgather",
 }
 
 

@@ -75,6 +75,8 @@ INSTANCE_DIM = re.compile(
 # metrics API. A new key is a conscious act, like a new group.
 KNOWN_LABELS = {
     "component",  # memory ledger component (utils/memwatch.py)
+    "fn",         # traced trainer entry point (bounded enum: train_step /
+                  # train_many — `trainer.traces`)
     "hop",        # sync lineage hop (bounded enum: commit/publish/fetch/
                   # apply/swap/serve — sync/lineage.py HOP_ORDER)
     "instance",   # fleet-merge node id (metrics.merge_prometheus)

@@ -4,6 +4,7 @@
     python tools/trace_report.py /tmp/serving_trace.json --by name --sort p99
     python tools/trace_report.py http://127.0.0.1:8501/tracez
     python tools/trace_report.py sub_dump.json pub_dump.json --trace <rid>
+    python tools/trace_report.py --xplane /tmp/prof/train [--steps 16]
 
 Reads the Chrome-trace JSON the flight recorder exports (`utils/trace.py
 dump_chrome`, serving `--trace-dump`, examples `--trace-dump`) — or, given
@@ -21,12 +22,26 @@ process-qualified `span_uid`/`parent_uid` args and, ACROSS the HTTP
 boundary, by `remote_parent` (the caller's span uid the callee's root span
 recorded off the `X-OETPU-Trace` header), and printed as one indented
 cross-process tree.
+
+`--xplane DIR` reads a DEVICE profile instead (`jax.profiler.trace(DIR)`,
+`chip_smoke.py --profile`): the newest `*.xplane.pb` under DIR, reduced by the
+program's `trace.device_report` to, per device, busy and idle time, time per
+`trace.scope` stage (innermost name, and rolled up by the outermost), the
+unscoped remainder with its largest ops, `scoped_share`, and the longest idle
+gaps named by the host annotation (`oetpu.<group>.<name>` = a `trace.span`)
+over each. The ops' stages are joined in from every `*.hlo.txt` under DIR —
+the compiled programs' text, which `chip_smoke.py --profile` writes there (or
+`jitted.lower(...).compile().as_text()`); `--steps N` gives the train steps
+inside the trace where it carries no `StepTraceAnnotation`.
 """
 
 from __future__ import annotations
 
 import argparse
+import glob
 import json
+import os
+import sys
 from typing import Dict, List
 
 
@@ -158,11 +173,55 @@ def trace_tree(events: List[dict], request_id: str) -> List[str]:
     return lines
 
 
+def format_device_report(rep: dict) -> str:
+    """`trace.device_report`'s dict as text: one block per device."""
+    steps = rep.get("steps")
+    lines = []
+    for name, d in rep["devices"].items():
+        per = (lambda s: f"{s / steps * 1e3:10.4f}") if steps else \
+            (lambda s: " " * 10)
+        lines.append(
+            f"{name}: busy {d['busy_s']:.4f} s, idle {d['idle_s']:.4f} s, "
+            f"scoped_share {100 * d['scoped_share']:.2f}%"
+            + (f", {steps} steps of {d['step_ms']:.4f} ms" if steps else ""))
+        lines.append(f"  {'scope (innermost)':34s}{'seconds':>10s}"
+                     f"{'ms/step' if steps else '':>10s}{'% busy':>8s}")
+        for scope, s in d["scope_s"].items():
+            lines.append(f"  {scope:34s}{s:10.4f}{per(s)}"
+                         f"{100 * s / d['busy_s']:8.2f}")
+        lines.append(f"  {'(unscoped)':34s}{d['unscoped_s']:10.4f}"
+                     f"{per(d['unscoped_s'])}"
+                     f"{100 * d['unscoped_s'] / d['busy_s']:8.2f}")
+        for label, s in d["unscoped_top"]:
+            lines.append(f"      {label[:60]:60s}{s:10.4f}")
+        lines.append("  rolled up by outermost scope: " + ", ".join(
+            f"{k} {v:.4f}" for k, v in d["rollup_s"].items()))
+        lines.append("  classes: " + ", ".join(
+            f"{k} {v:.4f}" for k, v in d["class_s"].items())
+            + f", exposed collective {d['exposed_collective_s']:.4f}")
+        lines.append("  longest idle gaps: " + (", ".join(
+            f"{who} {s * 1e3:.3f} ms" for who, s in d["idle_gaps"])
+            or "none"))
+    return "\n".join(lines) if lines else "(no device ops in the trace)"
+
+
+def xplane_report(xplane_dir: str, steps=None) -> dict:
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from openembedding_tpu.utils import trace
+    scopes: Dict[str, str] = {}
+    for path in sorted(glob.glob(os.path.join(xplane_dir, "**", "*.hlo.txt"),
+                                 recursive=True)):
+        with open(path) as f:
+            scopes.update(trace.scope_map(f.read()))
+    return trace.device_report(xplane_dir, steps=steps, scopes=scopes)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="per-group latency table (or, with --trace, a stitched "
                     "cross-process span tree) from trace.dump_chrome() dumps")
-    ap.add_argument("dump", nargs="+",
+    ap.add_argument("dump", nargs="*",
                     help="Chrome-trace JSON path(s), or live node "
                          "http(s)://host:port[/tracez] URL(s)")
     ap.add_argument("--by", choices=("name", "group"), default="name",
@@ -173,7 +232,17 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", default=None, metavar="REQUEST_ID",
                     help="render ONE trace as a stitched cross-process span "
                          "tree instead of the latency table")
+    ap.add_argument("--xplane", default=None, metavar="DIR",
+                    help="reduce the device profile under DIR to time per "
+                         "trace.scope stage instead (module doc)")
+    ap.add_argument("--steps", type=int, default=None,
+                    help="with --xplane: train steps inside the trace")
     args = ap.parse_args(argv)
+    if args.xplane is not None:
+        print(format_device_report(xplane_report(args.xplane, args.steps)))
+        return 0
+    if not args.dump:
+        ap.error("give a dump (or --xplane DIR)")
     events: List[dict] = []
     for path in args.dump:
         events.extend(load_events(path))
