@@ -1011,9 +1011,10 @@ class Trainer:
             for k, v in metrics.get("stats", {}).items():
                 if k.endswith("_overflow"):
                     oflow = oflow + jnp.asarray(v).astype(jnp.int32)
-            return state, (metrics["loss"], oflow)
+            return state, (metrics["loss"], oflow,
+                           self._scan_stats(metrics.get("stats", {})))
 
-        state, (losses, oflows) = jax.lax.scan(body, state, batches)
+        state, (losses, oflows, kept) = jax.lax.scan(body, state, batches)
 
         if layouts:
             tables = dict(state.tables)
@@ -1027,7 +1028,18 @@ class Trainer:
         # "overflow": exchange-bucket drops summed over the window (the scan
         # returns no per-step stats; this one scalar is what capacity
         # governance needs — see MeshTrainer.check_overflow)
-        return state, {"loss": losses, "overflow": jnp.sum(oflows)}
+        return state, {"loss": losses, "overflow": jnp.sum(oflows),
+                       **self._window_stats(kept)}
+
+    def _scan_stats(self, stats) -> Dict:
+        """What a `train_many` window keeps of each step's stats beside the
+        overflow sum (the scan stacks it over the K steps), and
+        `_window_stats` what the window's metrics say of it: nothing here
+        (`MeshTrainer` keeps what the owner side of its exchange counted)."""
+        return {}
+
+    def _window_stats(self, kept) -> Dict:
+        return {}
 
     def jit_train_many(self):
         """Scan-fused multi-step driver (state DONATED, like jit_train_step)."""

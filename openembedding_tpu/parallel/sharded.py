@@ -41,10 +41,39 @@ on the grad push so AUC holds fp32 parity. Id buckets and duplicate-count
 lanes are always exact. `S == 1` specializes to identity routing (no
 collectives, no bucket scatters, no wire quantization).
 
-Static capacity: each (src, dst) bucket holds `capacity` ids. `capacity == n` is exact
-but moves S*n ids; real workloads set a capacity_factor so capacity ~ factor * n / S
-and watch the overflow counters (dropped ids pull zeros / drop grads — divergence from
-the reference's unbounded buffers, surfaced in metrics).
+Static capacity: each (src, dst) bucket of the WIRE holds `capacity` ids.
+`capacity == n` (exact mode, `capacity_factor=0`) can never drop an id and moves
+S*n id slots; a capacity_factor sizes the wire's buckets to ~ factor * n / S,
+with overflow counters to watch (dropped ids pull zeros / drop grads — divergence
+from the reference's unbounded buffers, surfaced in metrics). `capacity_factor`
+sizes the wire ONLY: what the owner then works over is the next paragraph's.
+
+WHAT THE OWNER WORKS OVER: the slots it received, not S x capacity. Gather,
+scatter and the segment sums pay per POSITION, valid or empty, and in exact
+mode at least 1 - 1/S of the S*n received slots are empty on average (83% in
+the four-chip benchmark cell). So after the id
+all_to_all the plan compacts the receive side once (`_owner_view` ->
+`ExchangePlan.owner`) to a static working size W = n, the device's own number
+of positions — what a balanced exchange delivers at most on average, and the
+size `Trainer` works at. `unique_and_route` gives a unique id the bucket slot
+"rank within its owner group", so the valid slots of every received bucket are
+a PREFIX; compaction is S contiguous block copies at the running offsets
+sum(r_<s) (`_compact`: each later block overwrites the empty tail of the one
+before), never a per-position scatter, and it keeps the received source-major
+order, so the owner's cross-source reduction adds in the same order and the
+result is the same bit for bit. The pull's serve (`_serve_rows`) and the push's
+apply (`_owner_apply`, which compacts the received grad payload with the same
+offsets) run over that view; served rows go back to the wire's bucket layout by
+S masked block copies (`_expand`). Exactness is kept: in a step where the ids
+received by a shard do not fit W (ids crowding one owner), that shard runs the
+SAME functions over all S * cap slots (a `lax.cond` on `OwnerView.fits`; per
+shard, so no collective sits inside). Where S * cap <= n already (S == 1, or a
+capacity_factor <= 1) nothing is compacted and no conditional is traced. The
+step stats count it (`exchange_load_stats`): `owner_fill` (received ids over
+W, the fullest shard) and `owner_full_steps`; on a device profile the block
+copies sit under `exchange.compact` and a full-size step's ops under
+`exchange.full_size`. The pipelined conflict patch (`grouped_conflict_patch`)
+indexes received slots by position and keeps the bucket layout.
 
 SIZING RULE for `capacity_factor` (f): bucket (src, dst) must hold the unique
 ids of src's batch slice owned by dst. With u unique ids per device batch of n
@@ -54,8 +83,10 @@ Uniform ids: p_max ~ 1/S, so f >= u/n (<= 1). Zipfian CTR traffic concentrates
 2-4x on hot shards after hashing -> start at f in [1, 2], watch
 `pull_overflow`/`push_overflow` in the step stats (psum'd per batch) and the
 table-level `overflow` counter, raise f while they fire. f = 0 (exact mode,
-cap = n) can never drop but moves S*n ids per a2a. Tested in
-`tests/test_capacity_and_migration.py`.
+cap = n) can never drop; it moves S*n id slots per a2a (0.38 ms of the
+four-chip step for all three wires, PERF.md), and the owner does not pay for
+the empty ones. Tested in `tests/test_capacity_and_migration.py` and
+`tests/test_owner_compact.py`.
 
 Out-of-vocab ids (array tables) are masked invalid end to end: they pull zeros and
 their gradients are dropped, identical to the single-device path (`ops/sparse.py`).
@@ -146,6 +177,26 @@ from .mesh import DATA_AXIS
 HOT_NUM_PROBES = 16
 
 
+class OwnerView(NamedTuple):
+    """The received ids of one plan compacted to the owner's working size W
+    (module doc "WHAT THE OWNER WORKS OVER"): the valid prefixes of the S
+    received buckets laid end to end in source-major order, EMPTY after. Made
+    once per plan, after the id all_to_all (`_owner_view`), and used by the
+    pull's serve and the push's apply of the same step."""
+
+    ids: jax.Array      # (W[, 2]) valid ids first, source-major; EMPTY after
+    valid: jax.Array    # (W,)
+    counts: jax.Array   # (S,) int32 r_s: valid ids received from source s
+    offsets: jax.Array  # (S,) int32 sum of r over the sources before s (<= W)
+    total: jax.Array    # () int32 sum of r_s
+
+    @property
+    def fits(self) -> jax.Array:
+        """() bool: the received ids fit W; False = this step works full
+        size."""
+        return self.total <= self.valid.shape[0]
+
+
 class ExchangePlan(NamedTuple):
     """The routing state shared between a pull and its matching push (reference: the
     cached request/offset maps inside the pull handler reused at apply_response and
@@ -170,6 +221,18 @@ class ExchangePlan(NamedTuple):
     # the wire — that `grouped_conflict_patch` replays so the patched rows
     # AND the post-patch residuals are bit-identical to the serial schedule
     ef_stash: Optional[jax.Array] = None
+    # the received ids at the owner's working size; None where the receive
+    # side is no larger than that (S == 1, or S * cap <= n) and the owner
+    # works over `recv_ids` as they are
+    owner: Optional[OwnerView] = None
+
+
+def _flat_recv(plan: ExchangePlan):
+    """The whole receive buffer as one flat run of slots: (S * cap[, 2]) ids
+    (split-pair buckets keep their lane dim) and their (S * cap,) validity."""
+    ids = plan.recv_ids
+    return (ids.reshape(-1, 2) if ids.ndim == 3 else ids.reshape(-1)), \
+        plan.recv_valid.reshape(-1)
 
 
 def _a2a(what: str, x: jax.Array, axis) -> jax.Array:
@@ -184,6 +247,60 @@ def _bucket_capacity(n: int, num_shards: int, capacity_factor: float) -> int:
     if capacity_factor <= 0:  # exact mode
         return n
     return max(1, min(n, int(-(-capacity_factor * n // num_shards))))
+
+
+def _compact(x: jax.Array, offsets: jax.Array, W: int, fill=0) -> jax.Array:
+    """(S, cap, ...) received buckets -> (W, ...): bucket s copied whole at
+    the running offset of the valid ids before it. A bucket's valid slots are
+    a PREFIX (`unique_and_route` gives a unique id the slot "rank within its
+    owner group"), so each later block overwrites exactly the empty tail of
+    the one before: S contiguous block copies, no per-position scatter, and
+    the source-major order of the valid slots is kept."""
+    with _trace.scope("exchange", "compact"):
+        S, cap = x.shape[:2]
+        # cap slots of slack: a block copied at offset <= W ends inside the
+        # buffer, so `dynamic_update_slice` never clamps its start
+        buf = jnp.broadcast_to(jnp.asarray(fill, x.dtype),
+                               (W + cap,) + x.shape[2:])
+        for s in range(S):
+            buf = jax.lax.dynamic_update_slice_in_dim(buf, x[s], offsets[s], 0)
+        return buf[:W]
+
+
+def _expand(y: jax.Array, view: OwnerView, cap: int) -> jax.Array:
+    """Inverse of `_compact` for what the owner serves: (W, ...) rows in
+    compact order -> (S, cap, ...) in the wire's bucket layout, zeros past
+    each bucket's r_s valid slots (what the full-size serve leaves there)."""
+    with _trace.scope("exchange", "compact"):
+        S = view.counts.shape[0]
+        pad = jnp.concatenate([y, jnp.zeros((cap,) + y.shape[1:], y.dtype)])
+        lane = jnp.arange(cap, dtype=jnp.int32).reshape(
+            (cap,) + (1,) * (y.ndim - 1))
+        return jnp.stack([
+            jnp.where(lane < view.counts[s],
+                      jax.lax.dynamic_slice_in_dim(pad, view.offsets[s], cap, 0),
+                      jnp.zeros((), y.dtype))
+            for s in range(S)])
+
+
+def _owner_view(recv_ids: jax.Array, recv_valid: jax.Array,
+                n: int) -> Optional[OwnerView]:
+    """The plan's `OwnerView` at working size W = n, the device's own number
+    of positions; None (trace time) where the S * cap received slots are no
+    more than that."""
+    S, cap = recv_valid.shape
+    if S * cap <= n:
+        return None
+    with _trace.scope("exchange", "compact"):
+        counts = jnp.sum(recv_valid, axis=1, dtype=jnp.int32)
+        ends = jnp.cumsum(counts)
+        offsets = jnp.minimum(ends - counts, n)
+        if recv_ids.ndim == 3:
+            from ..ops.id64 import PAIR_EMPTY as empty
+        else:
+            empty = -1
+        ids = _compact(recv_ids, offsets, n, fill=empty)
+        return OwnerView(ids, bucket_validity(ids), counts, offsets, ends[-1])
 
 
 def _id_valid(spec: EmbeddingSpec, ids: jax.Array) -> jax.Array:
@@ -341,8 +458,9 @@ def make_plan(spec: EmbeddingSpec, ids: jax.Array, *, axis: str = DATA_AXIS,
         # derives validity from the ids and no bool mask rides the wire]
         recv_ids = _a2a("ids", buckets.bucket_ids, axis)
         recv_valid = bucket_validity(recv_ids)
-        return ExchangePlan(uniq, buckets, recv_ids, recv_valid, cap, hot_slot,
-                            0 if hot is None else hot.weights.shape[0], moved)
+    return ExchangePlan(uniq, buckets, recv_ids, recv_valid, cap, hot_slot,
+                        0 if hot is None else hot.weights.shape[0], moved,
+                        owner=_owner_view(recv_ids, recv_valid, n))
 
 
 def _client_route(spec: EmbeddingSpec, flat: jax.Array, S: int,
@@ -412,10 +530,12 @@ def grouped_make_plans(specs, ids_list, *, axis: str = DATA_AXIS,
         templates = [(cap, b.bucket_ids.ndim == 3, b.bucket_ids.dtype)
                      for _, b, cap, _, _ in parts]
         segs = split_owner_buckets(recv, templates)
-        return [ExchangePlan(uniq, buckets, seg, bucket_validity(seg), cap, hs,
-                             0 if hot is None else hot.weights.shape[0], mv)
-                for (uniq, buckets, cap, hs, mv), seg, hot
-                in zip(parts, segs, hots)]
+        valids = [bucket_validity(seg) for seg in segs]
+    return [ExchangePlan(uniq, buckets, seg, valid, cap, hs,
+                         0 if hot is None else hot.weights.shape[0], mv,
+                         owner=_owner_view(seg, valid, uniq.order.shape[0]))
+            for (uniq, buckets, cap, hs, mv), seg, valid, hot
+            in zip(parts, segs, valids, hots)]
 
 
 def _flat_axis_index(axis) -> jax.Array:
@@ -449,6 +569,12 @@ def exchange_load_stats(plan: ExchangePlan, *, axis: str = DATA_AXIS
       a2a bucket (one-hot at this shard, so the psum assembles the
       per-source vector). The hash-routing bucket-occupancy/overflow
       predictor: raise `capacity_factor` while it nears 1.0.
+    - ``owner_fill[d]`` / ``owner_full_steps[d]`` — only where the owner
+      compacts what it receives (`plan.owner`): the valid ids shard *d*
+      received over its working size W, and 1 where they did not fit and the
+      shard took the full-size path this step (one-hot like `bucket_fill`).
+      Folded to `exchange.owner_fill{table=}` (the fullest shard) and the
+      `exchange.owner_full_steps{table=}` counter.
 
     `metrics.record_step_stats` folds these into labeled gauges
     (`exchange.shard_rows{table=,shard=}`) and the derived
@@ -467,9 +593,18 @@ def exchange_load_stats(plan: ExchangePlan, *, axis: str = DATA_AXIS
             w, plan.buckets.owner, num_segments=S + 1,
             indices_are_sorted=True)[:S].astype(jnp.int32)
         occ = routed.max().astype(jnp.float32) / float(max(plan.cap, 1))
-        fill = jnp.zeros((S,), jnp.float32).at[_flat_axis_index(axis)].set(occ)
-        return {"shard_rows": routed, "shard_positions": positions,
-                "bucket_fill": fill}
+        me = _flat_axis_index(axis)
+        fill = jnp.zeros((S,), jnp.float32).at[me].set(occ)
+        out = {"shard_rows": routed, "shard_positions": positions,
+               "bucket_fill": fill}
+        view = plan.owner
+        if view is not None:
+            W = view.valid.shape[0]
+            out["owner_fill"] = jnp.zeros((S,), jnp.float32).at[me].set(
+                view.total.astype(jnp.float32) / float(W))
+            out["owner_full_steps"] = jnp.zeros((S,), jnp.int32).at[me].set(
+                (~view.fits).astype(jnp.int32))
+        return out
 
 
 def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
@@ -495,88 +630,134 @@ def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
     `return_stash=True` (the pipelined prefetch) returns a third value: the
     PRE-serve residual gathered per recv slot ((S, cap, dim) f32; None when
     no EF ran) — `grouped_conflict_patch` replays it against the post-apply
-    weights to reproduce exactly what a serial serve would have shipped."""
+    weights to reproduce exactly what a serial serve would have shipped.
+
+    Where the plan holds an `OwnerView` the work (`_serve_flat`) runs over its
+    W compacted slots and the rows (and the stash) go back to the bucket
+    layout by S masked block copies (`_expand`); in a step whose received ids
+    do not fit, the same function runs over all S * cap slots (module doc
+    "WHAT THE OWNER WORKS OVER")."""
     with _trace.scope("exchange", "owner_serve"):
         S = jax.lax.axis_size(axis)
-        pair = plan.recv_ids.ndim == 3  # (S, cap, 2) split-pair buckets
-        flat_recv = (plan.recv_ids.reshape(-1, 2) if pair
-                     else plan.recv_ids.reshape(-1))
-        flat_valid = plan.recv_valid.reshape(-1)
-        need_ef = train and fmt != "fp32" and state.ef is not None
-        ef_idx = None
-        mig = state.mig
-        m_found = None
-        if mig is not None:
-            m_found, m_rank, _ = _mig_find(mig, flat_recv, flat_valid)
-            main_valid = flat_valid & ~m_found
+        view = plan.owner
+
+        def serve(ids, valid):
+            return _serve_flat(spec, state, ids, valid, S, train=train,
+                               fmt=fmt, return_stash=return_stash)
+
+        def full_size():
+            return serve(*_flat_recv(plan))
+
+        if view is None:
+            writes, rows, stash = full_size()
         else:
-            main_valid = flat_valid
-        if spec.use_hash_table:
-            if pair:
-                from ..ops.id64 import PAIR_EMPTY
-                probe = jnp.where(main_valid[:, None], flat_recv, PAIR_EMPTY)
-            else:
-                probe = jnp.where(main_valid, flat_recv, -1)
-            if train:
-                from ..tables.hash_table import hash_lookup_train
-                old_overflow = state.overflow
-                state, rows = hash_lookup_train(state, probe,
-                                                out_dim=spec.output_dim)
-                # overflow is replicated table-level state: psum the per-shard increment
-                delta = jax.lax.psum(state.overflow - old_overflow, axis)
-                state = state.replace(overflow=old_overflow + delta)
-                if need_ef:
-                    # post-insert probe: the residual lives at the row's slot
-                    # (invalid/annex positions probe EMPTY -> miss -> OOB index)
-                    from ..tables.hash_table import hash_find
-                    capacity = state.keys.shape[0]
-                    slot = hash_find(state.keys, probe)
-                    ef_idx = jnp.where(slot < capacity, slot, capacity)
-            else:
-                from ..tables.hash_table import hash_lookup
-                rows = hash_lookup(state, probe)
+            def compact():
+                writes, rows, stash = serve(view.ids, view.valid)
+
+                def back(y):
+                    return _expand(y, view, plan.cap).reshape(
+                        (-1,) + y.shape[1:])
+                return writes, back(rows), \
+                    None if stash is None else back(stash)
+            writes, rows, stash = jax.lax.cond(
+                view.fits, compact, _full_size_scope(full_size))
+        if spec.use_hash_table and train:
+            # overflow is replicated table-level state: psum the per-shard
+            # increment (out here: the branch taken above is per shard)
+            delta = jax.lax.psum(writes["overflow"] - state.overflow, axis)
+            writes["overflow"] = state.overflow + delta
+        state = state.replace(**writes)
+        rows = rows.reshape(S, plan.cap, -1)
+        if not return_stash:
+            return state, rows
+        return state, rows, (None if stash is None else
+                             stash.reshape(S, plan.cap, spec.output_dim))
+
+
+def _full_size_scope(fn):
+    """`fn` under the stage name `exchange.full_size`: on a device profile the
+    ops of a step that did not fit the working size sit under it, the compact
+    path's ops do not."""
+    def scoped():
+        with _trace.scope("exchange", "full_size"):
+            return fn()
+    return scoped
+
+
+def _serve_flat(spec: EmbeddingSpec, state: EmbeddingTableState,
+                flat_recv: jax.Array, flat_valid: jax.Array, S: int, *,
+                train: bool, fmt: str, return_stash: bool):
+    """`_serve_rows` over one flat run of received slots, whatever its length
+    (the compacted view or the whole receive buffer); no collective. ->
+    (the state fields the serve wrote: `keys`/`overflow` on a hash insert,
+    `ef` under error feedback; (m, width) rows in `fmt`; the (m, dim)
+    pre-serve residuals or None)."""
+    pair = flat_recv.ndim == 2  # split-pair ids
+    need_ef = train and fmt != "fp32" and state.ef is not None
+    ef_idx = None
+    writes = {}
+    mig = state.mig
+    m_found = None
+    if mig is not None:
+        m_found, m_rank, _ = _mig_find(mig, flat_recv, flat_valid)
+        main_valid = flat_valid & ~m_found
+    else:
+        main_valid = flat_valid
+    if spec.use_hash_table:
+        if pair:
+            from ..ops.id64 import PAIR_EMPTY
+            probe = jnp.where(main_valid[:, None], flat_recv, PAIR_EMPTY)
         else:
-            local_rows = jnp.where(main_valid, flat_recv // S, -1)
-            rows = lookup_rows(state.weights, local_rows)
-            if rows.shape[1] != spec.output_dim:
-                # packed weights+slots layout inside train_many's scan
-                # (`ops/sparse.packed_layout`): slice the weight columns out of
-                # the gathered packed rows — the gather is latency-bound, the
-                # slot bytes ride free
-                rows = rows[:, :spec.output_dim]
+            probe = jnp.where(main_valid, flat_recv, -1)
+        if train:
+            from ..tables.hash_table import hash_lookup_train
+            inserted, rows = hash_lookup_train(state, probe,
+                                               out_dim=spec.output_dim)
+            writes.update(keys=inserted.keys, overflow=inserted.overflow)
             if need_ef:
-                ef_idx = jnp.where(main_valid, flat_recv // S,
-                                   state.ef.shape[0]).astype(jnp.int32)
-        if m_found is not None:
-            M = mig.weights.shape[0]
-            arows = lookup_rows(mig.weights, jnp.where(m_found, m_rank, M))
-            rows = jnp.where(m_found[:, None], arows.astype(rows.dtype), rows)
-        stash = None
-        if fmt == "fp32":
-            if return_stash:
-                return state, rows.reshape(S, plan.cap, spec.output_dim), None
-            return state, rows.reshape(S, plan.cap, spec.output_dim)
-        # owner-edge encode: the pull a2a operand is already int8/bf16
-        from ..ops import wire as wire_mod
-        x = rows.astype(jnp.float32)
-        if need_ef:
-            # invalid/annex slots index OOB: the gather fills 0, the scatter
-            # drops. Duplicate recv slots (one id requested by several sources)
-            # gather the same w+ef and write the same residual — deterministic.
-            ef_prev = state.ef.at[ef_idx].get(mode="fill", fill_value=0) \
-                .astype(jnp.float32)
-            x = x + ef_prev
-            enc = wire_mod.pack_inband(x, fmt)
-            ef_new = x - wire_mod.unpack_inband(enc, spec.output_dim, fmt)
-            state = state.replace(ef=state.ef.at[ef_idx].set(
-                ef_new.astype(state.ef.dtype), mode="drop"))
-            if return_stash:
-                stash = ef_prev.reshape(S, plan.cap, spec.output_dim)
+                # post-insert probe: the residual lives at the row's slot
+                # (invalid/annex positions probe EMPTY -> miss -> OOB index)
+                from ..tables.hash_table import hash_find
+                capacity = inserted.keys.shape[0]
+                slot = hash_find(inserted.keys, probe)
+                ef_idx = jnp.where(slot < capacity, slot, capacity)
         else:
-            enc = wire_mod.pack_inband(x, fmt)
-        if return_stash:
-            return state, enc.reshape(S, plan.cap, -1), stash
-        return state, enc.reshape(S, plan.cap, -1)
+            from ..tables.hash_table import hash_lookup
+            rows = hash_lookup(state, probe)
+    else:
+        local_rows = jnp.where(main_valid, flat_recv // S, -1)
+        rows = lookup_rows(state.weights, local_rows)
+        if rows.shape[1] != spec.output_dim:
+            # packed weights+slots layout inside train_many's scan
+            # (`ops/sparse.packed_layout`): slice the weight columns out of
+            # the gathered packed rows — the gather is latency-bound, the
+            # slot bytes ride free
+            rows = rows[:, :spec.output_dim]
+        if need_ef:
+            ef_idx = jnp.where(main_valid, flat_recv // S,
+                               state.ef.shape[0]).astype(jnp.int32)
+    if m_found is not None:
+        M = mig.weights.shape[0]
+        arows = lookup_rows(mig.weights, jnp.where(m_found, m_rank, M))
+        rows = jnp.where(m_found[:, None], arows.astype(rows.dtype), rows)
+    if fmt == "fp32":
+        return writes, rows, None
+    # owner-edge encode: the pull a2a operand is already int8/bf16
+    from ..ops import wire as wire_mod
+    x = rows.astype(jnp.float32)
+    if not need_ef:
+        return writes, wire_mod.pack_inband(x, fmt), None
+    # invalid/annex slots index OOB: the gather fills 0, the scatter
+    # drops. Duplicate recv slots (one id requested by several sources)
+    # gather the same w+ef and write the same residual — deterministic.
+    ef_prev = state.ef.at[ef_idx].get(mode="fill", fill_value=0) \
+        .astype(jnp.float32)
+    x = x + ef_prev
+    enc = wire_mod.pack_inband(x, fmt)
+    ef_new = x - wire_mod.unpack_inband(enc, spec.output_dim, fmt)
+    writes["ef"] = state.ef.at[ef_idx].set(ef_new.astype(state.ef.dtype),
+                                           mode="drop")
+    return writes, enc, ef_prev if return_stash else None
 
 
 def _merge_hot_rows(plan: ExchangePlan, uniq_rows: jax.Array,
@@ -835,56 +1016,51 @@ def sharded_apply_gradients(
     new_hot = (None if plan.hot_slot is None or state.hot is None
                else _hot_apply(spec, optimizer, state.hot, plan, g, axis,
                                fmt=hot_fmt))
+    stats = {"push_overflow": buckets.overflow}
     if S == 1:
         # identity routing (see make_plan): the local unique slots ARE the
         # server's receive buffer — no bucket scatter, no grad/count a2a
-        rids = uniq.unique_ids
-        rg = g
-        rc = jnp.where(valid, uniq.counts, 0)
-    elif fmt == "fp32":
-        # scatter grads into the plan's bucket positions (payload follows its
-        # id), with the duplicate COUNT riding as extra payload lanes — the
-        # raw int32 bits BITCAST into the grad dtype (exact for any count, no
-        # upcast: one f32 lane, or two bf16 lanes). Folding the counts into
-        # the grad payload makes the push ONE all_to_all instead of two.
+        new_state = _apply_unique(spec, state, optimizer, uniq.unique_ids, g,
+                                  jnp.where(valid, uniq.counts, 0), S,
+                                  packed=packed)
+    else:
         counts_i32 = jnp.where(valid, uniq.counts, 0).astype(jnp.int32)
-        count_lanes = jax.lax.bitcast_convert_type(counts_i32, g.dtype)
-        count_lanes = count_lanes.reshape(counts_i32.shape[0], -1)
-        lanes = count_lanes.shape[1]
-        payload = jnp.concatenate([g, count_lanes], axis=1)
-        width = spec.output_dim + lanes
-        g_buckets = _scatter_buckets(payload, buckets, S, cap)
+        if fmt == "fp32":
+            # scatter grads into the plan's bucket positions (payload follows
+            # its id), with the duplicate COUNT riding as extra payload lanes
+            # — the raw int32 bits BITCAST into the grad dtype (exact for any
+            # count, no upcast: one f32 lane, or two bf16 lanes). Folding the
+            # counts into the grad payload makes the push ONE all_to_all
+            # instead of two.
+            count_lanes = jax.lax.bitcast_convert_type(counts_i32, g.dtype)
+            count_lanes = count_lanes.reshape(counts_i32.shape[0], -1)
+            lanes = count_lanes.shape[1]
+            payload = jnp.concatenate([g, count_lanes], axis=1)
 
-        recv = _a2a("grads", g_buckets, axis)
+            def decode(flat):
+                tail = flat[:, spec.output_dim:]
+                return flat[:, :spec.output_dim], \
+                    jax.lax.bitcast_convert_type(
+                        tail[:, 0] if lanes == 1 else tail,
+                        jnp.int32).reshape(-1)
+        else:
+            # narrow push: client-edge encode so the a2a operand is int8/bf16
+            # (counts still bit-exact in the trailing lanes; empty slots are
+            # zero bits -> grad 0, scale 0, count 0). int8 grads round with
+            # the deterministic hash dither — unbiased pushes, no residual
+            # needed on the client (the pull-side ef handles the row
+            # direction).
+            payload = wire_mod.encode_grads(g, counts_i32, fmt,
+                                            stochastic=(fmt == "int8"))
 
+            def decode(flat):
+                rg32, rc = wire_mod.decode_grads(flat, spec.output_dim, fmt)
+                return rg32.astype(g.dtype), rc
+        recv = _a2a("grads", _scatter_buckets(payload, buckets, S, cap), axis)
         # server side: cross-source re-dedup + fused optimizer (MPSC reduce
         # + update)
-        rids = (plan.recv_ids.reshape(-1, 2) if plan.recv_ids.ndim == 3
-                else plan.recv_ids.reshape(-1))
-        flat = recv.reshape(-1, width)
-        rg = flat[:, :spec.output_dim]
-        tail = flat[:, spec.output_dim:]
-        rc = jax.lax.bitcast_convert_type(
-            tail[:, 0] if lanes == 1 else tail, jnp.int32).reshape(-1)
-    else:
-        # narrow push: client-edge encode so the a2a operand is int8/bf16
-        # (counts still bit-exact in the trailing lanes; empty slots are
-        # zero bits -> grad 0, scale 0, count 0). int8 grads round with the
-        # deterministic hash dither — unbiased pushes, no residual needed
-        # on the client (the pull-side ef handles the row direction).
-        counts_i32 = jnp.where(valid, uniq.counts, 0).astype(jnp.int32)
-        payload = wire_mod.encode_grads(g, counts_i32, fmt,
-                                        stochastic=(fmt == "int8"))
-        g_buckets = _scatter_buckets(payload, buckets, S, cap)
-        recv = _a2a("grads", g_buckets, axis)
-        rids = (plan.recv_ids.reshape(-1, 2) if plan.recv_ids.ndim == 3
-                else plan.recv_ids.reshape(-1))
-        rg32, rc = wire_mod.decode_grads(
-            recv.reshape(-1, recv.shape[-1]), spec.output_dim, fmt)
-        rg = rg32.astype(g.dtype)
-    stats = {"push_overflow": buckets.overflow}
-    new_state = _apply_unique(spec, state, optimizer, rids, rg, rc, S,
-                              packed=packed)
+        new_state = _owner_apply(spec, state, optimizer, plan, recv, decode,
+                                 S, packed=packed)
     if new_hot is not None:
         new_state = new_state.replace(hot=new_hot)
     return new_state, stats
@@ -900,6 +1076,45 @@ def _scatter_buckets(payload: jax.Array, buckets: BucketResult, S: int,
                              buckets.owner * cap + buckets.slot, S * cap)
         return jnp.zeros((S * cap, width), payload.dtype).at[flat_pos].set(
             payload, mode="drop").reshape(S, cap, width)
+
+
+def _owner_apply(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
+                 plan: ExchangePlan, recv: jax.Array, decode, S: int,
+                 packed=None) -> EmbeddingTableState:
+    """Server-side tail of a push over what this shard RECEIVED: `recv` is
+    the (S, cap, width) grad payload as it left the all_to_all, `decode` maps
+    (m, width) payload rows to their (grads, exact counts). Where the plan
+    holds an `OwnerView` the payload is compacted like the ids were (the same
+    S block copies at the same offsets, so slot i of the view's ids meets its
+    own gradient) and decode + `_apply_unique` run over W slots; in a step
+    whose received ids do not fit they run over all S * cap. Either way the
+    valid slots keep their source-major order, so the cross-source reduction
+    adds in one order and the result is the same bit for bit."""
+    view = plan.owner
+
+    def apply(ids, payload):
+        rg, rc = decode(payload)
+        new = _apply_unique(spec, state, optimizer, ids, rg, rc, S,
+                            packed=packed)
+        return new.weights, new.slots, \
+            None if new.mig is None else (new.mig.weights, new.mig.slots)
+
+    def full_size():
+        return apply(_flat_recv(plan)[0], recv.reshape(-1, recv.shape[-1]))
+
+    if view is None:
+        weights, slots, annex = full_size()
+    else:
+        weights, slots, annex = jax.lax.cond(
+            view.fits,
+            lambda: apply(view.ids, _compact(recv, view.offsets,
+                                             view.valid.shape[0])),
+            _full_size_scope(full_size))
+    state = state.replace(weights=weights, slots=slots)
+    if annex is not None:
+        state = state.replace(mig=state.mig.replace(weights=annex[0],
+                                                    slots=annex[1]))
+    return state
 
 
 def _apply_unique(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
@@ -1137,18 +1352,17 @@ def grouped_apply_gradients(
         plan.buckets, S, plan.cap)
                 for plan, g, rc in zip(plans, gs, counts_list)]
     recv = _a2a("grads", jnp.concatenate(payloads, axis=1), axis)
-    width = recv.shape[-1]
     off = 0
     for spec, state, opt, plan, g, packed in zip(
             specs, states, optimizers, plans, gs, packed_list):
-        seg = recv[:, off:off + plan.cap].reshape(-1, width)
+        seg = recv[:, off:off + plan.cap]
         off += plan.cap
-        rg32, rc = wire_mod.decode_grads(seg, dim, fmt)
-        rids = (plan.recv_ids.reshape(-1, 2) if plan.recv_ids.ndim == 3
-                else plan.recv_ids.reshape(-1))
-        new_states.append(_apply_unique(
-            spec, state, opt, rids, rg32.astype(g.dtype), rc, S,
-            packed=packed))
+
+        def decode(flat, dtype=g.dtype):
+            rg32, rc = wire_mod.decode_grads(flat, dim, fmt)
+            return rg32.astype(dtype), rc
+        new_states.append(_owner_apply(spec, state, opt, plan, seg, decode,
+                                       S, packed=packed))
         stats_list.append({"push_overflow": plan.buckets.overflow})
     return new_states, stats_list
 
@@ -1179,7 +1393,7 @@ def plan_carry(plan: ExchangePlan) -> dict:
     return {"uniq": plan.uniq, "buckets": plan.buckets,
             "recv_ids": plan.recv_ids, "recv_valid": plan.recv_valid,
             "hot_slot": plan.hot_slot, "mig_moved": plan.mig_moved,
-            "ef_stash": plan.ef_stash}
+            "ef_stash": plan.ef_stash, "owner": plan.owner}
 
 
 def plan_from_carry(carry: dict, cap: int, hot_rows: int) -> ExchangePlan:
@@ -1187,7 +1401,8 @@ def plan_from_carry(carry: dict, cap: int, hot_rows: int) -> ExchangePlan:
     carried arrays with the trace-time static ints re-attached."""
     return ExchangePlan(carry["uniq"], carry["buckets"], carry["recv_ids"],
                         carry["recv_valid"], cap, carry["hot_slot"],
-                        hot_rows, carry["mig_moved"], carry["ef_stash"])
+                        hot_rows, carry["mig_moved"], carry["ef_stash"],
+                        carry["owner"])
 
 
 def conflict_patch_cap(cap: int, conflict_factor: float) -> int:

@@ -1574,6 +1574,29 @@ class MeshTrainer(Trainer):
             return super().train_many(state, batches)
         return self._train_many_pipelined(state, batches)
 
+    def _scan_stats(self, stats):
+        """A step's `owner_fill` / `owner_full_steps` (present where the
+        owner compacts what it receives, `sharded.exchange_load_stats`),
+        folded over the shards: the fullest shard's fill, and 1 where any
+        shard took the full-size path."""
+        kept = {}
+        for key, vec in stats.items():
+            table, _, stat = key.partition("/")
+            if stat in _metrics.OWNER_STATS:
+                kept.setdefault(stat, {})[table] = jnp.max(vec)
+        return kept
+
+    def _window_stats(self, kept):
+        """The stacked `_scan_stats` folded over a window's steps:
+        "owner_fill" {table: the fullest step} and "owner_full_steps"
+        {table: steps that took the full-size path}; both empty where no
+        table's receive side is compacted."""
+        return {
+            "owner_fill": {t: jnp.max(v) for t, v
+                           in kept.get("owner_fill", {}).items()},
+            "owner_full_steps": {t: jnp.sum(v) for t, v
+                                 in kept.get("owner_full_steps", {}).items()}}
+
     def _train_many_pipelined(self, state: TrainState, batches):
         """Prologue / steady-state / epilogue around `lax.scan`:
 
@@ -1649,7 +1672,12 @@ class MeshTrainer(Trainer):
         tables, plans0, rows0, pf_stats = self._pipeline_prefetch(
             state.tables, b0, ps_specs)
         state = state.replace(tables=tables)
-        total_oflow = jax.lax.psum(stats_overflow(pf_stats), self.axis)
+        # ... and what its owners counted, on the same psum
+        total_oflow, owner0 = jax.lax.psum(
+            (stats_overflow(pf_stats),
+             {k: v for k, v in pf_stats.items()
+              if k.partition("/")[2] in _metrics.OWNER_STATS}), self.axis)
+        kept = self._scan_stats(owner0)
         # static plan ints (cap, hot_rows) travel out of band — shapes are
         # uniform over the window, so the prologue's trace-time values hold
         statics = {n: (plans0[n].cap, plans0[n].hot_rows) for n in plans0}
@@ -1684,13 +1712,18 @@ class MeshTrainer(Trainer):
             oflow = stats_overflow(metrics.get("stats", {}))
             pre_n = {n: {"plan": plan_carry(plans_n[n]), "rows": patched[n]}
                      for n in plans_n}
-            return (state, pre_n), (metrics["loss"], oflow, conflict, coflow)
+            return (state, pre_n), (
+                metrics["loss"], oflow, conflict, coflow,
+                self._scan_stats(metrics.get("stats", {})))
 
         if K > 1:
             head = jax.tree_util.tree_map(lambda x: x[:-1], batches)
             nxt = jax.tree_util.tree_map(lambda x: x[1:], batches)
-            (state, pre), (losses, oflows, conflicts, coflows) = jax.lax.scan(
-                body, (state, pre0), (head, nxt))
+            (state, pre), (losses, oflows, conflicts, coflows, kepts) = \
+                jax.lax.scan(body, (state, pre0), (head, nxt))
+            kept = jax.tree_util.tree_map(
+                lambda first, rest: jnp.concatenate([first[None], rest]),
+                kept, kepts)
             total_oflow = total_oflow + jnp.sum(oflows)
             conflict = {n: jnp.sum(conflicts[n]) for n in conflicts}
             coflow = jnp.sum(coflows)
@@ -1722,27 +1755,40 @@ class MeshTrainer(Trainer):
                 tables[name] = ts.replace(weights=w, slots=slots)
             state = state.replace(tables=tables)
         return state, {"loss": losses, "overflow": total_oflow,
-                       "conflict": conflict, "conflict_overflow": coflow}
+                       "conflict": conflict, "conflict_overflow": coflow,
+                       **self._window_stats(kept)}
 
     def record_window_stats(self, metrics) -> None:
-        """Fold a train_many window's host-visible counters into gauges —
-        pipelined windows publish `exchange.conflict_rows{table=}` plus the
-        pcap-dropped `exchange.conflict_overflow`. ONE device_get per
-        window (the window-level sibling of `metrics.record_step_stats`);
-        no-op on serial windows."""
-        conflict = (metrics.get("conflict")
-                    if isinstance(metrics, dict) else None)
-        if not conflict:
+        """Fold a train_many window's host-visible counters into series:
+        where the owner compacts what it receives,
+        `exchange.owner_fill{table=}` (gauge: the window's fullest step on
+        its fullest shard) and `exchange.owner_full_steps{table=}` (counter:
+        steps that took the full-size path); pipelined windows publish
+        `exchange.conflict_rows{table=}` plus the pcap-dropped
+        `exchange.conflict_overflow`. ONE device_get per window (the
+        window-level sibling of `metrics.record_step_stats`); a no-op on a
+        window that holds none of them."""
+        if not isinstance(metrics, dict):
             return
-        import numpy as np
-        vals = jax.device_get(conflict)
-        for name, v in vals.items():
-            _metrics.observe("exchange.conflict_rows", float(np.asarray(v)),
-                             "gauge", labels={"table": name})
-        co = metrics.get("conflict_overflow")
-        if co is not None:
+        keys = ("owner_fill", "owner_full_steps", "conflict",
+                "conflict_overflow")
+        vals = {k: metrics[k] for k in keys
+                if jax.tree_util.tree_leaves(metrics.get(k))}
+        if not vals:
+            return
+        vals = jax.device_get(vals)
+        for name, v in vals.get("owner_fill", {}).items():
+            _metrics.observe("exchange.owner_fill", float(v), "gauge",
+                             labels={"table": name})
+        for name, v in vals.get("owner_full_steps", {}).items():
+            _metrics.observe("exchange.owner_full_steps", float(v), "sum",
+                             labels={"table": name})
+        for name, v in vals.get("conflict", {}).items():
+            _metrics.observe("exchange.conflict_rows", float(v), "gauge",
+                             labels={"table": name})
+        if "conflict_overflow" in vals:
             _metrics.observe("exchange.conflict_overflow",
-                             float(np.asarray(jax.device_get(co))), "gauge")
+                             float(vals["conflict_overflow"]), "gauge")
 
     def _observe_wire_cost(self, ps_specs, batch, *, pipelined=False):
         """Publish the static wire-cost model of the traced step (runs once
@@ -1929,7 +1975,8 @@ class MeshTrainer(Trainer):
         stacked_spec = jax.tree_util.tree_map(
             lambda p: P(None, *p), bspec, is_leaf=lambda x: isinstance(x, P))
 
-        metrics_spec = {"loss": P(), "overflow": P()}
+        metrics_spec = {"loss": P(), "overflow": P(),
+                        "owner_fill": P(), "owner_full_steps": P()}
         if self._pipeline_on():
             # the pipelined window reports two extra replicated counters;
             # the serial branch keeps EXACTLY the round-17 spec dict (the

@@ -263,8 +263,10 @@ def record_step_stats(stats: Dict[str, "object"]) -> Dict[str, "object"]:
     (S,) array folds into per-shard labeled gauges
     (`exchange.shard_rows{table=,shard=}`), `shard_positions` additionally
     derives the `exchange.shard_imbalance{table=}` histogram (max/mean over
-    shards — Parallax's access-skew number), and
-    `pull_unique`/`pull_indices` derive `exchange.unique_ratio{table=}`.
+    shards — Parallax's access-skew number),
+    `pull_unique`/`pull_indices` derive `exchange.unique_ratio{table=}`, and
+    `owner_fill`/`owner_full_steps` fold over the shards to
+    `exchange.owner_fill{table=}` and `exchange.owner_full_steps{table=}`.
 
     Hot-row replication stats (`{var}/hot_hits` / `hot_unique` /
     `hot_bytes_saved`, present when `MeshTrainer(hot_rows=...)` is on) derive
@@ -295,9 +297,10 @@ def record_step_stats(stats: Dict[str, "object"]) -> Dict[str, "object"]:
         table_stat = sep and "/" not in stat
         try:
             if np.ndim(value) >= 1:
-                if table_stat and stat in _SHARD_STATS:
-                    _fold_shard_stat(var, stat,
-                                     np.asarray(value, np.float64).reshape(-1))
+                if table_stat and stat in _SHARD_STATS + OWNER_STATS:
+                    fold = (_fold_owner_stat if stat in OWNER_STATS
+                            else _fold_shard_stat)
+                    fold(var, stat, np.asarray(value, np.float64).reshape(-1))
                     continue
                 if np.size(value) > 1:
                     continue  # unknown vector stat: nothing sane to fold
@@ -400,6 +403,9 @@ def _fold_health(per_table: Dict[str, Dict[str, float]],
 
 # per-shard vector stats emitted by `parallel/sharded.exchange_load_stats`
 _SHARD_STATS = ("shard_rows", "shard_positions", "bucket_fill")
+# ... and the two that fold over the shards to ONE series a table: how full
+# the fullest owner's working size was, and whether any owner overran it
+OWNER_STATS = ("owner_fill", "owner_full_steps")
 
 
 def _fold_shard_stat(var: str, stat: str, vec) -> None:
@@ -415,6 +421,19 @@ def _fold_shard_stat(var: str, stat: str, vec) -> None:
         if mean > 0:
             observe("exchange.shard_imbalance", float(vec.max()) / mean,
                     "hist", labels={"table": var})
+
+
+def _fold_owner_stat(var: str, stat: str, vec) -> None:
+    """The owner's two per-shard vectors fold over the shards to one series
+    a table: `exchange.owner_fill{table=}` (gauge, the fullest shard) and
+    `exchange.owner_full_steps{table=}` (counter: steps in which some shard
+    took the full-size path)."""
+    if stat == "owner_fill":
+        observe("exchange.owner_fill", float(vec.max()), "gauge",
+                labels={"table": var})
+    else:
+        observe("exchange.owner_full_steps", float(vec.max() > 0), "sum",
+                labels={"table": var})
 
 
 def report(reset: bool = False) -> Dict[str, float]:
