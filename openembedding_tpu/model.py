@@ -55,9 +55,29 @@ def binary_logloss(logits: jax.Array, labels: jax.Array,
 # Dense-path optimizer reuse: every dense leaf is a 1-row table.
 # ---------------------------------------------------------------------------
 
+# A dense leaf of this many elements or more keeps its optimizer slots IN ITS OWN
+# SHAPE and is updated in it. The historical layout, every leaf one row of
+# `size` columns, makes the update reshape weight, gradient and new weight
+# between the leaf's tiled layout and a (1, size) one: free for a 400 x 400
+# kernel, three passes over 160 MB for an (8, 2688, 1856) stack of experts
+# (68 ms of a step at 623M parameters, v5e). Smaller leaves keep (1, size),
+# which is what every checkpoint written so far holds.
+DENSE_LEAF_SHAPED_SLOTS = 1 << 20
+
+
+def _leaf_shaped(optimizer: SparseOptimizer, p) -> bool:
+    widths = optimizer.slot_shapes(p.size)
+    return (p.ndim >= 2 and p.size >= DENSE_LEAF_SHAPED_SLOTS and bool(widths)
+            and all(w == p.size for w in widths.values()))
+
+
 def init_dense_slots(optimizer: SparseOptimizer, params) -> Any:
-    return jax.tree_util.tree_map(
-        lambda p: optimizer.init_slots(1, p.size, p.dtype), params)
+    def init(p):
+        slots = optimizer.init_slots(1, p.size, p.dtype)
+        if _leaf_shaped(optimizer, p):
+            slots = {k: v.reshape(p.shape) for k, v in slots.items()}
+        return slots
+    return jax.tree_util.tree_map(init, params)
 
 
 def dense_apply(optimizer: SparseOptimizer, params, slots, grads) -> Tuple[Any, Any]:
@@ -67,9 +87,14 @@ def dense_apply(optimizer: SparseOptimizer, params, slots, grads) -> Tuple[Any, 
     ones = jnp.ones((1,), jnp.int32)
     new_params, new_slots = [], []
     for p, s, g in zip(leaves, slot_leaves, grad_leaves):
+        # one row of `size` columns, or (slots in the leaf's shape) its own
+        # leading dim as the rows: the update is elementwise either way
+        shape, counts = (1, p.size), ones
+        if {v.shape for v in s.values()} == {p.shape}:
+            shape, counts = p.shape, jnp.ones((p.shape[0],), jnp.int32)
         # optimizer math in f32 (see SparseOptimizer.init_slots) even for bf16 params
-        nw, ns = optimizer.apply(p.reshape(1, -1).astype(jnp.float32), s,
-                                 g.reshape(1, -1).astype(jnp.float32), ones)
+        nw, ns = optimizer.apply(p.reshape(shape).astype(jnp.float32), s,
+                                 g.reshape(shape).astype(jnp.float32), counts)
         new_params.append(nw.reshape(p.shape).astype(p.dtype))
         new_slots.append(ns)
     return (jax.tree_util.tree_unflatten(treedef, new_params),
@@ -644,21 +669,25 @@ class Trainer:
                 ids = jnp.asarray(batch["sparse"][spec.feature_name])
                 embedded[name] = combine(spec, ids, sad_rows(table, ids))
             attach_ids(embedded, model, batch)
+            fr_new, module_stats = None, {}
             if train_apply is not None:
                 logits, fr_new = train_apply({"params": dense_params},
                                              embedded, batch.get("dense"))
+            elif self._module_stats:
+                logits, module_stats = model.module.apply_with_stats(
+                    {"params": dense_params}, embedded, batch.get("dense"))
             else:
                 logits = model.module.apply({"params": dense_params},
                                             embedded, batch.get("dense"))
-                fr_new = None
-            return self._loss(logits, batch), (logits, fr_new)
+            return self._loss(logits, batch), (logits, fr_new, module_stats)
 
         # forward, combine, loss and backward: one stage (the backward's ops
         # read `transpose(jvp(dense.tower))`, which still holds the name)
         with _trace.scope("dense", "tower"):
-            (loss, (logits, fr_new)), (dense_grads, row_grads) = \
+            (loss, (logits, fr_new, module_stats)), (dense_grads, row_grads) = \
                 jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)(
                     tr0, pulled)
+        stats.update({"module/" + k: v for k, v in module_stats.items()})
 
         # sentinel reads the PRE-reduction dense grads: per-shard local
         # sumsq psums (via reduce_metrics) to one well-defined global
@@ -1031,15 +1060,37 @@ class Trainer:
         return state, {"loss": losses, "overflow": jnp.sum(oflows),
                        **self._window_stats(kept)}
 
+    @property
+    def _module_stats(self) -> Dict[str, str]:
+        """{stat: fold} a module counts a step (`module.window_stats`, with
+        `apply_with_stats` handing them over): `moe.pairs_here` and the like.
+        The fold ("avg", "max", "sum") is over a window's steps and is the
+        series' kind in `metrics.report()`."""
+        return dict(getattr(self.model.module, "window_stats", None) or ())
+
     def _scan_stats(self, stats) -> Dict:
         """What a `train_many` window keeps of each step's stats beside the
         overflow sum (the scan stacks it over the K steps), and
-        `_window_stats` what the window's metrics say of it: nothing here
-        (`MeshTrainer` keeps what the owner side of its exchange counted)."""
-        return {}
+        `_window_stats` what the window's metrics say of it: here the
+        module's own counters, under "module" (`MeshTrainer` keeps what the
+        owner side of its exchange counted)."""
+        return {k: stats["module/" + k] for k in self._module_stats}
 
     def _window_stats(self, kept) -> Dict:
-        return {}
+        fold = {"avg": jnp.mean, "max": jnp.max, "sum": jnp.sum}
+        out = {k: fold[how](kept[k])
+               for k, how in self._module_stats.items() if k in kept}
+        return {"module": out} if out else {}
+
+    def record_window_stats(self, metrics) -> None:
+        """Fold a `train_many` window's module counters into series named as
+        the module names them (`moe.pairs_here`, ...). ONE device_get per
+        window; a no-op on a window that holds none."""
+        vals = metrics.get("module") if isinstance(metrics, dict) else None
+        if not vals:
+            return
+        for name, v in jax.device_get(vals).items():
+            _metrics.observe(name, float(v), self._module_stats[name])
 
     def jit_train_many(self):
         """Scan-fused multi-step driver (state DONATED, like jit_train_step)."""

@@ -21,6 +21,7 @@ from .two_tower import TwoTower, make_two_tower, in_batch_softmax_loss
 from .sequential import (SASRec, bert4rec_mask_id, make_bert4rec,
                          make_sasrec, sasrec_bce_loss,
                          synthetic_masked_sequences, synthetic_sequences)
+from .nemotron_h import NemotronH, make_nemotron_h, softmax_xent
 
 _FAMILIES = {
     "lr": make_lr, "wdl": make_wdl, "deepfm": make_deepfm,
@@ -28,6 +29,7 @@ _FAMILIES = {
     "two_tower": make_two_tower,
     "sasrec": make_sasrec,
     "bert4rec": make_bert4rec,
+    "nemotron_h": make_nemotron_h,
 }
 
 
@@ -59,5 +61,6 @@ __all__ = [
     "TwoTower", "make_two_tower", "in_batch_softmax_loss",
     "SASRec", "make_sasrec", "sasrec_bce_loss", "synthetic_sequences",
     "make_bert4rec", "bert4rec_mask_id", "synthetic_masked_sequences",
+    "NemotronH", "make_nemotron_h", "softmax_xent",
     "CRITEO_NUM_SPARSE", "CRITEO_NUM_DENSE",
 ]

@@ -1,0 +1,600 @@
+"""Hybrid state-space / mixture-of-experts decoder (NemotronH: Nemotron-3-Nano).
+
+A decoder-only language model behind the SAME sparse path as every CTR model
+here: the token embedding is an `Embedding` (ids (B, S), row width = hidden)
+pulled, deduplicated and updated by `Trainer`'s table path; the flax module is
+the decoder stack, the final RMSNorm and the untied output head; `label` is the
+(B, S) next-token ids and the loss is mean softmax cross-entropy over the
+vocabulary held (`softmax_xent`). bf16 compute on f32 parameters; decays,
+softmax, router, logits and loss in f32.
+
+Every block is `x + mixer(RMSNorm(x))`; the pattern's letters pick the mixer:
+
+- `M`, Mamba-2: `in_proj` -> [z | xBC | dt]; depthwise causal conv (with bias)
+  and SiLU over xBC; x (heads x head_dim), B and C (groups x state);
+  dt = softplus(dt + dt_bias); A = -exp(A_log);
+  h_t = exp(dt A) h_{t-1} + dt B_t (x) x_t; y_t = C_t . h_t + D x_t, computed
+  chunk by chunk (`ssd_chunked`); y = grouped RMSNorm(y * SiLU(z)); `out_proj`.
+- `*`, grouped-query causal attention without a rotary embedding, computed
+  query block by query block over the keys a block can see
+  (`blockwise_causal_attention`: no S x S array).
+- `E`, routed experts: f32 router, s = sigmoid(logits), the top k of
+  s + correction bias (a buffer: no gradient reaches it), weights = chosen s
+  over their sum, times `routed_scaling_factor`; expert = down(relu(up(x))^2);
+  plus one shared expert of the same form, always on. The layer is TOLD WHICH
+  EXPERTS IT HOLDS (`experts_held`, `expert_offset`): it routes over all
+  `n_routed_experts` and adds only its own experts' terms; what absent experts
+  would add is left out, and that partial sum goes on to the next layer (one
+  chip's share of an expert-parallel deployment; on one chip there is no
+  exchange and nothing stands in for one). (token, choice) pairs are sorted
+  by expert, the pairs held here are compacted to a static working size by
+  the exchange's own owner view (`parallel/sharded._owner_view`: the same
+  sort / count / compact / `fits`), the experts run as one grouped matrix
+  product (`jax.lax.ragged_dot`) over that view, and a step whose pairs do
+  not fit runs the SAME function over all T x k pairs (`lax.cond`): no token
+  is dropped at any imbalance.
+
+Stage names (`utils/trace.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
+`attn.{qkv,core,out}`, `moe.{route,dispatch,experts,combine,shared}`,
+`lm.{head,loss}`. Counters: the module hands `Trainer` per-step `moe.*` stats
+(`apply_with_stats`, `window_stats`).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..embedding import Embedding
+from ..initializers import Normal
+from ..model import EmbeddingModel
+from ..utils import trace as _trace
+
+TOKEN = "token"
+NEG_INF = -1e30
+BLOCK_ROWS = 256  # rows of one expert's block in the routed layer's layout
+
+
+def softmax_xent(logits: jax.Array, labels: jax.Array, weight=None) -> jax.Array:
+    """Mean softmax cross-entropy of (B, S, V) f32 logits against (B, S) ids.
+    `weight` (B,) or (B, S) turns the mean into a weighted mean."""
+    with _trace.scope("lm", "loss"):
+        logits = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(
+            logits, labels.astype(jnp.int32)[..., None], axis=-1)[..., 0]
+        per = lse - picked
+        if weight is None:
+            return jnp.mean(per)
+        w = jnp.asarray(weight, per.dtype)
+        w = jnp.broadcast_to(w.reshape(w.shape + (1,) * (per.ndim - w.ndim)),
+                             per.shape)
+        return jnp.sum(per * w) / jnp.maximum(jnp.sum(w), 1.0)
+
+
+def rms_norm(x, scale, eps, groups: int = 1):
+    """RMSNorm in f32 over the last axis (over each of `groups` equal slices
+    of it), times `scale`; the input's dtype out."""
+    x32 = x.astype(jnp.float32)
+    g = x32.reshape(x32.shape[:-1] + (groups, -1))
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True) + eps)
+    return (g.reshape(x32.shape) * scale).astype(x.dtype)
+
+
+def _mm(spec, a, b, dtype):
+    """einsum with `dtype` inputs and f32 accumulation and result."""
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32):
+    """The Mamba-2 recurrence h_t = exp(dt_t A) h_{t-1} + dt_t B_t (x) x_t,
+    y_t = C_t . h_t, chunk by chunk (state-space duality: inside a chunk a
+    masked matrix product, between chunks the recurrence on chunk states).
+    x (Bt, L, H, P); dt (Bt, L, H) f32, already positive; A (H,) f32 negative;
+    B, C (Bt, L, G, N), H % G == 0. -> (Bt, L, H, P) f32. L need not be a
+    multiple of `chunk`: the tail is padded with dt = 0, which neither decays
+    nor feeds the state. Decays are f32; matrix products take `dtype` inputs."""
+    Bt, L, H, P = x.shape
+    G, N = B.shape[2:]
+    r = H // G
+    pad = (-L) % chunk
+    if pad:
+        x, dt, B, C = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                       for t in (x, dt, B, C))
+    c = (L + pad) // chunk
+    dt = dt.astype(jnp.float32).reshape(Bt, c, chunk, G, r)
+    xd = (x.astype(jnp.float32).reshape(Bt, c, chunk, G, r, P) * dt[..., None])
+    Bc = B.reshape(Bt, c, chunk, G, N)
+    Cc = C.reshape(Bt, c, chunk, G, N)
+    a = dt * A.astype(jnp.float32).reshape(G, r)
+    acs = jnp.cumsum(a, axis=2)                              # (Bt,c,Q,G,r)
+    # inside a chunk: y_l += sum_{s<=l} (C_l . B_s) exp(acs_l - acs_s) dt_s x_s
+    seg = acs[:, :, :, None] - acs[:, :, None, :]            # (Bt,c,l,s,G,r)
+    tri = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None, None]
+    decay = jnp.exp(jnp.where(tri, seg, -jnp.inf))
+    cb = _mm("bclgn,bcsgn->bclsg", Cc, Bc, dtype)
+    y = _mm("bclsgr,bcsgrp->bclgrp", cb[..., None] * decay, xd, dtype)
+    # what a chunk leaves behind: sum_s exp(acs_end - acs_s) dt_s B_s (x) x_s
+    left = jnp.exp(acs[:, :, -1:] - acs)                     # (Bt,c,Q,G,r)
+    states = _mm("bcsgn,bcsgrp->bcgrpn", Bc, xd * left[..., None], dtype)
+    # between chunks: the state entering chunk z is sum_{c<z} exp(sum of the
+    # chunk totals strictly between) states_c (a (c, c) lower-triangular product)
+    tot = acs[:, :, -1]                                      # (Bt,c,G,r)
+    run = jnp.cumsum(tot, axis=1)
+    between = (run - tot)[:, :, None] - run[:, None, :]      # (Bt,z,c,G,r)
+    low = jnp.tril(jnp.ones((c, c), bool), -1)[:, :, None, None]
+    carry = jnp.exp(jnp.where(low, between, -jnp.inf))
+    entering = _mm("bzcgr,bcgrpn->bzgrpn", carry, states, dtype)
+    y = y + _mm("bclgn,bcgrpn->bclgrp", Cc, entering, dtype) * \
+        jnp.exp(acs)[..., None]
+    return y.reshape(Bt, c * chunk, H, P)[:, :L]
+
+
+def blockwise_causal_attention(q, k, v, *, block: int = 512):
+    """Causal softmax attention, grouped-query: q (B, S, Hq, D), k and v
+    (B, S, Hkv, D), Hq % Hkv == 0; scale D^-1/2; softmax in f32. One block of
+    queries at a time against the keys it can see (keys [0, block end)), each
+    block rematerialised in the backward pass: nothing of size S x S is kept,
+    and the blocks above the diagonal are never computed."""
+    B, S, Hq, D = q.shape
+    Hkv = k.shape[2]
+    qg = q.reshape(B, S, Hkv, Hq // Hkv, D)
+    scale = 1.0 / math.sqrt(D)
+
+    @jax.checkpoint
+    def one(qb, kb, vb, lo):
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kb,
+                       preferred_element_type=jnp.float32) * scale
+        qpos = lo + jnp.arange(qb.shape[1])[:, None]
+        s = jnp.where(qpos >= jnp.arange(kb.shape[1])[None, :], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(vb.dtype)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", p, vb,
+                          preferred_element_type=jnp.float32).astype(qb.dtype)
+
+    out = [one(qg[:, lo:lo + block], k[:, :lo + block], v[:, :lo + block], lo)
+           for lo in range(0, S, block)]
+    return jnp.concatenate(out, axis=1).reshape(B, S, Hq, D)
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """A = -(1, 2, ..., H), a head (the HF Mamba-2 mixer's own start)."""
+    return jnp.log(jnp.arange(1, shape[0] + 1, dtype=dtype))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32, lo=1e-3, hi=1e-1):
+    """softplus^-1 of time steps spaced evenly in log between `lo` and `hi`
+    (the published `time_step_min` / `max`; a draw there, a ramp here)."""
+    dt = jnp.exp(jnp.linspace(math.log(lo), math.log(hi), shape[0], dtype=dtype))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class Mamba2Mixer(nn.Module):
+    hidden: int
+    num_heads: int
+    head_dim: int
+    n_groups: int
+    state: int
+    conv_kernel: int
+    chunk: int
+    eps: float
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        H, P, G, N, K = (self.num_heads, self.head_dim, self.n_groups,
+                         self.state, self.conv_kernel)
+        inner, bc = H * P, G * N
+        conv_dim = inner + 2 * bc
+        with _trace.scope("ssm", "in_proj"):
+            zxbcdt = nn.Dense(inner + conv_dim + H, use_bias=False,
+                              dtype=self.dtype, name="in_proj")(x)
+            z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], axis=-1)
+        with _trace.scope("ssm", "conv"):
+            w = self.param("conv_kernel", nn.initializers.normal(K ** -0.5),
+                           (K, conv_dim))
+            b = self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
+            S = xbc.shape[1]
+            padded = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+            acc = b.astype(jnp.float32)
+            for j in range(K):  # tap j reads position t - (K - 1) + j
+                acc = acc + padded[:, j:j + S].astype(jnp.float32) * w[j]
+            xbc = jax.nn.silu(acc).astype(self.dtype)
+            xs, Bm, Cm = jnp.split(xbc, [inner, inner + bc], axis=-1)
+        with _trace.scope("ssm", "scan"):
+            dt_bias = self.param("dt_bias", _dt_bias_init, (H,))
+            A_log = self.param("A_log", _a_log_init, (H,))
+            D = self.param("D", nn.initializers.ones, (H,))
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            xh = xs.reshape(xs.shape[:2] + (H, P))
+            y = ssd_chunked(xh, dt, -jnp.exp(A_log.astype(jnp.float32)),
+                            Bm.reshape(Bm.shape[:2] + (G, N)),
+                            Cm.reshape(Cm.shape[:2] + (G, N)),
+                            self.chunk, self.dtype)
+            y = y + xh.astype(jnp.float32) * D[:, None]
+            y = y.reshape(xs.shape)
+        with _trace.scope("ssm", "gate_norm"):
+            scale = self.param("norm_scale", nn.initializers.ones, (inner,))
+            y = y * jax.nn.silu(z.astype(jnp.float32))
+            y = rms_norm(y, scale, self.eps, groups=G).astype(self.dtype)
+        with _trace.scope("ssm", "out_proj"):
+            return nn.Dense(self.hidden, use_bias=False, dtype=self.dtype,
+                            name="out_proj")(y)
+
+
+class Attention(nn.Module):
+    hidden: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    block: int = 512
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        B, S, _ = x.shape
+        Hq, Hkv, D = self.num_heads, self.num_kv_heads, self.head_dim
+
+        def proj(name, heads):
+            y = nn.Dense(heads * D, use_bias=False, dtype=self.dtype,
+                         name=name)(x)
+            return y.reshape(B, S, heads, D)
+
+        with _trace.scope("attn", "qkv"):
+            q, k, v = proj("q_proj", Hq), proj("k_proj", Hkv), proj("v_proj", Hkv)
+        with _trace.scope("attn", "core"):
+            o = blockwise_causal_attention(q, k, v, block=self.block)
+        with _trace.scope("attn", "out"):
+            return nn.Dense(self.hidden, use_bias=False, dtype=self.dtype,
+                            name="o_proj")(o.reshape(B, S, Hq * D))
+
+
+def _relu2(h):
+    """relu(h)^2 in f32, handed on in the dtype it came in."""
+    return jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(h.dtype)
+
+
+def _relu2_mlp(x, up, down, dtype):
+    h = _relu2(jnp.dot(x, up.astype(dtype)))
+    return jnp.dot(h, down.astype(dtype), preferred_element_type=jnp.float32)
+
+
+class MoE(nn.Module):
+    hidden: int
+    n_routed_experts: int
+    top_k: int
+    expert_width: int
+    shared_width: int
+    experts_held: int
+    expert_offset: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    # static working size in (token, choice) pairs; 0 = twice what a balanced
+    # router sends the held experts, rounded up to a block (an untrained
+    # router sent the held experts 1.0-1.2 x the balanced load, the fullest
+    # of them 2.6-3.3 x the mean: v5e, PR 28; the split among the experts
+    # costs nothing, `run`)
+    working_pairs: int = 0
+    dtype: jnp.dtype = jnp.bfloat16
+
+    def _working_size(self, tokens: int) -> int:
+        if self.working_pairs:
+            return int(self.working_pairs)
+        mean = tokens * self.top_k * self.experts_held / self.n_routed_experts
+        return int(-(-2.0 * mean // BLOCK_ROWS) * BLOCK_ROWS)
+
+    @nn.compact
+    def __call__(self, x):
+        from ..parallel import sharded
+        B, S, D = x.shape
+        T, k, E = B * S, self.top_k, self.experts_held
+        xt = x.reshape(T, D)
+        init = nn.initializers.lecun_normal()
+        up = self.param("experts_up", init, (E, D, self.expert_width))
+        down = self.param("experts_down", init, (E, self.expert_width, D))
+
+        with _trace.scope("moe", "route"):
+            router = self.param("router_kernel", init,
+                                (D, self.n_routed_experts))
+            bias = self.param("router_correction_bias", nn.initializers.zeros,
+                              (self.n_routed_experts,))
+            logits = jnp.dot(xt.astype(jnp.float32), router,
+                             precision=jax.lax.Precision.HIGHEST)
+            score = jax.nn.sigmoid(logits)
+            _, chosen = jax.lax.top_k(score + jax.lax.stop_gradient(bias), k)
+            gate = jnp.take_along_axis(score, chosen, axis=-1)
+            if self.norm_topk_prob:
+                gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+            gate = (gate * self.routed_scaling_factor).reshape(T * k)
+
+        with _trace.scope("moe", "dispatch"):
+            # (token, choice) pairs sorted by held expert; pairs of experts
+            # held elsewhere sort last and are this layer's empty slots
+            local = chosen.reshape(T * k) - self.expert_offset
+            key = jnp.where((local >= 0) & (local < E), local, E)
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            held = key[order] < E
+            loads = jnp.sum(key[:, None] == jnp.arange(E)[None, :], axis=0,
+                            dtype=jnp.int32)
+            total = jnp.sum(loads, dtype=jnp.int32)
+            tokens = jnp.where(held, order // k, -1)[None]
+            view = sharded._owner_view(tokens, held[None],
+                                       self._working_size(T))
+
+        ends = jnp.cumsum(loads)
+        rows = BLOCK_ROWS
+
+        def run(tokens, valid, pairs, start=0):
+            """The held experts over one run of slots sorted by expert (the
+            slots [start, start + n) of the sorted order). Every expert's
+            slots are laid out from a block boundary (blocks of `rows` rows,
+            so n / rows + E blocks hold any split of the run among the
+            experts), each block takes its expert's weights, and up / relu^2 /
+            down are batched products over the blocks; then weigh and add to
+            the tokens. The cost is the same at any imbalance."""
+            n = tokens.shape[0]
+            blocks = -(-n // rows) + E
+            with _trace.scope("moe", "dispatch"):
+                first = jnp.clip(ends - loads - start, 0, n)     # in the run
+                size = jnp.clip(ends - start, 0, n) - first
+                took = -(-size // rows)                          # blocks each
+                upto = jnp.cumsum(took)
+                owner = jnp.minimum(jnp.searchsorted(
+                    upto, jnp.arange(blocks, dtype=jnp.int32), side="right"),
+                    E - 1).astype(jnp.int32)                      # (blocks,)
+                lane = jnp.arange(blocks * rows, dtype=jnp.int32).reshape(
+                    blocks, rows) - ((upto - took) * rows)[owner][:, None]
+                at = jnp.clip(first[owner][:, None] + lane, 0, n - 1)
+                ok = (lane >= 0) & (lane < size[owner][:, None]) & valid[at]
+                token = jnp.where(ok, tokens[at], T)
+                xs = xt[jnp.minimum(token, T - 1)]               # (blocks, rows, D)
+                pick = jax.nn.one_hot(owner, E, dtype=self.dtype)
+            with _trace.scope("moe", "experts"):
+                # a block's weights: its expert's, picked by a one-hot product
+                # (its transpose sums the blocks' gradients by expert)
+                up_b = jnp.einsum("be,edf->bdf", pick, up.astype(self.dtype))
+                down_b = jnp.einsum("be,efd->bfd", pick, down.astype(self.dtype))
+                h = _relu2(jnp.einsum("brd,bdf->brf", xs, up_b))
+                ys = jnp.einsum("brf,bfd->brd", h, down_b,
+                                preferred_element_type=jnp.float32)
+            with _trace.scope("moe", "combine"):
+                w = jnp.where(ok, gate[pairs[at]], 0.0).astype(jnp.float32)
+                out = jnp.zeros((T, D), jnp.float32).at[token].add(
+                    ys * w[..., None], mode="drop")
+            return out, jnp.sum(ok, dtype=jnp.int32)
+
+        def compact():
+            pairs = sharded._compact(order[None], view.offsets,
+                                     view.valid.shape[0])
+            return run(view.ids, view.valid, pairs)
+
+        def full_size():
+            """All T x k slots, a working size at a time through the same
+            `run`; each pass rematerialised, a pass with no held pair
+            skipped: the memory of the compact path at any load."""
+            n = view.valid.shape[0]
+            pad = (-(T * k)) % n
+            feed = (jnp.pad(tokens[0], (0, pad), constant_values=-1),
+                    jnp.pad(held, (0, pad)), jnp.pad(order, (0, pad)))
+
+            @jax.checkpoint  # keeps `start` alone; the pass is made again
+            def one(start):
+                tk, vd, pr = (jax.lax.dynamic_slice_in_dim(f, start, n)
+                              for f in feed)
+                return jax.lax.cond(
+                    vd[0], lambda: run(tk, vd, pr, start),
+                    lambda: (jnp.zeros((T, D), jnp.float32),
+                             jnp.zeros((), jnp.int32)))
+
+            def add(acc, start):
+                out, did = one(start)
+                return (acc[0] + out, acc[1] + did), None
+
+            zero = (jnp.zeros((T, D), jnp.float32), jnp.zeros((), jnp.int32))
+            return jax.lax.scan(add, zero, jnp.arange(
+                0, T * k + pad, n, dtype=jnp.int32))[0]
+
+        if view is None:  # the working size holds every pair there is
+            routed, done = run(tokens[0], held, order)
+            full = jnp.zeros((), jnp.int32)
+        else:
+            routed, done = jax.lax.cond(
+                view.fits, compact, sharded._full_size_scope(full_size))
+            full = (~view.fits).astype(jnp.int32)
+
+        with _trace.scope("moe", "shared"):
+            shared = _relu2_mlp(
+                xt, self.param("shared_up", init, (D, self.shared_width)),
+                self.param("shared_down", init, (self.shared_width, D)),
+                self.dtype)
+        stats = {"pairs_here": total.astype(jnp.float32),
+                 "load_max_over_mean": jnp.max(loads).astype(jnp.float32) * E
+                 / jnp.maximum(total, 1).astype(jnp.float32),
+                 "full_steps": full, "dropped": total - done}
+        return (routed + shared).reshape(B, S, D), stats
+
+
+@dataclasses.dataclass(frozen=True)
+class Dims:
+    """Every size of the stack, as the published config names them."""
+
+    hidden_size: int
+    mamba_num_heads: int
+    mamba_head_dim: int
+    n_groups: int
+    ssm_state_size: int
+    conv_kernel: int
+    chunk_size: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    n_routed_experts: int
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    moe_shared_expert_intermediate_size: int
+    experts_held: int
+    expert_offset: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    working_pairs: int
+    eps: float
+    attention_block: int
+
+
+class Block(nn.Module):
+    """x + mixer(RMSNorm(x)) -> (x, the mixer's step stats)."""
+
+    kind: str
+    dims: Dims
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.dims
+        scale = self.param("norm_scale", nn.initializers.ones, (c.hidden_size,))
+        h = rms_norm(x, scale, c.eps)
+        stats = {}
+        if self.kind == "M":
+            h = Mamba2Mixer(c.hidden_size, c.mamba_num_heads, c.mamba_head_dim,
+                            c.n_groups, c.ssm_state_size, c.conv_kernel,
+                            c.chunk_size, c.eps, self.dtype, name="mixer")(h)
+        elif self.kind == "*":
+            h = Attention(c.hidden_size, c.num_attention_heads,
+                          c.num_key_value_heads, c.head_dim,
+                          c.attention_block, self.dtype, name="mixer")(h)
+        elif self.kind == "E":
+            h, stats = MoE(c.hidden_size, c.n_routed_experts, c.num_experts_per_tok,
+                           c.moe_intermediate_size,
+                           c.moe_shared_expert_intermediate_size,
+                           c.experts_held, c.expert_offset,
+                           c.routed_scaling_factor, c.norm_topk_prob,
+                           c.working_pairs, self.dtype, name="mixer")(h)
+        else:
+            raise ValueError(f"unknown layer kind {self.kind!r} (M, E or *)")
+        return x + h.astype(x.dtype), stats
+
+
+def _keep_products(prim, *avals, **params):
+    """Remat policy: a layer keeps the outputs of its plain matrix
+    products (no batch dims) for its backward pass, but never a routed
+    block's weights picked by the one-hot product (a (blocks, E) x (E, D, F)
+    product: 300 MB a pick, made again from the weights in no time)."""
+    if (prim is jax.lax.dot_general_p and avals[0].ndim == 2
+            and avals[1].ndim == 3):
+        return False
+    return jax.checkpoint_policies.dots_with_no_batch_dims_saveable(
+        prim, *avals, **params)
+
+
+class NemotronH(nn.Module):
+    """The decoder stack over pulled token rows -> (B, S, vocabulary) f32
+    logits. `pattern` holds one letter a layer (M, E, *)."""
+
+    pattern: str
+    vocabulary: int
+    dims: Dims
+    compute_dtype: jnp.dtype = jnp.bfloat16
+
+    # per-step stats -> how a `train_many` window folds them (`Trainer`)
+    window_stats = (("moe.pairs_here", "avg"), ("moe.load_max_over_mean", "max"),
+                    ("moe.full_steps", "sum"), ("moe.dropped", "sum"))
+
+    @nn.compact
+    def __call__(self, embedded, dense_inputs=None, *, with_stats=False):
+        x = embedded[TOKEN].astype(self.compute_dtype)
+        # a layer keeps its input and its plain products' outputs for the
+        # backward pass and makes the rest again: 8,192 tokens x 9 layers of
+        # activations beside 8 GB of state (v5e: 13.7 GiB in all; 17.9 with
+        # everything kept)
+        block = nn.remat(Block, policy=_keep_products)
+        per_layer = []
+        for i, kind in enumerate(self.pattern):
+            x, stats = block(kind, self.dims, self.compute_dtype,
+                             name=f"layers_{i}")(x)
+            if stats:
+                per_layer.append(stats)
+        with _trace.scope("lm", "head"):
+            scale = self.param("norm_f_scale", nn.initializers.ones,
+                               (self.dims.hidden_size,))
+            x = rms_norm(x, scale, self.dims.eps)
+            head = self.param("lm_head", nn.initializers.lecun_normal(),
+                              (self.dims.hidden_size, self.vocabulary))
+            logits = jnp.dot(x, head.astype(self.compute_dtype),
+                             preferred_element_type=jnp.float32)
+        if not with_stats:
+            return logits
+        return logits, _fold_layers(per_layer)
+
+    def apply_with_stats(self, variables, embedded, dense_inputs=None):
+        """-> (logits, {stat name: scalar}): the step's `window_stats`."""
+        return self.apply(variables, embedded, dense_inputs, with_stats=True)
+
+
+def _fold_layers(per_layer):
+    """One step's `moe.*` over its expert layers: pairs a layer (mean), the
+    worst layer's load ratio, 1 if any layer ran full size, drops summed."""
+    if not per_layer:
+        return {}
+    col = {k: jnp.stack([s[k] for s in per_layer]) for k in per_layer[0]}
+    return {"moe.pairs_here": jnp.mean(col["pairs_here"]),
+            "moe.load_max_over_mean": jnp.max(col["load_max_over_mean"]),
+            "moe.full_steps": jnp.max(col["full_steps"]),
+            "moe.dropped": jnp.sum(col["dropped"])}
+
+
+def make_nemotron_h(vocabulary: int, hidden_size: int, pattern: str, *,
+                    mamba_num_heads: int, mamba_head_dim: int, n_groups: int,
+                    ssm_state_size: int, conv_kernel: int = 4,
+                    chunk_size: int = 128, num_attention_heads: int,
+                    num_key_value_heads: int, head_dim: int,
+                    n_routed_experts: int, num_experts_per_tok: int,
+                    moe_intermediate_size: int,
+                    moe_shared_expert_intermediate_size: int,
+                    experts_held: Optional[int] = None, expert_offset: int = 0,
+                    routed_scaling_factor: float = 1.0,
+                    norm_topk_prob: bool = True, working_pairs: int = 0,
+                    eps: float = 1e-5, attention_block: int = 512,
+                    optimizer=None,
+                    compute_dtype=jnp.bfloat16) -> EmbeddingModel:
+    """NemotronH language model as an `EmbeddingModel`. Batches:
+    {"sparse": {"token": (B, S) int32}, "label": (B, S) int32 next tokens}.
+    `experts_held` / `expert_offset`: the routed experts this program holds,
+    [offset, offset + held) of `n_routed_experts` (default: all of them);
+    `vocabulary`: the rows of the table and of the head held here."""
+    held = n_routed_experts if experts_held is None else experts_held
+    if not 0 < held <= n_routed_experts - expert_offset:
+        raise ValueError(f"experts [{expert_offset}, {expert_offset + held}) "
+                         f"are not among {n_routed_experts}")
+    if num_attention_heads % num_key_value_heads or mamba_num_heads % n_groups:
+        raise ValueError("query heads must divide by key/value heads and "
+                         "mamba heads by groups")
+    if set(pattern) - set("ME*"):
+        raise ValueError(f"pattern {pattern!r}: one of M, E, * a layer")
+    dims = Dims(
+        hidden_size=hidden_size, mamba_num_heads=mamba_num_heads,
+        mamba_head_dim=mamba_head_dim, n_groups=n_groups,
+        ssm_state_size=ssm_state_size, conv_kernel=conv_kernel,
+        chunk_size=chunk_size, num_attention_heads=num_attention_heads,
+        num_key_value_heads=num_key_value_heads, head_dim=head_dim,
+        n_routed_experts=n_routed_experts,
+        num_experts_per_tok=num_experts_per_tok,
+        moe_intermediate_size=moe_intermediate_size,
+        moe_shared_expert_intermediate_size=moe_shared_expert_intermediate_size,
+        experts_held=held, expert_offset=expert_offset,
+        routed_scaling_factor=routed_scaling_factor,
+        norm_topk_prob=norm_topk_prob, working_pairs=working_pairs, eps=eps,
+        attention_block=attention_block)
+    module = NemotronH(pattern=pattern, vocabulary=vocabulary, dims=dims,
+                       compute_dtype=compute_dtype)
+    emb = Embedding(vocabulary, hidden_size, name=TOKEN,
+                    embeddings_initializer=Normal(stddev=1.0),
+                    optimizer=optimizer)
+    config = dict(family="nemotron_h", vocabulary=vocabulary, pattern=pattern,
+                  compute_dtype=jnp.dtype(compute_dtype).name,
+                  **dataclasses.asdict(dims))
+    return EmbeddingModel(module, [emb], loss_fn=softmax_xent, config=config)

@@ -30,7 +30,7 @@ from it carries the names of whichever build wrote the entry — none at all if
 that build predates the scopes. `chip_smoke.py --profile` compiles its text
 past the cache for that reason (`fresh_hlo_text`).
 
-A scope is a `<layer>.<stage>` token of the vocabulary's four layers found in
+A scope is a `<layer>.<stage>` token of the vocabulary's layers (`LAYERS`) found in
 the path, through `jvp(...)`/`transpose(...)` wrappers. Scopes nest
 (`exchange.owner_apply/sparse.apply`): an op is keyed by its whole chain
 (`path_s`), by its innermost token (`scope_s`: "the apply, wherever it runs")
@@ -57,7 +57,7 @@ import os
 import re
 from typing import Dict, Iterable, List, Mapping, Optional, Tuple
 
-LAYERS = ("sparse", "exchange", "dense", "trainer")
+LAYERS = ("sparse", "exchange", "dense", "trainer", "ssm", "attn", "moe", "lm")
 CONTAINERS = {"while", "conditional", "call"}
 COLLECTIVES = ("all-to-all", "all-reduce", "all-gather", "reduce-scatter",
                "collective-permute", "collective-broadcast",
@@ -91,17 +91,18 @@ def scope_map(compiled_or_hlo_text) -> Dict[str, str]:
     `op_name`, joined by "/"; "" for an instruction no scope covers. The
     innermost scope is `path.rsplit("/", 1)[-1]`.
 
-    One rule beyond the metadata: a `copy` the compiler put in for a layout
-    (it has no scope of its own: it copies a parameter, or a loop's result)
-    takes the scope of the instructions that consume it, where they agree —
-    the table-sized copies in front of `sparse.pack` and `sparse.unpack`
-    exist because of those stages."""
+    One rule beyond the metadata: an instruction the compiler made itself (it
+    carries no `op_name` at all: a layout `copy`, the two halves of an async
+    copy or slice, a hoisted convert or mask) or a `copy` with no scope of
+    its own takes the scope of the instructions that consume it, where they
+    agree, through chains of such instructions — the table-sized copies in
+    front of `sparse.pack` and `sparse.unpack` exist because of those stages."""
     text = compiled_or_hlo_text
     if not isinstance(text, str):
         text = text.as_text()
     out: Dict[str, str] = {}
     users: Dict[str, List[str]] = {}
-    copies = []
+    made = []
     for line in text.splitlines():
         m = _INSTR.match(line)
         if m is None:
@@ -112,12 +113,19 @@ def scope_map(compiled_or_hlo_text) -> Dict[str, str]:
         body = line[m.end():].split(", metadata=", 1)[0]
         for operand in _OPERAND.findall(body):
             users.setdefault(operand, []).append(name)
-        if out[name] == UNSCOPED and opcode_of(line) == "copy":
-            copies.append(name)
-    for name in copies:
-        paths = {out[u] for u in users.get(name, [])}
-        if len(paths) == 1:
-            out[name] = paths.pop()
+        if out[name] == UNSCOPED and (meta is None or opcode_of(line) == "copy"):
+            made.append(name)
+    for _ in range(4):  # a chain is copy-start -> copy-done -> bitcast -> user
+        left = []
+        for name in made:
+            paths = {out[u] for u in users.get(name, [])}
+            if len(paths) == 1 and UNSCOPED not in paths:
+                out[name] = paths.pop()
+            else:
+                left.append(name)
+        if len(left) == len(made):
+            break
+        made = left
     return out
 
 

@@ -57,7 +57,10 @@ The stage vocabulary (`<layer>.<stage>`; README "Observability"):
 `sparse.{dedup,pull,reduce,apply,pack,unpack}`, `exchange.{route,wire,
 a2a_ids,a2a_rows,a2a_grads,owner_serve,owner_apply,reassemble,stats}`,
 `dense.{tower,reduce,update,gather}`, `trainer.{metrics,prefetch,
-conflict_patch,sentinel}`.
+conflict_patch,sentinel}`; and inside `dense.tower` a language-model tower's
+own (`models/nemotron_h.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
+`attn.{qkv,core,out}`, `moe.{route,dispatch,experts,combine,shared}`,
+`lm.{head,loss}`.
 """
 
 from __future__ import annotations
