@@ -402,18 +402,23 @@ def serve_rows(spec: EmbeddingSpec, ids, lookup_fn) -> jax.Array:
 
 def apply_gradients(spec: EmbeddingSpec, state: EmbeddingTableState,
                     optimizer: SparseOptimizer, ids: jax.Array,
-                    grads: jax.Array) -> EmbeddingTableState:
+                    grads: jax.Array, *, with_load: bool = False):
     """Single-shard push+update fused: duplicate grads summed, optimizer applied once
     per unique id (reference: push `EmbeddingPushOperator.cpp` + store
-    `EmbeddingStoreOperator.cpp` collapsed into one step — SPMD needs no batch gate)."""
+    `EmbeddingStoreOperator.cpp` collapsed into one step — SPMD needs no batch gate).
+    -> the new table state; `with_load` -> (state, the step's apply load:
+    `ops/sparse.py` "WHAT THE APPLY WORKS OVER")."""
     flat_ids, _ = _flat_ids(spec, ids)
     flat_grads = grads.reshape(-1, spec.output_dim)
     if spec.use_hash_table:
         from .tables.hash_table import hash_apply_gradients
-        return hash_apply_gradients(state, optimizer, flat_ids, flat_grads)
-    weights, slots = sparse_apply_dense_table(
-        optimizer, state.weights, state.slots, flat_ids, flat_grads)
-    return state.replace(weights=weights, slots=slots)
+        return hash_apply_gradients(state, optimizer, flat_ids, flat_grads,
+                                    with_load=with_load)
+    weights, slots, load = sparse_apply_dense_table(
+        optimizer, state.weights, state.slots, flat_ids, flat_grads,
+        with_load=True)
+    state = state.replace(weights=weights, slots=slots)
+    return (state, load) if with_load else state
 
 
 class Embedding:

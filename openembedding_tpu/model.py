@@ -211,6 +211,42 @@ class EmbeddingModel:
         return self._dim_groups
 
 
+def _table_stats(stats, names) -> Dict[str, Dict[str, jax.Array]]:
+    """{stat: {table: value}} of a step's `{table}/{stat}` keys named in
+    `names`; a mesh's per-shard vector folds to its largest entry (the
+    fullest shard; 1 where any shard ran full size)."""
+    kept: Dict[str, Dict[str, jax.Array]] = {}
+    for key, v in stats.items():
+        table, _, stat = key.partition("/")
+        if stat in names:
+            kept.setdefault(stat, {})[table] = jnp.max(v)
+    return kept
+
+
+def _fold_table_stats(kept, names) -> Dict[str, Dict[str, jax.Array]]:
+    """`_table_stats` stacked over a window's steps -> the fullest step of a
+    `*_fill`, the count of steps of a `*_full_steps`; a name no table
+    reported reads {}."""
+    return {stat: {t: (jnp.max if _metrics.is_fill(stat) else jnp.sum)(v)
+                   for t, v in kept.get(stat, {}).items()}
+            for stat in names}
+
+
+def _window_values(metrics, keys) -> Dict:
+    """The entries of a window's metrics under `keys` that hold anything, on
+    the host (ONE device_get)."""
+    if not isinstance(metrics, dict):
+        return {}
+    return jax.device_get({k: metrics[k] for k in keys
+                           if jax.tree_util.tree_leaves(metrics.get(k))})
+
+
+def _observe_table_stats(vals) -> None:
+    for stat, by_table in vals.items():
+        for table, v in by_table.items():
+            _metrics.observe_table_stat(table, stat, v)
+
+
 class Trainer:
     """Builds jitted train/eval steps for an EmbeddingModel on one device.
 
@@ -911,7 +947,7 @@ class Trainer:
         """-> (new_table, stats)."""
         with _trace.scope("sparse", "apply"):
             return apply_gradients(spec, table, self.opt_for(spec), ids,
-                                   grads), {}
+                                   grads, with_load=True)
 
     def table_lookup(self, spec, table, ids):
         return lookup(spec, table, ids)
@@ -987,11 +1023,11 @@ class Trainer:
                 from .tables.hash_table import hash_apply_gradients_packed
                 return hash_apply_gradients_packed(
                     table, self.opt_for(spec), flat_ids, flat_grads, layout,
-                    spec.output_dim), {}
-            packed = sparse_apply_packed_table(
+                    spec.output_dim)
+            packed, load = sparse_apply_packed_table(
                 self.opt_for(spec), table.weights, layout, spec.output_dim,
                 flat_ids, flat_grads)
-            return table.replace(weights=packed), {}
+            return table.replace(weights=packed), load
 
     def train_many(self, state: TrainState, batches) -> Tuple[TrainState, Dict]:
         """K steps in ONE compiled program via lax.scan over stacked batches
@@ -1071,26 +1107,34 @@ class Trainer:
     def _scan_stats(self, stats) -> Dict:
         """What a `train_many` window keeps of each step's stats beside the
         overflow sum (the scan stacks it over the K steps), and
-        `_window_stats` what the window's metrics say of it: here the
-        module's own counters, under "module" (`MeshTrainer` keeps what the
-        owner side of its exchange counted)."""
-        return {k: stats["module/" + k] for k in self._module_stats}
+        `_window_stats` what the window's metrics say of it: each table's
+        apply load (`ops/sparse.py` "WHAT THE APPLY WORKS OVER") as
+        "apply_fill" {table: the fullest step} and "apply_full_steps"
+        {table: steps on the last rung}, and the module's own counters under
+        "module" (`MeshTrainer` adds what the owner side of its exchange
+        counted)."""
+        return {**{k: stats["module/" + k] for k in self._module_stats},
+                **_table_stats(stats, _metrics.APPLY_STATS)}
 
     def _window_stats(self, kept) -> Dict:
         fold = {"avg": jnp.mean, "max": jnp.max, "sum": jnp.sum}
-        out = {k: fold[how](kept[k])
-               for k, how in self._module_stats.items() if k in kept}
-        return {"module": out} if out else {}
+        out = _fold_table_stats(kept, _metrics.APPLY_STATS)
+        module = {k: fold[how](kept[k])
+                  for k, how in self._module_stats.items() if k in kept}
+        if module:
+            out["module"] = module
+        return out
 
     def record_window_stats(self, metrics) -> None:
-        """Fold a `train_many` window's module counters into series named as
-        the module names them (`moe.pairs_here`, ...). ONE device_get per
-        window; a no-op on a window that holds none."""
-        vals = metrics.get("module") if isinstance(metrics, dict) else None
-        if not vals:
-            return
-        for name, v in jax.device_get(vals).items():
+        """Fold a `train_many` window's counters into series: each table's
+        `sparse.apply_fill{table=}` (gauge) and
+        `sparse.apply_full_steps{table=}` (counter), and the module's own
+        named as the module names them (`moe.pairs_here`, ...). ONE
+        device_get per window; a no-op on a window that holds none."""
+        vals = _window_values(metrics, ("module",) + _metrics.APPLY_STATS)
+        for name, v in vals.pop("module", {}).items():
             _metrics.observe(name, float(v), self._module_stats[name])
+        _observe_table_stats(vals)
 
     def jit_train_many(self):
         """Scan-fused multi-step driver (state DONATED, like jit_train_step)."""

@@ -12,6 +12,7 @@ scattered with out-of-bounds indices and `mode='drop'`, so they can never corrup
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Dict, Tuple
 
@@ -66,8 +67,7 @@ def scatter_rows(weights: jax.Array, rows: jax.Array, values: jax.Array,
     `valid=None` means `rows` is already fully routed (invalid entries already
     carry out-of-bounds indices). `sorted_unique`: rows genuinely ascending and
     duplicate-free — TPU scatters serialize without these hints; this is the
-    difference between a vectorized update and a 106k-iteration row loop (see
-    tools/step_bisect.py measurements)."""
+    difference between a vectorized update and a row loop over every slot."""
     n_rows = weights.shape[0]
     if valid is None:
         target = rows
@@ -82,14 +82,21 @@ def scatter_rows(weights: jax.Array, rows: jax.Array, values: jax.Array,
 # packed table layout (weights + optimizer slots in ONE array)
 # ---------------------------------------------------------------------------
 #
-# The fused apply is HBM-LATENCY-bound: each gather/scatter pair over k unique
-# rows costs ~147 ns/row regardless of row width (PERF.md). Storing weights
-# and slots separately pays one pair PER ARRAY (Adagrad: 2 pairs = ~27 ms for
-# 106k rows on v5e); concatenating them column-wise into one (rows, dim+Σslot)
-# array pays ONE pair (~19 ms measured, 1.44x). The packed form only exists
-# inside `Trainer.train_many`'s scan (pack at entry, unpack at exit, amortized
-# over K steps) so checkpoints, serving, offload and the sharded protocol all
-# keep the split layout.
+# The fused apply is LATENCY-bound: gather and scatter pay per SLOT of the
+# unique buffer, hardly per byte. On the v5e (PERF.md sections 5-6; PR 29's
+# probe on the 2^25 x 20 packed table): the scatter 11.2 / 8.5 / 5.7 ms over
+# 106,496 / 79,872 / 53,248 slots = 105 ns a slot, the sorted gather 3.0 / 2.2
+# / 1.4 ms = 28 ns a slot, padding slots routed out of bounds included. At
+# packed width 128 (2^22 rows) the scatter moves 6.5x the bytes in 0.6x the
+# time and pays per VALID row: 6.8 ms over 106,496 slots, 6.6 over 79,872, the
+# same ~72k rows in both; gather and row math still pay per slot. Storing
+# weights and slots separately pays one gather/scatter pair PER ARRAY;
+# concatenating them column-wise into one (rows, dim+Σslot) array pays ONE
+# pair. The packed form
+# only exists inside `Trainer.train_many`'s scan (pack at entry, unpack at
+# exit, amortized over K steps) so checkpoints, serving, offload and the
+# sharded protocol all keep the split layout. What the pair runs over is
+# "WHAT THE APPLY WORKS OVER", further down.
 #
 # Width gate: XLA's gather for 32 < width < 128 materializes a 128-lane-padded
 # 2.0x temp copy of the WHOLE table every scan iteration (measured via
@@ -168,8 +175,9 @@ def _dedup_routed(n_rows: int, row_ids: jax.Array, grads: jax.Array,
     - every invalid unique slot i maps to the DISTINCT out-of-bounds row
       n_rows + i, so `idx` is genuinely ascending and duplicate-free — the
       indices_are_sorted/unique_indices promises hold exactly and XLA emits
-      the vectorized gather/scatter instead of a serialized row loop (the
-      difference between 25 ms and sub-ms on v5e; tools/step_bisect.py)."""
+      the vectorized gather/scatter instead of a serialized row loop;
+    - invalid ids sort LAST under that key, so the valid unique slots are a
+      prefix of (`g`, `counts`, `idx`): what `_over_unique_prefix` cuts."""
     n = row_ids.shape[0]
     if pre_counts is None:
         pre_counts = jnp.ones((n,), jnp.int32)
@@ -186,6 +194,92 @@ def _dedup_routed(n_rows: int, row_ids: jax.Array, grads: jax.Array,
     return g, counts, idx
 
 
+# ---------------------------------------------------------------------------
+# WHAT THE APPLY WORKS OVER: the unique rows it has, not every position.
+#
+# `_dedup_routed` dedups n positions into a buffer of n slots (static shapes),
+# and the gather of the rows to update, the optimizer's row math and the
+# scatter back all pay per SLOT, whether the slot holds a row or is padding
+# routed out of bounds and dropped (PERF.md section 5; PR 27 measured the same
+# scatter at 42.0 ms over 425,984 slots and 11.0 ms over 106,496 with the same
+# ~70k rows in it; only the scatter of rows 128 wide and wider gets padding
+# nearly free). Under Zipf traffic a third of the slots are padding.
+#
+# - PREFIX: invalid ids sort last (key `n_rows`), so the valid unique rows are
+#   the first `n_valid = sum(counts > 0)` slots of (`idx`, `g`, `counts`) and
+#   cutting the buffer to a working size W >= n_valid is the static slice
+#   `[:W]`: no copy, no permutation. Padding slots inside the slice keep their
+#   distinct out-of-bounds rows, so the sorted/unique promises hold exactly.
+# - LADDER: W is the smallest rung of `apply_ladder(n)` that holds n_valid,
+#   chosen on the device each step (`lax.switch`). Rungs are quarters of n,
+#   each rounded up to a multiple of 128 and clamped to n, equal rungs merged
+#   (106,496 -> 26,624 / 53,248 / 79,872 / 106,496). Quarters: Zipf batches
+#   land on the 3/4 rung with 7-9% to spare, click logs with many
+#   low-cardinality fields on the lower ones; eighths would double the program
+#   text for no cell. A buffer too short to split (n <= 128) traces no switch.
+# - WHERE: tables of FAST_MEMORY_BYTES and more. A smaller one the TPU
+#   compiler keeps in the chip's fast memory across the scan (layout `S(1)`),
+#   where the scatter costs 40 ns a slot; a conditional's operands live in
+#   HBM, so the switch would take it out: PR 29 measured the dim-64 cell's
+#   2^22 x 2 first-order table (32 MiB) at 4.2 -> 6.1 ms a step that way.
+# - EXACT: the last rung is n, the code as it was, so no row is ever dropped;
+#   a row's update reads only that row, so every rung leaves the same table
+#   bit for bit. A step on the last rung runs under `sparse.full_size`.
+# - COUNTED: both applies hand back, on request, the step's load
+#   {"apply_fill": n_valid / n, "apply_full_steps": 1 on the last of
+#   several rungs (a buffer with one rung has nothing to overrun: 0)}; the
+#   trainers carry it as `{table}/apply_fill` in the step's stats and fold it
+#   to `sparse.apply_fill{table=}` / `sparse.apply_full_steps{table=}`.
+# `segment_reduce`, the dedup and the forward pull pay per input position and
+# are not part of this.
+# ---------------------------------------------------------------------------
+
+# what a v5e's compiler keeps in fast memory: a 64 and an 80 MiB table yes, a
+# 128 MiB one no (compiles for a described v5e, PR 29)
+FAST_MEMORY_BYTES = 128 << 20
+
+
+def apply_ladder(n: int) -> Tuple[int, ...]:
+    """The working sizes an apply over a unique buffer of n slots chooses
+    from, ascending; the last is always n."""
+    return tuple(sorted({min(n, -(-n * q // 512) * 128) for q in (1, 2, 3, 4)}))
+
+
+def _over_unique_prefix(counts: jax.Array, tables, tail):
+    """`tail(W, settle)` (gather, row math and scatter into `tables` over the
+    first W slots of what `_dedup_routed` returned) at the smallest rung that
+    holds the step's valid unique rows -> (tail's result, the step's load).
+
+    `settle` takes (the tables, their new rows) through on the way to the
+    scatter. Inside the switch it is an `optimization_barrier`: it says in the
+    program that the scatter's table is the one the gather has finished
+    reading. Without it the TPU compiler updates the table in place only in
+    the first and the last branch of a conditional and COPIES it in every
+    branch between (PR 29's probe: +9.8 ms a step for the 2.7 GB dim-9 table,
+    PERF.md section 6; `tests/test_tpu_compile.py` pins it)."""
+    n = counts.shape[0]
+    nbytes = sum(x.size * x.dtype.itemsize
+                 for x in jax.tree_util.tree_leaves(tables))
+    ladder = apply_ladder(n) if nbytes >= FAST_MEMORY_BYTES else (n,)
+    n_valid = jnp.sum(counts > 0, dtype=jnp.int32)
+    load = {"apply_fill": n_valid.astype(jnp.float32) / n,
+            "apply_full_steps": jnp.zeros((), jnp.int32)}
+    if len(ladder) == 1:  # nothing to choose from, so nothing to overrun
+        return tail(n, lambda x: x), load
+    rung = jnp.sum(n_valid > jnp.asarray(ladder[:-1], jnp.int32),
+                   dtype=jnp.int32)
+    load["apply_full_steps"] = (rung == len(ladder) - 1).astype(jnp.int32)
+    settle = jax.lax.optimization_barrier
+
+    def full_size():
+        with _trace.scope("sparse", "full_size"):
+            return tail(n, settle)
+
+    return jax.lax.switch(
+        rung, [functools.partial(tail, W, settle) for W in ladder[:-1]]
+        + [full_size]), load
+
+
 def sparse_apply_packed_table(
     optimizer,
     packed: jax.Array,
@@ -194,23 +288,28 @@ def sparse_apply_packed_table(
     row_ids: jax.Array,
     grads: jax.Array,
     pre_counts: jax.Array = None,
-) -> jax.Array:
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """`sparse_apply_dense_table` over the packed layout: identical dedup and
-    optimizer math, ONE gather + ONE scatter instead of one pair per array."""
+    optimizer math, ONE gather + ONE scatter instead of one pair per array.
+    The scan's form, so always -> (packed, the step's load)."""
     with _trace.scope("sparse", "apply"):
         g, counts, idx = _dedup_routed(packed.shape[0], row_ids, grads, pre_counts)
-        rows = _gather_rows(packed, idx, sorted_unique=True)  # (n, W) f32
-        s_rows = {}
-        off = dim
-        for name, w in layout:
-            s_rows[name] = rows[:, off:off + w]
-            off += w
-        new_w, new_s = optimizer.apply(rows[:, :dim], s_rows,
-                                       g.astype(jnp.float32), counts)
-        new_rows = jnp.concatenate(
-            [new_w] + [new_s[name] for name, _ in layout], axis=1)
-        return scatter_rows(packed, idx, new_rows.astype(packed.dtype),
-                            sorted_unique=True)
+
+        def tail(W, settle):
+            rows = _gather_rows(packed, idx[:W], sorted_unique=True)  # (W, width) f32
+            s_rows = {}
+            off = dim
+            for name, w in layout:
+                s_rows[name] = rows[:, off:off + w]
+                off += w
+            new_w, new_s = optimizer.apply(rows[:, :dim], s_rows,
+                                           g[:W].astype(jnp.float32), counts[:W])
+            table, new_rows = settle((packed, jnp.concatenate(
+                [new_w] + [new_s[name] for name, _ in layout], axis=1)))
+            return scatter_rows(table, idx[:W], new_rows.astype(packed.dtype),
+                                sorted_unique=True)
+
+        return _over_unique_prefix(counts, packed, tail)
 
 
 def sparse_apply_dense_table(
@@ -220,8 +319,12 @@ def sparse_apply_dense_table(
     row_ids: jax.Array,
     grads: jax.Array,
     pre_counts: jax.Array = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Fused sparse update of a dense (array) table shard.
+    *,
+    with_load: bool = False,
+):
+    """Fused sparse update of a dense (array) table shard -> (weights, slots),
+    with `with_load` -> (weights, slots, the step's load: "WHAT THE APPLY
+    WORKS OVER" above).
 
     row_ids: (n,) local row indices (may contain duplicates and padding);
     grads: (n, dim) per-occurrence gradients; pre_counts: (n,) multiplicity already
@@ -235,23 +338,31 @@ def sparse_apply_dense_table(
         g, counts, idx = _dedup_routed(weights.shape[0], row_ids, grads, pre_counts)
 
         from .pallas_sparse import maybe_fused_apply
-        fused = maybe_fused_apply(optimizer, weights, slots, idx, g, counts)
-        if fused is not None:
-            return fused
 
-        # Optimizer math always runs in float32, whatever the table dtype: in bf16,
-        # beta_2^t rounds to 1.0 (killing Adam's lr_t) and g^2 accumulators lose most of
-        # their mantissa. Slots are stored f32 (`SparseOptimizer.init_slots`); weights are
-        # upcast for the update and cast back on scatter (TPU-idiomatic mixed precision).
-        w_rows = _gather_rows(weights, idx, sorted_unique=True).astype(jnp.float32)
-        s_rows = {k: _gather_rows(v, idx, sorted_unique=True)
-                  for k, v in slots.items()}
-        new_w, new_s = optimizer.apply(w_rows, s_rows, g.astype(jnp.float32), counts)
-        # idx is fully routed (invalid -> distinct OOB rows): valid=None
-        weights = scatter_rows(weights, idx, new_w.astype(weights.dtype),
-                               sorted_unique=True)
-        slots = {k: scatter_rows(slots[k], idx,
-                                 new_s[k].astype(slots[k].dtype),
-                                 sorted_unique=True)
-                 for k in slots}
-        return weights, slots
+        def tail(W, settle):
+            idx_w, g_w, counts_w = idx[:W], g[:W], counts[:W]
+            fused = maybe_fused_apply(optimizer, weights, slots, idx_w, g_w,
+                                      counts_w)
+            if fused is not None:
+                return fused
+
+            # Optimizer math always runs in float32, whatever the table dtype: in bf16,
+            # beta_2^t rounds to 1.0 (killing Adam's lr_t) and g^2 accumulators lose most of
+            # their mantissa. Slots are stored f32 (`SparseOptimizer.init_slots`); weights are
+            # upcast for the update and cast back on scatter (TPU-idiomatic mixed precision).
+            w_rows = _gather_rows(weights, idx_w,
+                                  sorted_unique=True).astype(jnp.float32)
+            s_rows = {k: _gather_rows(v, idx_w, sorted_unique=True)
+                      for k, v in slots.items()}
+            (w, s), (new_w, new_s) = settle(((weights, slots), optimizer.apply(
+                w_rows, s_rows, g_w.astype(jnp.float32), counts_w)))
+            # idx is fully routed (invalid -> distinct OOB rows): valid=None
+            return (scatter_rows(w, idx_w, new_w.astype(w.dtype),
+                                 sorted_unique=True),
+                    {k: scatter_rows(s[k], idx_w, new_s[k].astype(s[k].dtype),
+                                     sorted_unique=True)
+                     for k in s})
+
+        (weights, slots), load = _over_unique_prefix(
+            counts, (weights, slots), tail)
+        return (weights, slots, load) if with_load else (weights, slots)

@@ -1020,9 +1020,9 @@ def sharded_apply_gradients(
     if S == 1:
         # identity routing (see make_plan): the local unique slots ARE the
         # server's receive buffer — no bucket scatter, no grad/count a2a
-        new_state = _apply_unique(spec, state, optimizer, uniq.unique_ids, g,
-                                  jnp.where(valid, uniq.counts, 0), S,
-                                  packed=packed)
+        new_state, load = _apply_unique(
+            spec, state, optimizer, uniq.unique_ids, g,
+            jnp.where(valid, uniq.counts, 0), S, packed=packed)
     else:
         counts_i32 = jnp.where(valid, uniq.counts, 0).astype(jnp.int32)
         if fmt == "fp32":
@@ -1059,11 +1059,23 @@ def sharded_apply_gradients(
         recv = _a2a("grads", _scatter_buckets(payload, buckets, S, cap), axis)
         # server side: cross-source re-dedup + fused optimizer (MPSC reduce
         # + update)
-        new_state = _owner_apply(spec, state, optimizer, plan, recv, decode,
-                                 S, packed=packed)
+        new_state, load = _owner_apply(spec, state, optimizer, plan, recv,
+                                       decode, S, packed=packed)
+    stats.update(_apply_load_stats(load, axis))
     if new_hot is not None:
         new_state = new_state.replace(hot=new_hot)
     return new_state, stats
+
+
+def _apply_load_stats(load: Dict[str, jax.Array], axis) -> Dict[str, jax.Array]:
+    """This shard's apply load (`ops/sparse.py` "WHAT THE APPLY WORKS OVER":
+    `apply_fill`, `apply_full_steps`) one-hot at this shard, like
+    `owner_fill`: the stats psum assembles the per-shard vectors."""
+    with _trace.scope("exchange", "stats"):
+        S = jax.lax.axis_size(axis)
+        me = _flat_axis_index(axis)
+        return {k: jnp.zeros((S,), v.dtype).at[me].set(v)
+                for k, v in load.items()}
 
 
 def _scatter_buckets(payload: jax.Array, buckets: BucketResult, S: int,
@@ -1080,7 +1092,7 @@ def _scatter_buckets(payload: jax.Array, buckets: BucketResult, S: int,
 
 def _owner_apply(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
                  plan: ExchangePlan, recv: jax.Array, decode, S: int,
-                 packed=None) -> EmbeddingTableState:
+                 packed=None):
     """Server-side tail of a push over what this shard RECEIVED: `recv` is
     the (S, cap, width) grad payload as it left the all_to_all, `decode` maps
     (m, width) payload rows to their (grads, exact counts). Where the plan
@@ -1089,23 +1101,25 @@ def _owner_apply(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
     own gradient) and decode + `_apply_unique` run over W slots; in a step
     whose received ids do not fit they run over all S * cap. Either way the
     valid slots keep their source-major order, so the cross-source reduction
-    adds in one order and the result is the same bit for bit."""
+    adds in one order and the result is the same bit for bit. -> (state, the
+    main table's apply load, `_apply_unique`)."""
     view = plan.owner
 
     def apply(ids, payload):
         rg, rc = decode(payload)
-        new = _apply_unique(spec, state, optimizer, ids, rg, rc, S,
-                            packed=packed)
+        new, load = _apply_unique(spec, state, optimizer, ids, rg, rc, S,
+                                  packed=packed)
         return new.weights, new.slots, \
-            None if new.mig is None else (new.mig.weights, new.mig.slots)
+            None if new.mig is None else (new.mig.weights, new.mig.slots), \
+            load
 
     def full_size():
         return apply(_flat_recv(plan)[0], recv.reshape(-1, recv.shape[-1]))
 
     if view is None:
-        weights, slots, annex = full_size()
+        weights, slots, annex, load = full_size()
     else:
-        weights, slots, annex = jax.lax.cond(
+        weights, slots, annex, load = jax.lax.cond(
             view.fits,
             lambda: apply(view.ids, _compact(recv, view.offsets,
                                              view.valid.shape[0])),
@@ -1114,19 +1128,21 @@ def _owner_apply(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
     if annex is not None:
         state = state.replace(mig=state.mig.replace(weights=annex[0],
                                                     slots=annex[1]))
-    return state
+    return state, load
 
 
 def _apply_unique(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
                   rids: jax.Array, rg: jax.Array, rc: jax.Array, S: int,
-                  packed=None) -> EmbeddingTableState:
+                  packed=None):
     """Server-side tail of a push: cross-source re-dedup (the MPSC reducer,
     `MpscGradientReducer.h`) + ONE fused optimizer apply per unique row.
     `rids`/`rg`/`rc` are the received flat ids, grads and exact duplicate
     counts (count 0 = empty/invalid slot). Received MIGRATED ids apply into
     the annex (this shard is their assigned owner) through the identical
     sparse-apply machinery — the received buffer keeps its source-major
-    order, so the per-row reduction is bit-identical to the home shard's."""
+    order, so the per-row reduction is bit-identical to the home shard's.
+    -> (state, the main table's apply load: `ops/sparse.py` "WHAT THE APPLY
+    WORKS OVER"; the annex's apply works the same way and is not counted)."""
     with _trace.scope("exchange", "owner_apply"):
         mig = state.mig
         if mig is not None:
@@ -1159,13 +1175,14 @@ def _apply_unique(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
             counts = rc
         if packed is not None:
             from ..ops.sparse import sparse_apply_packed_table
-            new_packed = sparse_apply_packed_table(
+            new_packed, load = sparse_apply_packed_table(
                 optimizer, state.weights, packed, spec.output_dim, rows, rg,
                 pre_counts=counts)
-            return state.replace(weights=new_packed)
-        weights, slots = sparse_apply_dense_table(
-            optimizer, state.weights, state.slots, rows, rg, pre_counts=counts)
-        return state.replace(weights=weights, slots=slots)
+            return state.replace(weights=new_packed), load
+        weights, slots, load = sparse_apply_dense_table(
+            optimizer, state.weights, state.slots, rows, rg, pre_counts=counts,
+            with_load=True)
+        return state.replace(weights=weights, slots=slots), load
 
 
 # ---------------------------------------------------------------------------
@@ -1342,10 +1359,12 @@ def grouped_apply_gradients(
         for spec, state, opt, plan, g, rc, packed in zip(
                 specs, states, optimizers, plans, gs, counts_list,
                 packed_list):
-            new_states.append(_apply_unique(
+            new, load = _apply_unique(
                 spec, state, opt, plan.uniq.unique_ids, g, rc, S,
-                packed=packed))
-            stats_list.append({"push_overflow": plan.buckets.overflow})
+                packed=packed)
+            new_states.append(new)
+            stats_list.append({"push_overflow": plan.buckets.overflow,
+                               **_apply_load_stats(load, axis)})
         return new_states, stats_list
     payloads = [_scatter_buckets(
         wire_mod.encode_grads(g, rc, fmt, stochastic=(fmt == "int8")),
@@ -1361,9 +1380,11 @@ def grouped_apply_gradients(
         def decode(flat, dtype=g.dtype):
             rg32, rc = wire_mod.decode_grads(flat, dim, fmt)
             return rg32.astype(dtype), rc
-        new_states.append(_owner_apply(spec, state, opt, plan, seg, decode,
-                                       S, packed=packed))
-        stats_list.append({"push_overflow": plan.buckets.overflow})
+        new, load = _owner_apply(spec, state, opt, plan, seg, decode, S,
+                                 packed=packed)
+        new_states.append(new)
+        stats_list.append({"push_overflow": plan.buckets.overflow,
+                           **_apply_load_stats(load, axis)})
     return new_states, stats_list
 
 

@@ -23,7 +23,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..embedding import EmbeddingSpec, EmbeddingTableState, HotRows, MigRows
-from ..model import EmbeddingModel, TrainState, Trainer, init_dense_slots
+from ..model import (EmbeddingModel, TrainState, Trainer, _fold_table_stats,
+                     _observe_table_stats, _table_stats, _window_values,
+                     init_dense_slots)
 from ..optimizers import SparseOptimizer
 from ..utils import metrics as _metrics
 from ..utils import trace as _trace
@@ -1574,28 +1576,23 @@ class MeshTrainer(Trainer):
             return super().train_many(state, batches)
         return self._train_many_pipelined(state, batches)
 
+    _WINDOW_TABLE_STATS = _metrics.APPLY_STATS + _metrics.OWNER_STATS
+
     def _scan_stats(self, stats):
-        """A step's `owner_fill` / `owner_full_steps` (present where the
-        owner compacts what it receives, `sharded.exchange_load_stats`),
-        folded over the shards: the fullest shard's fill, and 1 where any
-        shard took the full-size path."""
-        kept = {}
-        for key, vec in stats.items():
-            table, _, stat = key.partition("/")
-            if stat in _metrics.OWNER_STATS:
-                kept.setdefault(stat, {})[table] = jnp.max(vec)
-        return kept
+        """A step's apply load (`Trainer._scan_stats`) and, where the owner
+        compacts what it receives, its `owner_fill` / `owner_full_steps`
+        (`sharded.exchange_load_stats`); each per-shard vector folded over
+        the shards: the fullest shard's fill, and 1 where any shard ran full
+        size."""
+        return _table_stats(stats, self._WINDOW_TABLE_STATS)
 
     def _window_stats(self, kept):
-        """The stacked `_scan_stats` folded over a window's steps:
-        "owner_fill" {table: the fullest step} and "owner_full_steps"
-        {table: steps that took the full-size path}; both empty where no
-        table's receive side is compacted."""
-        return {
-            "owner_fill": {t: jnp.max(v) for t, v
-                           in kept.get("owner_fill", {}).items()},
-            "owner_full_steps": {t: jnp.sum(v) for t, v
-                                 in kept.get("owner_full_steps", {}).items()}}
+        """The stacked `_scan_stats` folded over a window's steps: under
+        "apply_fill" / "owner_fill" {table: the fullest step}, under
+        "apply_full_steps" / "owner_full_steps" {table: steps that ran full
+        size}; the owner's two are empty where no table's receive side is
+        compacted."""
+        return _fold_table_stats(kept, self._WINDOW_TABLE_STATS)
 
     def _train_many_pipelined(self, state: TrainState, batches):
         """Prologue / steady-state / epilogue around `lax.scan`:
@@ -1721,9 +1718,6 @@ class MeshTrainer(Trainer):
             nxt = jax.tree_util.tree_map(lambda x: x[1:], batches)
             (state, pre), (losses, oflows, conflicts, coflows, kepts) = \
                 jax.lax.scan(body, (state, pre0), (head, nxt))
-            kept = jax.tree_util.tree_map(
-                lambda first, rest: jnp.concatenate([first[None], rest]),
-                kept, kepts)
             total_oflow = total_oflow + jnp.sum(oflows)
             conflict = {n: jnp.sum(conflicts[n]) for n in conflicts}
             coflow = jnp.sum(coflows)
@@ -1742,6 +1736,14 @@ class MeshTrainer(Trainer):
                                          {n: pre[n]["rows"] for n in pre})
         state, metrics = step_tail(state, bl, pulled, {}, plans_l)
         total_oflow = total_oflow + stats_overflow(metrics.get("stats", {}))
+        # the window's K values of each kept stat: the owners' from the
+        # prologue's prefetch and the body's, the applies' from the body's
+        # steps and this one (the folds take no notice of the order)
+        kept = {**kept, **self._scan_stats(metrics.get("stats", {}))}
+        if K > 1:
+            kept = jax.tree_util.tree_map(
+                lambda edge, rest: jnp.concatenate([edge[None], rest]),
+                kept, kepts)
         last = jnp.reshape(metrics["loss"], (1,))
         losses = last if losses is None else jnp.concatenate([losses, last])
 
@@ -1760,6 +1762,8 @@ class MeshTrainer(Trainer):
 
     def record_window_stats(self, metrics) -> None:
         """Fold a train_many window's host-visible counters into series:
+        each table's `sparse.apply_fill{table=}` /
+        `sparse.apply_full_steps{table=}` (`Trainer.record_window_stats`);
         where the owner compacts what it receives,
         `exchange.owner_fill{table=}` (gauge: the window's fullest step on
         its fullest shard) and `exchange.owner_full_steps{table=}` (counter:
@@ -1768,27 +1772,16 @@ class MeshTrainer(Trainer):
         `exchange.conflict_overflow`. ONE device_get per window (the
         window-level sibling of `metrics.record_step_stats`); a no-op on a
         window that holds none of them."""
-        if not isinstance(metrics, dict):
-            return
-        keys = ("owner_fill", "owner_full_steps", "conflict",
-                "conflict_overflow")
-        vals = {k: metrics[k] for k in keys
-                if jax.tree_util.tree_leaves(metrics.get(k))}
-        if not vals:
-            return
-        vals = jax.device_get(vals)
-        for name, v in vals.get("owner_fill", {}).items():
-            _metrics.observe("exchange.owner_fill", float(v), "gauge",
-                             labels={"table": name})
-        for name, v in vals.get("owner_full_steps", {}).items():
-            _metrics.observe("exchange.owner_full_steps", float(v), "sum",
-                             labels={"table": name})
-        for name, v in vals.get("conflict", {}).items():
+        vals = _window_values(
+            metrics, self._WINDOW_TABLE_STATS
+            + ("conflict", "conflict_overflow"))
+        for name, v in vals.pop("conflict", {}).items():
             _metrics.observe("exchange.conflict_rows", float(v), "gauge",
                              labels={"table": name})
         if "conflict_overflow" in vals:
             _metrics.observe("exchange.conflict_overflow",
-                             float(vals["conflict_overflow"]), "gauge")
+                             float(vals.pop("conflict_overflow")), "gauge")
+        _observe_table_stats(vals)
 
     def _observe_wire_cost(self, ps_specs, batch, *, pipelined=False):
         """Publish the static wire-cost model of the traced step (runs once
@@ -1976,7 +1969,8 @@ class MeshTrainer(Trainer):
             lambda p: P(None, *p), bspec, is_leaf=lambda x: isinstance(x, P))
 
         metrics_spec = {"loss": P(), "overflow": P(),
-                        "owner_fill": P(), "owner_full_steps": P()}
+                        "owner_fill": P(), "owner_full_steps": P(),
+                        "apply_fill": P(), "apply_full_steps": P()}
         if self._pipeline_on():
             # the pipelined window reports two extra replicated counters;
             # the serial branch keeps EXACTLY the round-17 spec dict (the

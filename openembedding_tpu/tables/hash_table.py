@@ -387,26 +387,30 @@ def _grad_slots_and_counts(state, ids: jax.Array):
         return jnp.clip(slot, 0, capacity), pre_counts
 
 
-def hash_apply_gradients(state, optimizer, ids: jax.Array, grads: jax.Array):
+def hash_apply_gradients(state, optimizer, ids: jax.Array, grads: jax.Array,
+                         *, with_load: bool = False):
     """Push+update: translate ids -> slots (no insert; forward pull inserted them),
-    then run the shared fused sparse apply over slot indices."""
+    then run the shared fused sparse apply over slot indices. `with_load` ->
+    (state, the step's apply load, `ops/sparse.py`)."""
     from ..ops.sparse import sparse_apply_dense_table
 
     slot, pre_counts = _grad_slots_and_counts(state, ids)
-    weights, slots = sparse_apply_dense_table(
+    weights, slots, load = sparse_apply_dense_table(
         optimizer, state.weights, state.slots, slot, grads,
-        pre_counts=pre_counts)
-    return state.replace(weights=weights, slots=slots)
+        pre_counts=pre_counts, with_load=True)
+    state = state.replace(weights=weights, slots=slots)
+    return (state, load) if with_load else state
 
 
 def hash_apply_gradients_packed(state, optimizer, ids: jax.Array,
                                 grads: jax.Array, layout, dim: int):
     """`hash_apply_gradients` over the packed weights+slots layout: same probe
-    and drop semantics, one gather/scatter pair (`sparse_apply_packed_table`)."""
+    and drop semantics, one gather/scatter pair (`sparse_apply_packed_table`).
+    Only the scan calls it: -> (state, the step's apply load)."""
     from ..ops.sparse import sparse_apply_packed_table
 
     slot, pre_counts = _grad_slots_and_counts(state, ids)
-    packed = sparse_apply_packed_table(
+    packed, load = sparse_apply_packed_table(
         optimizer, state.weights, layout, dim, slot, grads,
         pre_counts=pre_counts)
-    return state.replace(weights=packed)
+    return state.replace(weights=packed), load

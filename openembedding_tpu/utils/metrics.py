@@ -266,7 +266,9 @@ def record_step_stats(stats: Dict[str, "object"]) -> Dict[str, "object"]:
     shards — Parallax's access-skew number),
     `pull_unique`/`pull_indices` derive `exchange.unique_ratio{table=}`, and
     `owner_fill`/`owner_full_steps` fold over the shards to
-    `exchange.owner_fill{table=}` and `exchange.owner_full_steps{table=}`.
+    `exchange.owner_fill{table=}` and `exchange.owner_full_steps{table=}`,
+    `apply_fill`/`apply_full_steps` (a scalar on one device) to
+    `sparse.apply_fill{table=}` and `sparse.apply_full_steps{table=}`.
 
     Hot-row replication stats (`{var}/hot_hits` / `hot_unique` /
     `hot_bytes_saved`, present when `MeshTrainer(hot_rows=...)` is on) derive
@@ -296,11 +298,14 @@ def record_step_stats(stats: Dict[str, "object"]) -> Dict[str, "object"]:
         var, sep, stat = key.partition("/")
         table_stat = sep and "/" not in stat
         try:
+            if table_stat and stat in _TABLE_SERIES:
+                _fold_table_stat(
+                    var, stat, np.asarray(value, np.float64).reshape(-1))
+                continue
             if np.ndim(value) >= 1:
-                if table_stat and stat in _SHARD_STATS + OWNER_STATS:
-                    fold = (_fold_owner_stat if stat in OWNER_STATS
-                            else _fold_shard_stat)
-                    fold(var, stat, np.asarray(value, np.float64).reshape(-1))
+                if table_stat and stat in _SHARD_STATS:
+                    _fold_shard_stat(
+                        var, stat, np.asarray(value, np.float64).reshape(-1))
                     continue
                 if np.size(value) > 1:
                     continue  # unknown vector stat: nothing sane to fold
@@ -403,9 +408,17 @@ def _fold_health(per_table: Dict[str, Dict[str, float]],
 
 # per-shard vector stats emitted by `parallel/sharded.exchange_load_stats`
 _SHARD_STATS = ("shard_rows", "shard_positions", "bucket_fill")
-# ... and the two that fold over the shards to ONE series a table: how full
-# the fullest owner's working size was, and whether any owner overran it
+# ... and those that fold over the shards to ONE series a table: how full the
+# fullest owner's working size was and whether any owner overran it
+# (`parallel/sharded.py` "WHAT THE OWNER WORKS OVER"); the valid unique rows
+# over the apply's unique buffer and whether the apply ran its last rung
+# (`ops/sparse.py` "WHAT THE APPLY WORKS OVER"; a scalar on one device)
 OWNER_STATS = ("owner_fill", "owner_full_steps")
+APPLY_STATS = ("apply_fill", "apply_full_steps")
+_TABLE_SERIES = {"owner_fill": ("exchange.owner_fill", "gauge"),
+                 "owner_full_steps": ("exchange.owner_full_steps", "sum"),
+                 "apply_fill": ("sparse.apply_fill", "gauge"),
+                 "apply_full_steps": ("sparse.apply_full_steps", "sum")}
 
 
 def _fold_shard_stat(var: str, stat: str, vec) -> None:
@@ -423,17 +436,25 @@ def _fold_shard_stat(var: str, stat: str, vec) -> None:
                     "hist", labels={"table": var})
 
 
-def _fold_owner_stat(var: str, stat: str, vec) -> None:
-    """The owner's two per-shard vectors fold over the shards to one series
-    a table: `exchange.owner_fill{table=}` (gauge, the fullest shard) and
-    `exchange.owner_full_steps{table=}` (counter: steps in which some shard
-    took the full-size path)."""
-    if stat == "owner_fill":
-        observe("exchange.owner_fill", float(vec.max()), "gauge",
-                labels={"table": var})
-    else:
-        observe("exchange.owner_full_steps", float(vec.max() > 0), "sum",
-                labels={"table": var})
+def is_fill(stat: str) -> bool:
+    """Of OWNER_STATS / APPLY_STATS: a fill (folds to its largest value), not
+    a count of full-size steps (folds to a sum)."""
+    return _TABLE_SERIES[stat][1] == "gauge"
+
+
+def observe_table_stat(var: str, stat: str, value: float) -> None:
+    """One of OWNER_STATS / APPLY_STATS, already folded over shards (and, for
+    a window, over its steps) -> its series: the fills are gauges (the
+    fullest), the `*_full_steps` counters (steps that ran full size)."""
+    series, kind = _TABLE_SERIES[stat]
+    observe(series, float(value), kind, labels={"table": var})
+
+
+def _fold_table_stat(var: str, stat: str, vec) -> None:
+    """A step's per-shard vector of such a stat: the fullest shard's fill;
+    1 where some shard ran full size."""
+    top = float(vec.max())
+    observe_table_stat(var, stat, top if is_fill(stat) else top > 0)
 
 
 def report(reset: bool = False) -> Dict[str, float]:

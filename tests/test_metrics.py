@@ -61,6 +61,38 @@ def test_record_step_stats_from_device_dict():
     assert rep['trainer.pull_indices{table="categorical"}'] == 128
 
 
+@pytest.mark.parametrize("shape", ["scalar", "per_shard"])
+def test_record_step_stats_folds_the_apply_load(shape):
+    """`{table}/apply_fill` / `apply_full_steps` (`ops/sparse.py` "WHAT THE
+    APPLY WORKS OVER"): a scalar from one device, a per-shard vector from a
+    mesh; either way ONE series a table: the fullest shard's fill as a gauge,
+    and a counter of steps in which some shard ran its last rung."""
+    import numpy as np
+    fill = {"scalar": np.float32(0.675),
+            "per_shard": np.asarray([0.61, 0.675, 0.64, 0.6], np.float32)}[shape]
+    full = {"scalar": np.int32(0), "per_shard": np.zeros(4, np.int32)}[shape]
+    for step in range(3):
+        last = step == 2
+        metrics.record_step_stats({
+            "user/apply_fill": fill * (1.2 if last else 1.0),
+            "user/apply_full_steps": full + (1 if last else 0),
+            "item/apply_fill": np.float32(0.25),
+            "item/apply_full_steps": np.int32(0)})
+    rep = metrics.report()
+    assert rep['sparse.apply_fill{table="user"}'] == pytest.approx(0.675 * 1.2)
+    assert rep['sparse.apply_full_steps{table="user"}'] == 1
+    assert rep['sparse.apply_fill{table="item"}'] == pytest.approx(0.25)
+    assert rep['sparse.apply_full_steps{table="item"}'] == 0
+    # one series a table: no per-shard gauges, no generic `trainer.*` counter
+    assert not [k for k in rep if "shard=" in k or k.startswith("trainer.apply")
+                or k.startswith("user.") or k.startswith("item.")]
+    # a gauge survives a windowed reset, the counter starts over
+    metrics.report(reset=True)
+    rep = metrics.report()
+    assert rep['sparse.apply_fill{table="user"}'] == pytest.approx(0.675 * 1.2)
+    assert rep['sparse.apply_full_steps{table="user"}'] == 0
+
+
 def test_record_step_stats_single_host_sync_and_mixed_types(monkeypatch):
     """The hot-path contract: ONE jax.device_get for the whole stats dict
     (per-key float() on device arrays = one host sync per stat), accepting

@@ -16,7 +16,8 @@ import openembedding_tpu as embed
 from openembedding_tpu.data import synthetic_criteo
 from openembedding_tpu.model import Trainer
 from openembedding_tpu.models import make_deepfm
-from openembedding_tpu.ops.sparse import (packed_layout, pack_table,
+from openembedding_tpu.ops.sparse import (apply_ladder, packed_layout,
+                                          pack_table,
                                           sparse_apply_dense_table,
                                           sparse_apply_packed_table,
                                           unpack_table)
@@ -67,7 +68,7 @@ def test_packed_apply_matches_split(opt_name):
 
     sw, ss = jax.jit(lambda w, s: sparse_apply_dense_table(opt, w, s, ids, g))(
         w, slots)
-    packed = jax.jit(lambda w, s: sparse_apply_packed_table(
+    packed, _ = jax.jit(lambda w, s: sparse_apply_packed_table(
         opt, pack_table(w, s, lay), lay, dim, ids, g))(w, slots)
     pw, ps = unpack_table(packed, lay, dim, w.dtype)
     np.testing.assert_array_equal(np.asarray(sw), np.asarray(pw))
@@ -276,11 +277,26 @@ def _count_table_scatters(txt, shape):
     return len(direct) + len(lowered)
 
 
-def test_packed_scan_compiles_one_scatter_per_table():
+@pytest.fixture
+def ladder(request, monkeypatch):
+    """"small_table": a table under `FAST_MEMORY_BYTES` keeps the program as
+    it was (no switch); "ladder": the gate lifted, as for a table of 128 MiB
+    and more. -> the scatters a table then compiles to."""
+    from openembedding_tpu.ops import sparse
+    if request.param == "ladder":
+        monkeypatch.setattr(sparse, "FAST_MEMORY_BYTES", 0)
+        return len(apply_ladder(256 * 26))
+    return 1
+
+
+@pytest.mark.parametrize("ladder", ["small_table", "ladder"], indirect=True)
+def test_packed_scan_compiles_one_scatter_per_table(ladder):
     """Structural pin on the packed win: the compiled train_many updates the
-    table through ONE scatter into the packed (V, 20) array — never the two
-    split-layout scatters ((V, 10) weights + (V, 10) accum) — and temps stay
-    far below a second table copy. HLO-shape matching is deliberately narrow;
+    table through ONE scatter into the packed (V, 20) array (with the apply's
+    choice of a working size, one in each of its branches, of which a step
+    runs one) — never the two split-layout scatters ((V, 10) weights +
+    (V, 10) accum) — and temps stay far below a second table copy,
+    conditional and all. HLO-shape matching is deliberately narrow;
     if an XLA upgrade reshuffles instruction names, update the patterns, but
     a reappearing split-shape scatter or a table-sized temp is a real
     regression."""
@@ -296,7 +312,10 @@ def test_packed_scan_compiles_one_scatter_per_table():
     txt = compiled.as_text()
     packed = _count_table_scatters(txt, f"{V},20")
     split = _count_table_scatters(txt, f"{V},10")
-    assert packed == 1, f"expected 1 packed-table scatter, found {packed}"
+    assert ladder in (1, 4)
+    assert packed == ladder, \
+        f"expected {ladder} packed-table scatter(s), found {packed}"
+    assert (" conditional(" in txt) == (ladder > 1)
     assert split == 0, f"split-layout scatters reappeared: {split}"
 
     ma = compiled.memory_analysis()
@@ -307,7 +326,8 @@ def test_packed_scan_compiles_one_scatter_per_table():
             f"inside the scan (packed table is {packed_bytes})")
 
 
-def test_packed_scan_dim64_split_first_order_one_scatter_each():
+@pytest.mark.parametrize("ladder", ["small_table", "ladder"], indirect=True)
+def test_packed_scan_dim64_split_first_order_one_scatter_each(ladder):
     """The dim-64 benchmark configuration: split
     first-order auto-engages at lane-multiple dims, so train_many packs BOTH
     tables — categorical 64+64 -> (V, 128) lane-exact, first_order 1+1 ->
@@ -332,8 +352,11 @@ def test_packed_scan_dim64_split_first_order_one_scatter_each():
     split = (_count_table_scatters(txt, f"{V},64")
              + _count_table_scatters(txt, f"{V},65")
              + _count_table_scatters(txt, f"{V},1"))
-    assert cat == 1, f"expected 1 packed categorical scatter, found {cat}"
-    assert fo == 1, f"expected 1 packed first-order scatter, found {fo}"
+    # with the ladder one scatter a rung, of which a step runs one
+    assert cat == ladder, \
+        f"expected {ladder} packed categorical scatter(s), found {cat}"
+    assert fo == ladder, \
+        f"expected {ladder} packed first-order scatter(s), found {fo}"
     assert split == 0, f"split-layout scatters reappeared: {split}"
 
 
@@ -378,3 +401,150 @@ def test_seq_mesh_train_many_packed_matches_step_loop():
         np.testing.assert_array_equal(
             np.asarray(sm.tables[name].weights),
             np.asarray(state2.tables[name].weights))
+
+
+# -- the apply over the unique prefix (ops/sparse.py "WHAT THE APPLY WORKS
+# OVER"): gather, row math and scatter run over the smallest rung of
+# `apply_ladder(n)` that holds the step's valid unique rows, and leave the
+# table of the full-size path (the ladder patched to `(n,)`: no switch is
+# traced, the code as it was) bit for bit. Tables under `FAST_MEMORY_BYTES`
+# are left alone, so these tests lift that gate ------------------------------
+
+_N, _ROWS, _DIM = 512, 1024, 8          # apply_ladder(512) = (128, 256, 384, 512)
+# name -> distinct valid rows among the n positions
+_ID_CASES = {"all_equal": 1, "share_0.2": 102, "share_0.6": 307,
+             "share_0.9": 461, "all_distinct": _N, "exactly_W": 256,
+             "W_plus_1": 257, "invalid_mixed": 300}
+_KINDS = ("array_packed", "array_split", "hash_packed", "hash_split",
+          "bf16_split")
+
+
+def _prefix_case(kind, case):
+    """-> (run() -> (tables, load), the host's count of valid unique rows).
+    `invalid_mixed` plants negative ids and, on array tables, `pre_counts`
+    of 0 (on hash tables: ids the pull never inserted, which the apply gives
+    count 0), all outside the `_ID_CASES[case]` rows that stay valid."""
+    from openembedding_tpu.embedding import (EmbeddingSpec, init_table_state,
+                                             lookup_train)
+    from openembedding_tpu.tables.hash_table import (
+        hash_apply_gradients, hash_apply_gradients_packed)
+    rng = np.random.default_rng(sorted(_ID_CASES).index(case))
+    u = _ID_CASES[case]
+    hashed = kind.startswith("hash")
+    space = (1 << 40) if hashed else _ROWS
+    pool = rng.choice(space, size=u + 64, replace=False) if hashed \
+        else rng.permutation(_ROWS)[:u + 64]
+    pool, spare = pool[:u], pool[u:]
+    ids = rng.permutation(np.concatenate([pool, rng.choice(pool, _N - u)]))
+    pre = np.ones((_N,), np.int32)
+    inserted = ids
+    if case == "invalid_mixed":
+        # 150 positions go invalid; every valid row keeps one position
+        first = np.unique(ids, return_index=True)[1]
+        free = np.setdiff1d(np.arange(_N), first)
+        bad = rng.choice(free, 150, replace=False)
+        ids[bad[:50]] = -1 - rng.integers(0, 5, 50)        # negative ids
+        ids[bad[50:]] = rng.choice(spare, 100)              # rows nobody ...
+        if hashed:
+            inserted = np.delete(ids, bad)                  # ... pulled
+        else:
+            pre[bad[50:]] = 0                               # ... counts
+    assert np.unique(ids[(ids >= 0) & (pre > 0) & np.isin(ids, pool)]).size == u
+    ids = jnp.asarray(ids, jnp.int64 if hashed else jnp.int32)
+    g = jnp.asarray(rng.standard_normal((_N, _DIM)), jnp.float32)
+    opt = embed.Adagrad(learning_rate=0.1)
+    if hashed:
+        spec = EmbeddingSpec("t", -1, _DIM, capacity=4096)
+        state, _ = lookup_train(spec, init_table_state(spec, opt),
+                                jnp.asarray(inserted, jnp.int64))
+        assert int(state.overflow) == 0
+        if kind == "hash_split":
+            def fn(st):
+                st, load = hash_apply_gradients(st, opt, ids, g, with_load=True)
+                return (st.keys, st.weights, st.slots), load
+        else:
+            lay = packed_layout(_DIM, state.slots)
+
+            def fn(st):
+                st = st.replace(weights=pack_table(st.weights, st.slots, lay),
+                                slots={})
+                st, load = hash_apply_gradients_packed(st, opt, ids, g, lay,
+                                                       _DIM)
+                return (st.keys, st.weights), load
+        # a fresh function a run: a patched ladder must be traced, not cached
+        return (lambda: jax.device_get(jax.jit(lambda st: fn(st))(state))), u
+    dtype = jnp.bfloat16 if kind == "bf16_split" else jnp.float32
+    w = jnp.asarray(rng.standard_normal((_ROWS, _DIM)), dtype)
+    slots = opt.init_slots(_ROWS, _DIM)
+    pre = jnp.asarray(pre)
+    if kind == "array_packed":
+        lay = packed_layout(_DIM, slots)
+
+        def fn(w, s):
+            return sparse_apply_packed_table(
+                opt, pack_table(w, s, lay), lay, _DIM, ids, g, pre)
+    else:
+        def fn(w, s):
+            w, s, load = sparse_apply_dense_table(opt, w, s, ids, g, pre,
+                                                  with_load=True)
+            return (w, s), load
+    return (lambda: jax.device_get(jax.jit(lambda w, s: fn(w, s))(w, slots))), u
+
+
+@pytest.mark.parametrize("case", sorted(_ID_CASES))
+@pytest.mark.parametrize("kind", _KINDS)
+def test_apply_over_the_unique_prefix_is_the_full_size_apply(
+        kind, case, monkeypatch):
+    from openembedding_tpu.ops import sparse
+    assert apply_ladder(_N) == (128, 256, 384, _N)
+    monkeypatch.setattr(sparse, "FAST_MEMORY_BYTES", 0)  # these are KiB
+    run, n_valid = _prefix_case(kind, case)
+    got, load = run()
+    monkeypatch.setattr(sparse, "apply_ladder", lambda n: (n,))
+    want, full = run()
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the load is the host's count, and the rung the smallest that holds it
+    np.testing.assert_array_equal(load["apply_fill"],
+                                  np.float32(n_valid) / np.float32(_N))
+    assert int(load["apply_full_steps"]) == int(n_valid > 384)
+    # one rung leaves nothing to overrun: the nulled path counts no step
+    assert int(full["apply_full_steps"]) == 0
+    np.testing.assert_array_equal(full["apply_fill"], load["apply_fill"])
+
+
+def test_apply_ladder_rule():
+    """Quarters of n, each rounded up to a multiple of 128 and clamped to n,
+    equal rungs merged; the last rung is always n."""
+    assert apply_ladder(106496) == (26624, 53248, 79872, 106496)
+    assert apply_ladder(8192) == (2048, 4096, 6144, 8192)
+    assert apply_ladder(416) == (128, 256, 384, 416)
+    assert apply_ladder(300) == (128, 256, 300)
+    assert apply_ladder(104) == (104,)      # too short to split: no switch
+    for n in (1, 127, 128, 129, 1000, 4097, 106496):
+        lad = apply_ladder(n)
+        assert lad[-1] == n and list(lad) == sorted(set(lad))
+        assert all(r % 128 == 0 for r in lad[:-1])
+
+
+@pytest.mark.parametrize("gate,switched", [(None, False), (0, True)],
+                         ids=["table_under_fast_memory", "gate_lifted"])
+def test_a_small_table_traces_no_switch(gate, switched, monkeypatch):
+    """A table the compiler can keep in fast memory (under
+    `FAST_MEMORY_BYTES`) gets the program as it was; a buffer too short to
+    split (n <= 128) likewise, whatever the table."""
+    from openembedding_tpu.ops import sparse
+    if gate is not None:
+        monkeypatch.setattr(sparse, "FAST_MEMORY_BYTES", gate)
+    opt = embed.Adagrad(learning_rate=0.1)
+    w = jnp.zeros((_ROWS, _DIM), jnp.float32)
+    slots = opt.init_slots(_ROWS, _DIM)
+
+    def text(n):
+        ids, g = jnp.zeros((n,), jnp.int32), jnp.zeros((n, _DIM), jnp.float32)
+        return jax.jit(lambda w, s: sparse_apply_dense_table(
+            opt, w, s, ids, g)).lower(w, slots).as_text()
+    assert ("stablehlo.case" in text(_N)) == switched
+    assert "stablehlo.case" not in text(128)

@@ -1,6 +1,7 @@
 """Scan-fused multi-step training (jit_train_many) must equal step-by-step."""
 
 import numpy as np
+import pytest
 
 import jax
 
@@ -8,7 +9,9 @@ import openembedding_tpu as embed
 from openembedding_tpu.data import synthetic_criteo
 from openembedding_tpu.model import Trainer
 from openembedding_tpu.models import make_deepfm
+from openembedding_tpu.ops.sparse import apply_ladder
 from openembedding_tpu.parallel import MeshTrainer, make_mesh
+from openembedding_tpu.utils import metrics as _metrics
 
 VOCAB = 1 << 10
 K = 4
@@ -65,3 +68,188 @@ def test_mesh_train_many_matches_step_by_step():
         np.asarray(state_a.tables["categorical"].weights),
         np.asarray(state_b.tables["categorical"].weights),
         rtol=1e-5, atol=1e-7)
+
+
+# -- the apply's load (ops/sparse.py "WHAT THE APPLY WORKS OVER"): each table's
+# `apply_fill` / `apply_full_steps` ride the step's stats and the window's
+# metrics, and fold to `sparse.apply_fill{table=}` / `sparse.apply_full_steps
+# {table=}` --------------------------------------------------------------------
+
+B_LOAD = 64
+N_LOAD = B_LOAD * 26       # 1,664 positions: apply_ladder = (512, 896, 1280, 1664)
+V_LOAD = 1 << 12
+
+
+@pytest.fixture
+def fresh_metrics(monkeypatch):
+    """An empty registry, and the ladder's gate lifted: tables under
+    `FAST_MEMORY_BYTES` (every table here) are otherwise left alone."""
+    from openembedding_tpu.ops import sparse
+    monkeypatch.setattr(sparse, "FAST_MEMORY_BYTES", 0)
+    _metrics._REGISTRY.clear()
+    yield _metrics
+    _metrics._REGISTRY.clear()
+
+
+def _batches_with_unique(counts, seed=0, vocab=V_LOAD):
+    """One batch of 64 examples a count: its 1,664 ids hold exactly that many
+    distinct rows."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for u in counts:
+        pool = rng.permutation(vocab)[:u]
+        ids = rng.permutation(np.concatenate([pool, rng.choice(pool, N_LOAD - u)]))
+        out.append({"sparse": {"categorical": ids.reshape(B_LOAD, 26).astype(np.int32)},
+                    "dense": rng.normal(size=(B_LOAD, 13)).astype(np.float32),
+                    "label": rng.integers(0, 2, (B_LOAD,)).astype(np.float32)})
+    return out
+
+
+def _same_tables(sa, sb):
+    assert set(sa.tables) == set(sb.tables)
+    for name in sa.tables:
+        a, b = sa.tables[name], sb.tables[name]
+        np.testing.assert_array_equal(np.asarray(a.weights), np.asarray(b.weights))
+        assert set(a.slots) == set(b.slots)
+        for k in a.slots:
+            np.testing.assert_array_equal(np.asarray(a.slots[k]),
+                                          np.asarray(b.slots[k]))
+
+
+@pytest.mark.parametrize("dim", [9, 64, 33])
+def test_train_many_over_different_rungs_equals_the_step_loop(dim, fresh_metrics):
+    """K batches that land on the four rungs (one id; 0.4; 0.7; all distinct):
+    the packed scan (dim 9: one table; dim 64: two), the split scan (dim 33:
+    not packable) and K `train_step` calls leave the same tables bit for bit,
+    and the window's load is the host's count."""
+    assert apply_ladder(N_LOAD) == (512, 896, 1280, N_LOAD)
+    counts = [1, 666, 1165, N_LOAD]
+    batches = _batches_with_unique(counts, seed=dim)
+    model = make_deepfm(vocabulary=V_LOAD, dim=dim, hidden=(8,))
+    tr = Trainer(model, embed.Adagrad(learning_rate=0.05), seed=1)
+    assert bool(tr._packed_layouts(tr.init(batches[0]))) == (dim != 33)
+
+    state_a = tr.init(batches[0])
+    step = tr.jit_train_step()
+    for b, u in zip(batches, counts):
+        state_a, m = step(state_a, b)
+        for table in model.ps_specs():
+            np.testing.assert_array_equal(
+                np.asarray(m["stats"][f"{table}/apply_fill"]),
+                np.float32(u) / np.float32(N_LOAD))
+            assert int(m["stats"][f"{table}/apply_full_steps"]) == int(u > 1280)
+    tr.record_step_stats(m)  # the last step: all distinct, the last rung
+    rep = fresh_metrics.report()
+    for table in model.ps_specs():
+        assert rep[f'sparse.apply_fill{{table="{table}"}}'] == 1.0
+        assert rep[f'sparse.apply_full_steps{{table="{table}"}}'] == 1.0
+
+    state_b, mm = tr.jit_train_many()(tr.init(batches[0]), _stack(batches))
+    _same_tables(state_a, state_b)
+    assert set(mm["apply_fill"]) == set(model.ps_specs())
+    for table in model.ps_specs():
+        assert float(mm["apply_fill"][table]) == 1.0       # the fullest step
+        assert int(mm["apply_full_steps"][table]) == 1     # steps on the last rung
+    fresh_metrics._REGISTRY.clear()
+    tr.record_window_stats(mm)
+    rep = fresh_metrics.report()
+    for table in model.ps_specs():
+        assert rep[f'sparse.apply_fill{{table="{table}"}}'] == 1.0
+        assert rep[f'sparse.apply_full_steps{{table="{table}"}}'] == 1.0
+
+
+def test_apply_full_steps_counts_all_distinct_batches(fresh_metrics):
+    model = make_deepfm(vocabulary=V_LOAD, dim=9, hidden=(8,))
+    tr = Trainer(model, embed.Adagrad(learning_rate=0.05), seed=1)
+    batches = _batches_with_unique([N_LOAD] * K, seed=4)
+    _, mm = tr.jit_train_many()(tr.init(batches[0]), _stack(batches))
+    assert int(mm["apply_full_steps"]["categorical"]) == K
+    tr.record_window_stats(mm)
+    tr.record_window_stats(mm)  # a counter: windows add up
+    assert fresh_metrics.report()[
+        'sparse.apply_full_steps{table="categorical"}'] == 2 * K
+
+
+def test_apply_load_on_the_benchmark_generator(fresh_metrics):
+    """The benchmark's own generator at small size (Zipf 1.05): no step on
+    the last rung, and the window's fill is the fullest step's host count."""
+    from benchmark import generators
+    batches = generators.zipf_criteo_batches(
+        batch_size=B_LOAD, steps=K, id_space=V_LOAD, seed=2147483659,
+        alpha=1.05, num_fields=26, dense_dim=13)
+    shares = [np.unique(b["sparse"]["categorical"]).size / N_LOAD
+              for b in batches]
+    assert max(shares) <= 1280 / N_LOAD
+    model = make_deepfm(vocabulary=V_LOAD, dim=9, hidden=(8,))
+    tr = Trainer(model, embed.Adagrad(learning_rate=0.05), seed=1)
+    _, mm = tr.jit_train_many()(tr.init(batches[0]), _stack(batches))
+    assert int(mm["apply_full_steps"]["categorical"]) == 0
+    np.testing.assert_allclose(float(mm["apply_fill"]["categorical"]),
+                               max(shares), rtol=1e-6)
+    tr.record_window_stats(mm)
+    rep = fresh_metrics.report()
+    assert rep['sparse.apply_fill{table="categorical"}'] == \
+        pytest.approx(max(shares), rel=1e-6)
+    assert rep['sparse.apply_full_steps{table="categorical"}'] == 0
+
+
+@pytest.mark.parametrize("capacity_factor", [0.0, 1.0])
+def test_mesh_window_carries_the_apply_load_beside_owner_fill(
+        capacity_factor, fresh_metrics):
+    """Four devices, 16 examples each (416 positions a device; exact mode
+    hands the owner 4 x 416 slots and it compacts them to 416): every owner's
+    re-dedup'd unique rows over its buffer, the fullest shard of the fullest
+    step, next to `owner_fill`."""
+    S, per = 4, 16
+    n = per * 26
+    assert len(apply_ladder(n)) == 4
+    rng = np.random.default_rng(7)
+    # ids under 1,024: an owner receives about 340 slots of its 416, every step
+    ids = rng.integers(0, 1 << 10, (K, S * per, 26)).astype(np.int32)
+    stacked = {"sparse": {"categorical": ids},
+               "dense": rng.normal(size=(K, S * per, 13)).astype(np.float32),
+               "label": rng.integers(0, 2, (K, S * per)).astype(np.float32)}
+    model = make_deepfm(vocabulary=V_LOAD, dim=9, hidden=(8,))
+    tr = MeshTrainer(model, embed.Adagrad(learning_rate=0.05), seed=1,
+                     mesh=make_mesh(jax.devices()[:S]),
+                     capacity_factor=capacity_factor)
+    one = jax.tree_util.tree_map(lambda x: x[0], stacked)
+    state = tr.init(one)
+    state, mm = tr.jit_train_many(stacked, state)(state, stacked)
+    mm = jax.device_get(mm)
+    fills = [max(np.unique(ids[k][ids[k] % S == d]).size for d in range(S)) / n
+             for k in range(K)]
+    if capacity_factor == 0.0:
+        assert 0 < float(mm["owner_fill"]["categorical"]) <= 1.0
+        assert int(mm["owner_full_steps"]["categorical"]) == 0
+        np.testing.assert_allclose(float(mm["apply_fill"]["categorical"]),
+                                   max(fills), rtol=1e-6)
+    else:  # nothing to compact: the owner's buffer is the S buckets of the wire
+        assert mm["owner_fill"] == {}
+        assert 0 < float(mm["apply_fill"]["categorical"]) <= max(fills) + 1e-6
+    assert int(mm["apply_full_steps"]["categorical"]) == 0
+    # ... and the K steps leave the tables of the program with no ladder
+    # (under PR 27's `lax.cond(view.fits, ...)` in exact mode), bit for bit
+    from openembedding_tpu.ops import sparse
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sparse, "apply_ladder", lambda n: (n,))
+        tr0 = MeshTrainer(model, embed.Adagrad(learning_rate=0.05), seed=1,
+                          mesh=make_mesh(jax.devices()[:S]),
+                          capacity_factor=capacity_factor)
+        state0 = tr0.init(one)
+        state0, m0 = tr0.jit_train_many(stacked, state0)(state0, stacked)
+    assert int(jax.device_get(m0)["apply_full_steps"]["categorical"]) == 0
+    _same_tables(state, state0)
+    tr.record_window_stats(mm)
+    rep = fresh_metrics.report()
+    assert rep['sparse.apply_fill{table="categorical"}'] == \
+        pytest.approx(float(mm["apply_fill"]["categorical"]))
+    assert rep['sparse.apply_full_steps{table="categorical"}'] == 0
+    # the step loop: a per-shard vector in the step's stats, folded the same
+    step = tr.jit_train_step(one, state)
+    state, ms = step(state, one)
+    vec = np.asarray(ms["stats"]["categorical/apply_fill"])
+    assert vec.shape == (S,) and 0 < vec.max() <= 1.0
+    tr.record_step_stats(ms)
+    assert fresh_metrics.report()['sparse.apply_fill{table="categorical"}'] == \
+        pytest.approx(float(vec.max()))

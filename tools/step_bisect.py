@@ -116,7 +116,7 @@ def main():
 
         def papply(carry):
             return sparse_apply_packed_table(opt, carry, lay, DIM + 1, ids,
-                                             grads)
+                                             grads)[0]
         timeit_scan(papply, packed, "sparse apply PACKED")
 
         # 0b. whole train step on the packed state (what train_many scans)
