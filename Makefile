@@ -6,16 +6,16 @@
 # flips JAX to the cpu backend before it initializes, so no TPU is needed (or
 # claimed — a chip belongs to one process at a time). `make ci` is the one
 # command that must stay green. The chip itself is driven by `python
-# chip_smoke.py` and `python bench.py` (README "Running it").
+# chip_smoke.py` and `python3 -m benchmark.run` (README "Running it").
 
 PY ?= python
 CPU_ENV = env JAX_PLATFORMS=cpu \
 	XLA_FLAGS=--xla_force_host_platform_device_count=8
 
-.PHONY: ci test dryrun bench-smoke chip-smoke-cpu native lint lint-fast \
-	lint-budget lint-metrics weave capsule-smoke timeline-smoke
+.PHONY: ci test dryrun chip-smoke-cpu native lint lint-fast lint-budget \
+	weave capsule-smoke timeline-smoke
 
-ci: lint test dryrun bench-smoke weave capsule-smoke timeline-smoke
+ci: lint test dryrun weave capsule-smoke timeline-smoke
 
 # the full static-analysis + invariant-guard suite (tools/oelint): eleven
 # passes — trace-hazard (recompile hazards in jit-reachable code), host-sync
@@ -44,11 +44,6 @@ lint-fast:
 lint-budget:
 	$(CPU_ENV) $(PY) -m tools.oelint --update-budget
 
-# metric-name hygiene only (back-compat alias; the check is oelint's
-# metrics pass and runs as part of `make lint`)
-lint-metrics:
-	$(PY) tools/lint_metrics.py
-
 # deterministic concurrency testing (tools/oeweave): explore seeded-random +
 # preemption-bounded interleavings of the threaded control plane (subscriber
 # state machine, micro-batcher, persister, placement watcher, offload store,
@@ -70,14 +65,6 @@ dryrun:
 	$(CPU_ENV) $(PY) -c "import __graft_entry__ as g; \
 	fn, args = g.entry(); import jax; out = jax.jit(fn)(*args); \
 	print('entry OK, loss', float(out['loss'])); g.dryrun_multichip(8)"
-
-# the benchmark harness end to end on tiny shapes, in its one process, on the
-# CPU BY NAME (measures nothing — its JSON says "platform": "cpu"; proves every
-# case runs and the one-line JSON contract holds). bench.py exits non-zero
-# when any case failed or was skipped, and that fails this target.
-bench-smoke:
-	$(CPU_ENV) OETPU_BENCH_SCAN_STEPS=3 OETPU_BENCH_REPEATS=1 \
-	OETPU_BENCH_VOCAB=65536 OETPU_BENCH_BUDGET_S=480 $(PY) bench.py
 
 # rehearse chip_smoke.py's three stages (train -> serve -> mesh) at a tiny size
 # on 4 virtual CPU devices before spending chip time on it; the explicit sizes

@@ -5,7 +5,8 @@ correctness and counts bytes and collectives, and it needs no accelerator. A
 chip belongs to one process at a time, so a test run must never claim one —
 before any backend initializes we (a) point XLA at 8 virtual host devices and
 (b) flip jax's platform selection to cpu, whatever `JAX_PLATFORMS` says. The
-chip is for `chip_smoke.py` and `bench.py` (README "Running it").
+chip is for `chip_smoke.py` and `python3 -m benchmark.run` (README "Running
+it").
 """
 
 import os

@@ -666,8 +666,8 @@ class Trainer:
         # PULL: gather rows for this batch (non-differentiated w.r.t. the table — the
         # rows themselves are the leaf, exactly the reference's pull/push contract).
         # Hash tables insert unseen ids here, so pull threads the table state.
-        # MeshTrainer overrides tables_pull/tables_apply with the fused
-        # multi-table exchange (3 all_to_alls per dim-group, not per table).
+        # MeshTrainer's tables_pull/tables_apply are the sharded exchange
+        # (3 all_to_alls per dim-group).
         pulled_tables, pulled, stats, pull_plans = self.tables_pull(
             state.tables, batch, ps_specs, packed)
 
@@ -769,8 +769,8 @@ class Trainer:
 
     # hooks overridden by MeshTrainer:
     def tables_pull(self, tables, batch, ps_specs, packed):
-        """Pull every PS table's rows for this batch. Default: one pull per
-        table. MeshTrainer overrides with the fused dim-group exchange.
+        """Pull every PS table's rows for this batch: one local pull per
+        table. MeshTrainer overrides with the sharded dim-group exchange.
         -> ({name: new_table}, {name: rows}, {stat: v}, {name: plan})."""
         pulled_tables, pulled, stats, plans = {}, {}, {}, {}
         for name, spec in ps_specs.items():
@@ -784,8 +784,8 @@ class Trainer:
 
     def tables_apply(self, ps_specs, pulled_tables, batch, row_grads, packed,
                      plans):
-        """Push + fused update for every PS table. Default: one push per
-        table. MeshTrainer overrides with the fused dim-group exchange.
+        """Push + fused update for every PS table: one local apply per
+        table. MeshTrainer overrides with the sharded dim-group exchange.
         -> ({name: new_table}, {stat: v})."""
         new_tables, stats = {}, {}
         for name, spec in ps_specs.items():
@@ -984,8 +984,9 @@ class Trainer:
     def _packed_layouts(self, state: TrainState):
         """{name: column layout} for tables worth packing inside the scan
         (see `ops/sparse.packed_layout`). Applies per shard under MeshTrainer
-        too — its `_packed_pull`/`_packed_apply` hooks route through the
-        packed-aware sharded protocol (parallel/sharded.py)."""
+        too (widths are shard-invariant): its exchange serves packed rows by
+        their width and hands the layout to the owner's apply
+        (parallel/sharded.py)."""
         from .ops.sparse import packed_layout
         out = {}
         for name, spec in self.model.ps_specs().items():

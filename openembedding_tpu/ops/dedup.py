@@ -30,7 +30,7 @@ class UniqueResult(NamedTuple):
     # ids `seg`, so downstream reductions run as segment_sum(payload[order], seg,
     # indices_are_sorted=True) — the sorted path vectorizes on TPU while an
     # unsorted segment scatter-add serializes (28 ms vs 2.5 ms for the benchmark
-    # batch; tools/step_bisect.py)
+    # batch)
     order: jax.Array        # (n,) int32
     seg: jax.Array          # (n,) int32, ascending
 

@@ -407,10 +407,10 @@ def unpack_topk(wire: jax.Array, k: int, m: int) -> jax.Array:
 
 # ---------------------------------------------------------------------------
 # Static wire-cost model (bytes/step, collectives/step) — what the metrics
-# gauges, PERF.md and tools/wire_microbench.py report. The model prices the
-# a2a RESULT buffers (S * cap slots per table, self-shard included), which is
-# exactly what the oelint hlo-budget pass counts out of the compiled HLO —
-# `wire_model_delta` in tools/oelint/hlo_budget.json pins model == HLO.
+# gauges and PERF.md report. The model prices the a2a RESULT buffers (S * cap
+# slots per table, self-shard included), which is exactly what the oelint
+# hlo-budget pass counts out of the compiled HLO — `wire_model_delta` in
+# tools/oelint/hlo_budget.json pins model == HLO.
 # ---------------------------------------------------------------------------
 
 
@@ -420,8 +420,7 @@ def id_wire_itemsize(pair: bool, itemsize: int) -> int:
     return 8 if pair else itemsize
 
 
-def exchange_cost(tables, num_shards: int, fmt: str,
-                  fused: bool = True) -> dict:
+def exchange_cost(tables, num_shards: int, fmt: str) -> dict:
     """Static per-device wire cost of one train step.
 
     `tables`: list of dicts {dim, cap, pair (bool), id_itemsize} — one per
@@ -430,7 +429,6 @@ def exchange_cost(tables, num_shards: int, fmt: str,
     per-table wire dict, round 17); tables sharing (dim, fmt) form one
     dim-group — a mixed-format dim splits into one fused group per format,
     exactly how `MeshTrainer._exchange_groups` splits the compiled a2as.
-    `fused=False` prices the pre-round-6 per-table protocol for comparison.
     Bytes are what ONE device ships through the three all_to_alls (recv
     volume is symmetric). `bytes_scales` breaks out the in-band scale lanes
     (int8 only) already included in the row/grad totals — the honest price
@@ -440,20 +438,17 @@ def exchange_cost(tables, num_shards: int, fmt: str,
     groups = {}
     for t in tables:
         groups.setdefault((t["dim"], t.get("fmt", fmt)), []).append(t)
-    n_units = len(groups) if fused else len(tables)
     w = jnp.dtype(wire_dtype(fmt)).itemsize
     bytes_ids = bytes_rows = bytes_grads = bytes_scales = 0
     for (dim, tf), members in groups.items():
-        # fused groups widen mixed-layout ids to the common wire layout;
-        # a uniform group keeps its native layout (see dedup.concat_owner_buckets)
+        # a group widens mixed-layout ids to the common wire layout; a
+        # uniform group keeps its native layout (see dedup.concat_owner_buckets)
         pair_wire = any(m["pair"] for m in members)
         iid = max(m["id_itemsize"] for m in members)
         tw = jnp.dtype(wire_dtype(tf)).itemsize
         for m in members:
             cap = m["cap"]
-            per_id = (id_wire_itemsize(pair_wire, iid) if fused
-                      else id_wire_itemsize(m["pair"], m["id_itemsize"]))
-            bytes_ids += S * cap * per_id
+            bytes_ids += S * cap * id_wire_itemsize(pair_wire, iid)
             bytes_rows += S * cap * rows_wire_width(dim, tf) * tw
             bytes_grads += S * cap * grads_wire_width(dim, tf) * tw
             if tf == "int8":
@@ -461,11 +456,11 @@ def exchange_cost(tables, num_shards: int, fmt: str,
                 bytes_scales += S * cap * _SCALE_LANES * scale_blocks(dim) \
                     * tw * 2
     total = bytes_ids + bytes_rows + bytes_grads
-    return {"format": fmt, "num_shards": S, "fused": fused,
+    return {"format": fmt, "num_shards": S,
             "dim_groups": len(groups), "tables": len(tables),
             "wire_dtype": str(jnp.dtype(wire_dtype(fmt))),
             "wire_itemsize": int(w),
-            "collectives_per_step": 3 * n_units if S > 1 else 0,
+            "collectives_per_step": 3 * len(groups) if S > 1 else 0,
             "bytes_ids": int(bytes_ids), "bytes_rows": int(bytes_rows),
             "bytes_grads": int(bytes_grads),
             "bytes_scales": int(bytes_scales) if S > 1 else 0,

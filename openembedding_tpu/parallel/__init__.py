@@ -1,6 +1,6 @@
 from .mesh import make_mesh, table_sharding, replicated, batch_sharding
-from .sharded import (sharded_lookup_train, sharded_lookup, sharded_apply_gradients,
-                      deinterleave_rows, interleave_rows, exchange_load_stats)
+from .sharded import (sharded_lookup, deinterleave_rows, interleave_rows,
+                      exchange_load_stats)
 from .trainer import MeshTrainer, SeqMeshTrainer
 from .checkpoint import (save_sharded, load_sharded, snapshot_addressable,
                          checkpoint_layout)

@@ -158,7 +158,7 @@ collective, no extra wire bytes, identical bucket shapes.
 
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -507,9 +507,9 @@ def grouped_make_plans(specs, ids_list, *, axis: str = DATA_AXIS,
     segment of one concatenated array (`ops/dedup.concat_owner_buckets`), so
     the receive side recovers per-table buckets by slicing. `ids_list` must
     already be in each table's key layout (`adapt_batch_ids`). `hots`: one
-    Optional[HotRows] per table (hot ids skip the fused wire exactly like the
-    per-table path). `migs`: one Optional[MigRows] per table (the owner-
-    assignment indirection rides each table's own route)."""
+    Optional[HotRows] per table (hot ids never enter the buckets). `migs`:
+    one Optional[MigRows] per table (the owner-assignment indirection rides
+    each table's own route)."""
     with _trace.scope("exchange", "route"):
         S = jax.lax.axis_size(axis)
         if hots is None:
@@ -877,24 +877,16 @@ def _hot_apply(spec: EmbeddingSpec, optimizer, hot: HotRows,
 
 def _reassemble(plan: ExchangePlan, rows: jax.Array, out_shape,
                 dim: int, axis: str,
-                hot: Optional[HotRows] = None,
-                fmt: str = "fp32") -> jax.Array:
-    """Client side: rows back over the a2a, un-bucket, expand duplicates,
-    overlay the local hot-cache gather. At S=1 the served rows ARE the unique
-    rows (make_plan's identity plan) — no a2a, no unbucket gather. A narrow
-    `fmt` means `rows` is the owner-edge ENCODED buffer (`_serve_rows`): the
-    all_to_all moves it as-is — int8/bf16 through the collective — and the
-    decode runs here, at the client edge."""
+                hot: Optional[HotRows] = None) -> jax.Array:
+    """Client side of a pull whose `rows` are raw fp32-wire rows: rows back
+    over the a2a, un-bucket, expand duplicates, overlay the local hot-cache
+    gather. At S=1 the served rows ARE the unique rows (make_plan's identity
+    plan) — no a2a, no unbucket gather."""
     with _trace.scope("exchange", "reassemble"):
         if jax.lax.axis_size(axis) == 1:
             uniq_rows = rows[0]
         else:
             back = _a2a("rows", rows, axis)
-            if fmt != "fp32":
-                from ..ops import wire as wire_mod
-                back = wire_mod.unpack_inband(
-                    back.reshape(-1, back.shape[-1]), dim,
-                    fmt).reshape(back.shape[0], -1, dim)
             uniq_rows = unbucket(back, plan.buckets.owner, plan.buckets.slot)
         uniq_rows = _merge_hot_rows(plan, uniq_rows, hot)
         out = jnp.take(uniq_rows, plan.uniq.inverse, axis=0)
@@ -905,51 +897,6 @@ def _reassemble(plan: ExchangePlan, rows: jax.Array, out_shape,
 # host-sync lint pass (`make lint`): ANY device->host sync added inside —
 # jax.device_get, block_until_ready, np.asarray of a device value, float()
 # of a tracer — fails CI. The exchange functions below all carry it.
-# oelint: hot-path device_get=0
-def sharded_lookup_train(
-    spec: EmbeddingSpec,
-    state: EmbeddingTableState,
-    ids: jax.Array,
-    *,
-    axis: str = DATA_AXIS,
-    capacity_factor: float = 0.0,
-    load_stats: bool = True,
-    wire: Optional[str] = "fp32",
-) -> Tuple[EmbeddingTableState, jax.Array, Dict[str, jax.Array], ExchangePlan]:
-    """Training pull inside shard_map. Returns (new_shard_state, rows, stats, plan);
-    feed the plan to `sharded_apply_gradients` for the same batch.
-    `load_stats=False` drops the per-shard skew vectors
-    (`exchange_load_stats`) from the stats dict. `wire` selects the pull
-    a2a's payload format (default fp32, the bit-exact pre-round-13 wire;
-    None resolves $OETPU_WIRE like the fused path)."""
-    from ..ops import wire as wire_mod
-    ids = adapt_batch_ids(spec, state, ids)
-    plan = make_plan(spec, ids, axis=axis, capacity_factor=capacity_factor,
-                     hot=state.hot, mig=state.mig)
-    fmt = (wire_mod.wire_format(wire)
-           if jax.lax.axis_size(axis) > 1 else "fp32")
-    state, rows = _serve_rows(spec, state, plan, train=True, axis=axis,
-                              fmt=fmt)
-    out = _reassemble(plan, rows, _out_shape(spec, ids), spec.output_dim,
-                      axis, hot=state.hot, fmt=fmt)
-    if fmt != "fp32":
-        out = out.astype(spec.dtype)
-    stats = {
-        # reference accumulator counts id POSITIONS (lane-count agnostic)
-        "pull_indices": jnp.asarray(ids_positions(spec, ids), jnp.int32),
-        "pull_unique": plan.uniq.num_unique,                # `pull_unique` counter
-        "pull_overflow": plan.buckets.overflow,
-    }
-    if plan.hot_slot is not None:
-        stats.update(_hot_pull_stats(spec, plan, flatten_ids(spec, ids),
-                                     fmt))
-    if plan.mig_moved is not None:
-        stats.update(_mig_pull_stats(plan))
-    if load_stats:
-        stats.update(exchange_load_stats(plan, axis=axis))
-    return state, out, stats, plan
-
-
 # oelint: hot-path device_get=0
 def sharded_lookup(
     spec: EmbeddingSpec,
@@ -969,102 +916,6 @@ def sharded_lookup(
     _, rows = _serve_rows(spec, state, plan, train=False, axis=axis)
     return _reassemble(plan, rows, _out_shape(spec, ids), spec.output_dim,
                        axis, hot=state.hot)
-
-
-# oelint: hot-path device_get=0
-def sharded_apply_gradients(
-    spec: EmbeddingSpec,
-    state: EmbeddingTableState,
-    optimizer,
-    ids: jax.Array,
-    grads: jax.Array,
-    *,
-    axis: str = DATA_AXIS,
-    capacity_factor: float = 0.0,
-    plan: Optional[ExchangePlan] = None,
-    packed=None,
-    wire: Optional[str] = "fp32",
-    hot_wire: Optional[str] = None,
-) -> Tuple[EmbeddingTableState, Dict[str, jax.Array]]:
-    """Push + fused update inside shard_map. Pass the pull's `plan` to skip the
-    duplicate dedup/bucketing and id exchange.
-
-    `packed`: the column layout when the shard state holds the packed
-    weights+slots array (`ops/sparse.packed_layout`, inside
-    `Trainer.train_many`'s scan) — the update then pays one gather/scatter
-    pair per shard instead of one per array. `wire` selects the push a2a's
-    payload format (int8 grads round stochastically — the hash dither of
-    `ops/wire._dither`); `hot_wire` the hot-row reduction's (defaults to
-    `wire`)."""
-    from ..ops import wire as wire_mod
-    S = jax.lax.axis_size(axis)
-    fmt = wire_mod.wire_format(wire) if S > 1 else "fp32"
-    hot_fmt = (wire_mod.wire_format(hot_wire) if hot_wire is not None
-               else fmt)
-    if plan is None:
-        ids = adapt_batch_ids(spec, state, ids)
-        plan = make_plan(spec, ids, axis=axis, capacity_factor=capacity_factor,
-                         hot=state.hot, mig=state.mig)
-    gflat = grads.reshape(-1, spec.output_dim)
-    n = gflat.shape[0]
-    uniq, buckets, cap = plan.uniq, plan.buckets, plan.cap
-    # client-side pre-sum over local duplicates (`EmbeddingPushOperator.cpp:29-62`);
-    # sorted-segment path (see UniqueResult.segment_reduce)
-    with _trace.scope("exchange", "route"):
-        g = uniq.segment_reduce(gflat)
-        valid = (uniq.counts > 0) & _id_valid(spec, uniq.unique_ids)
-    new_hot = (None if plan.hot_slot is None or state.hot is None
-               else _hot_apply(spec, optimizer, state.hot, plan, g, axis,
-                               fmt=hot_fmt))
-    stats = {"push_overflow": buckets.overflow}
-    if S == 1:
-        # identity routing (see make_plan): the local unique slots ARE the
-        # server's receive buffer — no bucket scatter, no grad/count a2a
-        new_state, load = _apply_unique(
-            spec, state, optimizer, uniq.unique_ids, g,
-            jnp.where(valid, uniq.counts, 0), S, packed=packed)
-    else:
-        counts_i32 = jnp.where(valid, uniq.counts, 0).astype(jnp.int32)
-        if fmt == "fp32":
-            # scatter grads into the plan's bucket positions (payload follows
-            # its id), with the duplicate COUNT riding as extra payload lanes
-            # — the raw int32 bits BITCAST into the grad dtype (exact for any
-            # count, no upcast: one f32 lane, or two bf16 lanes). Folding the
-            # counts into the grad payload makes the push ONE all_to_all
-            # instead of two.
-            count_lanes = jax.lax.bitcast_convert_type(counts_i32, g.dtype)
-            count_lanes = count_lanes.reshape(counts_i32.shape[0], -1)
-            lanes = count_lanes.shape[1]
-            payload = jnp.concatenate([g, count_lanes], axis=1)
-
-            def decode(flat):
-                tail = flat[:, spec.output_dim:]
-                return flat[:, :spec.output_dim], \
-                    jax.lax.bitcast_convert_type(
-                        tail[:, 0] if lanes == 1 else tail,
-                        jnp.int32).reshape(-1)
-        else:
-            # narrow push: client-edge encode so the a2a operand is int8/bf16
-            # (counts still bit-exact in the trailing lanes; empty slots are
-            # zero bits -> grad 0, scale 0, count 0). int8 grads round with
-            # the deterministic hash dither — unbiased pushes, no residual
-            # needed on the client (the pull-side ef handles the row
-            # direction).
-            payload = wire_mod.encode_grads(g, counts_i32, fmt,
-                                            stochastic=(fmt == "int8"))
-
-            def decode(flat):
-                rg32, rc = wire_mod.decode_grads(flat, spec.output_dim, fmt)
-                return rg32.astype(g.dtype), rc
-        recv = _a2a("grads", _scatter_buckets(payload, buckets, S, cap), axis)
-        # server side: cross-source re-dedup + fused optimizer (MPSC reduce
-        # + update)
-        new_state, load = _owner_apply(spec, state, optimizer, plan, recv,
-                                       decode, S, packed=packed)
-    stats.update(_apply_load_stats(load, axis))
-    if new_hot is not None:
-        new_state = new_state.replace(hot=new_hot)
-    return new_state, stats
 
 
 def _apply_load_stats(load: Dict[str, jax.Array], axis) -> Dict[str, jax.Array]:
@@ -1186,16 +1037,17 @@ def _apply_unique(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
 
 
 # ---------------------------------------------------------------------------
-# Grouped multi-table exchange: tables sharing an embedding dim fuse their
-# three all_to_alls (ids / rows / grads+counts) into one each, and the row and
-# grad payloads optionally travel quantized (`ops/wire.py`). Per-table
-# dedup/routing, serving, and the optimizer apply are EXACTLY the per-table
-# protocol above — only the wire is shared, so a group of one table with fp32
-# wire is bit-identical to `sharded_lookup_train`/`sharded_apply_gradients`.
-# Since round 17 formats are per table: groups are keyed on (dim, fmt) —
-# `split_wire_groups` subdivides the model's dim-groups so every group the
-# protocol below sees is format-uniform (its encoded widths stay uniform and
-# the concat still fuses one a2a).
+# The training exchange, one GROUP of tables at a time: tables sharing an
+# embedding dim fuse their three all_to_alls (ids / rows / grads+counts) into
+# one each, and the row and grad payloads optionally travel quantized
+# (`ops/wire.py`). Dedup/routing, serving and the optimizer apply run per
+# table — grouping shares the wire, never the math: at fp32 wire a table
+# trains bit-identically whichever tables share its group
+# (`tests/test_wire.py` pins it against one group per table). Formats are per
+# table and groups are keyed on (dim, fmt) — `split_wire_groups` subdivides
+# the model's dim-groups so every group the protocol below sees is
+# format-uniform (its encoded widths stay uniform and the concat still fuses
+# one a2a).
 # ---------------------------------------------------------------------------
 
 

@@ -32,8 +32,7 @@ from ..utils import trace as _trace
 from .mesh import DATA_AXIS, make_mesh
 from .sharded import (build_hot_identity, build_mig_identity, hot_gather,
                       hot_writeback, mig_gather, mig_writeback,
-                      sharded_apply_gradients, sharded_lookup,
-                      sharded_lookup_train)
+                      sharded_lookup)
 
 
 class MeshTrainer(Trainer):
@@ -43,7 +42,6 @@ class MeshTrainer(Trainer):
                  capacity_factor: float = 0.0,
                  on_overflow: str = "count",
                  wire: Optional[str] = None,
-                 group_exchange: bool = True,
                  shard_stats: bool = True,
                  hot_rows: "int | Dict[str, int]" = 0,
                  mig_rows: "int | Dict[str, int]" = 0,
@@ -74,15 +72,14 @@ class MeshTrainer(Trainer):
         # per-(src,dst) bucket headroom for the a2a exchange; 0 = exact (capacity = n)
         self.capacity_factor = capacity_factor
         # wire payload format for the exchange a2as: None -> $OETPU_WIRE ->
-        # bf16 (ops/wire.py; "fp32" opts out of quantization entirely).
-        # Since round 13 the encode runs INSIDE the protocol (owner/client
-        # edge), so the compiled a2a operands carry this format — both the
-        # fused and the per-table paths. Since round 17 a PER-TABLE dict is
+        # bf16 (ops/wire.py; "fp32" opts out of quantization entirely). The
+        # encode runs INSIDE the protocol (owner/client edge), so the
+        # compiled a2a operands carry this format. A PER-TABLE dict is
         # accepted too ({"big_table": "int8", "*": "fp32"} — "*" the default
         # for unnamed tables): formats resolve once at trace time
-        # (`wire_for`), and the fused exchange splits dim-groups on
-        # (dim, fmt) so mixed-format tables ride separate a2a groups while
-        # same-format tables stay fused (`_exchange_groups`).
+        # (`wire_for`), and the exchange splits dim-groups on (dim, fmt) so
+        # mixed-format tables ride separate a2a groups while same-format
+        # tables stay fused (`_exchange_groups`).
         if isinstance(wire, dict):
             from ..ops import wire as wire_mod
             unknown = [k for k in wire
@@ -104,10 +101,6 @@ class MeshTrainer(Trainer):
         # wire format is int8 on a real mesh (bf16 truncation is unbiased
         # enough for AUC parity; int8 is not — PERF.md round 13)
         self.error_feedback = error_feedback
-        # group_exchange=False falls back to the pre-round-6 per-table
-        # protocol (3 all_to_alls per TABLE) — the comparison baseline
-        # tools/wire_microbench.py measures against
-        self.group_exchange = group_exchange
         # static wire-cost model of the last traced step (set at trace time;
         # also published as exchange.* gauges — utils/metrics.py)
         self.last_wire_cost = None
@@ -115,8 +108,8 @@ class MeshTrainer(Trainer):
         # telemetry: `sharded.exchange_load_stats` -> exchange.shard_rows /
         # shard_positions / bucket_fill vectors in the step stats, folded to
         # labeled gauges by `metrics.record_step_stats`). Pure array math on
-        # the routing plan — bench.py's `skew` case bounds the cost; turn
-        # off to shave the last percent from a tuned production step
+        # the routing plan; turn off to shave the last percent from a tuned
+        # production step
         self.shard_stats = shard_stats
         # bounded buckets can DROP ids (divergence from the reference's
         # unbounded buffers, `EmbeddingPullOperator.cpp:86-112`); the policy
@@ -1407,8 +1400,8 @@ class MeshTrainer(Trainer):
         """Dim-groups restricted to the tables actually pulled this step,
         then split by resolved per-table wire format: tables sharing
         (dim, fmt) stay fused on one a2a pair, mixed-format dims ride
-        separate groups. Uniform-format configs split into exactly the
-        round-13 dim-groups — same grouping, byte-identical HLO."""
+        separate groups. A uniform-format config keeps the model's
+        dim-groups as they are."""
         from .sharded import split_wire_groups
         groups = [[n for n in g if n in ps_specs]
                   for g in self.model.dim_groups()
@@ -1417,13 +1410,11 @@ class MeshTrainer(Trainer):
 
     # oelint: hot-path device_get=0
     def tables_pull(self, tables, batch, ps_specs, packed):
-        """Fused pull: 1 id a2a + 1 (optionally quantized) row a2a per
-        DIM-GROUP instead of per table (`sharded.grouped_lookup_train`).
+        """Sharded pull: 1 id a2a + 1 (optionally quantized) row a2a per
+        DIM-GROUP (`sharded.grouped_lookup_train`).
         Packed tables need no special pull path — `_serve_rows` self-detects
         packed rows by width."""
         self._observe_wire_cost(ps_specs, batch)
-        if not self.group_exchange:
-            return super().tables_pull(tables, batch, ps_specs, packed)
         from .sharded import grouped_lookup_train
         pulled_tables, pulled, stats, plans = {}, {}, {}, {}
         for names in self._exchange_groups(ps_specs):
@@ -1445,11 +1436,8 @@ class MeshTrainer(Trainer):
     # oelint: hot-path device_get=0
     def tables_apply(self, ps_specs, pulled_tables, batch, row_grads, packed,
                      plans):
-        """Fused push: 1 grads+counts a2a per DIM-GROUP
+        """Sharded push: 1 grads+counts a2a per DIM-GROUP
         (`sharded.grouped_apply_gradients`), reusing the pull's plans."""
-        if not self.group_exchange:
-            return super().tables_apply(ps_specs, pulled_tables, batch,
-                                        row_grads, packed, plans)
         from .sharded import grouped_apply_gradients
         new_tables, stats = {}, {}
         for names in self._exchange_groups(ps_specs):
@@ -1478,17 +1466,6 @@ class MeshTrainer(Trainer):
         default, so the serial path compiles byte-identical HLO."""
         return self.pipeline_steps and self.num_shards > 1
 
-    def _pipeline_groups(self, ps_specs):
-        """Exchange groups the pipelined loop fans over: the fused
-        (dim, fmt)-groups, or singleton groups under group_exchange=False
-        (the per-table protocol has no split-phase entry points; fp32
-        grouped vs per-table pulls are bit-identical — the round-6 pin — so
-        exactness is preserved there too)."""
-        groups = self._exchange_groups(ps_specs)
-        if not self.group_exchange:
-            return [[n] for g in groups for n in g]
-        return groups
-
     # oelint: hot-path device_get=0
     def _pipeline_prefetch(self, tables, batch, ps_specs):
         """Issue a batch's exchange a FULL STEP ahead: id plane (dedup/sort/
@@ -1499,9 +1476,8 @@ class MeshTrainer(Trainer):
         self._observe_wire_cost(ps_specs, batch, pipelined=True)
         new_tables = dict(tables)
         plans, rows, stats = {}, {}, {}
-        groups = self._pipeline_groups(ps_specs)
         with _trace.scope("trainer", "prefetch"):
-            for names in groups:
+            for names in self._exchange_groups(ps_specs):
                 specs = [ps_specs[n] for n in names]
                 ids_list = [jnp.asarray(batch["sparse"][s.feature_name])
                             for s in specs]
@@ -1524,7 +1500,7 @@ class MeshTrainer(Trainer):
         pure local math, no collective)."""
         from .sharded import grouped_finalize_pull
         pulled = {}
-        for names in self._pipeline_groups(ps_specs):
+        for names in self._exchange_groups(ps_specs):
             specs = [ps_specs[n] for n in names]
             ids_list = [jnp.asarray(batch["sparse"][s.feature_name])
                         for s in specs]
@@ -1548,7 +1524,7 @@ class MeshTrainer(Trainer):
         new_tables = dict(tables)
         coflow = jnp.zeros((), jnp.int32)
         with _trace.scope("trainer", "conflict_patch"):
-            for names in self._pipeline_groups(ps_specs):
+            for names in self._exchange_groups(ps_specs):
                 specs = [ps_specs[n] for n in names]
                 outs, stats_list, states = grouped_conflict_patch(
                     specs, [tables[n] for n in names],
@@ -1825,14 +1801,12 @@ class MeshTrainer(Trainer):
             if M:
                 _metrics.observe("placement.mig_rows", float(M), "gauge",
                                  labels={"table": name})
-        # since round 13 BOTH exchange protocols put the resolved wire format
-        # through the compiled a2as (in-band scales); the model prices the
-        # a2a RESULT buffers, the same thing oelint's hlo-budget counts.
-        # Per-table "fmt" keys make the model group on (dim, fmt) exactly
-        # like _exchange_groups does
+        # the resolved wire format goes through the compiled a2as (in-band
+        # scales); the model prices the a2a RESULT buffers, the same thing
+        # oelint's hlo-budget counts. Per-table "fmt" keys make the model
+        # group on (dim, fmt) exactly like _exchange_groups does
         fmt = self.wire_default()
-        cost = wire_mod.exchange_cost(
-            tables, self.num_shards, fmt, fused=self.group_exchange)
+        cost = wire_mod.exchange_cost(tables, self.num_shards, fmt)
         self.last_wire_cost = cost
         _metrics.observe_exchange_cost(cost)
         for name in ps_specs:
@@ -1901,32 +1875,6 @@ class MeshTrainer(Trainer):
             cost["hot_all_gather_bytes"] = ag
             cost["hot_wire_format"] = ",".join(sorted(hot_by_fmt))
             self.last_wire_cost = cost
-
-    # packed scan layout: the base `_packed_layouts` gate applies per shard
-    # (widths are shard-invariant); the sharded pull auto-slices packed rows
-    # and the apply takes the layout, so only the two hooks below differ.
-
-    def _packed_pull(self, spec, table, ids):
-        # the sharded pull self-detects packed rows by width (_serve_rows)
-        return self.table_pull(spec, table, ids)
-
-    def _packed_apply(self, spec, table, ids, grads, layout, plan=None):
-        return sharded_apply_gradients(
-            spec, table, self.opt_for(spec), ids, grads, axis=self.axis,
-            capacity_factor=self.capacity_factor, plan=plan, packed=layout,
-            wire=self.wire_for(spec.name), hot_wire=self.hot_wire)
-
-    def table_pull(self, spec, table, ids):
-        return sharded_lookup_train(
-            spec, table, ids, axis=self.axis,
-            capacity_factor=self.capacity_factor,
-            load_stats=self.shard_stats, wire=self.wire_for(spec.name))
-
-    def table_apply(self, spec, table, ids, grads, plan=None):
-        return sharded_apply_gradients(
-            spec, table, self.opt_for(spec), ids, grads, axis=self.axis,
-            capacity_factor=self.capacity_factor, plan=plan,
-            wire=self.wire_for(spec.name), hot_wire=self.hot_wire)
 
     def table_lookup(self, spec, table, ids):
         return sharded_lookup(spec, table, ids, axis=self.axis,
@@ -2074,7 +2022,7 @@ class SeqMeshTrainer(MeshTrainer):
 
     def __init__(self, model, optimizer=None, *, mesh: Mesh, seed: int = 0,
                  capacity_factor: float = 0.0, wire: Optional[str] = None,
-                 group_exchange: bool = True, shard_stats: bool = True,
+                 shard_stats: bool = True,
                  hot_rows: "int | Dict[str, int]" = 0,
                  mig_rows: "int | Dict[str, int]" = 0,
                  hot_wire: Optional[str] = None,
@@ -2088,7 +2036,6 @@ class SeqMeshTrainer(MeshTrainer):
                 f"{mesh.axis_names}")
         super().__init__(model, optimizer, mesh=mesh, seed=seed,
                          capacity_factor=capacity_factor, wire=wire,
-                         group_exchange=group_exchange,
                          shard_stats=shard_stats, hot_rows=hot_rows,
                          mig_rows=mig_rows, hot_wire=hot_wire,
                          error_feedback=error_feedback,

@@ -1,5 +1,5 @@
 """Persistent XLA compile cache for PROCESS ENTRY POINTS (`chip_smoke.py`,
-`bench.py`, `python -m openembedding_tpu.serving`, `examples/criteo_deepctr.py`).
+`python -m openembedding_tpu.serving`, `examples/criteo_deepctr.py`).
 
 Never called at library import and never under pytest: a cache directory is a
 process-wide JAX setting, so only the code that owns the process sets it.

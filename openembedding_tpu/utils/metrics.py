@@ -21,7 +21,7 @@ Beyond the reference: metric LABELS (`observe(name, v, labels={"table": ...})` -
 fixed log-spaced-bucket histograms exposing p50/p95/p99 in `report()` and proper
 `_bucket`/`_sum`/`_count` series in `prometheus_text()`.
 
-Naming scheme (enforced by `make lint-metrics` / tools/lint_metrics.py): metric
+Naming scheme (enforced by `make lint`, oelint's metrics pass): metric
 names are dot-joined lowercase `group.name[.qualifier]` segments of
 `[a-z0-9_]+` — e.g. `serving.predict.ms`, `sync.rollbacks`,
 `exchange.wire_bytes_per_step`. Per-instance dimensions (table, model) go in

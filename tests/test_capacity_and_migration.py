@@ -156,12 +156,12 @@ def test_train_many_reports_window_overflow():
 
 
 def test_zipfian_f1_drop_rate_and_auc_vs_exact():
-    """The PRODUCTION capacity config (f=1.0, bench mesh1f) on the traffic it
-    will actually see — Zipfian planted-signal streams — measured, not
-    assumed. At this deliberately small per-device batch (256 ids/device ->
-    32-id buckets, worst-case relative fluctuation; bench's 106k-id batches
-    sit far inside the sizing rule) the measured reality is: static f=1.0
-    drops ~3.9% of id positions and costs ~0.005 AUC; on_overflow='grow'
+    """The PRODUCTION capacity config (f=1.0) on the traffic it will
+    actually see — Zipfian planted-signal streams — measured, not assumed.
+    At this deliberately small per-device batch (256 ids/device -> 32-id
+    buckets, worst-case relative fluctuation; the benchmark's 106k-id
+    batches sit far inside the sizing rule) the measured reality is: static
+    f=1.0 drops ~3.9% of id positions and costs ~0.005 AUC; on_overflow='grow'
     confines drops to the first windows (~1.3% total, declining) and
     recovers the AUC to within noise of exact mode. Pins below bound those
     measurements with margin."""

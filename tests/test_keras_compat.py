@@ -677,6 +677,10 @@ def test_sequential_model_conversion_and_fit():
         from openembedding_tpu.inject import install
         install()
 
+        # the layers' initial weights and fit's shuffle come from keras's
+        # global seed: unseeded, one process in six starts where 8 epochs
+        # reach 0.52x of the first loss, not 0.5x
+        keras.utils.set_random_seed(0)
         rng = np.random.default_rng(0)
         V = 200
         ids = rng.integers(0, V, (256, 3)).astype(np.int32)

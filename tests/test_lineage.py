@@ -184,9 +184,12 @@ def test_hop_decomposition_with_injected_fetch_delay(tmp_path, publisher_node,
 
         pub = pub_srv.publishers["lin-0"]
         orig = pub.delta_table
+        slept = []
 
         def slow_table(*a, **kw):
+            t0 = time.time()
             time.sleep(0.25)
+            slept.append((t0, time.time()))
             return orig(*a, **kw)
 
         pub.delta_table = slow_table
@@ -201,7 +204,6 @@ def test_hop_decomposition_with_injected_fetch_delay(tmp_path, publisher_node,
     hops = lh["hops"]
     assert {"commit", "publish", "fetch", "apply", "swap"} <= set(hops)
     assert hops["fetch"] >= 200.0, hops  # the injected delay lands here
-    assert hops["fetch"] > hops["apply"] and hops["fetch"] > hops["swap"]
     # end-to-end freshness covers at least the stalled fetch
     assert st["freshness_ms"] is not None and st["freshness_ms"] >= 200.0
 
@@ -211,6 +213,12 @@ def test_hop_decomposition_with_injected_fetch_delay(tmp_path, publisher_node,
         assert rec.get(stamp) is not None, (stamp, rec)
     # birth -> ... -> swapped is non-decreasing within one clock domain pair
     assert rec["seen"] <= rec["fetched"] <= rec["applied"] <= rec["swapped"]
+    # ... and on NO other hop: every injected sleep lies inside the fetch
+    # hop's own window (stamps of one clock; how long apply and swap take on
+    # a loaded machine says nothing about where the delay went)
+    assert slept
+    for t0, t1 in slept:
+        assert rec["seen"] <= t0 and t1 <= rec["fetched"], (slept, rec)
 
     body = {"sparse": {"categorical": np.asarray(
         batches[0]["sparse"]["categorical"]).tolist()},
@@ -324,10 +332,18 @@ def test_freshness_slo_breach_and_recover_e2e(tmp_path):
     soak = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(soak)
     try:
-        report = soak.run(steps=20, persist_every=4, interval_s=0.05,
-                          step_delay_s=0.3, stall_s=2.5,
-                          stall_after_frac=0.25,
-                          freshness_threshold_ms=1100.0, timeline=True,
+        # a delta is born every 8 steps x 0.3 s = 2.4 s, and while payloads
+        # are withheld the served freshness is the age of the feed's NEWEST
+        # birth, so it climbs to one such period and no further: the
+        # threshold sits between that and what birth -> swap takes on a
+        # loaded machine (1.1 s against a period of 1.2 s left no room).
+        # The stall lifts 4 s past the first denied fetch (delta 9), about
+        # step 22; deltas 25 and 33 are born after it, so the exit verdict
+        # reads a delta that never waited
+        report = soak.run(steps=34, persist_every=8, interval_s=0.05,
+                          step_delay_s=0.3, stall_s=4.0,
+                          stall_after_frac=0.15,
+                          freshness_threshold_ms=1700.0, timeline=True,
                           workdir=str(tmp_path / "soak"), predict_threads=2,
                           quiet=True)
     finally:

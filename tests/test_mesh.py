@@ -17,8 +17,9 @@ import openembedding_tpu as embed
 from openembedding_tpu.embedding import EmbeddingSpec, EmbeddingTableState
 from openembedding_tpu.parallel import (MeshTrainer, deinterleave_rows,
                                         interleave_rows, make_mesh,
-                                        sharded_apply_gradients, sharded_lookup,
-                                        sharded_lookup_train)
+                                        sharded_lookup)
+from openembedding_tpu.parallel.sharded import (grouped_apply_gradients,
+                                                grouped_lookup_train)
 
 S = 8  # conftest forces 8 virtual CPU devices
 
@@ -75,56 +76,66 @@ def test_sharded_lookup_matches_gather(mesh):
     np.testing.assert_allclose(np.asarray(out), table[ids], rtol=1e-6)
 
 
-def test_sharded_train_pull_and_update_selfcheck(mesh):
+@pytest.mark.parametrize("n_tables", [1, 2])
+def test_sharded_train_pull_and_update_selfcheck(mesh, n_tables):
     """Reference-style self-checking workload: TestOptimizer + host replica, multiple
     rounds of pull/push/update with duplicate ids across devices, exact equality
-    (`entry/c_api_test.h:32-182`)."""
+    (`entry/c_api_test.h:32-182`). One exchange group of one table, and of two
+    tables sharing the wire: each table against its own replica."""
     rng = np.random.default_rng(1)
-    vocab, dim, per_dev = 48, 4, 12
+    dim, per_dev = 4, 12
+    vocabs = [48, 40][:n_tables]
     B = per_dev * S
     opt = embed.TestOptimizer(learning_rate=1.0, flip=100.0, init=0.0)
-    spec = EmbeddingSpec(name="v", input_dim=vocab, output_dim=dim, variable_id=0)
-    table0 = rng.normal(size=(vocab, dim)).astype(np.float32)
-    state = shard_table(mesh, spec, opt, table0)
-
-    # host replica
-    host_w = table0.copy()
-    host_flip = np.zeros((vocab, 1), np.float32)
+    specs = [EmbeddingSpec(name=f"v{t}", input_dim=vocab, output_dim=dim,
+                           variable_id=t) for t, vocab in enumerate(vocabs)]
+    host_w = [rng.normal(size=(vocab, dim)).astype(np.float32)
+              for vocab in vocabs]
+    states = [shard_table(mesh, spec, opt, w) for spec, w in zip(specs, host_w)]
+    host_w = [w.copy() for w in host_w]
+    host_flip = [np.zeros((vocab, 1), np.float32) for vocab in vocabs]
 
     table_spec = EmbeddingTableState(
         weights=P("data", None), slots={"flip_state": P("data", None)},
         keys=None, overflow=None)
 
-    def step(state, ids, grads):
-        state, rows, stats, plan = sharded_lookup_train(spec, state, ids)
-        state, push_stats = sharded_apply_gradients(spec, state, opt, ids, grads,
-                                                    plan=plan)
-        return state, rows, {**stats, **push_stats}
+    def step(states, ids_list, grads_list):
+        states, rows, stats, plans = grouped_lookup_train(
+            specs, states, ids_list, wire="fp32")
+        states, push_stats = grouped_apply_gradients(
+            specs, states, [opt] * n_tables, ids_list, grads_list,
+            plans=plans, wire="fp32")
+        return states, rows, [{**st, **pst}
+                              for st, pst in zip(stats, push_stats)]
 
     jstep = jax.jit(jax.shard_map(
         step, mesh=mesh,
-        in_specs=(table_spec, P("data"), P("data")),
-        out_specs=(table_spec, P("data"), P()), check_vma=False))
+        in_specs=([table_spec] * n_tables, P("data"), P("data")),
+        out_specs=([table_spec] * n_tables, P("data"), P()),
+        check_vma=False))
 
     for round_i in range(4):
-        ids = rng.integers(0, vocab, size=(B,))
-        grads = rng.normal(size=(B, dim)).astype(np.float32)
-        state, rows, stats = jstep(state, jnp.asarray(ids), jnp.asarray(grads))
-        # pull must have returned pre-update weights
-        np.testing.assert_allclose(np.asarray(rows), host_w[ids], rtol=1e-5,
-                                   err_msg=f"round {round_i} pull")
-        assert int(stats["v/pull_overflow"] if "v/pull_overflow" in stats
-                   else stats["pull_overflow"]) == 0
-        # host replica update: per unique id, summed grads / count + flip
-        for uid in np.unique(ids):
-            sel = ids == uid
-            g = grads[sel].sum(axis=0)
-            count = sel.sum()
-            host_flip[uid] = 100.0 - host_flip[uid]
-            host_w[uid] += 1.0 * g / count + host_flip[uid]
+        ids = [rng.integers(0, vocab, size=(B,)) for vocab in vocabs]
+        grads = [rng.normal(size=(B, dim)).astype(np.float32) for _ in vocabs]
+        states, rows, stats = jstep(states, [jnp.asarray(i) for i in ids],
+                                    [jnp.asarray(g) for g in grads])
+        for t in range(n_tables):
+            # pull must have returned pre-update weights
+            np.testing.assert_allclose(
+                np.asarray(rows[t]), host_w[t][ids[t]], rtol=1e-5,
+                err_msg=f"round {round_i} table {t} pull")
+            assert int(stats[t]["pull_overflow"]) == 0
+            # host replica update: per unique id, summed grads / count + flip
+            for uid in np.unique(ids[t]):
+                sel = ids[t] == uid
+                g = grads[t][sel].sum(axis=0)
+                count = sel.sum()
+                host_flip[t][uid] = 100.0 - host_flip[t][uid]
+                host_w[t][uid] += 1.0 * g / count + host_flip[t][uid]
 
-    final = deinterleave_rows(np.asarray(state.weights), S, vocab)
-    np.testing.assert_allclose(np.asarray(final), host_w, rtol=1e-4, atol=1e-4)
+    for state, w, vocab in zip(states, host_w, vocabs):
+        final = deinterleave_rows(np.asarray(state.weights), S, vocab)
+        np.testing.assert_allclose(np.asarray(final), w, rtol=1e-4, atol=1e-4)
 
 
 def make_batch(rng, vocab, B, fields=3):

@@ -157,7 +157,6 @@ _FEATURES = {
     "split_dim64_fp32": dict(dim=64, wire="fp32"),
     "split_dim64_bf16": dict(dim=64, wire="bf16"),
     "step_loop_dim9": dict(dim=9, many=False),
-    "per_table": dict(dim=9, group_exchange=False),
     "hash": dict(dim=9, hash_capacity=1 << 12),
     "annex": dict(dim=9, mig=8),
     "hot": dict(dim=9, hot=8),

@@ -333,8 +333,9 @@ def test_packed_scan_dim64_split_first_order_one_scatter_each(ladder):
     tables — categorical 64+64 -> (V, 128) lane-exact, first_order 1+1 ->
     (V, 2) sublane — and each updates through ONE packed scatter with no
     split-shape scatters left. The on-chip HBM claim (no 128-lane-padded temp
-    copy of the table at width 128) needs a chip run (`bench.py` dim64);
-    this pins the program STRUCTURE on any backend."""
+    copy of the table at width 128) needs a chip run (the `deepfm64.train_zipf`
+    cell of `benchmark/`, and `tests/test_tpu_compile.py` for a described
+    v5e); this pins the program STRUCTURE on any backend."""
     V = 1 << 14
     model = make_deepfm(vocabulary=V, dim=64)
     assert set(model.specs) == {"categorical", "first_order"}

@@ -44,8 +44,7 @@ def _stack(batches):
     return jax.tree_util.tree_map(lambda *xs: np.stack(xs), *batches)
 
 
-def _run_pair(hot=0, mig=0, group=True, k=K, seed=5, overlap=False,
-              wire="fp32"):
+def _run_pair(hot=0, mig=0, k=K, seed=5, overlap=False, wire="fp32"):
     """Train the same window serial and pipelined; return both (state,
     metrics) pairs. `overlap` plants heavy id overlap between consecutive
     batches so the speculative prefetch is guaranteed stale (the conflict
@@ -63,8 +62,8 @@ def _run_pair(hot=0, mig=0, group=True, k=K, seed=5, overlap=False,
     outs = []
     for pipe in (False, True):
         tr = MeshTrainer(model, embed.Adagrad(learning_rate=0.05), seed=1,
-                         hot_rows=hot, mig_rows=mig, group_exchange=group,
-                         wire=wire, pipeline_steps=pipe)
+                         hot_rows=hot, mig_rows=mig, wire=wire,
+                         pipeline_steps=pipe)
         state = tr.init(batches[0])
         if hot:
             state = tr.refresh_hot_rows(state, hot_ids=hot_ids)
@@ -106,10 +105,9 @@ def _assert_bit_exact(sa, ma, sb, mb):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("case", ["fused", "hot", "mig", "per_table"])
+@pytest.mark.parametrize("case", ["fused", "hot", "mig"])
 def test_pipelined_bit_exact(case):
-    kw = {"fused": {}, "hot": {"hot": 8}, "mig": {"mig": 8},
-          "per_table": {"group": False}}[case]
+    kw = {"fused": {}, "hot": {"hot": 8}, "mig": {"mig": 8}}[case]
     (_, sa, ma), (_, sb, mb) = _run_pair(**kw)
     _assert_bit_exact(sa, ma, sb, mb)
 

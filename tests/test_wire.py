@@ -3,14 +3,14 @@
 
 Covers the round-6 tentpole contracts:
 - 3 all_to_alls per DIM-GROUP (not per table), pinned at the HLO level for a
-  3-table / 2-group model (6 fused vs 9 unfused);
-- the fused exchange with fp32 wire is BIT-identical to the per-table
-  protocol (grouping only shares the wire, never the math);
+  3-table / 2-group model (6, against 9 with one group per table);
+- the fused exchange with fp32 wire is BIT-identical to the same exchange
+  run one table a group (grouping only shares the wire, never the math);
 - bf16 (default) / int8 (opt-in) wire: pull rows and pushed grads round-trip
   within format tolerance, duplicate-count lanes and overflow counters stay
   EXACT, table storage stays full-precision fp32;
 - the static wire-cost model: bf16 moves >= 1.8x fewer exchange bytes/step
-  than fp32 (the tools/wire_microbench.py acceptance number).
+  than fp32.
 
 The suite-wide default wire is pinned to fp32 in tests/conftest.py (parity
 tests elsewhere assert exact agreement); every lossy-format test here passes
@@ -170,6 +170,14 @@ def _batch(rng, vocab=64, dupes=True, hash_space=1 << 40,
             "label": rng.integers(0, 2, (B,)).astype(np.float32)}
 
 
+class _OneGroupPerTable(MeshTrainer):
+    """The reference the grouping pins compare against: the same exchange
+    with every table alone on its wire."""
+
+    def _exchange_groups(self, ps_specs):
+        return [[n] for g in super()._exchange_groups(ps_specs) for n in g]
+
+
 def _train(trainer, batches, state=None):
     if state is None:
         state = trainer.init(batches[0])
@@ -219,41 +227,41 @@ def _probe_tables(trainer, state, batches, vocab=64):
 
 def test_fused_step_compiles_three_all_to_alls_per_dim_group():
     """THE acceptance pin: a 3-table model in 2 dim-groups compiles to 6
-    all_to_alls per train step (3 per GROUP); the pre-fusion per-table
-    protocol (group_exchange=False) compiles the same model to 9."""
+    all_to_alls per train step (3 per GROUP); one group per table compiles
+    the same model to 9."""
     import re
 
-    def count_a2a(group_exchange):
+    def count_a2a(trainer_cls):
         rng = np.random.default_rng(0)
-        tr = MeshTrainer(_three_table_model(),
-                         embed.Adagrad(learning_rate=0.05), mesh=make_mesh(),
-                         group_exchange=group_exchange)
+        tr = trainer_cls(_three_table_model(),
+                         embed.Adagrad(learning_rate=0.05), mesh=make_mesh())
         b = _batch(rng)
         state = tr.init(b)
         step = tr.jit_train_step(b, state)
         txt = step.lower(state, b).compile().as_text()
         return len(re.findall(r" all-to-all(?:-start)?\(", txt))
 
-    assert count_a2a(True) == 6, "fused: expected 3 a2a per dim-group"
-    assert count_a2a(False) == 9, "unfused: expected 3 a2a per table"
+    assert count_a2a(MeshTrainer) == 6, "expected 3 a2a per dim-group"
+    assert count_a2a(_OneGroupPerTable) == 9, \
+        "one group per table: expected 3 a2a per table"
 
 
 def test_fused_fp32_bitexact_vs_per_table_protocol():
     """Grouping shares the WIRE, never the math: with fp32 wire the fused
-    exchange must reproduce the per-table protocol bit for bit (same dedup,
+    exchange must reproduce one group per table bit for bit (same dedup,
     same bucket contents, same apply order)."""
     rng = np.random.default_rng(1)
     batches = [_batch(rng) for _ in range(3)]
 
-    def run(group_exchange):
-        tr = MeshTrainer(_three_table_model(),
+    def run(trainer_cls):
+        tr = trainer_cls(_three_table_model(),
                          embed.Adagrad(learning_rate=0.1), mesh=make_mesh(),
-                         wire="fp32", group_exchange=group_exchange)
+                         wire="fp32")
         state, losses = _train(tr, batches)
         return _probe_tables(tr, state, batches), losses
 
-    fused, l_fused = run(True)
-    per_table, l_per = run(False)
+    fused, l_fused = run(MeshTrainer)
+    per_table, l_per = run(_OneGroupPerTable)
     np.testing.assert_array_equal(l_fused, l_per)
     for name in fused:
         np.testing.assert_array_equal(fused[name], per_table[name])
@@ -320,8 +328,8 @@ def test_overflow_drop_paths_unchanged_by_wire(fmt):
 
 def test_wire_cost_model_and_gauges():
     """Static cost model: bf16 >= 1.8x fewer exchange bytes/step than fp32
-    (the microbench acceptance bound), int8 beats bf16, fused <= unfused
-    collectives; the trainer publishes the gauges at trace time."""
+    int8 beats bf16, 3 collectives per dim-group; the trainer publishes the
+    gauges at trace time."""
     from openembedding_tpu.utils import metrics as M
 
     tables = [{"dim": 16, "cap": 128, "pair": False, "id_itemsize": 4},
@@ -331,8 +339,8 @@ def test_wire_cost_model_and_gauges():
     bf16 = wire.exchange_cost(tables, S, "bf16")
     int8 = wire.exchange_cost(tables, S, "int8")
     assert fp32["collectives_per_step"] == 6  # 2 dim-groups
-    assert wire.exchange_cost(tables, S, "fp32",
-                              fused=False)["collectives_per_step"] == 9
+    one = wire.exchange_cost(tables, 1, "fp32")  # one shard: nothing ships
+    assert one["collectives_per_step"] == 0 and one["bytes_per_step"] == 0
     assert fp32["bytes_per_step"] / bf16["bytes_per_step"] >= 1.8
     assert int8["bytes_per_step"] < bf16["bytes_per_step"]
 
@@ -352,7 +360,7 @@ def test_wire_cost_model_and_gauges():
 def test_grouped_pair_wire_x64_off():
     """Under x64-off the hash table keys in the split-pair layout; grouped
     with an int32 array table the fused id wire widens to pairs. Parity vs
-    the per-table protocol stays exact (fp32 wire)."""
+    one group per table stays exact (fp32 wire)."""
     with jax.enable_x64(False):
         rng = np.random.default_rng(5)
         # int32 ids (< 2^31: nothing to truncate); adapt_batch_ids widens
@@ -360,16 +368,16 @@ def test_grouped_pair_wire_x64_off():
         batches = [_batch(rng, hash_space=1 << 20, hash_dtype=np.int32)
                    for _ in range(2)]
 
-        def run(group_exchange):
-            tr = MeshTrainer(_three_table_model(),
+        def run(trainer_cls):
+            tr = trainer_cls(_three_table_model(),
                              embed.Adagrad(learning_rate=0.1),
-                             mesh=make_mesh(), wire="fp32",
-                             group_exchange=group_exchange)
+                             mesh=make_mesh(), wire="fp32")
             state, losses = _train(tr, batches)
             assert state.tables["b"].keys.ndim == 2  # pair-keyed
             return losses
 
-        np.testing.assert_array_equal(run(True), run(False))
+        np.testing.assert_array_equal(run(MeshTrainer),
+                                      run(_OneGroupPerTable))
 
 
 # ---------------------------------------------------------------------------
@@ -390,7 +398,7 @@ def test_mixed_wire_splits_dim_groups_and_pins_a2a_count():
         rng = np.random.default_rng(6)
         tr = MeshTrainer(_three_table_model(),
                          embed.Adagrad(learning_rate=0.05), mesh=make_mesh(),
-                         wire=wire_cfg, group_exchange=True)
+                         wire=wire_cfg)
         b = _batch(rng)
         state = tr.init(b)
         step = tr.jit_train_step(b, state)
@@ -438,7 +446,7 @@ def test_mixed_wire_counts_lanes_bit_exact_and_gauges_truthful():
         M._REGISTRY.clear()
         tr = MeshTrainer(_three_table_model(),
                          embed.Adagrad(learning_rate=0.1), mesh=make_mesh(),
-                         wire=wire_cfg, group_exchange=True)
+                         wire=wire_cfg)
         state = tr.init(batches[0])
         step = tr.jit_train_step(batches[0], state)
         stats = []
@@ -465,6 +473,36 @@ def test_mixed_wire_counts_lanes_bit_exact_and_gauges_truthful():
     assert rep['exchange.wire_dtype{table="a"}'] == 1.0   # s8 itemsize
     assert rep['exchange.wire_dtype{table="b"}'] == 4.0   # f32 itemsize
     assert rep['exchange.wire_dtype{table="w"}'] == 4.0
+
+
+def test_policy_wire_cuts_bytes_and_never_costs_vs_global_int8():
+    """`PlacementPolicy.recommend_wire` on skewed wide tables beside a dim-1
+    table: int8 for the wide ones, fp32 for the narrow one (int8 WIDENS a
+    dim-1 row: 1 B + scale lanes), so the mix ships no more exchange bytes
+    than int8 everywhere and cuts the fp32 wire by the codec's ratio (ids
+    and count lanes stay exact). Priced by the static model, which the
+    hlo-budget's `wire_model_delta` 0 holds equal to the compiled a2as."""
+    from openembedding_tpu.placement.policy import (PlacementPolicy,
+                                                    TableTelemetry)
+    skew = [(64, 0.3), (256, 0.45), (1024, 0.7), (4096, 0.9)]
+    rec = PlacementPolicy(hot_budget_bytes=0).recommend_wire(
+        [TableTelemetry("latent", 64, skew, 1e6),
+         TableTelemetry("hashed", 64, skew, 1e6),
+         TableTelemetry("first_order", 1, skew, 1e6)])
+    assert rec == {"latent": "int8", "hashed": "int8", "first_order": "fp32"}
+
+    def cost(fmts):
+        return wire.exchange_cost(
+            [{"dim": 64, "cap": 128, "pair": False, "id_itemsize": 4,
+              "fmt": fmts["latent"]},
+             {"dim": 64, "cap": 64, "pair": True, "id_itemsize": 8,
+              "fmt": fmts["hashed"]},
+             {"dim": 1, "cap": 128, "pair": False, "id_itemsize": 4,
+              "fmt": fmts["first_order"]}], S, "fp32")["bytes_per_step"]
+
+    mixed = cost(rec)
+    assert mixed <= cost(dict.fromkeys(rec, "int8"))
+    assert cost(dict.fromkeys(rec, "fp32")) / mixed >= 3.0
 
 
 def test_wire_dict_validation():

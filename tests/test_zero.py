@@ -583,3 +583,25 @@ def test_sparse_topk_dense_wire_cost():
     assert cost["bytes_per_step"] == cost["a2a_bytes"] + cost["ag_bytes"]
     with pytest.raises(ValueError, match="topk"):
         zero.dense_wire_cost(plan, "sparse_topk")
+
+
+def test_sparse_topk_policy_k_halves_int8_grad_bytes():
+    """In the sparse regime (density 0.01) the policy's k ships at most half
+    the int8 dense path's gradient a2a bytes; at density 0.5 the sparse
+    payload would cost more than int8 and the policy refuses it."""
+    from openembedding_tpu.placement.policy import PlacementPolicy
+
+    pol = PlacementPolicy(hot_budget_bytes=0)
+    params = {"w": jnp.zeros((4096,), jnp.float32)}
+    plan = zero.build_plan(params, embed.Adagrad(learning_rate=0.1), S)
+    int8 = zero.dense_wire_cost(plan, "int8")["a2a_bytes"]
+
+    mode, k, _ = pol.recommend_dense_wire(0.01, "int8", chunk=plan.chunk)
+    assert mode == "sparse_topk"
+    sparse = zero.dense_wire_cost(plan, "sparse_topk", topk=k)["a2a_bytes"]
+    assert sparse <= 0.5 * int8
+    mode, k, _ = pol.recommend_dense_wire(0.5, "int8", chunk=plan.chunk)
+    assert mode == "int8" and k is None
+    dense_k = pol._dense_topk(0.5, plan.chunk)
+    assert zero.dense_wire_cost(plan, "sparse_topk",
+                                topk=dense_k)["a2a_bytes"] > int8

@@ -20,8 +20,8 @@ code unless --no-slo-gate), the throttled control's inverted attribution,
 and a pooled-vs-inline reader bit-identity spot check (the determinism the
 reorder stage promises). The short configuration rides tier-1 via
 tests/test_ingest.py; `python tools/ingest_soak.py` runs the longer
-standalone battery (also the bench.py `ingest` case). `--weave` explores the feed ring's
-and parse pool's interleavings under tools/oeweave instead.
+standalone battery. `--weave` explores the feed ring's and parse pool's
+interleavings under tools/oeweave instead.
 """
 
 import argparse

@@ -12,7 +12,7 @@ Eleven passes over `openembedding_tpu/` (see each module's doc):
 - atomicity        — check-then-act on guarded state split across the lock
 - cond-wait        — Condition.wait predicate loops, notify under the lock
 - thread-lifecycle — every stored/started thread has a reachable join
-- metrics          — metric-name hygiene (the former tools/lint_metrics.py)
+- metrics          — metric-name hygiene
 
 Run them all with `make lint` / `python -m tools.oelint`; the runtime
 counterpart (executable never-re-jit + collective-fingerprint assertions) is

@@ -9,8 +9,8 @@ static wire-bytes model, and compares against the checked-in budget
 wire) to a pinned path fails `make lint` with a human-readable diff instead
 of silently costing every future step.
 
-Configurations (the acceptance matrix): the per-table protocol, the fused
-dim-group exchange, hot-row cache on/off, and all three wire formats —
+Configurations (the acceptance matrix): the fused dim-group exchange,
+hot-row cache on/off, and all three wire formats —
 collective counts AND `exchange.wire_bytes_per_step` are pinned per config.
 
 Regenerate after an intentional change:
@@ -64,26 +64,20 @@ _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
              "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
              "u64": 8}
 
-# the acceptance matrix: per-table vs fused, wire formats, hot on/off, and
-# full placement (hot cache + cold-tail migration directory) — the
-# `fused_fp32_placement` steady-state step must pin the IDENTICAL
+# the acceptance matrix: wire formats, hot on/off, and full placement (hot
+# cache + cold-tail migration directory) — the `fused_fp32_placement`
+# steady-state step must pin the IDENTICAL
 # exchange-collective set as `fused_fp32_hot` (3 a2a, 0 all-gather, same
 # wire bytes): the owner-assignment indirection is pure local math (two
 # extra hash probes riding the fused sort), never a wire collective. The
 # only delta is +4 scalar all-reduces — the `mig_unique`/`mig_hits` stats
 # riding the existing per-key stats psum (2 stats x 2 tables).
 CONFIGS = (
-    {"name": "per_table_fp32", "group_exchange": False, "wire": "fp32",
-     "hot_rows": 0},
-    {"name": "fused_fp32", "group_exchange": True, "wire": "fp32",
-     "hot_rows": 0},
-    {"name": "fused_bf16", "group_exchange": True, "wire": "bf16",
-     "hot_rows": 0},
-    {"name": "fused_int8", "group_exchange": True, "wire": "int8",
-     "hot_rows": 0},
-    {"name": "fused_fp32_hot", "group_exchange": True, "wire": "fp32",
-     "hot_rows": 32},
-    {"name": "fused_fp32_placement", "group_exchange": True, "wire": "fp32",
+    {"name": "fused_fp32", "wire": "fp32", "hot_rows": 0},
+    {"name": "fused_bf16", "wire": "bf16", "hot_rows": 0},
+    {"name": "fused_int8", "wire": "int8", "hot_rows": 0},
+    {"name": "fused_fp32_hot", "wire": "fp32", "hot_rows": 32},
+    {"name": "fused_fp32_placement", "wire": "fp32",
      "hot_rows": 32, "mig_rows": 32},
     # round-13 in-collective configs: the compiled a2a operands must carry
     # the narrow dtype — `forbid_a2a_dtypes` turns a silent fall-back to
@@ -92,33 +86,32 @@ CONFIGS = (
     # fused_int8_inband also runs error feedback (the default for int8) and
     # the two-stage s8 hot reduce; fused_fp32_hot_int8 isolates the hot
     # reduce's format from the exchange's.
-    {"name": "fused_bf16_inband", "group_exchange": True, "wire": "bf16",
+    {"name": "fused_bf16_inband", "wire": "bf16",
      "hot_rows": 32, "forbid_a2a_dtypes": ("f32",)},
-    {"name": "fused_int8_inband", "group_exchange": True, "wire": "int8",
+    {"name": "fused_int8_inband", "wire": "int8",
      "hot_rows": 32, "forbid_a2a_dtypes": ("f32", "bf16", "u16")},
-    {"name": "fused_fp32_hot_int8", "group_exchange": True, "wire": "fp32",
+    {"name": "fused_fp32_hot_int8", "wire": "fp32",
      "hot_rows": 32, "hot_wire": "int8"},
     # round-14 ZeRO dense sharding: the sharded dense update must cost
     # EXACTLY one reduce-scatter + one all-gather over the flat dense state
     # (bytes pinned below) and must not perturb the exchange collectives —
     # same a2a set and wire bytes as fused_fp32.
-    {"name": "fused_fp32_zero", "group_exchange": True, "wire": "fp32",
+    {"name": "fused_fp32_zero", "wire": "fp32",
      "hot_rows": 0, "dense_shard": True},
     # round-16 numerics sentinel: the health stats ride the step's stats
     # psum — the pinned contract is that sentinel=True costs ONLY a handful
     # of extra SCALAR all-reduces (one per health stat key) and changes the
     # exchange a2a set and wire bytes by exactly zero vs fused_fp32 (and
     # every sentinel-off config above stays byte-identical, delta 0).
-    {"name": "fused_fp32_sentinel", "group_exchange": True, "wire": "fp32",
+    {"name": "fused_fp32_sentinel", "wire": "fp32",
      "hot_rows": 0, "sentinel": True},
     # round-17 per-table wire: the one dim-8 group splits on (dim, fmt) into
     # TWO fused a2a groups (6 a2as, not 3) and the compiled payloads must
     # carry BOTH formats — `require_a2a_dtypes` fails the lint when either
     # side silently falls back (f32 gone = table "a" got quantized, s8 gone
     # = table "b" fell back to fp32), budget-independently.
-    {"name": "fused_mixed_wire", "group_exchange": True,
-     "wire": {"a": "fp32", "b": "int8"}, "hot_rows": 0,
-     "require_a2a_dtypes": ("f32", "s8")},
+    {"name": "fused_mixed_wire", "wire": {"a": "fp32", "b": "int8"},
+     "hot_rows": 0, "require_a2a_dtypes": ("f32", "s8")},
     # round-17 quantized dense ZeRO collectives: dense_wire="int8" replaces
     # the fp32 reduce-scatter with an s8 in-band a2a + per-replica fp32 sum
     # and ships the params all_gather on the u16 bf16 carrier. `pins` holds
@@ -126,7 +119,7 @@ CONFIGS = (
     # fall-back to the fp32 reduce_scatter fails `make lint` even straight
     # after --update-budget), and the s8 requirement pins the encoded grad
     # a2a itself.
-    {"name": "fused_fp32_zero_int8", "group_exchange": True, "wire": "fp32",
+    {"name": "fused_fp32_zero_int8", "wire": "fp32",
      "hot_rows": 0, "dense_shard": True, "dense_wire": "int8",
      "require_a2a_dtypes": ("s8",),
      "pins": {"hlo_reduce_scatter_bytes": 0}},
@@ -138,9 +131,8 @@ CONFIGS = (
     # extra scalar lane — the measured density that drives the crossover).
     # The unattributed pin proves the sparse scatter-sum decode stays local:
     # GSPMD must not insert resharding around the index-lane plumbing.
-    {"name": "fused_fp32_zero_sparse", "group_exchange": True,
-     "wire": "fp32", "hot_rows": 0, "dense_shard": True,
-     "dense_wire": "sparse_topk", "dense_stats": True,
+    {"name": "fused_fp32_zero_sparse", "wire": "fp32", "hot_rows": 0,
+     "dense_shard": True, "dense_wire": "sparse_topk", "dense_stats": True,
      "require_a2a_dtypes": ("s8",),
      "pins": {"hlo_reduce_scatter_bytes": 0,
               "unattributed_collectives": 0}},
@@ -153,9 +145,9 @@ CONFIGS = (
     # serial set — zero hidden wire beyond the patch. The unattributed pin
     # is update-proof: GSPMD must not insert resharding into the rotated
     # carry plumbing.
-    {"name": "fused_fp32_many", "group_exchange": True, "wire": "fp32",
+    {"name": "fused_fp32_many", "wire": "fp32",
      "hot_rows": 0, "train_many": 4},
-    {"name": "fused_fp32_pipelined", "group_exchange": True, "wire": "fp32",
+    {"name": "fused_fp32_pipelined", "wire": "fp32",
      "hot_rows": 0, "train_many": 4, "pipeline_steps": True,
      "pins": {"unattributed_collectives": 0}},
 )
@@ -294,8 +286,8 @@ def make_trainer(config: Dict):
         wire = dict(wire)  # MeshTrainer keeps the per-table dict as-is
     trainer = MeshTrainer(
         model, embed.Adagrad(learning_rate=0.1), mesh=make_mesh(),
-        wire=wire, group_exchange=config["group_exchange"],
-        hot_rows=config["hot_rows"], mig_rows=config.get("mig_rows", 0),
+        wire=wire, hot_rows=config["hot_rows"],
+        mig_rows=config.get("mig_rows", 0),
         hot_wire=config.get("hot_wire"),
         dense_shard=config.get("dense_shard", False),
         dense_wire=config.get("dense_wire"),

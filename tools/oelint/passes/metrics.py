@@ -1,8 +1,6 @@
 """metrics pass: metric-name hygiene at observe()/vtimer()/span() call sites.
 
-The fifth oelint pass — the former standalone `tools/lint_metrics.py`,
-folded into the framework (that script is now a thin alias so
-`make lint-metrics` keeps working). Rules are unchanged:
+The fifth oelint pass. Rules:
 
 - metric names are dot-joined lowercase `group.name[.qualifier]` segments of
   `[a-z0-9_]+` (utils/metrics.py naming scheme); timer/span call sites pass
@@ -28,7 +26,7 @@ from ..core import Finding, SourceFile
 
 NAME = "metrics"
 DIRS = ("openembedding_tpu", "examples", "tools")
-SKIP = ("tools/oelint", "tools/lint_metrics.py")
+SKIP = ("tools/oelint",)
 
 NAME_RE = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_]+)+$")
 SEGMENT = re.compile(r"^[a-z0-9_]+$")

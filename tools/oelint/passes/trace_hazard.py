@@ -48,8 +48,7 @@ DIRS = ("openembedding_tpu",)
 
 # the jitted protocol entry points (parallel/sharded.py, model.py Trainer)
 DEFAULT_ROOTS = {
-    "sharded_lookup_train", "grouped_lookup_train", "sharded_lookup",
-    "sharded_apply_gradients", "grouped_apply_gradients",
+    "grouped_lookup_train", "sharded_lookup", "grouped_apply_gradients",
     "hot_writeback", "hot_gather", "mig_writeback", "mig_gather",
     "train_step", "train_many", "eval_step",
 }

@@ -16,8 +16,7 @@ Asserted at exit: zero failed predicts across every swap, the subscriber
 ended IDLE at the trainer's final committed step (version lag 0), and at
 least K swaps actually happened (the soak is vacuous without them). The
 short configuration rides tier-1 via tests/test_sync.py::test_sync_soak_short;
-`python tools/sync_soak.py` runs the longer standalone battery (also a
-bench.py `sync` case).
+`python tools/sync_soak.py` runs the longer standalone battery.
 """
 
 import argparse
