@@ -110,7 +110,9 @@ class BucketResult(NamedTuple):
 
 def bucket_by_owner(ids: jax.Array, valid: jax.Array, num_shards: int,
                     capacity: int) -> BucketResult:
-    """Group ids into per-owner-shard buckets of static capacity.
+    """Group ids into per-owner-shard buckets of static capacity. The split,
+    per-slot form: the exchange routes through `unique_and_route`, and this
+    stays as its independent reference (`tests/test_dedup.py`).
 
     Owner layout matches the reference: `owner = id % num_shards`, row-within-shard
     `id // num_shards` (`EmbeddingPullOperator.cpp:74-84`). Elements beyond a bucket's
@@ -157,6 +159,69 @@ def bucket_by_owner(ids: jax.Array, valid: jax.Array, num_shards: int,
         return BucketResult(bucket_ids, bucket_valid, owner_out, slot_out, overflow)
 
 
+class RoutedBuckets(NamedTuple):
+    """What `unique_and_route` hands the exchange: the S outgoing id buckets
+    and where each one lies in the owner-major unique buffer. Owner s's ids are
+    the contiguous range `unique_ids[start[s] : start[s] + count[s]]`, in
+    order, at bucket slots 0..count[s]-1 — which is all `expand_blocks` /
+    `compact_blocks` need to build a payload's buckets or to read returned
+    rows back into unique order (S + 1 integers, not a position per slot)."""
+
+    bucket_ids: jax.Array   # (num_shards, capacity[, 2]) — EMPTY past count[s]
+    start: jax.Array        # (num_shards,) uint32 — first unique slot of owner s
+    count: jax.Array        # (num_shards,) int32 — ids in bucket s (<= capacity)
+    positions: jax.Array    # (num_shards,) int32 — id positions (duplicates
+    #                         counted) routed to owner s, overflowed or not
+    overflow: jax.Array     # () int32 — unique ids dropped because a bucket was full
+
+    @property
+    def bucket_valid(self) -> jax.Array:
+        """(num_shards, capacity) bool occupancy, derived from the ids."""
+        return bucket_validity(self.bucket_ids)
+
+
+def expand_blocks(y: jax.Array, offsets: jax.Array, counts: jax.Array,
+                  cap: int, fill=0) -> jax.Array:
+    """(W, ...) rows laid end to end -> (S, cap, ...) blocks: block s is
+    `y[offsets[s] : offsets[s] + cap]` with `fill` past its `counts[s]` rows.
+    S masked `dynamic_slice`s, never a per-row scatter. `offsets <= W`."""
+    S = counts.shape[0]
+    # cap rows of slack: a slice taken at offset <= W ends inside the buffer,
+    # so `dynamic_slice` never clamps its start
+    pad = jnp.concatenate([y, jnp.zeros((cap,) + y.shape[1:], y.dtype)])
+    lane = jnp.arange(cap, dtype=jnp.int32).reshape(
+        (cap,) + (1,) * (y.ndim - 1))
+    return jnp.stack([
+        jnp.where(lane < counts[s],
+                  jax.lax.dynamic_slice_in_dim(pad, offsets[s], cap, 0),
+                  jnp.asarray(fill, y.dtype))
+        for s in range(S)])
+
+
+def compact_blocks(x: jax.Array, offsets: jax.Array, W: int, fill=0,
+                   counts=None) -> jax.Array:
+    """Inverse of `expand_blocks`: (S, cap, ...) blocks -> (W, ...), block s
+    copied whole at `offsets[s]` (ascending), so each later block overwrites
+    the tail of the one before: S contiguous block copies, never a per-row
+    scatter, and the blocks' rows keep their order. Rows no block covers read
+    `fill`; with `counts`, so do the rows of block s past its `counts[s]`
+    (without, a block's tail is trusted to hold `fill` already, or to be
+    covered by the next block). `offsets <= W`."""
+    S, cap = x.shape[:2]
+    fill = jnp.asarray(fill, x.dtype)
+    if counts is not None:
+        lane = jnp.arange(cap, dtype=jnp.int32).reshape(
+            (1, cap) + (1,) * (x.ndim - 2))
+        x = jnp.where(lane < counts.reshape((S,) + (1,) * (x.ndim - 1)), x,
+                      fill)
+    # cap rows of slack: a block copied at offset <= W ends inside the
+    # buffer, so `dynamic_update_slice` never clamps its start
+    buf = jnp.broadcast_to(fill, (W + cap,) + x.shape[2:])
+    for s in range(S):
+        buf = jax.lax.dynamic_update_slice_in_dim(buf, x[s], offsets[s], 0)
+    return buf[:W]
+
+
 def unique_and_route(ids: jax.Array, valid: jax.Array, num_shards: int,
                      capacity: int, owner=None) -> tuple:
     """Fused dedup + owner routing: ONE multi-key sort where
@@ -165,13 +230,15 @@ def unique_and_route(ids: jax.Array, valid: jax.Array, num_shards: int,
     the reference does this client-side work on CPU off the device critical
     path, `EmbeddingPullOperator.cpp:60-112`; on TPU it rides the step).
 
-    Sorting by (owner, id, iota) yields uniques in OWNER-MAJOR id order, so a
-    unique's bucket slot is just its unique-rank minus its owner group's
-    start — no second sort, no searchsorted. Returns (UniqueResult,
-    BucketResult) with the same field contracts (only the order of
-    `unique_ids` differs: owner-major instead of plain id-sorted; all
-    consumers are order-agnostic — `inverse`, `counts`, `seg` stay mutually
-    consistent).
+    Sorting by (owner, id, iota) yields uniques in OWNER-MAJOR id order, and
+    that order is a CONTRACT the exchange depends on: owner s's unique ids are
+    one contiguous range of `unique_ids`, and its outgoing bucket is that
+    range in order. So the buckets are built by S masked block copies
+    (`expand_blocks`) and the callers build payload buckets and read returned
+    rows back the same way, from `RoutedBuckets.start` / `.count` — no second
+    sort, no searchsorted, no per-slot (owner, slot) position. `inverse`,
+    `counts` and `seg` stay mutually consistent with that order. Returns
+    (UniqueResult, RoutedBuckets).
 
     `valid` masks per-INPUT-id (invalid ids sort into a trailing pseudo-owner
     `num_shards` and never reach a bucket). `owner = id % num_shards` exactly
@@ -179,7 +246,9 @@ def unique_and_route(ids: jax.Array, valid: jax.Array, num_shards: int,
     per-position `owner` array ((n,) int32 in [0, num_shards]; the owner-
     assignment INDIRECTION of cold-tail re-sharding, `parallel/sharded.py`
     "COLD-TAIL RE-SHARDING"). A passed owner must be a pure function of the
-    id (duplicates of one id must agree) and is still masked by `valid`."""
+    id (duplicates of one id must agree) and is still masked by `valid`.
+    An owner's ids past `capacity` are dropped from its bucket and counted in
+    `overflow`; they keep their unique slots."""
     with _trace.scope("exchange", "route"):
         n = ids.shape[0]
         S = num_shards
@@ -212,38 +281,33 @@ def unique_and_route(ids: jax.Array, valid: jax.Array, num_shards: int,
                             num_unique.astype(jnp.int32), order.astype(jnp.int32),
                             seg)
 
-        # owner per UNIQUE slot: scatter the sorted owners through seg (padding
-        # slots >= num_unique keep the invalid pseudo-owner S)
-        u_owner = jnp.full((n,), S, jnp.int32).at[seg].set(
-            so, mode="drop", indices_are_sorted=True)
-        # bucket slot = unique rank within the owner group (seg is owner-major)
-        per_owner = jax.ops.segment_sum(is_new.astype(jnp.int32), so,
-                                        num_segments=S + 1)
-        start = jnp.concatenate(
-            [jnp.zeros((1,), jnp.int32),
-             jnp.cumsum(per_owner)[:-1].astype(jnp.int32)])
-        slot_u = jnp.where(u_owner < S,
-                           iota - start[jnp.clip(u_owner, 0, S - 1)], capacity)
-        in_cap = (u_owner < S) & (slot_u < capacity)
-        overflow = jnp.sum((u_owner < S) & (slot_u >= capacity)).astype(jnp.int32)
-        flat_pos = jnp.where(in_cap, u_owner * capacity + slot_u, S * capacity)
-        lanes = ids.shape[1:]
+        # uniques and positions per owner, from the sorted owners (`so` is
+        # ascending; the pseudo-owner S — invalid and carved-out positions —
+        # sorts last and is cut off): owner s's uniques are the range
+        # [start[s], start[s] + per_owner[s]) of the unique buffer
+        # (S masked reductions, not a segment sum: a scatter-add pays per
+        # position even into S + 1 segments)
+        mine = so[:, None] == jnp.arange(S, dtype=jnp.int32)
+        positions = jnp.sum(mine, axis=0, dtype=jnp.int32)
+        per_owner = jnp.sum(mine & is_new[:, None], axis=0, dtype=jnp.int32)
+        # unsigned: `dynamic_slice` wraps a signed offset if negative (three
+        # scalar ops an offset in the program; no time on the chip, PERF.md)
+        start = (jnp.cumsum(per_owner) - per_owner).astype(jnp.uint32)
+        count = jnp.minimum(per_owner, capacity)
+        overflow = jnp.sum(per_owner - count).astype(jnp.int32)
         # empty bucket slots hold the EMPTY sentinel, NOT zero (id 0 is a real
         # id): validity is then a pure function of the id payload, so the
         # exchange ships ONE all_to_all of ids instead of ids + a bool mask
-        # (`bucket_validity`), and the mask scatter disappears
+        # (`bucket_validity`)
         if ids.ndim == 2:
-            from .id64 import PAIR_EMPTY
-            empty = jnp.full((S * capacity,) + lanes, PAIR_EMPTY, ids.dtype)
+            from .id64 import PAIR_EMPTY as empty
         else:
-            empty = jnp.full((S * capacity,) + lanes, -1, ids.dtype)
-        bucket_ids = empty.at[flat_pos].set(
-            unique_ids, mode="drop").reshape((S, capacity) + lanes)
-        bucket_valid = bucket_validity(bucket_ids)
-        slot_out = jnp.where(in_cap, slot_u, capacity)
-        buckets = BucketResult(bucket_ids, bucket_valid, u_owner, slot_out,
-                               overflow)
-        return uniq, buckets
+            empty = -1
+        with _trace.scope("exchange", "bucket"):
+            bucket_ids = expand_blocks(unique_ids, start, count, capacity,
+                                       fill=empty)
+        return uniq, RoutedBuckets(bucket_ids, start, count, positions,
+                                   overflow)
 
 
 # ---------------------------------------------------------------------------
@@ -380,7 +444,9 @@ def compact_member_slots(member: jax.Array, pcap: int):
 
 def unbucket(bucket_rows: jax.Array, owner: jax.Array, slot: jax.Array) -> jax.Array:
     """Inverse of bucket_by_owner for per-id payloads: read back each input element's
-    row from its (owner, slot) position. bucket_rows: (num_shards, capacity, ...)."""
+    row from its (owner, slot) position. bucket_rows: (num_shards, capacity, ...).
+    A gather per slot: the reference the exchange's block copies
+    (`compact_blocks`) are tested against, not called by it."""
     with _trace.scope("exchange", "reassemble"):
         num_shards, capacity = bucket_rows.shape[:2]
         flat = bucket_rows.reshape((num_shards * capacity,) + bucket_rows.shape[2:])

@@ -39,7 +39,7 @@ in-band; int8 training adds pull-side error-feedback residuals
 (`EmbeddingTableState.ef`, served rows ship q(w+ef)) and stochastic rounding
 on the grad push so AUC holds fp32 parity. Id buckets and duplicate-count
 lanes are always exact. `S == 1` specializes to identity routing (no
-collectives, no bucket scatters, no wire quantization).
+collectives, no buckets, no wire quantization).
 
 Static capacity: each (src, dst) bucket of the WIRE holds `capacity` ids.
 `capacity == n` (exact mode, `capacity_factor=0`) can never drop an id and moves
@@ -74,6 +74,34 @@ W, the fullest shard) and `owner_full_steps`; on a device profile the block
 copies sit under `exchange.compact` and a full-size step's ops under
 `exchange.full_size`. The pipelined conflict patch (`grouped_conflict_patch`)
 indexes received slots by position and keeps the bucket layout.
+
+WHAT THE CLIENT SENDS: what it has, by S block copies, not S x capacity
+scatters. `unique_and_route` sorts by (owner, id), so the unique buffer comes
+out OWNER-MAJOR: owner s's ids are the contiguous range
+`unique_ids[start[s] : start[s] + count[s]]`, in order, and that range IS
+bucket s, at slots 0..count[s]-1 (`ops/dedup.RoutedBuckets`: S starts and S
+counts, not an (owner, slot) pair per unique slot). So every per-unique-slot
+array goes out the same way — the ids inside `unique_and_route`, the encoded
+gradient payload in `_to_buckets` — as S masked slices of the array padded by
+`cap` slots (`ops/dedup.expand_blocks`: lanes past count[s] hold the fill:
+EMPTY for ids, zeros for a payload, exactly what a scatter into a filled
+array left there), and what comes back in the bucket layout — the pulled
+rows, still encoded, and the conflict patch's stage and mask — is read into
+unique order by `_from_buckets`: bucket s copied at its owner's range, each
+later block overwriting the tail of the one before
+(`ops/dedup.compact_blocks`, the owner's `_compact` with each block masked
+past its count). The rows are decoded after that, n of them and not S x cap
+(decoding is row by row, so the order does not matter to a bit). Slots that
+no bucket holds (hot and invalid positions: the pseudo-owner S sorts last;
+an owner's ids past `cap`; padding) read zeros whatever an owner served for
+an EMPTY slot. No working size is chosen and no `fits` is needed, unlike
+the owner's side and the apply's: a bucket of `cap` slots always holds its
+owner's range, or drops the same tail past `cap` that a per-slot scatter
+dropped, and counts it in `overflow`. On a device profile the client's copies sit under
+`exchange.bucket`, apart from `exchange.route` (the sort, the unique buffer,
+the duplicate pre-sum, the encode) and from the owner's `exchange.compact`.
+The wire carries the same arrays bit for bit. Tested in
+`tests/test_client_buckets.py` against the per-slot scatter it replaced.
 
 SIZING RULE for `capacity_factor` (f): bucket (src, dst) must hold the unique
 ids of src's batch slice owned by dst. With u unique ids per device batch of n
@@ -164,8 +192,8 @@ import jax
 import jax.numpy as jnp
 
 from ..embedding import EmbeddingSpec, EmbeddingTableState, HotRows, MigRows
-from ..ops.dedup import (BucketResult, UniqueResult, bucket_by_owner,
-                         bucket_validity, carry_to_unique, unbucket,
+from ..ops.dedup import (RoutedBuckets, UniqueResult, bucket_validity,
+                         carry_to_unique, compact_blocks, expand_blocks,
                          unique_and_route, unique_with_counts)
 from ..ops.sparse import lookup_rows, sparse_apply_dense_table
 from ..utils import trace as _trace
@@ -203,7 +231,7 @@ class ExchangePlan(NamedTuple):
     by the push for the same batch)."""
 
     uniq: UniqueResult
-    buckets: BucketResult
+    buckets: RoutedBuckets
     recv_ids: jax.Array    # (S, cap) ids this shard must serve
     recv_valid: jax.Array  # (S, cap)
     cap: int
@@ -251,20 +279,13 @@ def _bucket_capacity(n: int, num_shards: int, capacity_factor: float) -> int:
 
 def _compact(x: jax.Array, offsets: jax.Array, W: int, fill=0) -> jax.Array:
     """(S, cap, ...) received buckets -> (W, ...): bucket s copied whole at
-    the running offset of the valid ids before it. A bucket's valid slots are
-    a PREFIX (`unique_and_route` gives a unique id the slot "rank within its
-    owner group"), so each later block overwrites exactly the empty tail of
+    the running offset of the valid ids before it (`ops/dedup.compact_blocks`).
+    A bucket's valid slots are a PREFIX (`unique_and_route` fills a bucket
+    from slot 0), so each later block overwrites exactly the empty tail of
     the one before: S contiguous block copies, no per-position scatter, and
     the source-major order of the valid slots is kept."""
     with _trace.scope("exchange", "compact"):
-        S, cap = x.shape[:2]
-        # cap slots of slack: a block copied at offset <= W ends inside the
-        # buffer, so `dynamic_update_slice` never clamps its start
-        buf = jnp.broadcast_to(jnp.asarray(fill, x.dtype),
-                               (W + cap,) + x.shape[2:])
-        for s in range(S):
-            buf = jax.lax.dynamic_update_slice_in_dim(buf, x[s], offsets[s], 0)
-        return buf[:W]
+        return compact_blocks(x, offsets, W, fill)
 
 
 def _expand(y: jax.Array, view: OwnerView, cap: int) -> jax.Array:
@@ -272,15 +293,26 @@ def _expand(y: jax.Array, view: OwnerView, cap: int) -> jax.Array:
     compact order -> (S, cap, ...) in the wire's bucket layout, zeros past
     each bucket's r_s valid slots (what the full-size serve leaves there)."""
     with _trace.scope("exchange", "compact"):
-        S = view.counts.shape[0]
-        pad = jnp.concatenate([y, jnp.zeros((cap,) + y.shape[1:], y.dtype)])
-        lane = jnp.arange(cap, dtype=jnp.int32).reshape(
-            (cap,) + (1,) * (y.ndim - 1))
-        return jnp.stack([
-            jnp.where(lane < view.counts[s],
-                      jax.lax.dynamic_slice_in_dim(pad, view.offsets[s], cap, 0),
-                      jnp.zeros((), y.dtype))
-            for s in range(S)])
+        return expand_blocks(y, view.offsets, view.counts, cap)
+
+
+def _to_buckets(payload: jax.Array, plan: "ExchangePlan") -> jax.Array:
+    """Per-unique-slot payload rows (n, ...) -> the plan's S outgoing buckets
+    (S, cap, ...): bucket s is owner s's range of the owner-major unique
+    buffer, zeros past its count (module doc "WHAT THE CLIENT SENDS")."""
+    with _trace.scope("exchange", "bucket"):
+        return expand_blocks(payload, plan.buckets.start, plan.buckets.count,
+                             plan.cap)
+
+
+def _from_buckets(x: jax.Array, plan: "ExchangePlan") -> jax.Array:
+    """What came back in the plan's bucket layout (S, cap, ...) ->
+    per-unique-slot rows (n, ...): bucket s copied at its owner's range.
+    Slots no bucket holds — hot and invalid ids, an overflowed tail, padding —
+    read zeros, whatever an owner put in a bucket's empty slots."""
+    with _trace.scope("exchange", "bucket"):
+        return compact_blocks(x, plan.buckets.start, plan.uniq.order.shape[0],
+                              counts=plan.buckets.count)
 
 
 def _owner_view(recv_ids: jax.Array, recv_valid: jax.Array,
@@ -414,8 +446,8 @@ def make_plan(spec: EmbeddingSpec, ids: jax.Array, *, axis: str = DATA_AXIS,
     """Dedup local ids, bucket by owner, exchange the id buckets (one all_to_all).
 
     Dedup and routing come out of ONE fused sort (`ops/dedup.unique_and_route`).
-    `S == 1` is specialized at trace time: every id is local, so the bucket
-    scatter and the id all_to_all vanish — the plan serves the unique ids
+    `S == 1` is specialized at trace time: every id is local, so the buckets
+    and the id all_to_all vanish — the plan serves the unique ids
     directly (the protocol's compute overhead at S=1 is the floor every
     multi-chip projection sits on; see PERF.md mesh1).
 
@@ -444,10 +476,10 @@ def make_plan(spec: EmbeddingSpec, ids: jax.Array, *, axis: str = DATA_AXIS,
             valid = (uniq.counts > 0) & _id_valid(spec, uniq.unique_ids)
             recv_ids = uniq.unique_ids[None]
             recv_valid = valid[None]
-            buckets = BucketResult(
-                bucket_ids=recv_ids, bucket_valid=recv_valid,
-                owner=jnp.zeros((n,), jnp.int32),
-                slot=jnp.arange(n, dtype=jnp.int32),
+            buckets = RoutedBuckets(
+                bucket_ids=recv_ids, start=jnp.zeros((1,), jnp.uint32),
+                count=jnp.sum(valid, dtype=jnp.int32)[None],
+                positions=jnp.full((1,), n, jnp.int32),
                 overflow=jnp.zeros((), jnp.int32))
             return ExchangePlan(uniq, buckets, recv_ids, recv_valid, n)
         uniq, buckets, cap, hot_slot, moved = _client_route(spec, flat, S,
@@ -581,17 +613,10 @@ def exchange_load_stats(plan: ExchangePlan, *, axis: str = DATA_AXIS
     `exchange.shard_imbalance{table=}` histogram."""
     with _trace.scope("exchange", "stats"):
         S = jax.lax.axis_size(axis)
-        routed = jnp.sum(plan.buckets.bucket_valid, axis=1).astype(jnp.int32)
-        # duplicate-weighted positions per destination: sum each unique slot's
-        # count into its owner segment. `buckets.owner` is ASCENDING (the
-        # owner-major sort in `unique_and_route`; zeros at S == 1), so this is
-        # the vectorized sorted-segment path — an unsorted scatter-add
-        # serializes (the ops/dedup.py lesson). Invalid/padding slots carry
-        # owner == S at S > 1 and count 0 at S == 1 — either way they drop out.
-        w = jnp.where(plan.uniq.counts > 0, plan.uniq.counts, 0).astype(jnp.int32)
-        positions = jax.ops.segment_sum(
-            w, plan.buckets.owner, num_segments=S + 1,
-            indices_are_sorted=True)[:S].astype(jnp.int32)
+        # both per-destination vectors come with the route (`RoutedBuckets`:
+        # counted over the sorted owners, S integers each)
+        routed = plan.buckets.count
+        positions = plan.buckets.positions
         occ = routed.max().astype(jnp.float32) / float(max(plan.cap, 1))
         me = _flat_axis_index(axis)
         fill = jnp.zeros((S,), jnp.float32).at[me].set(occ)
@@ -763,7 +788,7 @@ def _serve_flat(spec: EmbeddingSpec, state: EmbeddingTableState,
 def _merge_hot_rows(plan: ExchangePlan, uniq_rows: jax.Array,
                     hot: Optional[HotRows]) -> jax.Array:
     """Overlay the LOCAL hot-cache gather onto the exchange's unique rows
-    (cold left zeros at hot slots — their pseudo-owner S never unbuckets)."""
+    (cold left zeros at hot slots — their pseudo-owner S has no bucket)."""
     if hot is None or plan.hot_slot is None:
         return uniq_rows
     H = hot.weights.shape[0]
@@ -879,15 +904,15 @@ def _reassemble(plan: ExchangePlan, rows: jax.Array, out_shape,
                 dim: int, axis: str,
                 hot: Optional[HotRows] = None) -> jax.Array:
     """Client side of a pull whose `rows` are raw fp32-wire rows: rows back
-    over the a2a, un-bucket, expand duplicates, overlay the local hot-cache
-    gather. At S=1 the served rows ARE the unique rows (make_plan's identity
-    plan) — no a2a, no unbucket gather."""
+    over the a2a, read into unique order (`_from_buckets`), expand
+    duplicates, overlay the local hot-cache gather. At S=1 the served rows
+    ARE the unique rows (make_plan's identity plan) — no a2a, no buckets."""
     with _trace.scope("exchange", "reassemble"):
         if jax.lax.axis_size(axis) == 1:
             uniq_rows = rows[0]
         else:
             back = _a2a("rows", rows, axis)
-            uniq_rows = unbucket(back, plan.buckets.owner, plan.buckets.slot)
+            uniq_rows = _from_buckets(back, plan)
         uniq_rows = _merge_hot_rows(plan, uniq_rows, hot)
         out = jnp.take(uniq_rows, plan.uniq.inverse, axis=0)
         return out.reshape(out_shape + (dim,))
@@ -927,18 +952,6 @@ def _apply_load_stats(load: Dict[str, jax.Array], axis) -> Dict[str, jax.Array]:
         me = _flat_axis_index(axis)
         return {k: jnp.zeros((S,), v.dtype).at[me].set(v)
                 for k, v in load.items()}
-
-
-def _scatter_buckets(payload: jax.Array, buckets: BucketResult, S: int,
-                     cap: int) -> jax.Array:
-    """Scatter per-unique-slot payload rows (n, W) into their (owner, slot)
-    bucket positions -> (S, cap, W); invalid/overflowed slots drop."""
-    with _trace.scope("exchange", "route"):
-        width = payload.shape[1]
-        flat_pos = jnp.where((buckets.owner < S) & (buckets.slot < cap),
-                             buckets.owner * cap + buckets.slot, S * cap)
-        return jnp.zeros((S * cap, width), payload.dtype).at[flat_pos].set(
-            payload, mode="drop").reshape(S, cap, width)
 
 
 def _owner_apply(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
@@ -1067,6 +1080,26 @@ def split_wire_groups(groups, fmt_for):
     return out
 
 
+def _rows_round_trip(rows_list, dim: int, fmt: str, axis):
+    """ONE all_to_all for a dim-group's served rows. fp32 keeps the round-6
+    flow (mixed table dtypes promote at the concat, then widen); narrow
+    formats ship the buffers `_serve_rows` already encoded straight through
+    the collective. -> (what came back, STILL ENCODED and in the bucket
+    layout (S, sum of caps, width); `decode`: (m, width) rows -> (m, dim)
+    float32). Decoding is row by row, so the client reads its buckets back
+    into unique order first (`_from_buckets`) and decodes n rows, not
+    S x cap; each table then casts to its own dtype (exact for bf16-kept
+    tables)."""
+    from ..ops import wire as wire_mod
+    stacked = jnp.concatenate(rows_list, axis=1)
+    if fmt == "fp32":
+        S = stacked.shape[0]
+        enc = wire_mod.encode_rows(stacked.reshape(-1, dim), fmt)
+        stacked = enc.reshape(S, -1, enc.shape[-1])
+    back = _a2a("rows", stacked, axis)
+    return back, lambda flat: wire_mod.decode_rows(flat, dim, fmt)
+
+
 # oelint: hot-path device_get=0
 def grouped_lookup_train(
     specs, states, ids_list, *,
@@ -1111,29 +1144,13 @@ def grouped_lookup_train(
                 for spec, ids, plan, rows
                 in zip(specs, ids_list, plans, rows_list)]
     else:
-        # ONE all_to_all for the whole group's rows. fp32 keeps the round-6
-        # flow (mixed table dtypes promote at the concat); narrow formats
-        # ship the already-encoded int8/bf16 buffers straight through the
-        # collective — decode returns f32 and each table casts back to its
-        # own dtype (exact for bf16-kept tables)
-        stacked = jnp.concatenate(rows_list, axis=1)
-        if fmt == "fp32":
-            enc = wire_mod.encode_rows(stacked.reshape(-1, dim), fmt)
-            back = _a2a("rows", enc.reshape(S, -1, enc.shape[-1]), axis)
-            dec = wire_mod.decode_rows(
-                back.reshape(-1, enc.shape[-1]), dim, fmt).reshape(S, -1, dim)
-        else:
-            back = _a2a("rows", stacked, axis)
-            dec = wire_mod.unpack_inband(
-                back.reshape(-1, stacked.shape[-1]), dim,
-                fmt).reshape(S, -1, dim)
+        back, decode = _rows_round_trip(rows_list, dim, fmt, axis)
         outs, off = [], 0
         for spec, ids, plan, hot in zip(specs, ids_list, plans, hots):
-            seg = dec[:, off:off + plan.cap]
+            seg = back[:, off:off + plan.cap]
             off += plan.cap
             with _trace.scope("exchange", "reassemble"):
-                uniq_rows = unbucket(seg, plan.buckets.owner,
-                                     plan.buckets.slot)
+                uniq_rows = decode(_from_buckets(seg, plan))
                 uniq_rows = _merge_hot_rows(plan, uniq_rows, hot)
                 out = jnp.take(uniq_rows, plan.uniq.inverse, axis=0)
                 outs.append(out.astype(spec.dtype).reshape(
@@ -1218,9 +1235,8 @@ def grouped_apply_gradients(
             stats_list.append({"push_overflow": plan.buckets.overflow,
                                **_apply_load_stats(load, axis)})
         return new_states, stats_list
-    payloads = [_scatter_buckets(
-        wire_mod.encode_grads(g, rc, fmt, stochastic=(fmt == "int8")),
-        plan.buckets, S, plan.cap)
+    payloads = [_to_buckets(
+        wire_mod.encode_grads(g, rc, fmt, stochastic=(fmt == "int8")), plan)
                 for plan, g, rc in zip(plans, gs, counts_list)]
     recv = _a2a("grads", jnp.concatenate(payloads, axis=1), axis)
     off = 0
@@ -1345,23 +1361,12 @@ def grouped_prefetch(
                              if stash is not None else plan)
     plans = stashed_plans
     # same wire flow as grouped_lookup_train: ONE a2a for the group's rows
-    stacked = jnp.concatenate(rows_list, axis=1)
-    if fmt == "fp32":
-        enc = wire_mod.encode_rows(stacked.reshape(-1, dim), fmt)
-        back = _a2a("rows", enc.reshape(S, -1, enc.shape[-1]), axis)
-        dec = wire_mod.decode_rows(
-            back.reshape(-1, enc.shape[-1]), dim, fmt).reshape(S, -1, dim)
-    else:
-        back = _a2a("rows", stacked, axis)
-        dec = wire_mod.unpack_inband(
-            back.reshape(-1, stacked.shape[-1]), dim,
-            fmt).reshape(S, -1, dim)
+    back, decode = _rows_round_trip(rows_list, dim, fmt, axis)
     uniq_rows_list, off = [], 0
     for plan in plans:
-        seg = dec[:, off:off + plan.cap]
+        seg = back[:, off:off + plan.cap]
         off += plan.cap
-        uniq_rows_list.append(
-            unbucket(seg, plan.buckets.owner, plan.buckets.slot))
+        uniq_rows_list.append(decode(_from_buckets(seg, plan)))
     stats_list = []
     for spec, ids, plan in zip(specs, ids_list, plans):
         st = {
@@ -1553,8 +1558,8 @@ def grouped_conflict_patch(
             prow, mode="drop").reshape(S, cap, dim)
         smask = jnp.zeros((S * cap,), bool).at[flat_pos].set(
             live, mode="drop").reshape(S, cap)
-        patch_u = unbucket(stage, plan.buckets.owner, plan.buckets.slot)
-        mask_u = unbucket(smask, plan.buckets.owner, plan.buckets.slot)
+        patch_u = _from_buckets(stage, plan)
+        mask_u = _from_buckets(smask, plan)
         patched.append(jnp.where(mask_u[:, None],
                                  patch_u.astype(uniq_rows.dtype), uniq_rows))
         stats_list.append({

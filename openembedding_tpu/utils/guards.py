@@ -198,6 +198,28 @@ def collective_sequence(fn, *args, **kwargs):
     return seq
 
 
+def primitive_sites(fn, names, *args, **kwargs):
+    """[(primitive, name stack, operand and result shapes)] of every equation
+    `fn` traces to whose primitive is one of `names`, nested jaxprs included;
+    the name stack is the whole chain of `jax.named_scope`s (`trace.scope`
+    stages) down to the equation. Tracing only. For structural pins of the
+    kind "no scatter over S x capacity rows outside `exchange.full_size`"."""
+    import jax
+    found = []
+
+    def walk(jaxpr, under):
+        for eqn in jaxpr.eqns:
+            stack = f"{under}/{eqn.source_info.name_stack}"
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, stack)
+            if eqn.primitive.name in names:
+                found.append((eqn.primitive.name, stack, [
+                    tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars)
+                    if hasattr(v.aval, "shape")]))
+    walk(jax.make_jaxpr(fn)(*args, **kwargs).jaxpr, "")
+    return found
+
+
 # the most recent fingerprint computed in this process — postmortem capsules
 # (utils/capsule.py) embed it so a dump names the collective program that was
 # live at the failure without re-tracing anything

@@ -323,12 +323,12 @@ def test_unique_and_route_edges(case):
     if case == "single":
         assert int(uniq.num_unique) == 1
         assert occupancy == 1
-        assert int(buckets.owner[0]) == 5 % S
+        assert int(buckets.count[5 % S]) == 1
     elif case == "all_invalid":
         assert occupancy == 0
         assert int(buckets.overflow) == 0
         # every element routed to the invalid pseudo-owner
-        assert np.all(np.asarray(buckets.owner) == S)
+        assert np.all(np.asarray(buckets.count) == 0)
     else:
         assert int(uniq.num_unique) == 1
         assert occupancy == 1

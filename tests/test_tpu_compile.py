@@ -6,6 +6,12 @@ The apply's choice of a working size (`ops/sparse.py` "WHAT THE APPLY WORKS
 OVER") must not cost a table: PR 29's chip probe measured a plain `lax.switch`
 over the four rungs at +9.8 ms a step, a whole-table copy in every branch but
 the first and the last, which `_over_unique_prefix`'s `settle` barrier cures.
+
+The client's side of the exchange builds and reads its buckets by S block
+copies (`parallel/sharded.py` "WHAT THE CLIENT SENDS"): the four-chip cell's
+scan, traced for the four described chips at its real shapes, holds no
+scatter and no gather over S x capacity rows but in the owner's full-size
+branch, and still three all-to-alls a step.
 """
 
 import os
@@ -19,21 +25,26 @@ import jax.numpy as jnp
 import openembedding_tpu as embed
 from openembedding_tpu.ops.sparse import (FAST_MEMORY_BYTES, apply_ladder,
                                           sparse_apply_packed_table)
+from openembedding_tpu.utils import guards
 
 N = 4096 * 26  # the benchmark's positions a step
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
     # such a compile cannot be read back from the persistent cache
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -77,3 +88,53 @@ def test_apply_ladder_costs_no_table_copy_on_the_tpu(one_chip, rows, dim):
     copies = re.findall(rf"= {table}\S* (?:copy|copy-start)\(", text)
     assert not copies, f"{len(copies)} table-sized copies in the program"
     assert compiled.memory_analysis().temp_size_in_bytes < rows * 2 * dim * 4 // 8
+
+
+def test_four_chip_scan_has_no_per_slot_op_over_s_x_capacity_rows(topo):
+    """`deepfm9x4.train_zipf`'s program as traced for the 2x2 described
+    chips: 2^27 rows in four shards, 4096 examples a chip, exact mode, so
+    every bucket array of the wire has S x cap = 4 x 106,496 rows. PR 30's
+    scan scattered the ids and the gradient payload into them and gathered
+    the pulled rows out of them slot by slot (3.3 ms of a 28.3 ms step on the
+    chip, PERF.md); the only scatters and gathers of that length left are
+    the owner's, in a step whose received ids do not fit its working size."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from openembedding_tpu.models import make_deepfm
+    from openembedding_tpu.parallel import MeshTrainer, make_mesh
+    S, K, per_chip = 4, 2, 4096
+    B = S * per_chip
+
+    def trainer(devices):
+        return MeshTrainer(
+            make_deepfm(vocabulary=1 << 27, dim=9, hidden=(400, 400, 400),
+                        compute_dtype=jnp.bfloat16),
+            embed.Adagrad(learning_rate=0.05), mesh=make_mesh(devices),
+            wire="bf16")
+    one = {"sparse": {"categorical": np.zeros((B, 26), np.int32)},
+           "dense": np.zeros((B, 13), np.float32),
+           "label": np.zeros((B,), np.float32)}
+    # the state's shapes from the CPU mesh (nothing is made: 10.7 GB of table)
+    shapes = jax.eval_shape(trainer(jax.devices()[:S]).init, one)
+    tr = trainer(topo.devices)
+    state = jax.tree_util.tree_map(
+        lambda s, p: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(tr.mesh, p)),
+        shapes, tr._state_pspec_tree(shapes))
+    feed = NamedSharding(tr.mesh, P(None, tr.axis))
+    stacked = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct((K,) + x.shape, x.dtype,
+                                       sharding=feed), one)
+    many = tr.jit_train_many(jax.tree_util.tree_map(
+        lambda x: np.zeros((K,) + x.shape, x.dtype), one), state)
+    rows = S * per_chip * 26
+    sites = guards.primitive_sites(
+        many, ("scatter", "scatter-add", "gather"), state, stacked)
+    long = [(name, stack) for name, stack, shp in sites
+            if any(s and s[0] == rows for s in shp)]
+    assert long and all("exchange.full_size" in stack for _, stack in long), \
+        [site for site in long if "exchange.full_size" not in site[1]]
+    a2a = [c for c in guards.collective_sequence(many, state, stacked)
+           if c[0] == "all_to_all"]
+    assert len(a2a) == 3  # ids, rows, grads: one dim-group, as before
