@@ -505,14 +505,25 @@ class Trainer:
     def opt_for(self, spec: EmbeddingSpec) -> SparseOptimizer:
         return spec.optimizer or self.optimizer
 
-    def _loss(self, logits, batch):
+    def _loss(self, outputs, batch):
         """Pass the per-sample weight through when the batch carries one (padded
         tail batches from `data.CriteoBatcher`); loss fns without a weight arg
-        keep working for weightless batches."""
+        keep working for weightless batches. `outputs` is whatever the module
+        returned, one array of logits or a pytree of several (`_loss_terms`)."""
         w = batch.get("weight")
         if w is None:
-            return self.model.loss_fn(logits, batch["label"])
-        return self.model.loss_fn(logits, batch["label"], jnp.asarray(w))
+            return self.model.loss_fn(outputs, batch["label"])
+        return self.model.loss_fn(outputs, batch["label"], jnp.asarray(w))
+
+    def _loss_terms(self, outputs, batch):
+        """-> (loss, {stat: scalar}, the main logits). A module's output may
+        be a pytree (several heads): the `loss_fn` receives all of it, and
+        its FIRST leaf is the main logits, which is all the step's metrics
+        keep. A loss of several terms may name them by returning (loss,
+        {stat: scalar}); they ride with the module's own step stats."""
+        loss = self._loss(outputs, batch)
+        loss, terms = loss if isinstance(loss, tuple) else (loss, {})
+        return loss, terms, jax.tree_util.tree_leaves(outputs)[0]
 
     # -- init ---------------------------------------------------------------
 
@@ -715,7 +726,8 @@ class Trainer:
             else:
                 logits = model.module.apply({"params": dense_params},
                                             embedded, batch.get("dense"))
-            return self._loss(logits, batch), (logits, fr_new, module_stats)
+            loss, terms, logits = self._loss_terms(logits, batch)
+            return loss, (logits, fr_new, {**module_stats, **terms})
 
         # forward, combine, loss and backward: one stage (the backward's ops
         # read `transpose(jvp(dense.tower))`, which still holds the name)
@@ -970,7 +982,8 @@ class Trainer:
         attach_ids(embedded, model, batch)
         logits = model.module.apply({"params": state.dense_params}, embedded,
                                     batch.get("dense"))
-        return {"logits": logits, "loss": self._loss(logits, batch)}
+        loss, _, logits = self._loss_terms(logits, batch)
+        return {"logits": logits, "loss": loss}
 
     # -- jitted drivers ------------------------------------------------------
 

@@ -3,7 +3,10 @@
 Reference coverage (`documents/en/benchmark.md:6-16`, `examples/`,
 `test/benchmark/criteo_deepctr.py`): WDL (Wide&Deep), DeepFM, xDeepFM at dims 9/64,
 the LR subclass example (`examples/criteo_lr_subclass.py`), plus DLRM (the reference's
-PMem paper workload) and a two-tower retrieval model.
+PMem paper workload) and a two-tower retrieval model. Two language-model towers
+behind a token table ride the same train path: NemotronH (`nemotron_h.py`: Mamba-2,
+attention, routed experts) and JoyAI-LLM-Flash (`joyai_flash.py`: latent attention,
+routed SwiGLU experts, a multi-token-prediction module).
 
 TPU-first layout decision (differs deliberately from the reference's per-feature
 DeepCTR `Embedding` layers): all categorical fields share ONE row-sharded table, with
@@ -22,6 +25,7 @@ from .sequential import (SASRec, bert4rec_mask_id, make_bert4rec,
                          make_sasrec, sasrec_bce_loss,
                          synthetic_masked_sequences, synthetic_sequences)
 from .nemotron_h import NemotronH, make_nemotron_h, softmax_xent
+from .joyai_flash import JoyAIFlash, make_joyai_flash, mtp_xent
 
 _FAMILIES = {
     "lr": make_lr, "wdl": make_wdl, "deepfm": make_deepfm,
@@ -30,6 +34,7 @@ _FAMILIES = {
     "sasrec": make_sasrec,
     "bert4rec": make_bert4rec,
     "nemotron_h": make_nemotron_h,
+    "joyai_flash": make_joyai_flash,
 }
 
 
@@ -62,5 +67,6 @@ __all__ = [
     "SASRec", "make_sasrec", "sasrec_bce_loss", "synthetic_sequences",
     "make_bert4rec", "bert4rec_mask_id", "synthetic_masked_sequences",
     "NemotronH", "make_nemotron_h", "softmax_xent",
+    "JoyAIFlash", "make_joyai_flash", "mtp_xent",
     "CRITEO_NUM_SPARSE", "CRITEO_NUM_DENSE",
 ]

@@ -15,12 +15,14 @@ Every block is `x + mixer(RMSNorm(x))`; the pattern's letters pick the mixer:
   dt = softplus(dt + dt_bias); A = -exp(A_log);
   h_t = exp(dt A) h_{t-1} + dt B_t (x) x_t; y_t = C_t . h_t + D x_t, computed
   chunk by chunk (`ssd_chunked`); y = grouped RMSNorm(y * SiLU(z)); `out_proj`.
-- `*`, grouped-query causal attention without a rotary embedding, computed
-  query block by query block over the keys a block can see
-  (`blockwise_causal_attention`: no S x S array).
+- `*`, causal attention with grouped key/value heads and no rotary embedding,
+  computed query block by query block over the keys a block can see
+  (`blockwise_causal_attention`: no S x S array; shared with
+  `joyai_flash.py`'s latent attention, whose keys are wider than its values).
 - `E`, routed experts: f32 router, s = sigmoid(logits), the top k of
   s + correction bias (a buffer: no gradient reaches it), weights = chosen s
-  over their sum, times `routed_scaling_factor`; expert = down(relu(up(x))^2);
+  over their sum, times `routed_scaling_factor`; expert = down(relu(up(x))^2)
+  (`MoE(gated=True)`: down(silu(gate(x)) * up(x)), three weights an expert);
   plus one shared expert of the same form, always on. The layer is TOLD WHICH
   EXPERTS IT HOLDS (`experts_held`, `expert_offset`): it routes over all
   `n_routed_experts` and adds only its own experts' terms; what absent experts
@@ -29,10 +31,11 @@ Every block is `x + mixer(RMSNorm(x))`; the pattern's letters pick the mixer:
   exchange and nothing stands in for one). (token, choice) pairs are sorted
   by expert, the pairs held here are compacted to a static working size by
   the exchange's own owner view (`parallel/sharded._owner_view`: the same
-  sort / count / compact / `fits`), the experts run as one grouped matrix
-  product (`jax.lax.ragged_dot`) over that view, and a step whose pairs do
-  not fit runs the SAME function over all T x k pairs (`lax.cond`): no token
-  is dropped at any imbalance.
+  sort / count / compact / `fits`), every held expert's slots are laid out
+  from a block boundary (blocks of `BLOCK_ROWS` rows, each block's weights
+  picked by a one-hot product) and the experts run as batched products over
+  the blocks, and a step whose pairs do not fit runs the SAME function over
+  all T x k pairs (`lax.cond`): no token is dropped at any imbalance.
 
 Stage names (`utils/trace.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
 `attn.{qkv,core,out}`, `moe.{route,dispatch,experts,combine,shared}`,
@@ -60,21 +63,26 @@ NEG_INF = -1e30
 BLOCK_ROWS = 256  # rows of one expert's block in the routed layer's layout
 
 
-def softmax_xent(logits: jax.Array, labels: jax.Array, weight=None) -> jax.Array:
+def xent(logits: jax.Array, labels: jax.Array, weight=None) -> jax.Array:
     """Mean softmax cross-entropy of (B, S, V) f32 logits against (B, S) ids.
     `weight` (B,) or (B, S) turns the mean into a weighted mean."""
+    logits = logits.astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(
+        logits, labels.astype(jnp.int32)[..., None], axis=-1)[..., 0]
+    per = lse - picked
+    if weight is None:
+        return jnp.mean(per)
+    w = jnp.asarray(weight, per.dtype)
+    w = jnp.broadcast_to(w.reshape(w.shape + (1,) * (per.ndim - w.ndim)),
+                         per.shape)
+    return jnp.sum(per * w) / jnp.maximum(jnp.sum(w), 1.0)
+
+
+def softmax_xent(logits: jax.Array, labels: jax.Array, weight=None) -> jax.Array:
+    """`xent` as a model's `loss_fn`, under the stage name `lm.loss`."""
     with _trace.scope("lm", "loss"):
-        logits = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(
-            logits, labels.astype(jnp.int32)[..., None], axis=-1)[..., 0]
-        per = lse - picked
-        if weight is None:
-            return jnp.mean(per)
-        w = jnp.asarray(weight, per.dtype)
-        w = jnp.broadcast_to(w.reshape(w.shape + (1,) * (per.ndim - w.ndim)),
-                             per.shape)
-        return jnp.sum(per * w) / jnp.maximum(jnp.sum(w), 1.0)
+        return xent(logits, labels, weight)
 
 
 def rms_norm(x, scale, eps, groups: int = 1):
@@ -137,13 +145,14 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32):
 
 
 def blockwise_causal_attention(q, k, v, *, block: int = 512):
-    """Causal softmax attention, grouped-query: q (B, S, Hq, D), k and v
-    (B, S, Hkv, D), Hq % Hkv == 0; scale D^-1/2; softmax in f32. One block of
-    queries at a time against the keys it can see (keys [0, block end)), each
-    block rematerialised in the backward pass: nothing of size S x S is kept,
-    and the blocks above the diagonal are never computed."""
+    """Causal softmax attention: q (B, S, Hq, D), k (B, S, Hkv, D), v
+    (B, S, Hkv, Dv), Hq % Hkv == 0 (grouped key/value heads; Dv need not be
+    D) -> (B, S, Hq, Dv); scale D^-1/2; softmax in f32. One block of queries
+    at a time against the keys it can see (keys [0, block end)), each block
+    rematerialised in the backward pass: nothing of size S x S is kept, and
+    the blocks above the diagonal are never computed."""
     B, S, Hq, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[3]
     qg = q.reshape(B, S, Hkv, Hq // Hkv, D)
     scale = 1.0 / math.sqrt(D)
 
@@ -159,7 +168,7 @@ def blockwise_causal_attention(q, k, v, *, block: int = 512):
 
     out = [one(qg[:, lo:lo + block], k[:, :lo + block], v[:, :lo + block], lo)
            for lo in range(0, S, block)]
-    return jnp.concatenate(out, axis=1).reshape(B, S, Hq, D)
+    return jnp.concatenate(out, axis=1).reshape(B, S, Hq, Dv)
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -259,8 +268,17 @@ def _relu2(h):
     return jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(h.dtype)
 
 
-def _relu2_mlp(x, up, down, dtype):
-    h = _relu2(jnp.dot(x, up.astype(dtype)))
+def _swiglu(g, u):
+    """silu(g) * u in f32, handed on in the dtype it came in."""
+    return (jax.nn.silu(g.astype(jnp.float32))
+            * u.astype(jnp.float32)).astype(u.dtype)
+
+
+def _expert_mlp(x, gate, up, down, dtype):
+    """down(relu(up x)^2), or with a `gate` down(silu(gate x) * (up x))."""
+    h = jnp.dot(x, up.astype(dtype))
+    h = _relu2(h) if gate is None else _swiglu(
+        jnp.dot(x, gate.astype(dtype)), h)
     return jnp.dot(h, down.astype(dtype), preferred_element_type=jnp.float32)
 
 
@@ -281,6 +299,9 @@ class MoE(nn.Module):
     # costs nothing, `run`)
     working_pairs: int = 0
     dtype: jnp.dtype = jnp.bfloat16
+    # the expert's form: down(relu(up x)^2), or gated (SwiGLU, three weights
+    # an expert and the shared one): down(silu(gate x) * (up x))
+    gated: bool = False
 
     def _working_size(self, tokens: int) -> int:
         if self.working_pairs:
@@ -297,6 +318,8 @@ class MoE(nn.Module):
         init = nn.initializers.lecun_normal()
         up = self.param("experts_up", init, (E, D, self.expert_width))
         down = self.param("experts_down", init, (E, self.expert_width, D))
+        gate_w = self.param("experts_gate", init, (
+            E, D, self.expert_width)) if self.gated else None
 
         with _trace.scope("moe", "route"):
             router = self.param("router_kernel", init,
@@ -334,8 +357,9 @@ class MoE(nn.Module):
             slots [start, start + n) of the sorted order). Every expert's
             slots are laid out from a block boundary (blocks of `rows` rows,
             so n / rows + E blocks hold any split of the run among the
-            experts), each block takes its expert's weights, and up / relu^2 /
-            down are batched products over the blocks; then weigh and add to
+            experts), each block takes its expert's weights, and up (with gate,
+            where gated) / the activation / down are batched products over the
+            blocks; then weigh and add to
             the tokens. The cost is the same at any imbalance."""
             n = tokens.shape[0]
             blocks = -(-n // rows) + E
@@ -359,7 +383,10 @@ class MoE(nn.Module):
                 # (its transpose sums the blocks' gradients by expert)
                 up_b = jnp.einsum("be,edf->bdf", pick, up.astype(self.dtype))
                 down_b = jnp.einsum("be,efd->bfd", pick, down.astype(self.dtype))
-                h = _relu2(jnp.einsum("brd,bdf->brf", xs, up_b))
+                h = jnp.einsum("brd,bdf->brf", xs, up_b)
+                h = _relu2(h) if gate_w is None else _swiglu(jnp.einsum(
+                    "brd,bdf->brf", xs, jnp.einsum(
+                        "be,edf->bdf", pick, gate_w.astype(self.dtype))), h)
                 ys = jnp.einsum("brf,bfd->brd", h, down_b,
                                 preferred_element_type=jnp.float32)
             with _trace.scope("moe", "combine"):
@@ -408,8 +435,10 @@ class MoE(nn.Module):
             full = (~view.fits).astype(jnp.int32)
 
         with _trace.scope("moe", "shared"):
-            shared = _relu2_mlp(
-                xt, self.param("shared_up", init, (D, self.shared_width)),
+            shared = _expert_mlp(
+                xt, self.param("shared_gate", init, (D, self.shared_width))
+                if self.gated else None,
+                self.param("shared_up", init, (D, self.shared_width)),
                 self.param("shared_down", init, (self.shared_width, D)),
                 self.dtype)
         stats = {"pairs_here": total.astype(jnp.float32),

@@ -57,7 +57,8 @@ import os
 import re
 from typing import Dict, Iterable, List, Mapping, Optional, Tuple
 
-LAYERS = ("sparse", "exchange", "dense", "trainer", "ssm", "attn", "moe", "lm")
+LAYERS = ("sparse", "exchange", "dense", "trainer", "ssm", "attn", "moe", "lm",
+          "mlp", "mtp")
 CONTAINERS = {"while", "conditional", "call"}
 COLLECTIVES = ("all-to-all", "all-reduce", "all-gather", "reduce-scatter",
                "collective-permute", "collective-broadcast",

@@ -60,7 +60,8 @@ a2a_ids,a2a_rows,a2a_grads,owner_serve,owner_apply,reassemble,stats}`,
 conflict_patch,sentinel}`; and inside `dense.tower` a language-model tower's
 own (`models/nemotron_h.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
 `attn.{qkv,core,out}`, `moe.{route,dispatch,experts,combine,shared}`,
-`lm.{head,loss}`.
+`lm.{head,loss}`; (`models/joyai_flash.py`): `attn.{q_latent,kv_latent,rope,
+core,out}`, `mlp.dense`, `mtp.{merge,layer,head,loss}`.
 """
 
 from __future__ import annotations
