@@ -15,10 +15,11 @@ Every block is `x + mixer(RMSNorm(x))`; the pattern's letters pick the mixer:
   dt = softplus(dt + dt_bias); A = -exp(A_log);
   h_t = exp(dt A) h_{t-1} + dt B_t (x) x_t; y_t = C_t . h_t + D x_t, computed
   chunk by chunk (`ssd_chunked`); y = grouped RMSNorm(y * SiLU(z)); `out_proj`.
-- `*`, causal attention with grouped key/value heads and no rotary embedding,
-  computed query block by query block over the keys a block can see
-  (`blockwise_causal_attention`: no S x S array; shared with
-  `joyai_flash.py`'s latent attention, whose keys are wider than its values).
+- `*`, causal attention with grouped key/value heads and no rotary embedding
+  (`blockwise_causal_attention`: no S x S array; on a TPU one fused kernel,
+  `ops/flash_attention.py`, elsewhere query block by query block over the
+  keys a block can see; shared with `joyai_flash.py`'s latent attention,
+  whose keys are wider than its values).
 - `E`, routed experts: f32 router, s = sigmoid(logits), the top k of
   s + correction bias (a buffer: no gradient reaches it), weights = chosen s
   over their sum, times `routed_scaling_factor`; expert = down(relu(up(x))^2)
@@ -40,12 +41,14 @@ Every block is `x + mixer(RMSNorm(x))`; the pattern's letters pick the mixer:
 Stage names (`utils/trace.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
 `attn.{qkv,core,out}`, `moe.{route,dispatch,experts,combine,shared}`,
 `lm.{head,loss}`. Counters: the module hands `Trainer` per-step `moe.*` stats
-(`apply_with_stats`, `window_stats`).
+(`apply_with_stats`, `window_stats`); `attn.cores{path=}` counts the traced
+attention cores by what their shape allows (`blockwise_causal_attention`).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -56,6 +59,7 @@ import jax.numpy as jnp
 from ..embedding import Embedding
 from ..initializers import Normal
 from ..model import EmbeddingModel
+from ..utils import metrics as _metrics
 from ..utils import trace as _trace
 
 TOKEN = "token"
@@ -147,10 +151,40 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32):
 def blockwise_causal_attention(q, k, v, *, block: int = 512):
     """Causal softmax attention: q (B, S, Hq, D), k (B, S, Hkv, D), v
     (B, S, Hkv, Dv), Hq % Hkv == 0 (grouped key/value heads; Dv need not be
-    D) -> (B, S, Hq, Dv); scale D^-1/2; softmax in f32. One block of queries
-    at a time against the keys it can see (keys [0, block end)), each block
-    rematerialised in the backward pass: nothing of size S x S is kept, and
-    the blocks above the diagonal are never computed."""
+    D) -> (B, S, Hq, Dv); scale D^-1/2; softmax in f32; nothing of size
+    S x S is ever kept. The entry to two bodies that share no logic: at a
+    shape the fused kernel's tiling takes (`ops/flash_attention.tiling`) a
+    TPU lowering runs the kernel (its own blocks, scores in VMEM only) and
+    every other platform the plain body; at any other shape (`block` queries
+    a block) the plain body everywhere. Both are traced and the platform is
+    settled when the program is lowered, so `attn.cores{path=}`, counted
+    here once a traced call site, speaks for the SHAPE alone: "fused" = the
+    kernel would take it, "blockwise" = it refuses it. That the kernel ran
+    is the device trace's to say (a custom call under `attn.core`)."""
+    (_, S, Hq, D), Hkv, Dv = q.shape, k.shape[2], v.shape[3]
+    fused = _flash().tiling(S, D, Dv, Hq, Hkv) is not None
+    _metrics.observe("attn.cores", 1, "sum",
+                     labels={"path": "fused" if fused else "blockwise"})
+    plain = functools.partial(_blockwise_causal_attention, block=block)
+    if not fused:
+        return plain(q, k, v)
+    return jax.lax.platform_dependent(q, k, v, tpu=_flash().causal_attention,
+                                      default=plain)
+
+
+def _flash():
+    """`ops/flash_attention.py`, imported when a model first traces its
+    attention: Pallas is half a second of import, which the models that have
+    no attention (and their set-up times) are spared."""
+    from ..ops import flash_attention
+    return flash_attention
+
+
+def _blockwise_causal_attention(q, k, v, *, block):
+    """The plain body: one block of queries at a time against the keys it
+    can see (keys [0, block end)), each block rematerialised in the backward
+    pass, the blocks above the diagonal never computed; the f32 scores of a
+    block are an array of the program."""
     B, S, Hq, D = q.shape
     Hkv, Dv = k.shape[2], v.shape[3]
     qg = q.reshape(B, S, Hkv, Hq // Hkv, D)
@@ -508,16 +542,25 @@ class Block(nn.Module):
         return x + h.astype(x.dtype), stats
 
 
+@functools.cache
+def _kept_by_name():
+    return jax.checkpoint_policies.save_only_these_names(
+        _flash().OUT_NAME, _flash().LSE_NAME)
+
+
 def _keep_products(prim, *avals, **params):
     """Remat policy: a layer keeps the outputs of its plain matrix
     products (no batch dims) for its backward pass, but never a routed
     block's weights picked by the one-hot product (a (blocks, E) x (E, D, F)
-    product: 300 MB a pick, made again from the weights in no time)."""
+    product: 300 MB a pick, made again from the weights in no time); and it
+    keeps the fused attention core's output and log-sum-exp (by name: the
+    output projection's backward pass reads the one, the kernel's the
+    other), so the kernel's forward pass is not made again."""
     if (prim is jax.lax.dot_general_p and avals[0].ndim == 2
             and avals[1].ndim == 3):
         return False
-    return jax.checkpoint_policies.dots_with_no_batch_dims_saveable(
-        prim, *avals, **params)
+    return (jax.checkpoint_policies.dots_with_no_batch_dims_saveable(
+        prim, *avals, **params) or _kept_by_name()(prim, *avals, **params))
 
 
 class NemotronH(nn.Module):

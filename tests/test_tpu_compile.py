@@ -12,11 +12,19 @@ copies (`parallel/sharded.py` "WHAT THE CLIENT SENDS"): the four-chip cell's
 scan, traced for the four described chips at its real shapes, holds no
 scatter and no gather over S x capacity rows but in the owner's full-size
 branch, and still three all-to-alls a step.
+
+The attention core is one fused kernel on a TPU lowering
+(`ops/flash_attention.py`, behind `models/nemotron_h.blockwise_causal_
+attention`): at both language-model cells' shapes `value_and_grad` through the
+entry compiles to custom calls under the `attn.core` stage and to no f32
+array of a score block's size; a shape the tiling refuses compiles to the
+plain body.
 """
 
 import os
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -138,3 +146,56 @@ def test_four_chip_scan_has_no_per_slot_op_over_s_x_capacity_rows(topo):
     a2a = [c for c in guards.collective_sequence(many, state, stacked)
            if c[0] == "all_to_all"]
     assert len(a2a) == 3  # ids, rows, grads: one dim-group, as before
+
+
+# the two language-model cells' cores: (B, S, Hq, Hkv, D, Dv)
+CORES = {"joyai_keys192_values128": (2, 4096, 32, 32, 192, 128),
+         "nemotron_32_heads_over_2": (2, 4096, 32, 2, 128, 128)}
+SCORE_BLOCK = 2 * 32 * 512 * 512   # elements of one block's scores
+
+
+def _core_text(one_chip, shape, dtype=jnp.bfloat16):
+    """Optimised HLO and temporaries of value_and_grad through the entry,
+    under the stage name the models give it."""
+    from openembedding_tpu.models import nemotron_h as nh
+    from openembedding_tpu.utils import trace
+    B, S, Hq, Hkv, D, Dv = shape
+
+    def loss(q, k, v):
+        with trace.scope("attn", "core"):
+            o = nh.blockwise_causal_attention(q, k, v, block=512)
+        return jnp.sum(o.astype(jnp.float32))
+
+    def arg(*dims):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        arg(B, S, Hq, D), arg(B, S, Hkv, D), arg(B, S, Hkv, Dv)).compile()
+    return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+
+
+def _score_blocks(text):
+    """Element counts of the f32 arrays the text names that are at least a
+    block of queries by a block of keys in their two minor dimensions."""
+    shapes = ([int(d) for d in dims.split(",")]
+              for dims in set(re.findall(r"f32\[([0-9,]+)\]", text)))
+    return [int(np.prod(shape)) for shape in shapes
+            if len(shape) >= 2 and min(shape[-2:]) >= 512]
+
+
+@pytest.mark.parametrize("shape", CORES.values(), ids=CORES.keys())
+def test_attention_core_lowers_to_the_fused_kernel_on_the_tpu(one_chip, shape):
+    text, temp = _core_text(one_chip, shape)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2                      # forward, backward
+    assert all("attn.core" in line for line in calls), calls
+    assert max(_score_blocks(text), default=0) < SCORE_BLOCK
+    assert temp < 1 << 30
+
+
+def test_attention_core_the_tiling_refuses_lowers_to_the_plain_body(one_chip):
+    """A sequence of 4,000 splits into no block of 128 rows: the plain
+    blockwise body, its f32 scores an array of the program."""
+    text, _ = _core_text(one_chip, (2, 4000, 32, 2, 128, 128))
+    assert "tpu_custom_call" not in text
+    assert max(_score_blocks(text)) >= SCORE_BLOCK

@@ -81,6 +81,8 @@ KNOWN_LABELS = {
     "kind",       # operation kind within a group (bounded enum)
     "model",      # serving model sign
     "pass",       # oelint pass name (bounded by the pass registry)
+    "path",       # which body a traced attention core's shape allows (bounded
+                  # enum: fused / blockwise — `attn.cores`)
     "pool",       # parse-pool instance label (data/ingest.py)
     "rank",       # hot-row popularity rank bucket (utils/sketch.py)
     "ring",       # feed-ring instance label (data/ingest.py)
