@@ -1114,7 +1114,7 @@ class Trainer:
     def _module_stats(self) -> Dict[str, str]:
         """{stat: fold} a module counts a step (`module.window_stats`, with
         `apply_with_stats` handing them over): `moe.pairs_here` and the like.
-        The fold ("avg", "max", "sum") is over a window's steps and is the
+        The fold ("avg", "max", "min", "sum") is over a window's steps and is the
         series' kind in `metrics.report()`."""
         return dict(getattr(self.model.module, "window_stats", None) or ())
 
@@ -1131,7 +1131,8 @@ class Trainer:
                 **_table_stats(stats, _metrics.APPLY_STATS)}
 
     def _window_stats(self, kept) -> Dict:
-        fold = {"avg": jnp.mean, "max": jnp.max, "sum": jnp.sum}
+        fold = {"avg": jnp.mean, "max": jnp.max, "min": jnp.min,
+                "sum": jnp.sum}
         out = _fold_table_stats(kept, _metrics.APPLY_STATS)
         module = {k: fold[how](kept[k])
                   for k, how in self._module_stats.items() if k in kept}

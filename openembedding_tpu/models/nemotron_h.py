@@ -34,15 +34,16 @@ Every block is `x + mixer(RMSNorm(x))`; the pattern's letters pick the mixer:
   the exchange's own owner view (`parallel/sharded._owner_view`: the same
   sort / count / compact / `fits`), every held expert's slots are laid out
   from a block boundary (blocks of `BLOCK_ROWS` rows, each block's weights
-  picked by a one-hot product) and the experts run as batched products over
+  picked by a one-hot product, or gathered: `EXPERT_TILE`) and the experts run as batched products over
   the blocks, and a step whose pairs do not fit runs the SAME function over
   all T x k pairs (`lax.cond`): no token is dropped at any imbalance.
 
 Stage names (`utils/trace.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
-`attn.{qkv,core,out}`, `moe.{route,dispatch,experts,combine,shared}`,
-`lm.{head,loss}`. Counters: the module hands `Trainer` per-step `moe.*` stats
-(`apply_with_stats`, `window_stats`); `attn.cores{path=}` counts the traced
-attention cores by what their shape allows (`blockwise_causal_attention`).
+`attn.{qkv,core,gate,out}` (`gate`: `Attention(gate=True)`, `solar_open2.py`),
+`moe.{route,dispatch,experts,combine,shared}`, `lm.{head,loss}`. Counters: the
+module hands `Trainer` per-step `moe.*` stats (`apply_with_stats`,
+`window_stats`); `attn.cores{path=}` counts the traced attention cores by
+what their shape allows (`blockwise_causal_attention`).
 """
 
 from __future__ import annotations
@@ -65,6 +66,17 @@ from ..utils import trace as _trace
 TOKEN = "token"
 NEG_INF = -1e30
 BLOCK_ROWS = 256  # rows of one expert's block in the routed layer's layout
+# A block takes its expert's weights by a one-hot product where the held
+# experts fill whole tiles of this many, and by a gather where they do not.
+# The product is the faster pick (v5e, PR 34, one traced pair a cell: with the
+# gather Nemotron's step read 314.19 ms for 282.87, 8 experts; JoyAI's 350.24
+# for 327.59, 16: the compiler runs a batched product over GATHERED weights
+# as loops over slices). But it makes the compiler keep the experts' weights,
+# gradients AND accumulators with the expert axis second-minor all through a
+# `train_many` scan, as a second copy of that state PADDED to the tile: at 10
+# experts of 4096 x 1280, 24 copies of 320 MB for 200 MB of values, and the
+# scan needs 20.71 of the chip's 15.75 GiB; gathered, it fits (PERF.md 4, 7).
+EXPERT_TILE = 8
 
 
 def xent(logits: jax.Array, labels: jax.Array, weight=None) -> jax.Array:
@@ -205,6 +217,18 @@ def _blockwise_causal_attention(q, k, v, *, block):
     return jnp.concatenate(out, axis=1).reshape(B, S, Hq, Dv)
 
 
+def causal_conv(x, w, bias=None):
+    """Depthwise causal convolution over time: x (B, S, C), w (K, C) ->
+    (B, S, C) f32; tap j reads position t - (K - 1) + j, positions before
+    the sequence read 0. Shared with `solar_open2.py`'s linear layers."""
+    K, S = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    acc = 0.0 if bias is None else bias.astype(jnp.float32)
+    for j in range(K):
+        acc = acc + padded[:, j:j + S].astype(jnp.float32) * w[j]
+    return acc
+
+
 def _a_log_init(key, shape, dtype=jnp.float32):
     """A = -(1, 2, ..., H), a head (the HF Mamba-2 mixer's own start)."""
     return jnp.log(jnp.arange(1, shape[0] + 1, dtype=dtype))
@@ -242,12 +266,7 @@ class Mamba2Mixer(nn.Module):
             w = self.param("conv_kernel", nn.initializers.normal(K ** -0.5),
                            (K, conv_dim))
             b = self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
-            S = xbc.shape[1]
-            padded = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
-            acc = b.astype(jnp.float32)
-            for j in range(K):  # tap j reads position t - (K - 1) + j
-                acc = acc + padded[:, j:j + S].astype(jnp.float32) * w[j]
-            xbc = jax.nn.silu(acc).astype(self.dtype)
+            xbc = jax.nn.silu(causal_conv(xbc, w, b)).astype(self.dtype)
             xs, Bm, Cm = jnp.split(xbc, [inner, inner + bc], axis=-1)
         with _trace.scope("ssm", "scan"):
             dt_bias = self.param("dt_bias", _dt_bias_init, (H,))
@@ -277,6 +296,9 @@ class Attention(nn.Module):
     head_dim: int
     block: int = 512
     dtype: jnp.dtype = jnp.bfloat16
+    # an element-wise sigmoid gate as wide as the core's output, from the
+    # layer's input, before the output projection (`solar_open2.py`)
+    gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -292,9 +314,16 @@ class Attention(nn.Module):
             q, k, v = proj("q_proj", Hq), proj("k_proj", Hkv), proj("v_proj", Hkv)
         with _trace.scope("attn", "core"):
             o = blockwise_causal_attention(q, k, v, block=self.block)
+        o = o.reshape(B, S, Hq * D)
+        if self.gate:
+            with _trace.scope("attn", "gate"):
+                g = nn.Dense(Hq * D, use_bias=False, dtype=self.dtype,
+                             name="g_proj")(x)
+                o = (jax.nn.sigmoid(g.astype(jnp.float32))
+                     * o.astype(jnp.float32)).astype(self.dtype)
         with _trace.scope("attn", "out"):
             return nn.Dense(self.hidden, use_bias=False, dtype=self.dtype,
-                            name="o_proj")(o.reshape(B, S, Hq * D))
+                            name="o_proj")(o)
 
 
 def _relu2(h):
@@ -411,16 +440,24 @@ class MoE(nn.Module):
                 ok = (lane >= 0) & (lane < size[owner][:, None]) & valid[at]
                 token = jnp.where(ok, tokens[at], T)
                 xs = xt[jnp.minimum(token, T - 1)]               # (blocks, rows, D)
-                pick = jax.nn.one_hot(owner, E, dtype=self.dtype)
+                pick = None if E % EXPERT_TILE else jax.nn.one_hot(
+                    owner, E, dtype=self.dtype)
+
+            def of_block(w):
+                """(E, a, b) -> (blocks, a, b): a block's weights are its
+                expert's, picked by a one-hot product (its transpose sums
+                the blocks' gradients by expert) or gathered
+                (`EXPERT_TILE`)."""
+                if pick is None:
+                    return w.astype(self.dtype)[owner]
+                return jnp.einsum("ne,eab->nab", pick, w.astype(self.dtype))
+
             with _trace.scope("moe", "experts"):
-                # a block's weights: its expert's, picked by a one-hot product
-                # (its transpose sums the blocks' gradients by expert)
-                up_b = jnp.einsum("be,edf->bdf", pick, up.astype(self.dtype))
-                down_b = jnp.einsum("be,efd->bfd", pick, down.astype(self.dtype))
+                up_b = of_block(up)
+                down_b = of_block(down)
                 h = jnp.einsum("brd,bdf->brf", xs, up_b)
                 h = _relu2(h) if gate_w is None else _swiglu(jnp.einsum(
-                    "brd,bdf->brf", xs, jnp.einsum(
-                        "be,edf->bdf", pick, gate_w.astype(self.dtype))), h)
+                    "brd,bdf->brf", xs, of_block(gate_w)), h)
                 ys = jnp.einsum("brf,bfd->brd", h, down_b,
                                 preferred_element_type=jnp.float32)
             with _trace.scope("moe", "combine"):

@@ -39,7 +39,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 _LOCK = threading.Lock()
 _REGISTRY: Dict[str, "Accumulator"] = {}
 
-KINDS = ("sum", "avg", "max", "gauge", "hist")
+KINDS = ("sum", "avg", "max", "min", "gauge", "hist")
 
 # log-spaced histogram bucket upper bounds (le semantics): sqrt(2) steps from
 # 1e-3 up to ~1.9e5 — 56 buckets covering sub-us timer ticks to minutes-long
@@ -57,9 +57,9 @@ def _label_key(labels: Optional[Dict[str, str]]) -> str:
 
 class Accumulator:
     """A named metric. kind: "sum" (counter), "avg" (mean of observations),
-    "max" (high-water mark), "gauge" (last value), "hist" (log-spaced-bucket
-    latency/size histogram with p50/p95/p99). `labels` distinguishes series
-    of one metric (per-table, per-model)."""
+    "max" (high-water mark), "min" (low-water mark), "gauge" (last value),
+    "hist" (log-spaced-bucket latency/size histogram with p50/p95/p99).
+    `labels` distinguishes series of one metric (per-table, per-model)."""
 
     def __init__(self, name: str, kind: str = "sum", help: str = "",
                  labels: Optional[Dict[str, str]] = None):
@@ -123,6 +123,8 @@ class Accumulator:
                 return self._total / self._count if self._count else 0.0
             if self.kind == "max":
                 return self._max if self._count else 0.0
+            if self.kind == "min":
+                return self._min if self._count else 0.0
             return self._total
 
     def quantile(self, q: float) -> float:
@@ -547,7 +549,7 @@ def prometheus_text() -> str:
         base = "oetpu_" + a.name.translate(_SANE)
         family = base + ("_total" if a.kind == "sum" else "")
         ptype = {"sum": "counter", "avg": "gauge", "max": "gauge",
-                 "gauge": "gauge", "hist": "histogram"}[a.kind]
+                 "min": "gauge", "gauge": "gauge", "hist": "histogram"}[a.kind]
         if family not in seen:
             seen.add(family)
             if a.help:

@@ -61,7 +61,8 @@ conflict_patch,sentinel}`; and inside `dense.tower` a language-model tower's
 own (`models/nemotron_h.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
 `attn.{qkv,core,out}`, `moe.{route,dispatch,experts,combine,shared}`,
 `lm.{head,loss}`; (`models/joyai_flash.py`): `attn.{q_latent,kv_latent,rope,
-core,out}`, `mlp.dense`, `mtp.{merge,layer,head,loss}`.
+core,out}`, `mlp.dense`, `mtp.{merge,layer,head,loss}`; (`models/
+solar_open2.py`): `kda.{qkv,conv,gates,scan,gate_norm,out}`, `attn.gate`.
 """
 
 from __future__ import annotations

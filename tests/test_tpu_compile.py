@@ -15,10 +15,16 @@ branch, and still three all-to-alls a step.
 
 The attention core is one fused kernel on a TPU lowering
 (`ops/flash_attention.py`, behind `models/nemotron_h.blockwise_causal_
-attention`): at both language-model cells' shapes `value_and_grad` through the
+attention`): at the language-model cells' shapes `value_and_grad` through the
 entry compiles to custom calls under the `attn.core` stage and to no f32
 array of a score block's size; a shape the tiling refuses compiles to the
 plain body.
+
+The Solar-Open2 cell's scan (`models/solar_open2.py`: 966.7M parameters, 7.2
+GiB of state) fits the chip only because its routed layer GATHERS a block's
+weights: the one-hot pick makes the compiler keep the experts' weights,
+gradients and accumulators a second time, padded from 10 experts to 16
+(20.7 of 15.75 GiB). The whole scan is compiled here at the cell's real sizes.
 """
 
 import os
@@ -148,9 +154,11 @@ def test_four_chip_scan_has_no_per_slot_op_over_s_x_capacity_rows(topo):
     assert len(a2a) == 3  # ids, rows, grads: one dim-group, as before
 
 
-# the two language-model cells' cores: (B, S, Hq, Hkv, D, Dv)
+# the language-model cells' cores: (B, S, Hq, Hkv, D, Dv); 8,192 at 128 / 128
+# is the longest sequence the kernel's tiling takes
 CORES = {"joyai_keys192_values128": (2, 4096, 32, 32, 192, 128),
-         "nemotron_32_heads_over_2": (2, 4096, 32, 2, 128, 128)}
+         "nemotron_32_heads_over_2": (2, 4096, 32, 2, 128, 128),
+         "solar_8k_8_heads_over_1": (1, 8192, 8, 1, 128, 128)}
 SCORE_BLOCK = 2 * 32 * 512 * 512   # elements of one block's scores
 
 
@@ -199,3 +207,45 @@ def test_attention_core_the_tiling_refuses_lowers_to_the_plain_body(one_chip):
     text, _ = _core_text(one_chip, (2, 4000, 32, 2, 128, 128))
     assert "tpu_custom_call" not in text
     assert max(_score_blocks(text)) >= SCORE_BLOCK
+
+
+def test_solar_open2_scan_fits_the_chip_at_ten_experts(one_chip):
+    """`benchmark`'s `solar-open2.train_8k` at its real sizes: the 4-step scan
+    compiles for the described v5e (the compiler raises where it does not
+    fit), with one fused attention core forward and backward, three chunked
+    delta-rule scans (their solves custom calls) and no second copy of the
+    experts' state with the expert axis second-minor."""
+    import json
+
+    from openembedding_tpu import models
+    from openembedding_tpu.model import Trainer
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs",
+                        "solar-open2-250b-l4-h8of64.json")
+    with open(path) as f:
+        cfg = json.load(f)
+
+    def at(path):
+        node = cfg
+        for key in path.split("."):
+            node = node[key]
+        return node
+    model = models.make_solar_open2(
+        compute_dtype=jnp.dtype(cfg["tower_dtype"]),
+        **{kw: at(path) for path, kw in cfg["make_keywords"].items()})
+    tr = Trainer(model, embed.Adagrad(learning_rate=cfg["learning_rate"],
+                                      initial_accumulator_value=0.1,
+                                      epsilon=1e-7))
+    K, B, S = 4, 1, 8192
+    sample = {"sparse": {"token": np.zeros((B, S), np.int32)},
+              "label": np.zeros((B, S), np.int32)}
+    with jax.enable_x64(False):  # the cell's own setting; the suite's is on
+        state = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(tr.init, sample))
+        ids = jax.ShapeDtypeStruct((K, B, S), jnp.int32, sharding=one_chip)
+        text = tr.jit_train_many().lower(
+            state, {"sparse": {"token": ids}, "label": ids}).compile().as_text()
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
+        (state.dense_params, state.tables["token"].weights))) == 966_701_720
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert not re.search(r"f32\[10,(4096,1280|1280,4096)\]\{2,0,1", text)
