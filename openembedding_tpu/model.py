@@ -950,7 +950,11 @@ class Trainer:
 
     def table_pull(self, spec, table, ids):
         """-> (new_table, rows, stats, plan). The plan (routing/dedup state) is handed
-        back to table_apply so push reuses pull's work; None on single device."""
+        back to table_apply so push reuses pull's work; None here, where the
+        table is split into weights and slots and every position gathers its
+        row (inside the scan a packable table takes `_packed_pull`)."""
+        _metrics.observe("sparse.pulls", 1, "sum",
+                         labels={"path": "per_position"})
         with _trace.scope("sparse", "pull"):
             table, rows = lookup_train(spec, table, ids)
         return table, rows, {}, None
@@ -1010,22 +1014,33 @@ class Trainer:
         return out
 
     def _packed_pull(self, spec, table, ids):
-        """Pull from the packed layout: gather full packed rows (the gather is
-        latency-bound, the extra slot bytes ride free) and slice the weight
-        columns. Hash tables keep their normal probe/insert (keys are a
-        separate array either way)."""
+        """Pull from the packed layout -> (table, rows, stats, plan). An
+        array table plans its step first (`ops/sparse.plan_packed_rows`: the
+        apply's dedup, and ONE gather of the unique packed rows at the
+        apply's working size) and expands the weight columns to positions
+        from that small array; the plan goes on to `_packed_apply`, which
+        neither dedups nor gathers again. Hash tables keep their normal
+        probe/insert, per position (keys are a separate array either way),
+        and hand on no plan. `sparse.pulls{path=}` counts which, once a
+        table a trace. Stages: the dedup `sparse.dedup`, its counts
+        `sparse.reduce`, the unique gather and the expansion `sparse.pull`."""
+        _metrics.observe("sparse.pulls", 1, "sum", labels={
+            "path": "per_position" if spec.use_hash_table else "shared"})
         with _trace.scope("sparse", "pull"):
             from .embedding import _flat_ids
-            from .ops.sparse import lookup_rows
             flat, out_shape = _flat_ids(spec, ids)
+            plan = None
             if spec.use_hash_table:
                 from .tables.hash_table import hash_lookup_train
                 table, rows = hash_lookup_train(table, flat,
                                                 out_dim=spec.output_dim)
             else:
-                rows = lookup_rows(table.weights, flat)[:, :spec.output_dim]
+                from .ops.sparse import lookup_rows, plan_packed_rows
+                plan = plan_packed_rows(table.weights, flat)
+                rows = lookup_rows(plan.rows[:, :spec.output_dim],
+                                   plan.uniq.inverse)
             rows = rows.astype(spec.dtype).reshape(out_shape + (spec.output_dim,))
-            return table, rows, {}, None
+            return table, rows, {}, plan
 
     def _packed_apply(self, spec, table, ids, grads, layout, plan=None):
         with _trace.scope("sparse", "apply"):
@@ -1040,7 +1055,7 @@ class Trainer:
                     spec.output_dim)
             packed, load = sparse_apply_packed_table(
                 self.opt_for(spec), table.weights, layout, spec.output_dim,
-                flat_ids, flat_grads)
+                flat_ids, flat_grads, plan=plan)
             return table.replace(weights=packed), load
 
     def train_many(self, state: TrainState, batches) -> Tuple[TrainState, Dict]:

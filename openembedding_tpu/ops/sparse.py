@@ -14,13 +14,13 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Dict, Tuple
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..utils import trace as _trace
-from .dedup import unique_with_counts
+from .dedup import UniqueResult, unique_with_counts
 
 
 def lookup_rows(weights: jax.Array, rows: jax.Array,
@@ -162,6 +162,29 @@ def unpack_table(packed: jax.Array, layout, dim: int, weights_dtype
         return weights, slots
 
 
+def _route_unique(n_rows: int, row_ids: jax.Array, pre_counts: jax.Array):
+    """The dedup of both fused applies and of a packed table's plan: padding
+    (count 0) and negative ids sort under the out-of-range key `n_rows` (the
+    first of `_dedup_routed`'s invariants)."""
+    keep = (row_ids >= 0 if pre_counts is None
+            else (pre_counts > 0) & (row_ids >= 0))
+    return unique_with_counts(jnp.where(keep, row_ids, n_rows))
+
+
+def _unique_slots(n_rows: int, uniq, pre_counts: jax.Array):
+    """-> (counts, idx) of the unique buffer: what each slot sums of
+    `pre_counts` (0 on a sentinel slot; None = one a position, which the
+    dedup has counted already) and the row it stands for (invalid slots at
+    distinct out-of-bounds rows). Under `sparse.reduce`."""
+    n = uniq.order.shape[0]
+    counts = (uniq.counts if pre_counts is None
+              else uniq.segment_reduce(pre_counts))
+    counts = jnp.where(uniq.unique_ids < n_rows, counts, 0)
+    idx = jnp.where(counts > 0, uniq.unique_ids,
+                    n_rows + jnp.arange(n, dtype=uniq.unique_ids.dtype))
+    return counts, idx
+
+
 def _dedup_routed(n_rows: int, row_ids: jax.Array, grads: jax.Array,
                   pre_counts: jax.Array):
     """Shared dedup/sentinel prologue of both fused applies -> (g, counts, idx).
@@ -178,19 +201,14 @@ def _dedup_routed(n_rows: int, row_ids: jax.Array, grads: jax.Array,
       the vectorized gather/scatter instead of a serialized row loop;
     - invalid ids sort LAST under that key, so the valid unique slots are a
       prefix of (`g`, `counts`, `idx`): what `_over_unique_prefix` cuts."""
-    n = row_ids.shape[0]
     if pre_counts is None:
-        pre_counts = jnp.ones((n,), jnp.int32)
-    uniq = unique_with_counts(jnp.where((pre_counts > 0) & (row_ids >= 0),
-                                        row_ids, n_rows))
+        pre_counts = jnp.ones(row_ids.shape[:1], jnp.int32)
+    uniq = _route_unique(n_rows, row_ids, pre_counts)
     # the sums over duplicates get a name of their own: on the owner side of
     # the exchange they run over S times the positions
     with _trace.scope("sparse", "reduce"):
         g = uniq.segment_reduce(grads)
-        counts = uniq.segment_reduce(pre_counts)
-        counts = jnp.where(uniq.unique_ids < n_rows, counts, 0)
-        idx = jnp.where(counts > 0, uniq.unique_ids,
-                        n_rows + jnp.arange(n, dtype=uniq.unique_ids.dtype))
+        counts, idx = _unique_slots(n_rows, uniq, pre_counts)
     return g, counts, idx
 
 
@@ -230,8 +248,9 @@ def _dedup_routed(n_rows: int, row_ids: jax.Array, grads: jax.Array,
 #   several rungs (a buffer with one rung has nothing to overrun: 0)}; the
 #   trainers carry it as `{table}/apply_fill` in the step's stats and fold it
 #   to `sparse.apply_fill{table=}` / `sparse.apply_full_steps{table=}`.
-# `segment_reduce`, the dedup and the forward pull pay per input position and
-# are not part of this.
+# `segment_reduce` and the dedup pay per input position and are not part of
+# this. A packed table's forward pull is ("ONE DEDUP AND ONE TABLE GATHER A
+# STEP", further down): it reads the step's unique rows at the same rung.
 # ---------------------------------------------------------------------------
 
 # what a v5e's compiler keeps in fast memory: a 64 and an 80 MiB table yes, a
@@ -280,6 +299,70 @@ def _over_unique_prefix(counts: jax.Array, tables, tail):
         + [full_size]), load
 
 
+# ---------------------------------------------------------------------------
+# ONE DEDUP AND ONE TABLE GATHER A STEP (a packed table, whose scan holds
+# weights and optimizer slots in one array). The forward pull and the apply
+# read the SAME rows of the SAME table: nothing writes it between them (the
+# apply's scatter is the step's last op on it). A gather from the table is
+# latency-bound per index (28 ns against the 2.7 GB dim-9 table, above), and
+# the pull used to pay it once per POSITION (106,496 for about 72,000 rows)
+# and the apply once more per unique slot: 3.0 + 2.2 ms of a 19.7 ms step
+# (ledger, PR 34). So the step PLANS before it pulls (`plan_packed_rows`):
+# the apply's own dedup and routing of the ids, and ONE sorted gather of the
+# unique packed rows at the apply's rung. The pull expands the weight columns
+# to positions from that small array (by `uniq.inverse`: 0.16 ms for the
+# 106,496 positions on the v5e, 1.5 ns each against a table gather's 28) and
+# the apply
+# (`sparse_apply_packed_table(plan=)`) takes the plan's rows, order and
+# segments: it neither dedups nor gathers again. Same values in the same
+# places, so the table after a scan is the plan-less one bit for bit.
+# - The gather sits under the ladder's conditional and hands back rows padded
+#   to n so every branch has one shape; the apply's branch slices `[:W]`. A
+#   position whose id is invalid maps to a slot past the valid prefix, whose
+#   row reads 0 (out of bounds, or padding), as the per-position pull gave it.
+# - A caller with no pull to share with (the owner side of the exchange, a
+#   hash table's apply) passes no plan and gets the program it had.
+# ---------------------------------------------------------------------------
+
+
+class PackedPlan(NamedTuple):
+    """What a packed table's step knows of its ids before the pull, kept for
+    the apply (`plan_packed_rows`)."""
+
+    uniq: UniqueResult   # the dedup under the apply's routing (`_route_unique`)
+    counts: jax.Array    # (n,) int32: what each unique slot sums of the
+    #                      plan's `pre_counts`; 0 = sentinel or padding slot
+    idx: jax.Array       # (n,) the row a slot stands for (`_unique_slots`)
+    rows: jax.Array      # (n, width) f32: the packed rows of `idx`, gathered
+    #                      at the step's rung of `apply_ladder`, 0 past it
+
+
+def plan_packed_rows(packed: jax.Array, row_ids: jax.Array,
+                     pre_counts: jax.Array = None) -> PackedPlan:
+    """Dedup and route `row_ids` as `sparse_apply_packed_table` would and
+    gather the unique packed rows once, over the smallest rung of the ladder
+    that holds them ("ONE DEDUP AND ONE TABLE GATHER A STEP" above).
+    `pre_counts` as the apply's: ones by default, 0 = a position to leave
+    out; one that only says WHICH positions count (0 / 1) will do where the
+    apply brings the multiplicities."""
+    n = row_ids.shape[0]
+    uniq = _route_unique(packed.shape[0], row_ids, pre_counts)
+    with _trace.scope("sparse", "reduce"):
+        counts, idx = _unique_slots(packed.shape[0], uniq, pre_counts)
+
+    def gather(W, settle):
+        del settle  # nothing is written: the table is read and handed on
+        rows = _gather_rows(packed, idx[:W], sorted_unique=True)
+        return jnp.pad(rows, ((0, n - W), (0, 0)))
+
+    rows, _ = _over_unique_prefix(counts, packed, gather)
+    # the pull slices the weight columns out of `rows`: left alone the
+    # compiler sinks that slice into the branches, a conditional's outputs
+    # live in HBM, and the expansion from an HBM array of 10 lanes padded to
+    # 128 read 0.885 ms on the v5e against 0.161 from fast memory (probe, PR 35)
+    return PackedPlan(uniq, counts, idx, jax.lax.optimization_barrier(rows))
+
+
 def sparse_apply_packed_table(
     optimizer,
     packed: jax.Array,
@@ -288,15 +371,33 @@ def sparse_apply_packed_table(
     row_ids: jax.Array,
     grads: jax.Array,
     pre_counts: jax.Array = None,
+    *,
+    plan: PackedPlan = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """`sparse_apply_dense_table` over the packed layout: identical dedup and
     optimizer math, ONE gather + ONE scatter instead of one pair per array.
-    The scan's form, so always -> (packed, the step's load)."""
+    The scan's form, so always -> (packed, the step's load).
+
+    `plan`: what `plan_packed_rows` made of the SAME `row_ids` against this
+    `packed`, unwritten since: the apply then sums over the plan's segments
+    and updates the plan's rows, with no dedup and no gather of its own.
+    `pre_counts` given beside a plan are summed anew over its segments (they
+    must be positive exactly where the plan's were)."""
     with _trace.scope("sparse", "apply"):
-        g, counts, idx = _dedup_routed(packed.shape[0], row_ids, grads, pre_counts)
+        if plan is None:
+            g, counts, idx = _dedup_routed(packed.shape[0], row_ids, grads,
+                                           pre_counts)
+        else:
+            with _trace.scope("sparse", "reduce"):
+                g = plan.uniq.segment_reduce(grads)
+                counts, idx = plan.counts, plan.idx
+                if pre_counts is not None:
+                    counts = jnp.where(counts > 0,
+                                       plan.uniq.segment_reduce(pre_counts), 0)
 
         def tail(W, settle):
-            rows = _gather_rows(packed, idx[:W], sorted_unique=True)  # (W, width) f32
+            rows = (plan.rows[:W] if plan is not None else _gather_rows(
+                packed, idx[:W], sorted_unique=True))  # (W, width) f32
             s_rows = {}
             off = dim
             for name, w in layout:

@@ -9,6 +9,7 @@ numeric parity against the split-layout step path, (b) the width gate, and
 import numpy as np
 import pytest
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
@@ -549,3 +550,149 @@ def test_a_small_table_traces_no_switch(gate, switched, monkeypatch):
             opt, w, s, ids, g)).lower(w, slots).as_text()
     assert ("stablehlo.case" in text(_N)) == switched
     assert "stablehlo.case" not in text(128)
+
+
+# -- one dedup and one table gather a table a step (ops/sparse.py "ONE DEDUP
+# AND ONE TABLE GATHER A STEP"): an array table's packed scan plans before it
+# pulls, expands the unique rows to positions and hands the plan to the apply.
+# Same values in the same places: the scan leaves what K `train_step` calls
+# leave and what the scan without a plan (the per-position pull and an apply
+# that dedups and gathers for itself: the program as it was) leaves ----------
+
+_PB, _PF, _PROWS, _PDIM, _PK = 64, 8, 1024, 8, 3   # 512 positions a step
+# name -> (distinct valid rows a batch holds, what else it holds, combiner)
+_PLAN_CASES = {
+    "duplicates_rung_0": (40, "", ""),
+    "rung_1": (200, "", ""),
+    "rung_2": (300, "", ""),
+    "full_size_rung": (450, "", ""),
+    "negative_ids": (150, "negative", ""),
+    "ids_past_the_rows": (150, "past", ""),
+    "padded_bags_combined": (200, "padding", "mean"),
+}
+
+
+class _BagTower(nn.Module):
+    """A dense layer over the rows, flattened: (B, F, dim) as pulled, or
+    (B, dim) where the spec combines a bag."""
+
+    @nn.compact
+    def __call__(self, embedded, dense_inputs):
+        x = embedded["emb"].reshape(embedded["emb"].shape[0], -1)
+        return nn.Dense(1)(x)[:, 0]
+
+
+class _PerPositionTrainer(Trainer):
+    """The packed pull as it was: the full packed row once a position, no
+    plan, so `_packed_apply` calls `sparse_apply_packed_table` without one."""
+
+    def _packed_pull(self, spec, table, ids):
+        from openembedding_tpu.ops.sparse import lookup_rows
+        rows = lookup_rows(table.weights, ids.reshape(-1))[:, :spec.output_dim]
+        return table, rows.astype(spec.dtype).reshape(
+            ids.shape + (spec.output_dim,)), {}, None
+
+
+def _plan_batches(case):
+    valid, extra, _ = _PLAN_CASES[case]
+    rng = np.random.default_rng(sorted(_PLAN_CASES).index(case))
+    out = []
+    for _ in range(_PK):
+        pool = rng.permutation(_PROWS)[:valid]
+        ids = rng.permutation(np.concatenate(
+            [pool, rng.choice(pool, _PB * _PF - valid)]))
+        first = np.unique(ids, return_index=True)[1]
+        free = np.setdiff1d(np.arange(ids.size), first)  # every row keeps one
+        bad = rng.choice(free, min(120, free.size), replace=False)
+        if extra == "negative":
+            ids[bad] = -1 - rng.integers(0, 7, bad.size)
+        elif extra == "past":
+            ids[bad] = _PROWS + rng.integers(0, 3 * _PROWS, bad.size)
+            ids[bad[:4]] = [_PROWS, 2**31 - 1, _PROWS + 1, _PROWS]
+        elif extra == "padding":
+            ids[bad] = -1
+        out.append({"sparse": {"emb": ids.reshape(_PB, _PF).astype(np.int32)},
+                    "dense": None,
+                    "label": rng.integers(0, 2, (_PB,)).astype(np.float32)})
+        assert np.unique(ids[(ids >= 0) & (ids < _PROWS)]).size == valid
+    return out
+
+
+@pytest.mark.parametrize("case,gate", [(c, 0) for c in sorted(_PLAN_CASES)] + [
+    ("duplicates_rung_0", None), ("negative_ids", None)])
+def test_scan_with_the_shared_plan_is_the_step_loop_and_the_plan_less_scan(
+        case, gate, monkeypatch):
+    """gate 0: the ladder engaged, as for a table of `FAST_MEMORY_BYTES` and
+    more (both conditionals traced, the case's rung taken); None: a table
+    under it (no conditional, the gather at n)."""
+    from openembedding_tpu.ops import sparse
+    if gate is not None:
+        monkeypatch.setattr(sparse, "FAST_MEMORY_BYTES", gate)
+    valid, _, combiner = _PLAN_CASES[case]
+    assert apply_ladder(_PB * _PF) == (128, 256, 384, 512)
+    batches = _plan_batches(case)
+    stacked = jax.tree_util.tree_map(
+        lambda *xs: np.stack(xs) if xs[0] is not None else None, *batches,
+        is_leaf=lambda x: x is None)
+
+    def trainer(cls):
+        layer = embed.Embedding(_PROWS, _PDIM, name="emb", combiner=combiner)
+        return cls(embed.EmbeddingModel(_BagTower(), [layer]),
+                   embed.Adagrad(learning_rate=0.1), seed=2)
+
+    tr = trainer(Trainer)
+    assert "emb" in tr._packed_layouts(tr.init(batches[0]))
+    text = tr.jit_train_many().lower(tr.init(batches[0]), stacked).as_text()
+    # the pull's conditional and the apply's, or neither
+    assert text.count("stablehlo.case") == (2 if gate == 0 else 0)
+    planned, m_planned = tr.jit_train_many()(tr.init(batches[0]), stacked)
+    assert int(m_planned["apply_full_steps"]["emb"]) == \
+        (_PK if gate == 0 and valid > 384 else 0)
+
+    stepped, losses = tr.init(batches[0]), []
+    step = tr.jit_train_step()
+    for b in batches:
+        stepped, m = step(stepped, b)
+        losses.append(np.asarray(m["loss"]))
+    old = trainer(_PerPositionTrainer)
+    plan_less, m_plan_less = old.jit_train_many()(old.init(batches[0]), stacked)
+
+    for other, their_losses in ((stepped, np.stack(losses)),
+                                (plan_less, np.asarray(m_plan_less["loss"]))):
+        np.testing.assert_array_equal(np.asarray(m_planned["loss"]),
+                                      their_losses)
+        for a, b in zip(jax.tree_util.tree_leaves(planned.tables),
+                        jax.tree_util.tree_leaves(other.tables)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # a table that moved: the comparison is not of two untouched states
+    assert not np.array_equal(np.asarray(planned.tables["emb"].weights),
+                              np.asarray(tr.init(batches[0]).tables["emb"].weights))
+
+
+def test_plan_and_apply_take_positions_to_leave_out_and_multiplicities():
+    """What the owner side of the exchange will call them with: a plan made
+    from which positions count (0 / 1), an apply that brings the
+    multiplicities: the table of the plan-less apply, bit for bit."""
+    from openembedding_tpu.ops.sparse import plan_packed_rows
+    rng = np.random.default_rng(5)
+    n = 512
+    opt = embed.Adagrad(learning_rate=0.1)
+    slots = opt.init_slots(_PROWS, _PDIM)
+    lay = packed_layout(_PDIM, slots)
+    packed = pack_table(jnp.asarray(rng.standard_normal((_PROWS, _PDIM)),
+                                    jnp.float32), slots, lay)
+    ids = jnp.asarray(rng.integers(-2, _PROWS // 4, n), jnp.int32)
+    pre = jnp.asarray(rng.integers(0, 4, n), jnp.int32)   # a quarter padding
+    g = jnp.asarray(rng.standard_normal((n, _PDIM)), jnp.float32)
+
+    def planned(p):
+        plan = plan_packed_rows(p, ids, (pre > 0).astype(jnp.int32))
+        return sparse_apply_packed_table(opt, p, lay, _PDIM, ids, g, pre,
+                                         plan=plan)
+    want, load = jax.jit(lambda p: sparse_apply_packed_table(
+        opt, p, lay, _PDIM, ids, g, pre))(packed)
+    got, got_load = jax.jit(planned)(packed)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(got_load["apply_fill"], load["apply_fill"])
+    assert not np.array_equal(np.asarray(got), np.asarray(packed))

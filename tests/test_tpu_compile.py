@@ -6,6 +6,10 @@ The apply's choice of a working size (`ops/sparse.py` "WHAT THE APPLY WORKS
 OVER") must not cost a table: PR 29's chip probe measured a plain `lax.switch`
 over the four rungs at +9.8 ms a step, a whole-table copy in every branch but
 the first and the last, which `_over_unique_prefix`'s `settle` barrier cures.
+Since PR 35 the one-chip scan gathers the step's unique packed rows once,
+under a conditional of its own before the pull ("ONE DEDUP AND ONE TABLE
+GATHER A STEP"): neither conditional may copy the table, and the table is
+gathered from in the pull's branches alone, never once a position.
 
 The client's side of the exchange builds and reads its buckets by S block
 copies (`parallel/sharded.py` "WHAT THE CLIENT SENDS"): the four-chip cell's
@@ -102,6 +106,55 @@ def test_apply_ladder_costs_no_table_copy_on_the_tpu(one_chip, rows, dim):
     copies = re.findall(rf"= {table}\S* (?:copy|copy-start)\(", text)
     assert not copies, f"{len(copies)} table-sized copies in the program"
     assert compiled.memory_analysis().temp_size_in_bytes < rows * 2 * dim * 4 // 8
+
+
+def _table_gathers(text, table):
+    """(rows gathered, the op's stage path) of every gather in the optimised
+    HLO whose OPERAND has the shape `table` (a regex)."""
+    found = []
+    for comp in text.split("\n\n"):
+        shapes = dict(re.findall(r"%(\S+) = (\S+?)[{ ]", comp))
+        for out, operand, line in re.findall(
+                r"= \w+\[(\d+)[^\n]*? gather\(%(\S+?),([^\n]*)", comp):
+            if re.fullmatch(table, shapes.get(operand, "").split("{")[0]):
+                found.append((int(out), re.search(r'op_name="([^"]*)"',
+                                                  line).group(1)))
+    return found
+
+
+def test_shared_plan_scan_gathers_the_table_once_a_step_and_copies_none(one_chip):
+    """`deepfm9.train_zipf`'s scan at its real sizes (2 steps): two
+    conditionals over the four rungs (the pull's gather, the apply's row math
+    and scatter), no table-sized copy in any of the 8 branches, the scatter in
+    place in each of the apply's, and the 2.7 GB table gathered from in the
+    pull's four branches alone (a step runs one): W slots each, n only on the
+    full-size rung, and nowhere once a position."""
+    from openembedding_tpu.model import Trainer
+    from openembedding_tpu.models import make_deepfm
+    rows, B, K = 1 << 25, 4096, 2
+    tr = Trainer(make_deepfm(vocabulary=rows, dim=9, hidden=(400, 400, 400),
+                             compute_dtype=jnp.bfloat16),
+                 embed.Adagrad(learning_rate=0.05))
+    sample = {"sparse": {"categorical": np.zeros((B, 26), np.int32)},
+              "dense": np.zeros((B, 13), np.float32),
+              "label": np.zeros((B,), np.float32)}
+    with jax.enable_x64(False):  # the cell's own setting
+        state = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(tr.init, sample))
+        stacked = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct((K,) + x.shape, x.dtype,
+                                           sharding=one_chip), sample)
+        text = tr.jit_train_many().lower(state, stacked).compile().as_text()
+    table = rf"f32\[{rows},20\]"
+    assert text.count(" conditional(") == 2
+    assert not re.findall(rf"= {table}\S* (?:copy|copy-start)\(", text)
+    assert len(re.findall(rf"= {table}(\S*) fusion\([^\n]*/scatter", text)) == 4
+    gathers = sorted(_table_gathers(text, table))
+    assert [g[0] for g in gathers] == list(apply_ladder(N)), gathers
+    assert all("sparse.pull/" in path and "sparse.apply" not in path
+               and ("sparse.full_size" in path) == (n == N)
+               for n, path in gathers), gathers
 
 
 def test_four_chip_scan_has_no_per_slot_op_over_s_x_capacity_rows(topo):

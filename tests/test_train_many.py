@@ -3,7 +3,9 @@
 import numpy as np
 import pytest
 
+import flax.linen as nn
 import jax
+import jax.numpy as jnp
 
 import openembedding_tpu as embed
 from openembedding_tpu.data import synthetic_criteo
@@ -253,3 +255,40 @@ def test_mesh_window_carries_the_apply_load_beside_owner_fill(
     tr.record_step_stats(ms)
     assert fresh_metrics.report()['sparse.apply_fill{table="categorical"}'] == \
         pytest.approx(float(vec.max()))
+
+
+def test_sparse_pulls_counts_each_table_once_a_trace(fresh_metrics):
+    """`sparse.pulls{path=}`, counted where the pull is traced: the packed
+    scan's array table shares one plan with its apply ("shared"), its hash
+    table probes per position, and so does every table of the un-packed
+    step."""
+    class Tower(nn.Module):
+        @nn.compact
+        def __call__(self, embedded, dense_inputs):
+            x = jnp.concatenate([embedded[k].reshape(embedded[k].shape[0], -1)
+                                 for k in sorted(embedded)], axis=-1)
+            return nn.Dense(1)(x)[:, 0]
+
+    model = embed.EmbeddingModel(Tower(), [
+        embed.Embedding(VOCAB, 8, name="rows"),
+        embed.Embedding(-1, 8, name="keys", capacity=512)])
+    tr = Trainer(model, embed.Adagrad(learning_rate=0.05), seed=1)
+    rng = np.random.default_rng(0)
+    batches = [{"sparse": {"rows": rng.integers(0, VOCAB, (16, 4)).astype(np.int32),
+                           "keys": rng.integers(0, 10_000, (16, 4)).astype(np.int64)},
+                "dense": None,
+                "label": rng.integers(0, 2, (16,)).astype(np.float32)}
+               for _ in range(2)]
+    stacked = jax.tree_util.tree_map(
+        lambda *xs: np.stack(xs) if xs[0] is not None else None, *batches,
+        is_leaf=lambda x: x is None)
+    state = tr.init(batches[0])
+    assert set(tr._packed_layouts(state)) == {"rows", "keys"}
+    shared, each = 'sparse.pulls{path="shared"}', 'sparse.pulls{path="per_position"}'
+    assert shared not in fresh_metrics.report()     # `init` pulls nothing
+    tr.jit_train_many().lower(state, stacked)
+    assert (fresh_metrics.report()[shared], fresh_metrics.report()[each]) == (1, 1)
+    tr.jit_train_many().lower(state, stacked)       # a second trace counts again
+    assert (fresh_metrics.report()[shared], fresh_metrics.report()[each]) == (2, 2)
+    tr.jit_train_step().lower(state, batches[0])    # the split layout: no plan
+    assert (fresh_metrics.report()[shared], fresh_metrics.report()[each]) == (2, 4)
