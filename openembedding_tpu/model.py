@@ -20,6 +20,9 @@ The flax dense module is called as `module.apply({'params': p}, embedded, dense)
 from __future__ import annotations
 
 import dataclasses
+import threading
+import weakref
+from collections import deque
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -31,6 +34,7 @@ from .embedding import (Embedding, EmbeddingSpec, EmbeddingTableState,
                         apply_gradients, combine, init_table_state, lookup,
                         lookup_train)
 from .optimizers import Adagrad, SparseOptimizer
+from .utils import compile_cache as _compile_cache
 from .utils import metrics as _metrics
 from .utils import trace as _trace
 
@@ -245,6 +249,142 @@ def _observe_table_stats(vals) -> None:
     for stat, by_table in vals.items():
         for table, v in by_table.items():
             _metrics.observe_table_stat(table, stat, v)
+
+
+def _fold_window(module_stats, metrics) -> None:
+    """What `Trainer.record_window_stats` folds (its doc), given the module's
+    {stat: fold} and nothing else of the trainer: a pending window keeps this
+    function and never the trainer, so a trainer dropped with windows in
+    flight frees its programs."""
+    vals = _window_values(metrics, ("module",) + _metrics.APPLY_STATS)
+    for name, v in vals.pop("module", {}).items():
+        _metrics.observe(name, float(v), module_stats[name])
+    _observe_table_stats(vals)
+
+
+def _leaves_ready(window) -> bool:
+    """Every device array of a window's metrics is there to be read (host
+    values always are; a DELETED array has nothing to wait for, and asking it
+    `is_ready()` kills the process: the fold will say it is gone)."""
+    for leaf in jax.tree_util.tree_leaves(window):
+        if isinstance(leaf, jax.Array) and leaf.is_deleted():
+            continue
+        if hasattr(leaf, "is_ready") and not leaf.is_ready():
+            return False
+    return True
+
+
+def _first_leaf(window):
+    """What a window is known by: the first array of its metrics (None for a
+    window that holds none)."""
+    return next(iter(jax.tree_util.tree_leaves(window)), None)
+
+
+class _PendingWindows:
+    """The windows `jit_train_many`'s dispatch object has sent whose counters
+    are not in the registry yet, oldest first; the process has ONE.
+
+    A dispatch never waits on the device for a counter: `sent` keeps the new
+    window's metrics (a few device scalars) and folds AT MOST ONE older
+    window, and only if its arrays are ready. Whatever is still pending is
+    folded, blocking, when somebody READS the registry (`drain`, which
+    `metrics.report()` and `prometheus_text()` run first), and the oldest
+    entries when the queue would outgrow `LIMIT` (a loop that never fences
+    is held to `LIMIT` windows in flight by that). A window is folded ONCE
+    whoever asks: `fold_now` (`record_window_stats`) takes a pending window
+    out of the queue and passes over one that has been folded already, known
+    by a weak reference to its first array."""
+
+    LIMIT = 8
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._pending: deque = deque()  # (fold, metrics); guarded-by: _lock
+        self._folded: Dict[int, Any] = {}  # id(first leaf) -> weakref to it
+
+    def __len__(self) -> int:
+        return len(self._pending)
+
+    def sent(self, fold, window) -> None:
+        with self._lock:
+            self._pending.append((fold, window))
+            due = []
+            while len(self._pending) > self.LIMIT:
+                due.append(self._pending.popleft())
+            if not due and len(self._pending) > 1 \
+                    and _leaves_ready(self._pending[0][1]):
+                due.append(self._pending.popleft())
+        for entry in due:
+            self._fold(*entry)
+
+    def fold_now(self, fold, window) -> None:
+        with self._lock:
+            for i, (_, pending) in enumerate(self._pending):
+                if pending is window:
+                    del self._pending[i]
+                    break
+            else:
+                mark = _first_leaf(window)
+                seen = self._folded.get(id(mark))
+                if seen is not None and seen() is mark:
+                    return
+        self._fold(fold, window)
+
+    def drain(self) -> None:
+        with self._lock:
+            due = list(self._pending)
+            self._pending.clear()
+        for entry in due:
+            self._fold(*entry)
+
+    def _fold(self, fold, window) -> None:
+        try:
+            fold(window)
+        except Exception as e:  # a counter never costs a dispatch or a read:
+            # (arrays deleted under it, a backend gone) the window goes uncounted
+            _trace.event("trainer", "window_fold_error",
+                         error=f"{type(e).__name__}: {e}")
+            return
+        _metrics.observe("trainer.windows", 1, "sum",
+                         labels={"fn": "train_many"})
+        mark = _first_leaf(window)
+        try:
+            self._folded[id(mark)] = weakref.ref(
+                mark, lambda _, key=id(mark): self._folded.pop(key, None))
+        except TypeError:  # no leaf, or a plain Python number: not remembered
+            pass
+
+
+_WINDOWS = _PendingWindows()
+_metrics.before_read(_WINDOWS.drain)
+
+
+class TrainManyDispatch:
+    """What `jit_train_many` returns: the jitted K-step scan, called the same
+    way, with the program's own account of each call. Per call: the jitted
+    call runs inside `trace.span("trainer", "dispatch")` (the annotation
+    `oetpu.trainer.dispatch` on the profiler's clock, so an idle gap of the
+    device in ANY loop over this entry point has the program's name on it;
+    the histogram `trainer.dispatch.ms`) and inside
+    `compile_cache.entry("train_many")` (whatever it traces, compiles or
+    loads is `compile.*{fn="train_many"}`); the returned window's metrics go
+    to `_PendingWindows.sent`. Nothing else: `.lower` and every other
+    attribute are the jitted function's."""
+
+    def __init__(self, fn, fold):
+        self._fn = fn
+        self._fold = fold
+
+    def __call__(self, *args, **kwargs):
+        with _compile_cache.entry("train_many"), \
+                _trace.span("trainer", "dispatch"):
+            out = self._fn(*args, **kwargs)
+        if not isinstance(out[1]["loss"], jax.core.Tracer):
+            _WINDOWS.sent(self._fold, out[1])  # (a trace of it sends nothing)
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
 
 
 class Trainer:
@@ -528,6 +668,13 @@ class Trainer:
     # -- init ---------------------------------------------------------------
 
     def init(self, sample_batch: Dict[str, Any]) -> TrainState:
+        """The first TrainState, inside `trace.span("trainer", "init")`
+        (series `trainer.init.ms`); what it traces and compiles is
+        `compile.*{fn="init"}` (`utils/compile_cache.py`)."""
+        with _compile_cache.entry("init"), _trace.span("trainer", "init"):
+            return self._init_state(sample_batch)
+
+    def _init_state(self, sample_batch: Dict[str, Any]) -> TrainState:
         # the one warning jit can't emit: int64 ids under x64-off silently
         # truncate at the device boundary (hi lane lost) — the pair layout
         # (`ops/id64.py`, `synthetic_criteo(ids_dtype='pair')`) is the fix
@@ -1155,20 +1302,29 @@ class Trainer:
             out["module"] = module
         return out
 
+    def _window_fold(self):
+        """-> fold(metrics): a `train_many` window's counters into series,
+        holding nothing of this trainer (`_fold_window`)."""
+        return partial(_fold_window, self._module_stats)
+
     def record_window_stats(self, metrics) -> None:
-        """Fold a `train_many` window's counters into series: each table's
-        `sparse.apply_fill{table=}` (gauge) and
-        `sparse.apply_full_steps{table=}` (counter), and the module's own
-        named as the module names them (`moe.pairs_here`, ...). ONE
-        device_get per window; a no-op on a window that holds none."""
-        vals = _window_values(metrics, ("module",) + _metrics.APPLY_STATS)
-        for name, v in vals.pop("module", {}).items():
-            _metrics.observe(name, float(v), self._module_stats[name])
-        _observe_table_stats(vals)
+        """Fold a `train_many` window's counters into series NOW: each
+        table's `sparse.apply_fill{table=}` (gauge) and
+        `sparse.apply_full_steps{table=}` (counter), the module's own named
+        as the module names them (`moe.pairs_here`, ...), and
+        `trainer.windows{fn="train_many"}` (windows folded). ONE device_get,
+        which waits for the window. Nobody has to call it: a window sent
+        through `jit_train_many()` is folded by the entry point
+        (`_PendingWindows`), and ONCE whoever asks, so calling it on such a
+        window before or after counts nothing twice."""
+        _WINDOWS.fold_now(self._window_fold(), metrics)
 
     def jit_train_many(self):
-        """Scan-fused multi-step driver (state DONATED, like jit_train_step)."""
-        return jax.jit(self.train_many, donate_argnums=(0,))
+        """Scan-fused multi-step driver (state DONATED, like jit_train_step),
+        as the program's dispatch object (`TrainManyDispatch`)."""
+        return TrainManyDispatch(
+            jax.jit(self.train_many, donate_argnums=(0,)),
+            self._window_fold())
 
     def _many_fn(self, batches, state):
         """Cached jitted train_many (MeshTrainer overrides: its jit_train_many

@@ -23,9 +23,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..embedding import EmbeddingSpec, EmbeddingTableState, HotRows, MigRows
-from ..model import (EmbeddingModel, TrainState, Trainer, _fold_table_stats,
-                     _observe_table_stats, _table_stats, _window_values,
-                     init_dense_slots)
+from ..model import (EmbeddingModel, TrainManyDispatch, TrainState, Trainer,
+                     _fold_table_stats, _observe_table_stats, _table_stats,
+                     _window_values, init_dense_slots)
 from ..optimizers import SparseOptimizer
 from ..utils import metrics as _metrics
 from ..utils import trace as _trace
@@ -33,6 +33,23 @@ from .mesh import DATA_AXIS, make_mesh
 from .sharded import (build_hot_identity, build_mig_identity, hot_gather,
                       hot_writeback, mig_gather, mig_writeback,
                       sharded_lookup)
+
+
+_WINDOW_TABLE_STATS = _metrics.APPLY_STATS + _metrics.OWNER_STATS
+
+
+def _fold_mesh_window(metrics) -> None:
+    """`MeshTrainer._window_fold` (its doc); a function of the window alone,
+    so a pending window holds no trainer."""
+    vals = _window_values(
+        metrics, _WINDOW_TABLE_STATS + ("conflict", "conflict_overflow"))
+    for name, v in vals.pop("conflict", {}).items():
+        _metrics.observe("exchange.conflict_rows", float(v), "gauge",
+                         labels={"table": name})
+    if "conflict_overflow" in vals:
+        _metrics.observe("exchange.conflict_overflow",
+                         float(vals.pop("conflict_overflow")), "gauge")
+    _observe_table_stats(vals)
 
 
 class MeshTrainer(Trainer):
@@ -780,10 +797,10 @@ class MeshTrainer(Trainer):
 
     # -- init ----------------------------------------------------------------
 
-    def init(self, sample_batch) -> TrainState:
+    def _init_state(self, sample_batch) -> TrainState:
         """Global TrainState: dense params replicated; tables created directly sharded
         (jit + out_shardings — a full table never materializes on one device)."""
-        base = super().init(sample_batch)
+        base = super()._init_state(sample_batch)
         rep = NamedSharding(self.mesh, P())
         return self.dense_to_sharded(TrainState(
             step=jax.device_put(base.step, rep),
@@ -1552,15 +1569,13 @@ class MeshTrainer(Trainer):
             return super().train_many(state, batches)
         return self._train_many_pipelined(state, batches)
 
-    _WINDOW_TABLE_STATS = _metrics.APPLY_STATS + _metrics.OWNER_STATS
-
     def _scan_stats(self, stats):
         """A step's apply load (`Trainer._scan_stats`) and, where the owner
         compacts what it receives, its `owner_fill` / `owner_full_steps`
         (`sharded.exchange_load_stats`); each per-shard vector folded over
         the shards: the fullest shard's fill, and 1 where any shard ran full
         size."""
-        return _table_stats(stats, self._WINDOW_TABLE_STATS)
+        return _table_stats(stats, _WINDOW_TABLE_STATS)
 
     def _window_stats(self, kept):
         """The stacked `_scan_stats` folded over a window's steps: under
@@ -1568,7 +1583,7 @@ class MeshTrainer(Trainer):
         "apply_full_steps" / "owner_full_steps" {table: steps that ran full
         size}; the owner's two are empty where no table's receive side is
         compacted."""
-        return _fold_table_stats(kept, self._WINDOW_TABLE_STATS)
+        return _fold_table_stats(kept, _WINDOW_TABLE_STATS)
 
     def _train_many_pipelined(self, state: TrainState, batches):
         """Prologue / steady-state / epilogue around `lax.scan`:
@@ -1736,28 +1751,17 @@ class MeshTrainer(Trainer):
                        "conflict": conflict, "conflict_overflow": coflow,
                        **self._window_stats(kept)}
 
-    def record_window_stats(self, metrics) -> None:
-        """Fold a train_many window's host-visible counters into series:
+    def _window_fold(self):
+        """What `record_window_stats` folds of a window (`Trainer`'s doc):
         each table's `sparse.apply_fill{table=}` /
-        `sparse.apply_full_steps{table=}` (`Trainer.record_window_stats`);
-        where the owner compacts what it receives,
-        `exchange.owner_fill{table=}` (gauge: the window's fullest step on
-        its fullest shard) and `exchange.owner_full_steps{table=}` (counter:
-        steps that took the full-size path); pipelined windows publish
-        `exchange.conflict_rows{table=}` plus the pcap-dropped
+        `sparse.apply_full_steps{table=}`; where the owner compacts what it
+        receives, `exchange.owner_fill{table=}` (gauge: the window's fullest
+        step on its fullest shard) and `exchange.owner_full_steps{table=}`
+        (counter: steps that took the full-size path); pipelined windows
+        publish `exchange.conflict_rows{table=}` plus the pcap-dropped
         `exchange.conflict_overflow`. ONE device_get per window (the
-        window-level sibling of `metrics.record_step_stats`); a no-op on a
-        window that holds none of them."""
-        vals = _window_values(
-            metrics, self._WINDOW_TABLE_STATS
-            + ("conflict", "conflict_overflow"))
-        for name, v in vals.pop("conflict", {}).items():
-            _metrics.observe("exchange.conflict_rows", float(v), "gauge",
-                             labels={"table": name})
-        if "conflict_overflow" in vals:
-            _metrics.observe("exchange.conflict_overflow",
-                             float(vals.pop("conflict_overflow")), "gauge")
-        _observe_table_stats(vals)
+        window-level sibling of `metrics.record_step_stats`)."""
+        return _fold_mesh_window
 
     def _observe_wire_cost(self, ps_specs, batch, *, pipelined=False):
         """Publish the static wire-cost model of the traced step (runs once
@@ -1790,8 +1794,6 @@ class MeshTrainer(Trainer):
             # (Parallax: sparse behavior is dominated by it) reads straight
             # off /metrics as oetpu_exchange_pull_positions{table=...}
             _metrics.observe("exchange.pull_positions", float(n), "gauge",
-                             labels={"table": name})
-            _metrics.observe("exchange.bucket_capacity", float(cap), "gauge",
                              labels={"table": name})
             # row dim per table: lets offline consumers (tools/skew_report.py
             # --recommend) price hot/migrated rows from one /metrics scrape
@@ -1932,7 +1934,8 @@ class MeshTrainer(Trainer):
             out_specs=(state_spec, metrics_spec),
             check_vma=False,
         )
-        self._train_many_fn = jax.jit(many, donate_argnums=(0,))
+        self._train_many_fn = TrainManyDispatch(
+            jax.jit(many, donate_argnums=(0,)), self._window_fold())
         return self._train_many_fn
 
     def _many_fn(self, batches, state):
@@ -1945,8 +1948,8 @@ class MeshTrainer(Trainer):
         `next()` lands in `trainer.input_wait_ms` (via `input_timed`) and
         each window's wall time in the `trainer.window_ms` histogram — the
         denominator `data.ingest.input_wait_share` folds the waits against.
-        The first window compiles the driver (`jit_train_many`); window
-        stats fold through `record_window_stats` (one device_get each).
+        The first window compiles the driver (`jit_train_many`), whose
+        dispatch object folds every window's counters into series.
 
         `block=True` brackets every window with `block_until_ready` — the
         measured-soak mode, where window_ms is honest wall time per window.
@@ -1971,7 +1974,6 @@ class MeshTrainer(Trainer):
                     jax.block_until_ready(state)
             _metrics.observe("trainer.window_ms",
                              (_time.perf_counter() - t0) * 1e3, "hist")
-            self.record_window_stats(m)
             last_loss = m.get("loss") if isinstance(m, dict) else None
             n += 1
         if last_loss is not None:

@@ -30,6 +30,11 @@ from it carries the names of whichever build wrote the entry — none at all if
 that build predates the scopes. `chip_smoke.py --profile` compiles its text
 past the cache for that reason (`fresh_hlo_text`).
 
+An instruction with no scope in its own metadata (the compiler made it, or
+it sits in a loop body or a branch whose ops lost their `op_name`) is named
+by what consumes it and by what calls its computation: `scope_map`'s two
+rules.
+
 A scope is a `<layer>.<stage>` token of the vocabulary's layers (`LAYERS`) found in
 the path, through `jvp(...)`/`transpose(...)` wrappers. Scopes nest
 (`exchange.owner_apply/sparse.apply`): an op is keyed by its whole chain
@@ -68,6 +73,10 @@ UNSCOPED = ""
 _SCOPE = re.compile(r"(?<![\w.])((?:%s)\.[a-z][a-z0-9_]*)(?![\w.])"
                     % "|".join(LAYERS))
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+_CALLED = re.compile(
+    r"\b(?:body|condition|to_apply|calls|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|\bbranch_computations=\{([^}]*)\}")
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 _OPERAND = re.compile(r"%([\w.\-]+)")
 _OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
@@ -92,42 +101,117 @@ def scope_map(compiled_or_hlo_text) -> Dict[str, str]:
     `op_name`, joined by "/"; "" for an instruction no scope covers. The
     innermost scope is `path.rsplit("/", 1)[-1]`.
 
-    One rule beyond the metadata: an instruction the compiler made itself (it
-    carries no `op_name` at all: a layout `copy`, the two halves of an async
-    copy or slice, a hoisted convert or mask) or a `copy` with no scope of
-    its own takes the scope of the instructions that consume it, where they
-    agree, through chains of such instructions — the table-sized copies in
-    front of `sparse.pack` and `sparse.unpack` exist because of those stages."""
+    Two rules beyond the metadata. An instruction that HAS a scope keeps it
+    under both.
+
+    The consumers. An instruction the compiler made itself (it carries no
+    `op_name` at all: a layout `copy`, the two halves of an async copy or
+    slice, a hoisted convert or mask, the 26 `dynamic-update-slice` fusions
+    that stack a routed layer's blocks) or a `copy` with no scope of its own
+    takes the scope of the instructions that consume it, where they agree,
+    through chains of such instructions of any length: the table-sized copies
+    in front of `sparse.pack` and `sparse.unpack` exist because of those
+    stages. A `tuple` the compiler made, while it has no scope, is no
+    consumer: it carries a value out of a computation, or into a loop, and
+    works on nothing.
+
+    The call graph. The text is a list of computations (`%name (...) -> ...
+    {` ... `}`), and an instruction may call some (`body=`, `condition=`,
+    `to_apply=`, `calls=`, `branch_computations={...}`, `true_computation=`,
+    `false_computation=`). An instruction with no scope of its own takes the
+    scope path of the instruction that calls its computation, through nested
+    calls: the TPU compiler runs a batched product over gathered weights as a
+    `while` over slices whose body's ops carry no `op_name`, and the `while`
+    carries the product's. A `while`, `conditional` or `call` the compiler
+    made itself first takes the scope its consumers AND its producers agree
+    on. A computation called from two scopes gives its instructions none.
+
+    The consumers come first, because what consumes an instruction says more
+    of it than where it stands (a conditional of the routed layer is called
+    under `dense.tower` and nothing narrower; the blocks stacked inside it
+    are consumed by `moe.experts`), and once more after the call graph, for
+    the chains that end in an instruction the call graph named."""
     text = compiled_or_hlo_text
     if not isinstance(text, str):
         text = text.as_text()
     out: Dict[str, str] = {}
     users: Dict[str, List[str]] = {}
-    made = []
+    producers: Dict[str, List[str]] = {}  # of the containers alone
+    inside: Dict[str, List[str]] = {}   # computation -> its instructions
+    callers: Dict[str, List[str]] = {}  # computation -> who calls it
+    home: Dict[str, str] = {}           # instruction -> its computation
+    made, made_callers, carriers = [], [], set()
+    computation = ""
     for line in text.splitlines():
         m = _INSTR.match(line)
         if m is None:
+            header = _COMPUTATION.match(line)
+            if header is not None:
+                computation = header.group(1)
             continue
         name = m.group(1)
         meta = _OP_NAME.search(line)
         out[name] = scope_path(meta.group(1)) if meta else UNSCOPED
+        home[name] = computation
+        inside.setdefault(computation, []).append(name)
         body = line[m.end():].split(", metadata=", 1)[0]
-        for operand in _OPERAND.findall(body):
+        called = [c.strip().lstrip("%")
+                  for one, several in _CALLED.findall(body)
+                  for c in ([one] if one else several.split(","))]
+        for c in called:
+            callers.setdefault(c, []).append(name)
+        operands = [o for o in _OPERAND.findall(body) if o not in called]
+        for operand in operands:
             users.setdefault(operand, []).append(name)
-        if out[name] == UNSCOPED and (meta is None or opcode_of(line) == "copy"):
-            made.append(name)
-    for _ in range(4):  # a chain is copy-start -> copy-done -> bitcast -> user
-        left = []
-        for name in made:
-            paths = {out[u] for u in users.get(name, [])}
-            if len(paths) == 1 and UNSCOPED not in paths:
-                out[name] = paths.pop()
+        opcode = opcode_of(line)
+        if out[name] == UNSCOPED and (meta is None or opcode == "copy"):
+            if opcode in CONTAINERS:
+                made_callers.append(name)
+                producers[name] = operands
             else:
-                left.append(name)
-        if len(left) == len(made):
-            break
-        made = left
+                made.append(name)
+            if opcode == "tuple":
+                carriers.add(name)
+
+    def agreed(names) -> str:
+        paths = {out.get(n, UNSCOPED) for n in names
+                 if not (n in carriers and out[n] == UNSCOPED)}
+        return paths.pop() if len(paths) == 1 else UNSCOPED
+
+    def consumers():
+        left, before = [n for n in made if out[n] == UNSCOPED], None
+        while len(left) != before:  # until a pass names nothing more
+            for name in left:
+                out[name] = agreed(users.get(name, []))
+            left, before = [n for n in left if out[n] == UNSCOPED], len(left)
+
+    consumers()
+    for name in made_callers:
+        out[name] = agreed(users.get(name, []) + producers[name])
+    _inherit(out, inside, callers, home)
+    consumers()
     return out
+
+
+def _inherit(out: Dict[str, str], inside, callers, home) -> None:
+    """`scope_map`'s call-graph rule: every scopeless instruction of a called
+    computation takes the path its computation is called under."""
+    under: Dict[str, str] = {}
+
+    def path_of(computation: str) -> str:
+        if computation not in under:
+            under[computation] = UNSCOPED  # (HLO has no recursion: a guard)
+            paths = {out[c] or path_of(home[c])
+                     for c in callers.get(computation, [])}
+            under[computation] = paths.pop() if len(paths) == 1 else UNSCOPED
+        return under[computation]
+
+    for computation, names in inside.items():
+        path = path_of(computation)
+        if path:
+            for name in names:
+                if out[name] == UNSCOPED:
+                    out[name] = path
 
 
 def opcode_of(instruction: str) -> str:

@@ -189,6 +189,18 @@ def observe(name: str, value: float, kind: str = "sum",
     Accumulator.get(name, kind, labels=labels).observe(value)
 
 
+_BEFORE_READ: List[Callable[[], None]] = []
+
+
+def before_read(hook: Callable[[], None]) -> None:
+    """Run `hook()` at the start of every `report()` and `prometheus_text()`:
+    for values the program holds back until somebody reads them (the train
+    entry point's windows still on the device, `model._PendingWindows`: a
+    dispatch never waits for a counter, a read does). Registered once by the
+    module that owns the values; hooks observe, they do not read."""
+    _BEFORE_READ.append(hook)
+
+
 @contextmanager
 def vtimer(group: str, name: str):
     """Scope timer (reference VTIMER semantics: `VTIMER(1, group, name, ms)`
@@ -210,7 +222,6 @@ def observe_exchange_cost(cost: Dict[str, "object"]) -> None:
             float(cost.get("collectives_per_step", 0)), "gauge")
     observe("exchange.wire_bytes_per_step",
             float(cost.get("bytes_per_step", 0)), "gauge")
-    observe("exchange.dim_groups", float(cost.get("dim_groups", 0)), "gauge")
 
 
 def observe_sync_cost(cost: Dict[str, "object"]) -> None:
@@ -465,6 +476,8 @@ def report(reset: bool = False) -> Dict[str, float]:
     gauges (one-shot values like `exchange.*` wire costs would vanish from
     /metrics after the first periodic report) and histograms (Prometheus
     histogram series are cumulative by contract)."""
+    for hook in _BEFORE_READ:
+        hook()
     with _LOCK:
         accs = list(_REGISTRY.values())
     out: Dict[str, float] = {}
@@ -541,6 +554,8 @@ def prometheus_text() -> str:
     escaped; avg/max kinds emit a single well-typed gauge series; hist kinds
     emit cumulative `_bucket{le=...}` (empty interior buckets elided — le
     boundaries stay monotone), `_sum` and `_count` series."""
+    for hook in _BEFORE_READ:
+        hook()
     lines: List[str] = []
     with _LOCK:
         accs = sorted(_REGISTRY.values(), key=lambda a: (a.name, a.key))

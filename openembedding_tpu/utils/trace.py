@@ -49,9 +49,16 @@ Two clocks, two tools:
   per compile, so a clock read there times tracing, never a step. `scope` is
   `jax.named_scope` and nothing else: HLO metadata, no instruction, no flag,
   seen in a profile and never on /metrics. `scope_map` reads the stage of
-  every instruction off compiled HLO text; `device_report`
+  every instruction off compiled HLO text (through the call graph: a loop
+  body's ops take the scope of the `while` that calls them); `device_report`
   (`utils/devtrace.py`, `tools/trace_report.py --xplane`) reduces a device
   trace to time per stage.
+
+The train entry points' own host spans: `trainer.init` (`Trainer.init`) and
+`trainer.dispatch` (every call of `jit_train_many()`'s dispatch object,
+`model.TrainManyDispatch`); what they trace and compile is accounted by
+`utils/compile_cache.py` (`compile.*{fn=}`, and a `compile.<stage>` event
+here).
 
 The stage vocabulary (`<layer>.<stage>`; README "Observability"):
 `sparse.{dedup,pull,reduce,apply,pack,unpack}`, `exchange.{route,wire,

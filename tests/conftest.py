@@ -30,3 +30,17 @@ def pytest_configure(config):
     # counterpart elsewhere opt out of that window with this marker.
     config.addinivalue_line(
         "markers", "slow: excluded from the tier-1 timed window")
+
+
+import pytest
+
+
+@pytest.fixture(autouse=True)
+def _windows_fold_in_their_own_test():
+    """`jit_train_many`'s dispatch object leaves a window's counters pending
+    until they are ready or somebody reads the registry (`model.
+    _PendingWindows`); read it at every test's end, so that no window of one
+    test is folded into the series a later test counts."""
+    yield
+    from openembedding_tpu.utils import metrics
+    metrics.report()

@@ -67,8 +67,9 @@ def _batches(S, *, vocab=VOCAB, seed=0, pool=None, stride=1):
 
 def _train(S, stacked, *, full_size=False, many=True, dim=9, vocab=VOCAB,
            hash_capacity=0, hot=0, mig=0, **kw):
-    """K steps of a tiny DeepFM on S devices -> (trainer, state, metrics) on
-    the host. `full_size` nulls the mechanism for the run's traces."""
+    """K steps of a tiny DeepFM on S devices -> (trainer, state on the host,
+    metrics as the call returned them: `record_window_stats` knows a window
+    by its arrays). `full_size` nulls the mechanism for the run's traces."""
     one = jax.tree_util.tree_map(lambda x: x[0], stacked)
     orig = sharded._owner_view
     if full_size:
@@ -98,7 +99,7 @@ def _train(S, stacked, *, full_size=False, many=True, dim=9, vocab=VOCAB,
                 state, m = step(state, jax.tree_util.tree_map(
                     lambda x: x[k], stacked))
                 tr.record_step_stats(m)
-        return tr, jax.device_get(state), jax.device_get(m)
+        return tr, jax.device_get(state), m
     finally:
         sharded._owner_view = orig
 
@@ -227,6 +228,44 @@ def test_crowded_owner_takes_the_full_size_path(S, many):
     assert moved.all()
 
 
+@pytest.mark.parametrize("S", [4])
+def test_mesh_entry_point_publishes_the_owner_counters_unasked(S):
+    """Two windows of a crowded owner through `MeshTrainer.jit_train_many`
+    and NO call of `record_window_stats`: the registry holds what the
+    windows' own metrics say, and asking afterwards adds nothing."""
+    vocab = 1 << 16
+    stacked = _batches(S, vocab=vocab, stride=S)
+    one = jax.tree_util.tree_map(lambda x: x[0], stacked)
+    tr = MeshTrainer(make_deepfm(vocabulary=vocab, dim=9, hidden=(8,)),
+                     embed.Adagrad(learning_rate=0.05), seed=1,
+                     mesh=make_mesh(jax.devices()[:S]))
+    state = tr.init(one)
+    many = tr.jit_train_many(stacked, state)
+    assert tr.jit_train_many() is many  # ONE dispatch object a trainer
+    state, m1 = many(state, stacked)
+    state, m2 = many(state, stacked)
+    want = {
+        'exchange.owner_full_steps{table="categorical"}': 2 * K,
+        'exchange.owner_fill{table="categorical"}':
+            float(m2["owner_fill"]["categorical"]),
+        'sparse.apply_fill{table="categorical"}':
+            float(m2["apply_fill"]["categorical"]),
+        'sparse.apply_full_steps{table="categorical"}': float(
+            int(m1["apply_full_steps"]["categorical"])
+            + int(m2["apply_full_steps"]["categorical"])),
+        'trainer.windows{fn="train_many"}': 2}
+    rep = metrics.report()
+    assert {k: rep[k] for k in want} == want
+    assert rep["trainer.dispatch.ms"] > 0  # the span around each call
+    tr.record_window_stats(m1)
+    tr.record_window_stats(m2)
+    rep = metrics.report()
+    assert {k: rep[k] for k in want} == want
+    # the text of the scan comes through the object
+    assert "exchange.owner_apply" in many.lower(state, stacked).as_text(
+        debug_info=True)
+
+
 # -- (d) no compaction where the receive side is no larger than W -------------
 
 
@@ -342,6 +381,13 @@ def test_owner_fill_equals_the_host_count(S):
         fills.append(per_owner.max() / N)
     np.testing.assert_allclose(float(m["owner_fill"]["categorical"]),
                                max(fills), rtol=1e-6)
+    # nobody folded the window: the entry point did, at the latest when the
+    # registry is read
+    rep = metrics.report()
+    assert rep['exchange.owner_fill{table="categorical"}'] == \
+        pytest.approx(max(fills), rel=1e-6)
+    assert rep['exchange.owner_full_steps{table="categorical"}'] == 0
+    assert rep['trainer.windows{fn="train_many"}'] == 1
     # the step loop serves the last step's reading
     _, _, ms = _train(S, stacked, many=False)
     vec = np.asarray(ms["stats"]["categorical/owner_fill"])

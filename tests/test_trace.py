@@ -461,6 +461,143 @@ def test_scope_map_reads_nested_and_wrapped_names():
         "dot.2": "dense.tower", "copy.1": "", "add.3": ""}
 
 
+# A program in the TPU compiler's text form, cut to what `scope_map`'s two
+# rules read (names and forms from `solar-open2.train_8k`'s scan, my chip
+# run, PR 36; layouts and backend_config left out): computations, who calls
+# them, and `op_name`s.
+_CALL_GRAPH_TEXT = """HloModule jit_train_many, entry_computation_layout={()->f32[4]}
+
+%fused_computation.1 (p.1: f32[4]) -> f32[4] {
+  %p.1 = f32[4] parameter(0)
+  ROOT %negate.1 = f32[4] negate(%p.1)
+}
+
+%wide.while_body.778.sunk (wide.param.1: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %wide.param.1 = (s32[], f32[4]) parameter(0)
+  %get-tuple-element.1 = s32[] get-tuple-element(%wide.param.1), index=0
+  %get-tuple-element.2 = f32[4] get-tuple-element(%wide.param.1), index=1
+  %fusion.4767 = f32[4] fusion(%get-tuple-element.2), kind=kLoop, calls=%fused_computation.1
+  %add.12958 = s32[] add(%get-tuple-element.1, %get-tuple-element.1)
+  ROOT %tuple.12478 = (s32[], f32[4]) tuple(%add.12958, %fusion.4767)
+}
+
+%wide.while_cond.778 (wide.param.2: (s32[], f32[4])) -> pred[] {
+  %wide.param.2 = (s32[], f32[4]) parameter(0)
+  %get-tuple-element.3 = s32[] get-tuple-element(%wide.param.2), index=0
+  ROOT %compare.1 = pred[] compare(%get-tuple-element.3, %get-tuple-element.3), direction=LT
+}
+
+%region_compact.1 (b.1: f32[4]) -> f32[4] {
+  %b.1 = f32[4] parameter(0)
+  %fusion.20 = f32[4] fusion(%b.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_many)/while/body/exchange.owner_apply/cond/branch_0_fun/sparse.apply/scatter-add" stack_frame_id=7}
+  ROOT %broadcast.21 = f32[4] broadcast(%fusion.20), dimensions={0}, metadata={op_name="jit(train_many)/while/body/closed_call"}
+}
+
+%region_full.2 (b.2: f32[4]) -> f32[4] {
+  %b.2 = f32[4] parameter(0)
+  ROOT %fusion.30 = f32[4] fusion(%b.2), kind=kLoop, calls=%fused_computation.1
+}
+
+%region_sum.3 (x.3: f32[], y.3: f32[]) -> f32[] {
+  %x.3 = f32[] parameter(0)
+  %y.3 = f32[] parameter(1)
+  ROOT %add.3 = f32[] add(%x.3, %y.3)
+}
+
+%body.9 (param.9: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %param.9 = (s32[], f32[4]) parameter(0)
+  %get-tuple-element.91 = f32[4] get-tuple-element(%param.9), index=1
+  %fusion.92 = f32[4] fusion(%get-tuple-element.91), kind=kLoop, calls=%fused_computation.1
+  %get-tuple-element.93 = s32[] get-tuple-element(%param.9), index=0
+  ROOT %tuple.94 = (s32[], f32[4]) tuple(%get-tuple-element.93, %fusion.92)
+}
+
+%body.8 (param.8: f32[4]) -> f32[4] {
+  %param.8 = f32[4] parameter(0)
+  ROOT %fusion.82 = f32[4] fusion(%param.8), kind=kLoop, calls=%fused_computation.1
+}
+
+ENTRY %main.655 (state.1: f32[4], pred.1: pred[]) -> f32[4] {
+  %state.1 = f32[4] parameter(0)
+  %pred.1 = pred[] parameter(1)
+  %constant.1 = s32[] constant(0)
+  %tuple.15410 = (s32[], f32[4]) tuple(%constant.1, %state.1)
+  %while.1789 = (s32[], f32[4]) while(%tuple.15410), condition=%wide.while_cond.778, body=%wide.while_body.778.sunk, metadata={op_name="jit(train_many)/while/body/closed_call/dense.tower/transpose(jvp(SolarOpen2))/checkpoint/layers_2/moe/cond/branch_1_fun/moe.experts/scatter-add" stack_frame_id=274}
+  %get-tuple-element.10 = f32[4] get-tuple-element(%while.1789), index=1
+  %cond.3837 = f32[4] conditional(%pred.1, %get-tuple-element.10, %get-tuple-element.10), true_computation=%region_compact.1, false_computation=%region_full.2, metadata={op_name="jit(train_many)/while/body/exchange.owner_apply/cond"}
+  %constant.2 = f32[] constant(0)
+  %reduce.1 = f32[] reduce(%cond.3837, %constant.2), dimensions={0}, to_apply=%region_sum.3, metadata={op_name="jit(train_many)/while/body/dense.tower/moe.experts/reduce_sum"}
+  %reduce.2 = f32[] reduce(%cond.3837, %constant.2), dimensions={0}, to_apply=%region_sum.3, metadata={op_name="jit(train_many)/while/body/dense.tower/lm.head/reduce_sum"}
+  %multiply.9 = f32[4] multiply(%state.1, %state.1), metadata={op_name="jit(train_many)/while/body/dense.tower/kda.scan/mul"}
+  %tuple.9 = (s32[], f32[4]) tuple(%constant.1, %multiply.9)
+  %while.9 = (s32[], f32[4]) while(%tuple.9), condition=%wide.while_cond.778, body=%body.9
+  %get-tuple-element.99 = f32[4] get-tuple-element(%while.9), index=1
+  %add.9 = f32[4] add(%get-tuple-element.99, %get-tuple-element.99), metadata={op_name="jit(train_many)/while/body/dense.tower/kda.scan/add"}
+  %while.8 = f32[4] while(%multiply.9), condition=%wide.while_cond.778, body=%body.8
+  ROOT %add.8 = f32[4] add(%while.8, %add.9), metadata={op_name="jit(train_many)/while/body/dense.tower/lm.head/add"}
+}
+"""
+
+
+def test_scope_map_follows_the_call_graph():
+    scopes = trace.scope_map(_CALL_GRAPH_TEXT)
+    experts = "dense.tower/moe.experts"
+    # a `while` whose body's instructions carry no op_name: they take the
+    # while's path, through the fusion the body calls
+    for name in ("fusion.4767", "add.12958", "get-tuple-element.2",
+                 "tuple.12478", "wide.param.1"):
+        assert scopes[name] == experts, name
+    # ... and what the loop hands on is consumed under the conditional's name
+    assert scopes["get-tuple-element.10"] == "exchange.owner_apply"
+    # a conditional with a scoped branch: an instruction that HAS a scope
+    # keeps it, one with an op_name and no scope takes the caller's, and so
+    # does the whole scopeless branch
+    assert scopes["fusion.20"] == "exchange.owner_apply/sparse.apply"
+    assert scopes["broadcast.21"] == "exchange.owner_apply"
+    assert scopes["fusion.30"] == scopes["b.2"] == "exchange.owner_apply"
+    # a computation called from two scopes gives its instructions none; the
+    # condition both scopeless loops share is called from three
+    assert scopes["add.3"] == scopes["x.3"] == ""
+    assert scopes["compare.1"] == ""
+    # ... and `fused_computation.1`, called from everywhere, none either
+    assert scopes["negate.1"] == ""
+    # a scopeless `while` between agreeing producers and consumers: the tuple
+    # in front of it carries values and is no producer, its consumers agree
+    scan = "dense.tower/kda.scan"
+    for name in ("while.9", "get-tuple-element.99", "fusion.92", "tuple.94",
+                 "tuple.9"):
+        assert scopes[name] == scan, name
+    # ... and one whose producer and consumer disagree: none, nor its body
+    assert scopes["while.8"] == scopes["fusion.82"] == ""
+    # the entry computation's own made instructions: a constant two scopes
+    # consume stays unscoped
+    assert scopes["constant.2"] == ""
+
+
+def test_call_graph_scopes_partition_busy_time():
+    """Scope sums + unscoped = busy with the call graph's names, exactly."""
+    from openembedding_tpu.utils import devtrace
+
+    scopes = trace.scope_map(_CALL_GRAPH_TEXT)
+    timed = ["fusion.4767", "add.12958", "fusion.20", "fusion.30",
+             "reduce.1", "fusion.92", "fusion.82", "add.8", "compare.1"]
+    ops = [[f"%{n} = f32[4] fusion(%x)", 1000.0 * i, 600.0 + 10 * i]
+           for i, n in enumerate(timed)]
+    ops.append(["%while.1789 = (s32[], f32[4]) while(%t)", 0.0, 9000.0])
+    rep = devtrace.reduce_events(
+        {"devices": {"/device:TPU:0": {"ops": ops, "async": []}},
+         "host": [], "steps": []}, scopes=scopes, steps=1)
+    (dev,) = rep["devices"].values()
+    assert sum(dev["scope_s"].values()) + dev["unscoped_s"] == \
+        pytest.approx(dev["busy_s"], abs=1e-15)
+    assert dev["scope_s"]["moe.experts"] == pytest.approx(
+        (600 + 610 + 640) * 1e-9)   # the body's two ops and reduce.1
+    assert dev["scope_s"]["sparse.apply"] == pytest.approx(620e-9)
+    assert dev["scope_s"]["exchange.owner_apply"] == pytest.approx(630e-9)
+    assert dev["scope_s"]["kda.scan"] == pytest.approx(650e-9)
+    assert dev["unscoped_s"] == pytest.approx((660 + 680) * 1e-9)
+
+
 @pytest.fixture(scope="module")
 def recorded():
     """A v5e trace cut to a few hundred events (see its `recorded` key)."""

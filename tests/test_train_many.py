@@ -164,12 +164,18 @@ def test_apply_full_steps_counts_all_distinct_batches(fresh_metrics):
     model = make_deepfm(vocabulary=V_LOAD, dim=9, hidden=(8,))
     tr = Trainer(model, embed.Adagrad(learning_rate=0.05), seed=1)
     batches = _batches_with_unique([N_LOAD] * K, seed=4)
-    _, mm = tr.jit_train_many()(tr.init(batches[0]), _stack(batches))
+    many = tr.jit_train_many()
+    state, mm = many(tr.init(batches[0]), _stack(batches))
     assert int(mm["apply_full_steps"]["categorical"]) == K
     tr.record_window_stats(mm)
-    tr.record_window_stats(mm)  # a counter: windows add up
+    tr.record_window_stats(mm)  # the same window again: folded once
     assert fresh_metrics.report()[
-        'sparse.apply_full_steps{table="categorical"}'] == 2 * K
+        'sparse.apply_full_steps{table="categorical"}'] == K
+    _, mm2 = many(state, _stack(batches))
+    tr.record_window_stats(mm2)  # a counter: windows add up
+    rep = fresh_metrics.report()
+    assert rep['sparse.apply_full_steps{table="categorical"}'] == 2 * K
+    assert rep['trainer.windows{fn="train_many"}'] == 2
 
 
 def test_apply_load_on_the_benchmark_generator(fresh_metrics):
@@ -218,7 +224,6 @@ def test_mesh_window_carries_the_apply_load_beside_owner_fill(
     one = jax.tree_util.tree_map(lambda x: x[0], stacked)
     state = tr.init(one)
     state, mm = tr.jit_train_many(stacked, state)(state, stacked)
-    mm = jax.device_get(mm)
     fills = [max(np.unique(ids[k][ids[k] % S == d]).size for d in range(S)) / n
              for k in range(K)]
     if capacity_factor == 0.0:
@@ -292,3 +297,206 @@ def test_sparse_pulls_counts_each_table_once_a_trace(fresh_metrics):
     assert (fresh_metrics.report()[shared], fresh_metrics.report()[each]) == (2, 2)
     tr.jit_train_step().lower(state, batches[0])    # the split layout: no plan
     assert (fresh_metrics.report()[shared], fresh_metrics.report()[each]) == (2, 4)
+
+
+# -- the entry point accounts for itself: `jit_train_many()` returns the
+# program's dispatch object (`model.TrainManyDispatch`), which publishes every
+# window's counters without being asked and never waits on the device for one
+# (`model._PendingWindows`) -----------------------------------------------------
+
+
+class _CountingTower(nn.Module):
+    """A tower that counts: per-step stats the trainer folds over a window."""
+
+    window_stats = (("toy.rows", "sum"), ("toy.largest_logit", "max"))
+
+    @nn.compact
+    def __call__(self, embedded, dense_inputs=None, *, with_stats=False):
+        x = jnp.concatenate([embedded[k].reshape(embedded[k].shape[0], -1)
+                             for k in sorted(embedded)], axis=-1)
+        logits = nn.Dense(1)(x)[:, 0]
+        if not with_stats:
+            return logits
+        return logits, {"toy.rows": jnp.float32(x.shape[0]),
+                        "toy.largest_logit": jnp.max(logits)}
+
+    def apply_with_stats(self, variables, embedded, dense_inputs=None):
+        return self.apply(variables, embedded, dense_inputs, with_stats=True)
+
+
+def _window_series(rep):
+    return {k: v for k, v in rep.items()
+            if k.startswith(("sparse.apply_", "toy.", "trainer.windows"))}
+
+
+def _three_windows(seed):
+    """Three windows whose loads differ: all-distinct steps (the last rung)
+    in the first and the last."""
+    counts = ([N_LOAD, 700, N_LOAD, 1], [600, 5, 900, 40], [N_LOAD, 2, 3, 4])
+    return [_stack(_batches_with_unique(c, seed=seed + i))
+            for i, c in enumerate(counts)], 3
+
+
+def _toy_or_deepfm(kind):
+    if kind == "deepfm":
+        return make_deepfm(vocabulary=V_LOAD, dim=9, hidden=(8,))
+    return embed.EmbeddingModel(_CountingTower(), [
+        embed.Embedding(V_LOAD, 8, name="categorical")])
+
+
+@pytest.mark.parametrize("kind", ["deepfm", "module_stats"])
+def test_entry_point_publishes_every_window_without_being_asked(
+        kind, fresh_metrics):
+    """After dispatches of `jit_train_many()` and NO call of
+    `record_window_stats`, the registry holds what an explicit fold of every
+    window of the bare jitted scan gives."""
+    windows, full_steps = _three_windows(seed=11)
+    one = jax.tree_util.tree_map(lambda x: x[0], windows[0])
+
+    def run(through_entry_point):
+        fresh_metrics._REGISTRY.clear()
+        tr = Trainer(_toy_or_deepfm(kind), embed.Adagrad(learning_rate=0.05),
+                     seed=1)
+        state = tr.init(one)
+        many = tr.jit_train_many() if through_entry_point else \
+            jax.jit(tr.train_many, donate_argnums=(0,))
+        for w in windows:
+            state, m = many(state, w)
+            if not through_entry_point:
+                tr.record_window_stats(m)
+        return _window_series(fresh_metrics.report())
+
+    asked, unasked = run(False), run(True)
+    assert unasked == asked
+    assert unasked['trainer.windows{fn="train_many"}'] == 3
+    assert unasked['sparse.apply_full_steps{table="categorical"}'] == full_steps
+    if kind == "module_stats":
+        assert unasked["toy.rows"] == 3 * K * B_LOAD
+        assert "toy.largest_logit" in unasked
+
+
+def test_a_window_is_folded_once_whoever_asks(fresh_metrics):
+    """The entry point folds, the caller asks too (before the fold and after
+    it): `*_full_steps` and `trainer.windows` count each window once."""
+    windows, full_steps = _three_windows(seed=21)
+    tr = Trainer(make_deepfm(vocabulary=V_LOAD, dim=9, hidden=(8,)),
+                 embed.Adagrad(learning_rate=0.05), seed=1)
+    state = tr.init(jax.tree_util.tree_map(lambda x: x[0], windows[0]))
+    many = tr.jit_train_many()
+    state, m1 = many(state, windows[0])
+    tr.record_window_stats(m1)            # asked while it is pending
+    state, m2 = many(state, windows[1])
+    state, m3 = many(state, windows[2])
+    rep = fresh_metrics.report()          # the read folds what is pending
+    assert rep['trainer.windows{fn="train_many"}'] == 3
+    for m in (m1, m2, m3):                # asked again, after the fold
+        tr.record_window_stats(m)
+    rep = fresh_metrics.report()
+    assert rep['trainer.windows{fn="train_many"}'] == 3
+    assert rep['sparse.apply_full_steps{table="categorical"}'] == full_steps
+    assert full_steps == sum(int(m["apply_full_steps"]["categorical"])
+                             for m in (m1, m2, m3))
+
+
+class _NotReadyYet:
+    """A device array of a window still running."""
+
+    def __init__(self):
+        self.ready = False
+
+    def is_ready(self):
+        return self.ready
+
+
+def test_a_dispatch_does_not_wait_for_an_unready_window(fresh_metrics):
+    from openembedding_tpu import model as model_mod
+    pending = model_mod._WINDOWS
+    assert len(pending) == 0
+    folded = []
+    leaf = _NotReadyYet()
+    unready = {"apply_fill": {"categorical": leaf}}
+    pending.sent(lambda w: folded.append(w), unready)
+    windows, _ = _three_windows(seed=31)
+    tr = Trainer(make_deepfm(vocabulary=V_LOAD, dim=9, hidden=(8,)),
+                 embed.Adagrad(learning_rate=0.05), seed=1)
+    state = tr.init(jax.tree_util.tree_map(lambda x: x[0], windows[0]))
+    many = tr.jit_train_many()
+    try:
+        state, m1 = many(state, windows[0])
+        state, m2 = many(state, windows[1])
+        jax.block_until_ready((m1, m2))
+        # the oldest window is not ready: nothing was read, nothing folded,
+        # the later (ready) windows wait their turn behind it
+        assert folded == [] and len(pending) == 3
+        assert 'trainer.windows{fn="train_many"}' not in fresh_metrics._REGISTRY
+        leaf.ready = True
+        state, m3 = many(state, windows[2])
+        # ... and once it is, a dispatch folds it, and AT MOST that one
+        assert folded == [unready] and len(pending) == 3
+    finally:
+        pending.drain()
+    assert len(pending) == 0
+    assert fresh_metrics.report()['trainer.windows{fn="train_many"}'] == 4
+
+
+def test_the_queue_of_pending_windows_is_bounded(fresh_metrics, monkeypatch):
+    """Nothing ever reads the registry and no window ever reads ready: the
+    queue holds `LIMIT` windows, the oldest were folded (blocking) to make
+    room."""
+    from openembedding_tpu import model as model_mod
+    pending = model_mod._WINDOWS
+    assert len(pending) == 0
+    monkeypatch.setattr(model_mod, "_leaves_ready", lambda window: False)
+    windows, _ = _three_windows(seed=41)
+    tr = Trainer(make_deepfm(vocabulary=V_LOAD, dim=9, hidden=(8,)),
+                 embed.Adagrad(learning_rate=0.05), seed=1)
+    state = tr.init(jax.tree_util.tree_map(lambda x: x[0], windows[0]))
+    many = tr.jit_train_many()
+    sent = pending.LIMIT + 3
+    for i in range(sent):
+        state, _ = many(state, windows[i % 3])
+        assert len(pending) <= pending.LIMIT
+    assert len(pending) == pending.LIMIT
+    acc = fresh_metrics._REGISTRY['trainer.windows{fn="train_many"}']
+    assert acc.value() == 3
+    assert fresh_metrics.report()['trainer.windows{fn="train_many"}'] == sent
+    assert len(pending) == 0
+
+
+def test_lower_and_traces_go_through_the_dispatch_object(fresh_metrics):
+    from openembedding_tpu import model as model_mod
+    windows, _ = _three_windows(seed=51)
+    tr = Trainer(make_deepfm(vocabulary=V_LOAD, dim=9, hidden=(8,)),
+                 embed.Adagrad(learning_rate=0.05), seed=1)
+    state = tr.init(jax.tree_util.tree_map(lambda x: x[0], windows[0]))
+    many = tr.jit_train_many()
+    assert isinstance(many, model_mod.TrainManyDispatch)
+    text = many.lower(state, windows[0]).compile().as_text()
+    assert "sparse.apply" in text
+    # a trace of the object sends no window
+    shapes = jax.eval_shape(many, state, windows[0])
+    assert shapes[1]["loss"].shape == (K,)
+    assert len(model_mod._WINDOWS) == 0
+    assert 'trainer.windows{fn="train_many"}' not in fresh_metrics.report()
+
+
+def test_a_window_that_cannot_be_read_costs_no_dispatch(fresh_metrics):
+    """A window whose arrays were deleted under the queue goes uncounted, with
+    an event in the flight recorder; the dispatch and the read go on."""
+    from openembedding_tpu.utils import trace
+    windows, _ = _three_windows(seed=61)
+    tr = Trainer(make_deepfm(vocabulary=V_LOAD, dim=9, hidden=(8,)),
+                 embed.Adagrad(learning_rate=0.05), seed=1)
+    state = tr.init(jax.tree_util.tree_map(lambda x: x[0], windows[0]))
+    many = tr.jit_train_many()
+    state, m1 = many(state, windows[0])
+    jax.block_until_ready(m1)
+    for leaf in jax.tree_util.tree_leaves(m1):
+        leaf.delete()
+    state, m2 = many(state, windows[1])   # folds m1, which is gone
+    assert float(m2["loss"][0]) == float(m2["loss"][0])  # the call came back
+    rep = fresh_metrics.report()
+    assert rep['trainer.windows{fn="train_many"}'] == 1
+    errors = [e for e in trace.RECORDER.events()
+              if (e.group, e.name) == ("trainer", "window_fold_error")]
+    assert errors and "delete" in errors[-1].attrs["error"].lower()
