@@ -27,10 +27,11 @@ class UniqueResult(NamedTuple):
     counts: jax.Array       # (n,) int32 — duplicate multiplicity; 0 = padding slot
     num_unique: jax.Array   # () int32
     # sort permutation + SORTED segment ids: `payload[order]` has ascending segment
-    # ids `seg`, so downstream reductions run as segment_sum(payload[order], seg,
-    # indices_are_sorted=True) — the sorted path vectorizes on TPU while an
-    # unsorted segment scatter-add serializes (28 ms vs 2.5 ms for the benchmark
-    # batch)
+    # ids `seg`, so a caller's reduction runs as segment_sum(payload[order], seg,
+    # indices_are_sorted=True): a sorted scatter-add still pays per position
+    # (1.32 ms for the benchmark's f32[106496,10] gradients, 0.93 for an s32
+    # vector; v5e, PR 35), an unsorted one serializes. The dedup's own
+    # integers come out of sorts, with no such pass (`_run_heads`)
     order: jax.Array        # (n,) int32
     seg: jax.Array          # (n,) int32, ascending
 
@@ -58,30 +59,53 @@ def unique_with_counts(ids: jax.Array) -> UniqueResult:
             iota = jnp.arange(n, dtype=jnp.int32)
             s_hi, s_lo, order = jax.lax.sort(
                 (ids[:, 0], ids[:, 1], iota), num_keys=2)
-            sorted_ids = jnp.stack([s_hi, s_lo], axis=-1)
+            lanes = (s_hi, s_lo)
             is_new = jnp.concatenate(
                 [jnp.ones((1,), dtype=bool),
                  (s_hi[1:] != s_hi[:-1]) | (s_lo[1:] != s_lo[:-1])])
         else:
-            order = jnp.argsort(ids).astype(jnp.int32)
-            sorted_ids = ids[order]
+            # ONE stable sort gives the sorted ids and the permutation (an
+            # `argsort` is this sort with the ids thrown away, and `ids[order]`
+            # a gather over the n positions to get them back). Stable: `order`
+            # among duplicates fixes the order `segment_reduce` adds f32 in
+            sorted_ids, order = jax.lax.sort(
+                (ids, jnp.arange(n, dtype=jnp.int32)), num_keys=1,
+                is_stable=True)
+            lanes = (sorted_ids,)
             is_new = jnp.concatenate(
                 [jnp.ones((1,), dtype=bool), sorted_ids[1:] != sorted_ids[:-1]])
-        seg = (jnp.cumsum(is_new) - 1).astype(jnp.int32)  # ascending segment ids
-        num_unique = seg[-1] + 1
-        # duplicate writes to one segment all carry the same value, so .set is deterministic
-        unique_ids = jnp.zeros(sorted_ids.shape, ids.dtype).at[seg].set(
-            sorted_ids, mode="drop", indices_are_sorted=True)
-        counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), seg, num_segments=n,
-                                     indices_are_sorted=True)
-        # position -> unique slot: `order` is a permutation, so sorting `seg`
-        # by it is the map. A second sort, not `zeros.at[order].set(seg)`: the
-        # unsorted scatter pays per position (0.49 ms over the benchmark's
-        # 106,496 against the sort's 0.13; probe on the v5e, PR 35)
-        _, inverse = jax.lax.sort((order.astype(jnp.int32), seg), num_keys=1)
-        return UniqueResult(unique_ids, inverse, counts.astype(jnp.int32),
-                            num_unique.astype(jnp.int32), order.astype(jnp.int32),
-                            seg)
+        return _run_heads(lanes, is_new, order)
+
+
+def _run_heads(lanes: tuple, is_new: jax.Array,
+               order: jax.Array) -> UniqueResult:
+    """Run-length encode sorted ids (`lanes`: the (n,) ids, or a pair's two
+    lanes; `is_new` marks each run's first position, `order` is the
+    permutation that sorted them) by sorts, a cumsum and elementwise ops: no
+    scatter, scatter-add or gather over the n positions, each of which pays
+    per position on the TPU where a sort of the same vector costs a fraction
+    (0.49 / 0.93 / 0.76 ms against 0.13 over the benchmark's 106,496; v5e,
+    PR 35-37).
+
+    One sort on the key `position if head else n` brings the run heads to the
+    front in order, each with its id (0 for the rest) riding along. The
+    carried ids ARE `unique_ids`, the sorted key is every run's first
+    position and then n, and a run ends where the next begins:
+    `counts[k] = key[k + 1] - key[k]`, the last run ending at n, 0 past it.
+    Ties at n carry equal payloads, so no sort here needs to be stable."""
+    n = is_new.shape[0]
+    seg = (jnp.cumsum(is_new) - 1).astype(jnp.int32)  # ascending segment ids
+    head_pos, *heads = jax.lax.sort(
+        (jnp.where(is_new, jnp.arange(n, dtype=jnp.int32), n),)
+        + tuple(jnp.where(is_new, x, jnp.zeros((), x.dtype)) for x in lanes),
+        num_keys=1, is_stable=False)
+    unique_ids = heads[0] if len(heads) == 1 else jnp.stack(heads, axis=-1)
+    counts = jnp.concatenate(
+        [head_pos[1:], jnp.full((1,), n, jnp.int32)]) - head_pos
+    # position -> unique slot: `order` is a permutation, so sorting `seg` by
+    # it is the map (`zeros.at[order].set(seg)` is an unsorted scatter)
+    _, inverse = jax.lax.sort((order, seg), num_keys=1, is_stable=False)
+    return UniqueResult(unique_ids, inverse, counts, seg[-1] + 1, order, seg)
 
 
 def carry_to_unique(uniq: UniqueResult, values: jax.Array,
@@ -228,21 +252,24 @@ def compact_blocks(x: jax.Array, offsets: jax.Array, W: int, fill=0,
 
 def unique_and_route(ids: jax.Array, valid: jax.Array, num_shards: int,
                      capacity: int, owner=None) -> tuple:
-    """Fused dedup + owner routing: ONE multi-key sort where
-    `unique_with_counts` + `bucket_by_owner` pay two argsorts plus a
-    searchsorted (the S-invariant protocol compute the mesh1 bench surfaces —
-    the reference does this client-side work on CPU off the device critical
-    path, `EmbeddingPullOperator.cpp:60-112`; on TPU it rides the step).
+    """Fused dedup + owner routing: ONE multi-key sort orders the positions
+    for both, where `unique_with_counts` + `bucket_by_owner` pay a sort each,
+    a searchsorted and per-slot scatters (the S-invariant protocol compute the
+    mesh1 bench surfaces — the reference does this client-side work on CPU off
+    the device critical path, `EmbeddingPullOperator.cpp:60-112`; on TPU it
+    rides the step).
 
     Sorting by (owner, id, iota) yields uniques in OWNER-MAJOR id order, and
     that order is a CONTRACT the exchange depends on: owner s's unique ids are
     one contiguous range of `unique_ids`, and its outgoing bucket is that
     range in order. So the buckets are built by S masked block copies
     (`expand_blocks`) and the callers build payload buckets and read returned
-    rows back the same way, from `RoutedBuckets.start` / `.count` — no second
-    sort, no searchsorted, no per-slot (owner, slot) position. `inverse`,
-    `counts` and `seg` stay mutually consistent with that order. Returns
-    (UniqueResult, RoutedBuckets).
+    rows back the same way, from `RoutedBuckets.start` / `.count` — no
+    searchsorted, no per-slot (owner, slot) position. The unique buffer, the
+    counts and `inverse` come out of two more small sorts (`_run_heads`), so
+    no scatter or gather over the n positions is left here. `inverse`,
+    `counts` and `seg` stay mutually consistent with the owner-major order.
+    Returns (UniqueResult, RoutedBuckets).
 
     `valid` masks per-INPUT-id (invalid ids sort into a trailing pseudo-owner
     `num_shards` and never reach a bucket). `owner = id % num_shards` exactly
@@ -264,26 +291,18 @@ def unique_and_route(ids: jax.Array, valid: jax.Array, num_shards: int,
             owner_in = jnp.where(valid, owner_in, S)
             so, s_hi, s_lo, order = jax.lax.sort(
                 (owner_in, ids[:, 0], ids[:, 1], iota), num_keys=3)
-            sorted_ids = jnp.stack([s_hi, s_lo], axis=-1)
+            lanes = (s_hi, s_lo)
             id_change = (s_hi[1:] != s_hi[:-1]) | (s_lo[1:] != s_lo[:-1])
         else:
             owner_in = ((ids % S).astype(jnp.int32) if owner is None
                         else owner.astype(jnp.int32))
             owner_in = jnp.where(valid, owner_in, S)
             so, sorted_ids, order = jax.lax.sort((owner_in, ids, iota), num_keys=2)
+            lanes = (sorted_ids,)
             id_change = sorted_ids[1:] != sorted_ids[:-1]
         is_new = jnp.concatenate(
             [jnp.ones((1,), bool), (so[1:] != so[:-1]) | id_change])
-        seg = (jnp.cumsum(is_new) - 1).astype(jnp.int32)
-        num_unique = seg[-1] + 1
-        unique_ids = jnp.zeros(sorted_ids.shape, ids.dtype).at[seg].set(
-            sorted_ids, mode="drop", indices_are_sorted=True)
-        counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), seg, num_segments=n,
-                                     indices_are_sorted=True)
-        inverse = jnp.zeros((n,), jnp.int32).at[order].set(seg)
-        uniq = UniqueResult(unique_ids, inverse, counts.astype(jnp.int32),
-                            num_unique.astype(jnp.int32), order.astype(jnp.int32),
-                            seg)
+        uniq = _run_heads(lanes, is_new, order)
 
         # uniques and positions per owner, from the sorted owners (`so` is
         # ascending; the pseudo-owner S — invalid and carved-out positions —
@@ -308,8 +327,8 @@ def unique_and_route(ids: jax.Array, valid: jax.Array, num_shards: int,
         else:
             empty = -1
         with _trace.scope("exchange", "bucket"):
-            bucket_ids = expand_blocks(unique_ids, start, count, capacity,
-                                       fill=empty)
+            bucket_ids = expand_blocks(uniq.unique_ids, start, count,
+                                       capacity, fill=empty)
         return uniq, RoutedBuckets(bucket_ids, start, count, positions,
                                    overflow)
 

@@ -248,9 +248,11 @@ def _dedup_routed(n_rows: int, row_ids: jax.Array, grads: jax.Array,
 #   several rungs (a buffer with one rung has nothing to overrun: 0)}; the
 #   trainers carry it as `{table}/apply_fill` in the step's stats and fold it
 #   to `sparse.apply_fill{table=}` / `sparse.apply_full_steps{table=}`.
-# `segment_reduce` and the dedup pay per input position and are not part of
-# this. A packed table's forward pull is ("ONE DEDUP AND ONE TABLE GATHER A
-# STEP", further down): it reads the step's unique rows at the same rung.
+# `segment_reduce` pays per input position and is not part of this; the
+# dedup is sorts over the positions and holds no such pass (`ops/dedup.py`,
+# `_run_heads`). A packed table's forward pull is ("ONE DEDUP AND ONE TABLE
+# GATHER A STEP", further down): it reads the step's unique rows at the same
+# rung.
 # ---------------------------------------------------------------------------
 
 # what a v5e's compiler keeps in fast memory: a 64 and an 80 MiB table yes, a

@@ -6,7 +6,9 @@ one table shard (rows where `id % S == shard_index`, the reference's layout,
 
 PULL (reference `EmbeddingPullOperator`, client dedup -> per-node RPC -> server gather
 -> client reassemble):
-  1. dedup + owner-routing in ONE multi-key sort (`ops/dedup.unique_and_route`;
+  1. dedup + owner-routing ordered by ONE multi-key sort; the unique buffer,
+     the counts and the position -> slot map by two more small sorts, no
+     scatter or gather over the positions (`ops/dedup.unique_and_route`;
      client-side dedup, `c_api.cc:220-231`)
   2. `all_to_all` id buckets            [the RPC fan-out, now one ICI collective]
      — empty slots carry the EMPTY sentinel, validity derives from the payload
@@ -98,8 +100,9 @@ an EMPTY slot. No working size is chosen and no `fits` is needed, unlike
 the owner's side and the apply's: a bucket of `cap` slots always holds its
 owner's range, or drops the same tail past `cap` that a per-slot scatter
 dropped, and counts it in `overflow`. On a device profile the client's copies sit under
-`exchange.bucket`, apart from `exchange.route` (the sort, the unique buffer,
-the duplicate pre-sum, the encode) and from the owner's `exchange.compact`.
+`exchange.bucket`, apart from `exchange.route` (the sorts that make the
+unique buffer, the duplicate pre-sum, the encode) and from the owner's
+`exchange.compact`.
 The wire carries the same arrays bit for bit. Tested in
 `tests/test_client_buckets.py` against the per-slot scatter it replaced.
 

@@ -334,3 +334,157 @@ def test_unique_and_route_edges(case):
         assert occupancy == 1
         assert int(np.asarray(uniq.counts)[0]) == 16
         np.testing.assert_array_equal(np.asarray(uniq.inverse), 0)
+
+
+# -- PR 37: the dedup by sorts, held to the scatter-based bodies it replaced ---
+# (`tests/dedup_reference.py`: the parent's `unique_with_counts` and
+# `unique_and_route`, verbatim). Every field, every integer, every dtype.
+
+N_BENCH = 106_496       # the benchmark's positions a step (4096 x 26)
+
+
+def _zipf(n, vocab, seed=0):
+    rng = np.random.default_rng(seed)
+    return (rng.zipf(1.05, size=n) % vocab).astype(np.int32)
+
+
+def _pairs(raw):
+    from openembedding_tpu.ops.id64 import np_split_ids
+    return np_split_ids(np.where(raw < 0, -1, raw.astype(np.int64) + (1 << 40)))
+
+
+_UNIQUE_CASES = {
+    "zipf_106496": lambda: _zipf(N_BENCH, 1 << 25),
+    "zipf_small": lambda: _zipf(257, 64),
+    "all_equal": lambda: np.full((64,), 7, np.int32),
+    "all_distinct": lambda: np.random.default_rng(1).permutation(300)
+    .astype(np.int32),
+    "n_1": lambda: np.asarray([5], np.int32),
+    # what `_route_unique` sends: padding and negative ids under the key n_rows
+    "sentinel_heavy": lambda: np.where(
+        np.random.default_rng(2).random(512) < 0.7, 1000,
+        _zipf(512, 1000, seed=2)).astype(np.int32),
+    "all_sentinel": lambda: np.full((128,), 1000, np.int32),
+    "int64_ids": lambda: _zipf(257, 64).astype(np.int64) + (1 << 40),
+    "split_pair": lambda: _pairs(_zipf(513, 100, seed=3)),
+    "split_pair_low_word_ties": lambda: np.stack(
+        [np.random.default_rng(4).integers(0, 3, 200),
+         np.random.default_rng(5).integers(0, 3, 200)], -1).astype(np.uint32),
+}
+
+
+def _assert_fields_equal(got, want):
+    assert type(got)._fields == type(want)._fields
+    for name in type(want)._fields:
+        g, w = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(g, w, err_msg=name)
+
+
+@pytest.mark.parametrize("case", sorted(_UNIQUE_CASES))
+def test_unique_with_counts_equals_the_scatter_reference(case):
+    import dedup_reference
+    ids = jnp.asarray(_UNIQUE_CASES[case]())
+    _assert_fields_equal(jax.jit(unique_with_counts)(ids),
+                         jax.jit(dedup_reference.unique_with_counts)(ids))
+
+
+def _route_case(raw, S, cap=None, *, invalid=0.0, owner=None, pair=False):
+    """-> (ids, valid, S, cap, owner): validity and an explicit owner are
+    functions of the id VALUE, as the protocol's are."""
+    raw = np.asarray(raw)
+    if invalid:
+        bad = np.random.default_rng(0).random(int(raw.max()) + 1) < invalid
+        raw = np.where(bad[raw], -1, raw)
+    valid = raw >= 0
+    own = None
+    if owner == "assigned":     # in [0, S]: S = carved out of the exchange
+        own = jnp.asarray(((raw.astype(np.int64) * 7 + 3) % (S + 1))
+                          .astype(np.int32))
+    ids = _pairs(raw) if pair else raw.astype(np.int32)
+    return (jnp.asarray(ids), jnp.asarray(valid), S,
+            raw.shape[0] if cap is None else cap, own)
+
+
+_ROUTE_CASES = {
+    "zipf_106496": lambda: _route_case(_zipf(N_BENCH, 1 << 27), 4),
+    "zipf_106496_capacity_half": lambda: _route_case(
+        _zipf(N_BENCH, 1 << 27), 4, 13_312, invalid=0.1),
+    "zipf_small": lambda: _route_case(_zipf(257, 64), 4),
+    "zipf_small_8_shards": lambda: _route_case(_zipf(257, 64), 8),
+    "all_equal": lambda: _route_case(np.full((64,), 7), 4),
+    "all_distinct": lambda: _route_case(
+        np.random.default_rng(1).permutation(300), 4),
+    "n_1": lambda: _route_case(np.asarray([5]), 4, 8),
+    "valid_mask": lambda: _route_case(_zipf(257, 64), 4, invalid=0.3),
+    "all_invalid": lambda: _route_case(np.full((32,), -1), 4),
+    "explicit_owner": lambda: _route_case(_zipf(257, 64), 4,
+                                          owner="assigned"),
+    "explicit_owner_valid_mask": lambda: _route_case(
+        _zipf(513, 200), 4, invalid=0.2, owner="assigned"),
+    "full_bucket": lambda: _route_case(_zipf(257, 64), 4, 5),
+    "full_bucket_one_owner": lambda: _route_case(np.arange(64) * 4, 4, 4),
+    "split_pair": lambda: _route_case(_zipf(513, 100, seed=3), 4, pair=True),
+    "split_pair_valid_mask_full_bucket": lambda: _route_case(
+        _zipf(513, 100, seed=3), 4, 9, invalid=0.2, pair=True),
+    "split_pair_explicit_owner": lambda: _route_case(
+        _zipf(257, 64), 4, pair=True, owner="assigned"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ROUTE_CASES))
+def test_unique_and_route_equals_the_scatter_reference(case):
+    import dedup_reference
+    from openembedding_tpu.ops.dedup import unique_and_route
+    ids, valid, S, cap, owner = _ROUTE_CASES[case]()
+
+    def run(f):     # `owner` None is an empty pytree to `jit`
+        return jax.jit(lambda i, v, o: f(i, v, S, cap, owner=o))(
+            ids, valid, owner)
+
+    (uniq, buckets), (r_uniq, r_buckets) = (
+        run(unique_and_route), run(dedup_reference.unique_and_route))
+    _assert_fields_equal(uniq, r_uniq)
+    _assert_fields_equal(buckets, r_buckets)
+    if case.startswith("full_bucket") or "capacity_half" in case:
+        assert int(buckets.overflow) > 0       # the case is what it says
+
+
+def _n_sized_scatters_and_gathers(text, n):
+    """Scatter ops, and gather ops with an operand of n or more rows, in a
+    lowered (StableHLO) text."""
+    import re
+    found = []
+    for line in text.splitlines():
+        op = re.search(r"stablehlo\.(scatter|gather|dynamic_gather)\b", line)
+        if op is None:
+            continue
+        dims = [int(d) for d in re.findall(r"tensor<(\d+)[x>]", line)]
+        if op.group(1) == "scatter" or any(d >= n for d in dims):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("layout", ["single_lane", "split_pair"])
+@pytest.mark.parametrize("fn", ["unique_with_counts", "unique_and_route"])
+def test_the_dedup_lowers_to_no_scatter_or_gather_over_the_positions(
+        fn, layout):
+    """The pin on the mechanism (CPU lowering): run heads compacted by a sort,
+    counts by differences, ids out of the one sort. The reference's text holds
+    the passes this one must not, which keeps the probe honest."""
+    import dedup_reference
+    from openembedding_tpu.ops import dedup
+    n = 4096
+    ids = (jax.ShapeDtypeStruct((n, 2), jnp.uint32) if layout == "split_pair"
+           else jax.ShapeDtypeStruct((n,), jnp.int32))
+    valid = jax.ShapeDtypeStruct((n,), jnp.bool_)
+
+    def lowered(mod):
+        if fn == "unique_with_counts":
+            return jax.jit(mod.unique_with_counts).lower(ids).as_text()
+        return jax.jit(lambda i, v: mod.unique_and_route(i, v, 4, n)).lower(
+            ids, valid).as_text()
+
+    assert _n_sized_scatters_and_gathers(lowered(dedup), n) == []
+    assert "stablehlo.sort" in lowered(dedup)
+    assert _n_sized_scatters_and_gathers(lowered(dedup_reference), n)

@@ -696,3 +696,69 @@ def test_plan_and_apply_take_positions_to_leave_out_and_multiplicities():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     np.testing.assert_array_equal(got_load["apply_fill"], load["apply_fill"])
     assert not np.array_equal(np.asarray(got), np.asarray(packed))
+
+
+@pytest.mark.parametrize("where", ["Trainer", "MeshTrainer"])
+def test_k_step_scan_leaves_the_tables_of_the_scatter_based_dedup(
+        where, monkeypatch):
+    """PR 37: the dedup by sorts (`ops/dedup._run_heads`) against the bodies
+    it replaced (`tests/dedup_reference.py`, patched into every call site
+    while the second scan traces), and against K `train_step` calls: the
+    tables bit for bit, one chip's path and the 8-device exchange's (client
+    route + owner dedup)."""
+    import dedup_reference
+    from openembedding_tpu.parallel import MeshTrainer, make_mesh
+
+    V, steps = 4096, 4
+    batches = list(synthetic_criteo(64, id_space=V, steps=steps, seed=21))
+    stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *batches)
+
+    def trainer():
+        model = make_deepfm(vocabulary=V, dim=9, hidden=(8,))
+        opt = embed.Adagrad(learning_rate=0.05)
+        if where == "Trainer":
+            return Trainer(model, opt, seed=3)
+        return MeshTrainer(model, opt, seed=3, mesh=make_mesh(), wire="fp32")
+
+    def many(tr):
+        state = tr.init(batches[0])
+        fn = (tr.jit_train_many() if where == "Trainer"
+              else tr.jit_train_many(stacked, state))
+        return fn(state, stacked)
+
+    scanned, m = many(trainer())
+
+    tr = trainer()
+    stepped, losses = tr.init(batches[0]), []
+    step = (tr.jit_train_step() if where == "Trainer"
+            else tr.jit_train_step(batches[0], stepped))
+    for b in batches:
+        stepped, sm = step(stepped, b)
+        losses.append(np.asarray(sm["loss"]))
+
+    traced = []
+    for name in ("unique_with_counts", "unique_and_route"):
+        def counted(*a, _f=getattr(dedup_reference, name), _n=name, **kw):
+            traced.append(_n)
+            return _f(*a, **kw)
+        monkeypatch.setattr(dedup_reference, name, counted)
+    dedup_reference.patch_reference_dedup(monkeypatch)
+    referenced, rm = many(trainer())
+    monkeypatch.undo()
+    assert "unique_with_counts" in traced
+    assert ("unique_and_route" in traced) == (where == "MeshTrainer")
+
+    np.testing.assert_array_equal(np.asarray(m["loss"]),
+                                  np.asarray(rm["loss"]))
+    # (the mesh's scan and its step loop reduce the loss in another order: an
+    # ulp apart on the parent too; the tables are the step loop's to a bit)
+    np.testing.assert_allclose(np.asarray(m["loss"]), np.stack(losses),
+                               rtol=1e-6)
+    for other in (stepped, referenced):
+        for a, b in zip(jax.tree_util.tree_leaves(scanned.tables),
+                        jax.tree_util.tree_leaves(other.tables)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert not np.array_equal(
+        np.asarray(scanned.tables["categorical"].weights),
+        np.asarray(trainer().init(batches[0]).tables["categorical"].weights))
