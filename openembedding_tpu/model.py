@@ -130,6 +130,24 @@ def attach_ids(embedded: Dict[str, Any], model: "EmbeddingModel",
     return embedded
 
 
+# Reserved key for modules that declare `takes_tables = True`: maps the name
+# of every `sparse_as_dense` variable to the WHOLE table (input_dim,
+# output_dim), the very leaf of `dense_params["__embeddings__"]` its rows are
+# looked up from. A module that ties a table to another use (an output head
+# over the token table, `models/zaya1.py`) reads it here; autodiff then sums
+# the lookup's and that use's gradients and `dense_apply` takes ONE optimizer
+# step on the sum. Tables on the sparse path are never handed over whole.
+TABLES_KEY = "__tables__"
+
+
+def attach_tables(embedded: Dict[str, Any], model: "EmbeddingModel",
+                  sad_tables) -> Dict[str, Any]:
+    """Add `embedded[TABLES_KEY]` iff the module opted in via `takes_tables`."""
+    if getattr(model.module, "takes_tables", False):
+        embedded[TABLES_KEY] = dict(sad_tables)
+    return embedded
+
+
 def sad_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
     """Dense-mirrored ('Cache' mode) table gather through `lookup_rows` — the
     ONE implementation of the invalid-id contract (-1 pads and out-of-range
@@ -774,6 +792,10 @@ class Trainer:
                 shape = shape[:-1]
             out[name] = jnp.zeros(shape + (spec.output_dim,), spec.dtype)
         attach_ids(out, self.model, batch)
+        if getattr(self.model.module, "takes_tables", False):
+            out[TABLES_KEY] = {
+                name: jnp.zeros((spec.input_dim, spec.output_dim), spec.dtype)
+                for name, spec in self.model.sad_specs().items()}
         return out
 
     # -- the per-device step (pure; shard_map-able) -------------------------
@@ -863,6 +885,8 @@ class Trainer:
                 ids = jnp.asarray(batch["sparse"][spec.feature_name])
                 embedded[name] = combine(spec, ids, sad_rows(table, ids))
             attach_ids(embedded, model, batch)
+            attach_tables(embedded, model,
+                          dense_params.get("__embeddings__", {}))
             fr_new, module_stats = None, {}
             if train_apply is not None:
                 logits, fr_new = train_apply({"params": dense_params},
@@ -1131,6 +1155,8 @@ class Trainer:
             ids = jnp.asarray(batch["sparse"][spec.feature_name])
             embedded[name] = combine(spec, ids, sad_rows(table, ids))
         attach_ids(embedded, model, batch)
+        attach_tables(embedded, model,
+                      state.dense_params.get("__embeddings__", {}))
         logits = model.module.apply({"params": state.dense_params}, embedded,
                                     batch.get("dense"))
         loss, _, logits = self._loss_terms(logits, batch)

@@ -3,12 +3,15 @@
 Reference coverage (`documents/en/benchmark.md:6-16`, `examples/`,
 `test/benchmark/criteo_deepctr.py`): WDL (Wide&Deep), DeepFM, xDeepFM at dims 9/64,
 the LR subclass example (`examples/criteo_lr_subclass.py`), plus DLRM (the reference's
-PMem paper workload) and a two-tower retrieval model. Three language-model towers
+PMem paper workload) and a two-tower retrieval model. Four language-model towers
 behind a token table ride the same train path: NemotronH (`nemotron_h.py`: Mamba-2,
 attention, routed experts), JoyAI-LLM-Flash (`joyai_flash.py`: latent attention,
-routed SwiGLU experts, a multi-token-prediction module) and Solar-Open2
+routed SwiGLU experts, a multi-token-prediction module), Solar-Open2
 (`solar_open2.py`: gated delta-rule linear attention, gated softmax attention
-without positions, routed SwiGLU experts; a share of the heads held).
+without positions, routed SwiGLU experts; a share of the heads held) and ZAYA1
+(`zaya1.py`: compressed convolutional attention, a router MLP whose state
+travels up the stack, top-1 experts, the token table tied to the head and
+trained densely).
 
 TPU-first layout decision (differs deliberately from the reference's per-feature
 DeepCTR `Embedding` layers): all categorical fields share ONE row-sharded table, with
@@ -29,6 +32,7 @@ from .sequential import (SASRec, bert4rec_mask_id, make_bert4rec,
 from .nemotron_h import NemotronH, make_nemotron_h, softmax_xent
 from .joyai_flash import JoyAIFlash, make_joyai_flash, mtp_xent
 from .solar_open2 import SolarOpen2, kda_chunked, make_solar_open2
+from .zaya1 import Zaya1, make_zaya1
 
 _FAMILIES = {
     "lr": make_lr, "wdl": make_wdl, "deepfm": make_deepfm,
@@ -39,6 +43,7 @@ _FAMILIES = {
     "nemotron_h": make_nemotron_h,
     "joyai_flash": make_joyai_flash,
     "solar_open2": make_solar_open2,
+    "zaya1": make_zaya1,
 }
 
 
@@ -73,5 +78,6 @@ __all__ = [
     "NemotronH", "make_nemotron_h", "softmax_xent",
     "JoyAIFlash", "make_joyai_flash", "mtp_xent",
     "SolarOpen2", "make_solar_open2", "kda_chunked",
+    "Zaya1", "make_zaya1",
     "CRITEO_NUM_SPARSE", "CRITEO_NUM_DENSE",
 ]

@@ -372,8 +372,27 @@ class MoE(nn.Module):
         mean = tokens * self.top_k * self.experts_held / self.n_routed_experts
         return int(-(-2.0 * mean // BLOCK_ROWS) * BLOCK_ROWS)
 
+    def _route(self, xt):
+        """The built-in router: f32 linear map, sigmoid, the top k of score +
+        correction bias, weights = chosen scores (over their sum), scaled.
+        -> (chosen (T, k), gate (T, k))."""
+        init = nn.initializers.lecun_normal()
+        router = self.param("router_kernel", init,
+                            (xt.shape[-1], self.n_routed_experts))
+        bias = self.param("router_correction_bias", nn.initializers.zeros,
+                          (self.n_routed_experts,))
+        logits = jnp.dot(xt.astype(jnp.float32), router,
+                         precision=jax.lax.Precision.HIGHEST)
+        score = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(score + jax.lax.stop_gradient(bias),
+                                  self.top_k)
+        gate = jnp.take_along_axis(score, chosen, axis=-1)
+        if self.norm_topk_prob:
+            gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+        return chosen, gate * self.routed_scaling_factor
+
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, routing=None):
         from ..parallel import sharded
         B, S, D = x.shape
         T, k, E = B * S, self.top_k, self.experts_held
@@ -385,18 +404,11 @@ class MoE(nn.Module):
             E, D, self.expert_width)) if self.gated else None
 
         with _trace.scope("moe", "route"):
-            router = self.param("router_kernel", init,
-                                (D, self.n_routed_experts))
-            bias = self.param("router_correction_bias", nn.initializers.zeros,
-                              (self.n_routed_experts,))
-            logits = jnp.dot(xt.astype(jnp.float32), router,
-                             precision=jax.lax.Precision.HIGHEST)
-            score = jax.nn.sigmoid(logits)
-            _, chosen = jax.lax.top_k(score + jax.lax.stop_gradient(bias), k)
-            gate = jnp.take_along_axis(score, chosen, axis=-1)
-            if self.norm_topk_prob:
-                gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
-            gate = (gate * self.routed_scaling_factor).reshape(T * k)
+            if routing is None:
+                chosen, gate = self._route(xt)
+            else:
+                chosen, gate = routing
+            gate = gate.reshape(T * k)
 
         with _trace.scope("moe", "dispatch"):
             # (token, choice) pairs sorted by held expert; pairs of experts
@@ -505,18 +517,22 @@ class MoE(nn.Module):
                 view.fits, compact, sharded._full_size_scope(full_size))
             full = (~view.fits).astype(jnp.int32)
 
-        with _trace.scope("moe", "shared"):
-            shared = _expert_mlp(
-                xt, self.param("shared_gate", init, (D, self.shared_width))
-                if self.gated else None,
-                self.param("shared_up", init, (D, self.shared_width)),
-                self.param("shared_down", init, (self.shared_width, D)),
-                self.dtype)
+        shared = None
+        if self.shared_width:
+            with _trace.scope("moe", "shared"):
+                shared = _expert_mlp(
+                    xt, self.param("shared_gate", init, (D, self.shared_width))
+                    if self.gated else None,
+                    self.param("shared_up", init, (D, self.shared_width)),
+                    self.param("shared_down", init, (self.shared_width, D)),
+                    self.dtype)
         stats = {"pairs_here": total.astype(jnp.float32),
                  "load_max_over_mean": jnp.max(loads).astype(jnp.float32) * E
                  / jnp.maximum(total, 1).astype(jnp.float32),
                  "full_steps": full, "dropped": total - done}
-        return (routed + shared).reshape(B, S, D), stats
+        if shared is not None:  # after the stats: the op order of the older scans
+            routed = routed + shared
+        return routed.reshape(B, S, D), stats
 
 
 @dataclasses.dataclass(frozen=True)

@@ -69,7 +69,9 @@ own (`models/nemotron_h.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
 `attn.{qkv,core,out}`, `moe.{route,dispatch,experts,combine,shared}`,
 `lm.{head,loss}`; (`models/joyai_flash.py`): `attn.{q_latent,kv_latent,rope,
 core,out}`, `mlp.dense`, `mtp.{merge,layer,head,loss}`; (`models/
-solar_open2.py`): `kda.{qkv,conv,gates,scan,gate_norm,out}`, `attn.gate`.
+solar_open2.py`): `kda.{qkv,conv,gates,scan,gate_norm,out}`, `attn.gate`;
+(`models/zaya1.py`): `cca.{project,conv,mean_norm,out}`, `router.mlp` (inside
+`moe.route`).
 """
 
 from __future__ import annotations

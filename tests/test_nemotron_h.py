@@ -243,6 +243,53 @@ def test_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total + shared, want, atol=5e-5)
 
 
+@pytest.mark.parametrize("gated,norm,scaling", [
+    (False, True, 1.0), (True, True, 2.5), (True, False, 1.0)],
+    ids=["relu2_normalised", "swiglu_scaled", "swiglu_raw_scores"])
+def test_built_in_router_and_shared_expert_are_bit_for_bit_what_they_were(
+        gated, norm, scaling):
+    """The opening in `MoE` (routing handed in by the caller, `shared_width` 0
+    = no shared expert; `zaya1.py`) leaves the default path as it was: the
+    layer with its built-in router and its shared expert equals, BIT FOR BIT,
+    the same layer handed the routing of the router's formula written out
+    here as it stood before the opening (f32 linear map at highest, sigmoid,
+    the top k of score + bias, chosen scores over their sum, scaled), plus
+    the shared expert's formula."""
+    cfg = dict(CFG, norm_topk_prob=norm, routed_scaling_factor=scaling)
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(2, 24, 32)), jnp.float32)
+    layer = _moe_layer(cfg, 4, 4, gated=gated)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(1), x)["params"]
+    params = dict(params, router_correction_bias=jnp.asarray(
+        np.random.default_rng(4).normal(size=(16,)) * 0.1, jnp.float32))
+    got, stats = jax.jit(layer.apply)({"params": params}, x)
+
+    def as_it_was(p, x):
+        xt = x.reshape(-1, 32)
+        logits = jnp.dot(xt.astype(jnp.float32), p["router_kernel"],
+                         precision=jax.lax.Precision.HIGHEST)
+        score = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(
+            score + jax.lax.stop_gradient(p["router_correction_bias"]),
+            cfg["num_experts_per_tok"])
+        gate = jnp.take_along_axis(score, chosen, axis=-1)
+        if norm:
+            gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+        bare = nh.MoE(32, 16, cfg["num_experts_per_tok"],
+                      cfg["moe_intermediate_size"], 0, 4, 4,
+                      dtype=jnp.float32, gated=gated)
+        held = {k: v for k, v in p.items() if k.startswith("experts_")}
+        routed, s = bare.apply({"params": held}, x, (chosen, gate * scaling))
+        shared = nh._expert_mlp(xt, p.get("shared_gate"), p["shared_up"],
+                                p["shared_down"], jnp.float32)
+        return (routed.reshape(-1, 32) + shared).reshape(x.shape), s
+
+    want, stats_was = jax.jit(as_it_was)(params, x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert {k: float(v) for k, v in stats.items()} == \
+        {k: float(v) for k, v in stats_was.items()}
+    assert float(stats["pairs_here"]) > 0 and ("shared_gate" in params) == gated
+
+
 @pytest.mark.parametrize("working_pairs", [0, 16, 1 << 20])
 def test_no_token_dropped_when_every_token_chooses_held_experts(working_pairs):
     """A router bias planted so that every token's choices are all held here:
