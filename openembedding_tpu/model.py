@@ -1174,9 +1174,9 @@ class Trainer:
     def _packed_layouts(self, state: TrainState):
         """{name: column layout} for tables worth packing inside the scan
         (see `ops/sparse.packed_layout`). Applies per shard under MeshTrainer
-        too (widths are shard-invariant): its exchange serves packed rows by
-        their width and hands the layout to the owner's apply
-        (parallel/sharded.py)."""
+        too (widths are shard-invariant): its exchange takes the layout at
+        the owner's serve and apply (parallel/sharded.py "THE OWNER PLANS
+        ONCE A STEP")."""
         from .ops.sparse import packed_layout
         out = {}
         for name, spec in self.model.ps_specs().items():

@@ -322,8 +322,12 @@ def _over_unique_prefix(counts: jax.Array, tables, tail):
 #   to n so every branch has one shape; the apply's branch slices `[:W]`. A
 #   position whose id is invalid maps to a slot past the valid prefix, whose
 #   row reads 0 (out of bounds, or padding), as the per-position pull gave it.
-# - A caller with no pull to share with (the owner side of the exchange, a
-#   hash table's apply) passes no plan and gets the program it had.
+# - The owner side of the exchange plans the same way over the slots it
+#   received (`parallel/sharded.py` "THE OWNER PLANS ONCE A STEP": the slots
+#   to leave out at row -1 at the serve, the multiplicities at the apply).
+#   A caller with no pull to share with (a hash table's apply, a
+#   pipelined step whose rows were served a step early) passes no plan and
+#   gets the program it had.
 # ---------------------------------------------------------------------------
 
 

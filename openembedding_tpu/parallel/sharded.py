@@ -77,6 +77,50 @@ copies sit under `exchange.compact` and a full-size step's ops under
 `exchange.full_size`. The pipelined conflict patch (`grouped_conflict_patch`)
 indexes received slots by position and keeps the bucket layout.
 
+THE OWNER PLANS ONCE A STEP (a shard in the scan's packed layout, weights and
+optimizer slots in one array; `ops/sparse.py` "ONE DEDUP AND ONE TABLE GATHER A
+STEP" is the same mechanism on one chip). The serve and the apply of one step
+read the SAME rows of the shard and nothing writes it between them: the serve
+is the step's first op on it, the apply's scatter the last. A gather from the
+shard is latency-bound per index, and the serve paid it once a received SLOT
+(W = n of them for about two thirds as many rows) and the apply once more per
+unique slot: 3.03 + 2.22 ms of the four-chip cell's 22.5 ms step (ledger,
+PR 38). So the serve of `grouped_lookup_train` plans
+(`ops/sparse.plan_packed_rows` over the slots it serves: the apply's own dedup
+and routing, and ONE sorted gather of the unique packed rows at the apply's
+rung), every slot reads its weight columns out of that small array, and the
+plan rides `ExchangePlan.owner_plan` to `grouped_apply_gradients`, whose
+`_apply_unique` hands it to `sparse_apply_packed_table(plan=)`: no second dedup
+and no second gather. Same values in the same places: the shard after K steps
+is the plan-less one bit for bit (`tests/test_packed_layout.py`).
+- The plan leaves out what the serve's `main_valid` does (an empty slot; a
+  received id that lives in the migration annex): those slots' rows are -1,
+  which `plan_packed_rows` routes to its sentinel as it does a negative id,
+  so the plan needs no mask and its counts are the dedup's own (a 0 / 1 mask
+  would cost a segment sum over the slots: a gather by `order` 0.76 ms and a
+  scatter-add 0.93, the price of the apply's sum of the multiplicities). The
+  counts the apply brings are positive exactly on the slots the plan kept:
+  `recv_valid` and the pushed counts both come from `(uniq.counts > 0) &
+  _id_valid`, and the apply zeroes the annex ids' counts after the same
+  directory probe. So both pick the same rung of `apply_ladder`, and a hot row
+  (never in a bucket) or an annex row (served and applied apart) changes
+  nothing in the plan.
+- What engages it is visible at trace time, and everything else runs the
+  program it had: an array table (a hash table probes per slot); the packed
+  layout, handed down by the caller whose apply of the same step follows
+  (`grouped_lookup_train(packed_list=)`). `grouped_prefetch` serves step t+1
+  BEFORE step t's apply writes the shard, so a plan made there would be stale:
+  it passes no layout, serves per slot and hands on no plan; so do serving and
+  `train_step` (split layout). `exchange.owner_plans{path="shared" |
+  "per_slot"}` counts the choice, once a table a trace.
+- The serve and the apply each sit under `lax.cond(view.fits, compact,
+  full_size)`. The compact branch carries the real plan; the full-size branch
+  serves per slot and returns zeros in the plan's shapes (`_no_plan`), and the
+  apply's full-size branch dedups and gathers for itself. Both conditionals
+  branch on the same `view.fits`, so a plan is never read in a step that did
+  not make it. Where nothing is compacted (`plan.owner is None`) there is no
+  conditional and the plan rides as it is.
+
 WHAT THE CLIENT SENDS: what it has, by S block copies, not S x capacity
 scatters. `unique_and_route` sorts by (owner, id), so the unique buffer comes
 out OWNER-MAJOR: owner s's ids are the contiguous range
@@ -198,7 +242,9 @@ from ..embedding import EmbeddingSpec, EmbeddingTableState, HotRows, MigRows
 from ..ops.dedup import (RoutedBuckets, UniqueResult, bucket_validity,
                          carry_to_unique, compact_blocks, expand_blocks,
                          unique_and_route, unique_with_counts)
-from ..ops.sparse import lookup_rows, sparse_apply_dense_table
+from ..ops.sparse import (PackedPlan, lookup_rows, plan_packed_rows,
+                          sparse_apply_dense_table)
+from ..utils import metrics as _metrics
 from ..utils import trace as _trace
 from .mesh import DATA_AXIS
 
@@ -213,7 +259,9 @@ class OwnerView(NamedTuple):
     (module doc "WHAT THE OWNER WORKS OVER"): the valid prefixes of the S
     received buckets laid end to end in source-major order, EMPTY after. Made
     once per plan, after the id all_to_all (`_owner_view`), and used by the
-    pull's serve and the push's apply of the same step."""
+    pull's serve and the push's apply of the same step; the serve's
+    `PackedPlan` of a packed shard (`ExchangePlan.owner_plan`) is over these
+    W slots."""
 
     ids: jax.Array      # (W[, 2]) valid ids first, source-major; EMPTY after
     valid: jax.Array    # (W,)
@@ -256,6 +304,10 @@ class ExchangePlan(NamedTuple):
     # side is no larger than that (S == 1, or S * cap <= n) and the owner
     # works over `recv_ids` as they are
     owner: Optional[OwnerView] = None
+    # what the owner's serve knows of this step's rows, kept for the same
+    # step's apply (module doc "THE OWNER PLANS ONCE A STEP"); None where the
+    # serve gathered per slot
+    owner_plan: Optional[PackedPlan] = None
 
 
 def _flat_recv(plan: ExchangePlan):
@@ -637,8 +689,10 @@ def exchange_load_stats(plan: ExchangePlan, *, axis: str = DATA_AXIS
 
 def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
                 plan: ExchangePlan, *, train: bool, axis: str,
-                fmt: str = "fp32", return_stash: bool = False):
-    """Server side of a pull: gather this shard's rows for the received ids.
+                fmt: str = "fp32", return_stash: bool = False,
+                packed=None):
+    """Server side of a pull: gather this shard's rows for the received ids
+    -> (state, (S, cap, width) rows in `fmt`, stash, owner plan).
     With a migration directory, received MIGRATED ids (the indirection routed
     them here because this shard is their assigned owner) read from the annex
     instead of the main table — and are masked out of the main-table probe,
@@ -655,39 +709,55 @@ def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
     quantize WITHOUT a residual — their owner is the assigned shard, not
     the hash home the ef array is laid out for.
 
-    `return_stash=True` (the pipelined prefetch) returns a third value: the
+    `return_stash=True` (the pipelined prefetch) fills the third value: the
     PRE-serve residual gathered per recv slot ((S, cap, dim) f32; None when
     no EF ran) — `grouped_conflict_patch` replays it against the post-apply
     weights to reproduce exactly what a serial serve would have shipped.
 
+    `packed`: the column layout of an array table whose shard is in the scan's
+    packed form, given by a caller whose apply of the SAME step follows with
+    nothing written to the shard between (`grouped_lookup_train`). The serve
+    then plans the step (module doc "THE OWNER PLANS ONCE A STEP") and the
+    fourth value is the `PackedPlan` for `_owner_apply`; None otherwise, and
+    for a hash table, which probes per slot either way.
+    `exchange.owner_plans{path=}` counts which, once a table a trace.
+
     Where the plan holds an `OwnerView` the work (`_serve_flat`) runs over its
     W compacted slots and the rows (and the stash) go back to the bucket
     layout by S masked block copies (`_expand`); in a step whose received ids
-    do not fit, the same function runs over all S * cap slots (module doc
-    "WHAT THE OWNER WORKS OVER")."""
+    do not fit, the same function runs over all S * cap slots, per slot and
+    with no plan (module doc "WHAT THE OWNER WORKS OVER")."""
+    share = packed is not None and not spec.use_hash_table
+    _metrics.observe("exchange.owner_plans", 1, "sum",
+                     labels={"path": "shared" if share else "per_slot"})
     with _trace.scope("exchange", "owner_serve"):
         S = jax.lax.axis_size(axis)
         view = plan.owner
 
-        def serve(ids, valid):
+        def serve(ids, valid, share):
             return _serve_flat(spec, state, ids, valid, S, train=train,
-                               fmt=fmt, return_stash=return_stash)
-
-        def full_size():
-            return serve(*_flat_recv(plan))
+                               fmt=fmt, return_stash=return_stash,
+                               share=share)
 
         if view is None:
-            writes, rows, stash = full_size()
+            writes, rows, stash, owner_plan = serve(*_flat_recv(plan), share)
         else:
             def compact():
-                writes, rows, stash = serve(view.ids, view.valid)
+                writes, rows, stash, owner_plan = serve(view.ids, view.valid,
+                                                        share)
 
                 def back(y):
                     return _expand(y, view, plan.cap).reshape(
                         (-1,) + y.shape[1:])
                 return writes, back(rows), \
-                    None if stash is None else back(stash)
-            writes, rows, stash = jax.lax.cond(
+                    None if stash is None else back(stash), owner_plan
+
+            def full_size():
+                # the parent's program; both conditionals branch on the same
+                # `view.fits`, so the apply never reads this step's plan
+                return serve(*_flat_recv(plan), False)[:3] + (
+                    _no_plan(state.weights, view) if share else None,)
+            writes, rows, stash, owner_plan = jax.lax.cond(
                 view.fits, compact, _full_size_scope(full_size))
         if spec.use_hash_table and train:
             # overflow is replicated table-level state: psum the per-shard
@@ -696,10 +766,22 @@ def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
             writes["overflow"] = state.overflow + delta
         state = state.replace(**writes)
         rows = rows.reshape(S, plan.cap, -1)
-        if not return_stash:
-            return state, rows
-        return state, rows, (None if stash is None else
-                             stash.reshape(S, plan.cap, spec.output_dim))
+        if stash is not None:
+            stash = stash.reshape(S, plan.cap, spec.output_dim)
+        return state, rows, stash, owner_plan
+
+
+def _no_plan(packed: jax.Array, view: OwnerView) -> PackedPlan:
+    """Zeros in the shapes of the plan that the compact serve makes of
+    `view`: what the full-size branch of the serve's conditional returns in
+    its place (a conditional's branches return one shape) and the apply's
+    full-size branch never reads."""
+    like = jax.ShapeDtypeStruct
+    shapes = jax.eval_shape(plan_packed_rows,
+                            like(packed.shape, packed.dtype),
+                            like(view.ids.shape, view.ids.dtype))
+    return jax.tree_util.tree_map(lambda x: jnp.zeros(x.shape, x.dtype),
+                                  shapes)
 
 
 def _full_size_scope(fn):
@@ -714,15 +796,16 @@ def _full_size_scope(fn):
 
 def _serve_flat(spec: EmbeddingSpec, state: EmbeddingTableState,
                 flat_recv: jax.Array, flat_valid: jax.Array, S: int, *,
-                train: bool, fmt: str, return_stash: bool):
+                train: bool, fmt: str, return_stash: bool, share: bool):
     """`_serve_rows` over one flat run of received slots, whatever its length
     (the compacted view or the whole receive buffer); no collective. ->
     (the state fields the serve wrote: `keys`/`overflow` on a hash insert,
     `ef` under error feedback; (m, width) rows in `fmt`; the (m, dim)
-    pre-serve residuals or None)."""
+    pre-serve residuals or None; with `share`, an array table's shard in the
+    packed layout, the step's `PackedPlan` over these slots, else None)."""
     pair = flat_recv.ndim == 2  # split-pair ids
     need_ef = train and fmt != "fp32" and state.ef is not None
-    ef_idx = None
+    ef_idx = owner_plan = None
     writes = {}
     mig = state.mig
     m_found = None
@@ -754,13 +837,25 @@ def _serve_flat(spec: EmbeddingSpec, state: EmbeddingTableState,
             rows = hash_lookup(state, probe)
     else:
         local_rows = jnp.where(main_valid, flat_recv // S, -1)
-        rows = lookup_rows(state.weights, local_rows)
-        if rows.shape[1] != spec.output_dim:
-            # packed weights+slots layout inside train_many's scan
-            # (`ops/sparse.packed_layout`): slice the weight columns out of
-            # the gathered packed rows — the gather is latency-bound, the
-            # slot bytes ride free
-            rows = rows[:, :spec.output_dim]
+        if share:
+            # the apply's dedup and routing of these slots and ONE gather of
+            # the unique packed rows; every slot reads its weight columns
+            # from that small array, an invalid one a row past the valid
+            # prefix: 0. The slots to leave out (empty, or an annex row's, as
+            # the apply zeroes their counts) are the rows at -1: no mask to
+            # sum, the plan's counts are the dedup's own
+            with _trace.scope("sparse", "pull"):
+                owner_plan = plan_packed_rows(state.weights, local_rows)
+                rows = lookup_rows(owner_plan.rows[:, :spec.output_dim],
+                                   owner_plan.uniq.inverse)
+        else:
+            rows = lookup_rows(state.weights, local_rows)
+            if rows.shape[1] != spec.output_dim:
+                # packed weights+slots layout inside train_many's scan
+                # (`ops/sparse.packed_layout`), served with no apply of the
+                # same step to share with (the pipelined prefetch): the full
+                # packed row once a slot, the weight columns sliced out
+                rows = rows[:, :spec.output_dim]
         if need_ef:
             ef_idx = jnp.where(main_valid, flat_recv // S,
                                state.ef.shape[0]).astype(jnp.int32)
@@ -769,12 +864,12 @@ def _serve_flat(spec: EmbeddingSpec, state: EmbeddingTableState,
         arows = lookup_rows(mig.weights, jnp.where(m_found, m_rank, M))
         rows = jnp.where(m_found[:, None], arows.astype(rows.dtype), rows)
     if fmt == "fp32":
-        return writes, rows, None
+        return writes, rows, None, owner_plan
     # owner-edge encode: the pull a2a operand is already int8/bf16
     from ..ops import wire as wire_mod
     x = rows.astype(jnp.float32)
     if not need_ef:
-        return writes, wire_mod.pack_inband(x, fmt), None
+        return writes, wire_mod.pack_inband(x, fmt), None, owner_plan
     # invalid/annex slots index OOB: the gather fills 0, the scatter
     # drops. Duplicate recv slots (one id requested by several sources)
     # gather the same w+ef and write the same residual — deterministic.
@@ -785,7 +880,7 @@ def _serve_flat(spec: EmbeddingSpec, state: EmbeddingTableState,
     ef_new = x - wire_mod.unpack_inband(enc, spec.output_dim, fmt)
     writes["ef"] = state.ef.at[ef_idx].set(ef_new.astype(state.ef.dtype),
                                            mode="drop")
-    return writes, enc, ef_prev if return_stash else None
+    return writes, enc, ef_prev if return_stash else None, owner_plan
 
 
 def _merge_hot_rows(plan: ExchangePlan, uniq_rows: jax.Array,
@@ -941,7 +1036,7 @@ def sharded_lookup(
     ids = adapt_batch_ids(spec, state, ids)
     plan = make_plan(spec, ids, axis=axis, capacity_factor=capacity_factor,
                      hot=state.hot, mig=state.mig)
-    _, rows = _serve_rows(spec, state, plan, train=False, axis=axis)
+    _, rows, _, _ = _serve_rows(spec, state, plan, train=False, axis=axis)
     return _reassemble(plan, rows, _out_shape(spec, ids), spec.output_dim,
                        axis, hot=state.hot)
 
@@ -968,29 +1063,33 @@ def _owner_apply(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
     own gradient) and decode + `_apply_unique` run over W slots; in a step
     whose received ids do not fit they run over all S * cap. Either way the
     valid slots keep their source-major order, so the cross-source reduction
-    adds in one order and the result is the same bit for bit. -> (state, the
-    main table's apply load, `_apply_unique`)."""
+    adds in one order and the result is the same bit for bit. Where the
+    serve of this step planned (`plan.owner_plan`), the apply over the same
+    slots takes the plan: the compact branch, or the only one where nothing
+    is compacted; the full-size branch dedups and gathers for itself, as the
+    serve's did. -> (state, the main table's apply load, `_apply_unique`)."""
     view = plan.owner
 
-    def apply(ids, payload):
+    def apply(ids, payload, owner_plan=None):
         rg, rc = decode(payload)
         new, load = _apply_unique(spec, state, optimizer, ids, rg, rc, S,
-                                  packed=packed)
+                                  packed=packed, plan=owner_plan)
         return new.weights, new.slots, \
             None if new.mig is None else (new.mig.weights, new.mig.slots), \
             load
 
-    def full_size():
-        return apply(_flat_recv(plan)[0], recv.reshape(-1, recv.shape[-1]))
+    def all_slots():
+        return _flat_recv(plan)[0], recv.reshape(-1, recv.shape[-1])
 
     if view is None:
-        weights, slots, annex, load = full_size()
+        weights, slots, annex, load = apply(*all_slots(), plan.owner_plan)
     else:
         weights, slots, annex, load = jax.lax.cond(
             view.fits,
             lambda: apply(view.ids, _compact(recv, view.offsets,
-                                             view.valid.shape[0])),
-            _full_size_scope(full_size))
+                                             view.valid.shape[0]),
+                          plan.owner_plan),
+            _full_size_scope(lambda: apply(*all_slots())))
     state = state.replace(weights=weights, slots=slots)
     if annex is not None:
         state = state.replace(mig=state.mig.replace(weights=annex[0],
@@ -1000,7 +1099,7 @@ def _owner_apply(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
 
 def _apply_unique(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
                   rids: jax.Array, rg: jax.Array, rc: jax.Array, S: int,
-                  packed=None):
+                  packed=None, plan: Optional[PackedPlan] = None):
     """Server-side tail of a push: cross-source re-dedup (the MPSC reducer,
     `MpscGradientReducer.h`) + ONE fused optimizer apply per unique row.
     `rids`/`rg`/`rc` are the received flat ids, grads and exact duplicate
@@ -1008,6 +1107,9 @@ def _apply_unique(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
     the annex (this shard is their assigned owner) through the identical
     sparse-apply machinery — the received buffer keeps its source-major
     order, so the per-row reduction is bit-identical to the home shard's.
+    `plan`: what the serve of this step made of the same `rids` (module doc
+    "THE OWNER PLANS ONCE A STEP"): the packed apply then sums `rg` and `rc`
+    over the plan's segments and updates the plan's rows.
     -> (state, the main table's apply load: `ops/sparse.py` "WHAT THE APPLY
     WORKS OVER"; the annex's apply works the same way and is not counted)."""
     with _trace.scope("exchange", "owner_apply"):
@@ -1044,7 +1146,7 @@ def _apply_unique(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
             from ..ops.sparse import sparse_apply_packed_table
             new_packed, load = sparse_apply_packed_table(
                 optimizer, state.weights, packed, spec.output_dim, rows, rg,
-                pre_counts=counts)
+                pre_counts=counts, plan=plan)
             return state.replace(weights=new_packed), load
         weights, slots, load = sparse_apply_dense_table(
             optimizer, state.weights, state.slots, rows, rg, pre_counts=counts,
@@ -1110,12 +1212,16 @@ def grouped_lookup_train(
     capacity_factor: float = 0.0,
     wire: Optional[str] = None,
     load_stats: bool = True,
+    packed_list=None,
 ):
     """Fused training pull for one dim-group. Returns (new_states, outs,
     stats_list, plans) — parallel lists in the input order; feed `plans` to
     `grouped_apply_gradients` for the same batch. `load_stats=False` drops
     the per-shard skew vectors (`exchange_load_stats`) from each table's
-    stats dict."""
+    stats dict. `packed_list`: as `grouped_apply_gradients`'s, the column
+    layout of each table whose shard is in the scan's packed form; such an
+    array table's owner plans its step here and the plan rides `plans` to
+    the apply (module doc "THE OWNER PLANS ONCE A STEP")."""
     from ..ops import wire as wire_mod
     S = jax.lax.axis_size(axis)
     dim = specs[0].output_dim
@@ -1131,16 +1237,20 @@ def grouped_lookup_train(
                                capacity_factor=capacity_factor, hots=hots,
                                migs=[state.mig for state in states])
     fmt = wire_mod.wire_format(wire) if S > 1 else "fp32"
-    new_states, rows_list = [], []
-    for spec, state, plan in zip(specs, states, plans):
+    if packed_list is None:
+        packed_list = [None] * len(specs)
+    new_states, rows_list, planned = [], [], []
+    for spec, state, plan, packed in zip(specs, states, plans, packed_list):
         # narrow formats encode PER TABLE at the owner edge (`_serve_rows`)
         # so each table's error-feedback residuals see their own rows; the
         # encoded widths are uniform across the dim-group, so the concat
         # below still fuses ONE a2a
-        state, rows = _serve_rows(spec, state, plan, train=True, axis=axis,
-                                  fmt=fmt)
+        state, rows, _, owner_plan = _serve_rows(
+            spec, state, plan, train=True, axis=axis, fmt=fmt, packed=packed)
         new_states.append(state)
         rows_list.append(rows)
+        planned.append(plan._replace(owner_plan=owner_plan))
+    plans = planned
     if S == 1:
         outs = [_reassemble(plan, rows, _out_shape(spec, ids),
                             spec.output_dim, axis)
@@ -1233,7 +1343,7 @@ def grouped_apply_gradients(
                 packed_list):
             new, load = _apply_unique(
                 spec, state, opt, plan.uniq.unique_ids, g, rc, S,
-                packed=packed)
+                packed=packed, plan=plan.owner_plan)
             new_states.append(new)
             stats_list.append({"push_overflow": plan.buckets.overflow,
                                **_apply_load_stats(load, axis)})
@@ -1353,9 +1463,11 @@ def grouped_prefetch(
     fmt = wire_mod.wire_format(wire)
     new_states, rows_list, stashed_plans = [], [], []
     for spec, state, plan in zip(specs, states, plans):
-        state, rows, stash = _serve_rows(spec, state, plan, train=True,
-                                         axis=axis, fmt=fmt,
-                                         return_stash=True)
+        # served a step BEFORE the apply that precedes its use writes the
+        # shard: a plan made here would be stale, so per slot and no plan
+        state, rows, stash, _ = _serve_rows(spec, state, plan, train=True,
+                                            axis=axis, fmt=fmt,
+                                            return_stash=True)
         new_states.append(state)
         rows_list.append(rows)
         # the pre-serve EF residuals ride the plan to the conflict patch

@@ -1428,9 +1428,10 @@ class MeshTrainer(Trainer):
     # oelint: hot-path device_get=0
     def tables_pull(self, tables, batch, ps_specs, packed):
         """Sharded pull: 1 id a2a + 1 (optionally quantized) row a2a per
-        DIM-GROUP (`sharded.grouped_lookup_train`).
-        Packed tables need no special pull path — `_serve_rows` self-detects
-        packed rows by width."""
+        DIM-GROUP (`sharded.grouped_lookup_train`). The layouts of the tables
+        the scan holds packed go down with it: the owner of such an array
+        table plans its step at the serve and the plan rides `plans` to
+        `tables_apply` (`sharded.py` "THE OWNER PLANS ONCE A STEP")."""
         self._observe_wire_cost(ps_specs, batch)
         from .sharded import grouped_lookup_train
         pulled_tables, pulled, stats, plans = {}, {}, {}, {}
@@ -1442,7 +1443,8 @@ class MeshTrainer(Trainer):
                 specs, [tables[n] for n in names], ids_list,
                 axis=self.axis, capacity_factor=self.capacity_factor,
                 wire=self.wire_for(names[0]),
-                load_stats=self.shard_stats)
+                load_stats=self.shard_stats,
+                packed_list=[packed.get(n) for n in names])
             for n, ts, out, st, pl in zip(names, new_states, outs,
                                           stats_list, plan_list):
                 pulled_tables[n], pulled[n], plans[n] = ts, out, pl

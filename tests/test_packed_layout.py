@@ -160,40 +160,181 @@ def test_train_many_packed_hash_table_matches_step_loop():
                                       np.asarray(v))
 
 
-def test_mesh_train_many_packed_matches_step_loop():
-    """MeshTrainer's scan packs per shard: jit_train_many (packed, plan-reusing
-    sharded apply) == sequential jit_train_step (split) on the same 8-device
-    mesh — losses and final sharded tables exact."""
-    from openembedding_tpu.parallel import MeshTrainer, make_mesh
+# name -> (devices, wire, what the batches hold). "eight_shards" is the family's
+# first member: DeepFM, no ladder (a small table), the scan against the step
+# loop. The others are the owner's shared plan (`parallel/sharded.py` "THE
+# OWNER PLANS ONCE A STEP") at S = 4 with the ladder engaged: a device has
+# 512 positions, so W = 512 and apply_ladder(512) = (128, 256, 384, 512)
+_MESH_CASES = {
+    "eight_shards": (8, "fp32", "criteo"),
+    "four_shards_compact_fp32": (4, "fp32", "spread"),
+    "four_shards_compact_bf16": (4, "bf16", "spread"),
+    "four_shards_full_size_step": (4, "fp32", "one_owner"),
+    "four_shards_bad_ids_bf16": (4, "bf16", "bad_ids"),
+    # a migration annex and a replicated hot cache beside the shard: their
+    # rows are served and applied apart, the plan leaves them out
+    "four_shards_annex_and_hot": (4, "fp32", "placed"),
+    # error feedback writes `state.ef`, not the weights (against the
+    # plan-less scan alone: the step loop draws the rounding another way)
+    "four_shards_int8_error_feedback": (4, "int8", "spread"),
+    # nothing to compact and no conditional: the plan rides as it is
+    "one_shard": (1, "fp32", "spread"),
+}
+_MB, _MF, _MV, _MK = 256, 8, 4096, 3     # 2,048 positions a step, 512 a device
 
-    V, steps = 4096, 4
-    model = make_deepfm(vocabulary=V, dim=8)
-    mesh = make_mesh()
-    trainer = MeshTrainer(model, embed.Adagrad(learning_rate=0.05), mesh=mesh)
-    batches = list(synthetic_criteo(64, id_space=V, steps=steps, seed=13))
-    stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *batches)
 
-    state = trainer.init(batches[0])
-    many = trainer.jit_train_many(stacked, state)
-    sm, metrics = many(state, stacked)
+def _mesh_batches(holds):
+    """K batches over `_MV` ids. "spread" and "placed": Zipf-like duplicates
+    over every owner; "one_owner": ids that are all shard 0's (multiples of
+    4), 300 and more distinct a device, so that shard receives over W = 512
+    and works full size while the other three receive nothing; "bad_ids":
+    negative, out-of-vocabulary and padding ids among the valid ones."""
+    rng = np.random.default_rng(39)
+    out = []
+    for _ in range(_MK):
+        if holds == "one_owner":
+            ids = 4 * rng.integers(0, _MV // 4, (_MB, _MF))
+        else:
+            ids = (rng.zipf(1.3, (_MB, _MF)) * 7919 + rng.integers(
+                0, 64, (_MB, _MF))) % _MV
+        ids = ids.astype(np.int64)
+        if holds == "bad_ids":
+            bad = rng.random((_MB, _MF))
+            ids = np.where(bad < 0.1, -1, ids)                      # padding
+            ids = np.where((bad >= 0.1) & (bad < 0.2),
+                           -2 - rng.integers(0, 5, ids.shape), ids)
+            ids = np.where((bad >= 0.2) & (bad < 0.3),
+                           _MV + rng.integers(0, 3 * _MV, ids.shape), ids)
+            ids[0, :3] = [_MV, 2**31 - 1, _MV + 3]
+        out.append({"sparse": {"emb": ids}, "dense": None,
+                    "label": rng.integers(0, 2, (_MB,)).astype(np.float32)})
+    return out
 
-    trainer2 = MeshTrainer(model, embed.Adagrad(learning_rate=0.05), mesh=mesh)
-    state2 = trainer2.init(batches[0])
-    step = trainer2.jit_train_step(batches[0], state2)
-    losses = []
-    for b in batches:
-        state2, m = step(state2, b)
-        losses.append(float(m["loss"]))
 
-    np.testing.assert_allclose(np.asarray(metrics["loss"]), losses,
-                               rtol=0, atol=0)
-    (name, spec), = model.ps_specs().items()
+@pytest.mark.parametrize("case", sorted(_MESH_CASES))
+def test_mesh_train_many_packed_matches_step_loop(case, monkeypatch):
+    """MeshTrainer's scan packs per shard: jit_train_many (packed, the owner's
+    serve planning the step and its apply reusing the plan) == sequential
+    jit_train_step (split) on the same mesh — losses and final sharded tables
+    exact. The four-shard cases also hold it to the plan-less scan (the
+    serve per slot, the apply with its own dedup and gather: the program as
+    it was), at both wires, in a step that fits the owner's working size and
+    in one that does not, and watch what the apply is handed beside a plan:
+    counts positive exactly on the slots the plan kept (the others it routed
+    to its sentinel), and a rung that is the plan's."""
+    from openembedding_tpu.ops import sparse
+    from openembedding_tpu.parallel import MeshTrainer, make_mesh, sharded
+
+    shards, wire, holds = _MESH_CASES[case]
+    mesh = make_mesh(jax.devices()[:shards])
+    if holds == "criteo":
+        batches = list(synthetic_criteo(64, id_space=4096, steps=4, seed=13))
+
+        def trainer():
+            return MeshTrainer(make_deepfm(vocabulary=4096, dim=8),
+                               embed.Adagrad(learning_rate=0.05), mesh=mesh)
+    else:
+        monkeypatch.setattr(sparse, "FAST_MEMORY_BYTES", 0)
+        assert apply_ladder(_MB * _MF // 4) == (128, 256, 384, 512)
+        batches = _mesh_batches(holds)
+
+        placed = 16 if holds == "placed" else 0
+
+        def trainer():
+            layer = embed.Embedding(_MV, _PDIM, name="emb")
+            return MeshTrainer(embed.EmbeddingModel(_BagTower(), [layer]),
+                               embed.Adagrad(learning_rate=0.1), seed=2,
+                               mesh=mesh, wire=wire, hot_rows=placed,
+                               mig_rows=placed)
+    stacked = jax.tree_util.tree_map(
+        lambda *xs: np.stack(xs) if xs[0] is not None else None, *batches,
+        is_leaf=lambda x: x is None)
+
+    # what the owner's apply is handed beside a plan, a record a shard a step
+    seen = []
+    real_apply = sparse.sparse_apply_packed_table
+
+    def watched(opt, packed, layout, dim, row_ids, grads, pre_counts=None, *,
+                plan=None):
+        if plan is not None:
+            mask = plan.counts[plan.uniq.inverse] > 0   # the slots it kept
+            ladder = jnp.asarray(apply_ladder(row_ids.shape[0])[:-1])
+            counts = jnp.where(plan.counts > 0,
+                               plan.uniq.segment_reduce(pre_counts), 0)
+            jax.debug.callback(
+                lambda *r: seen.append([np.asarray(x) for x in r]),
+                jnp.all((pre_counts > 0) == mask), jnp.sum(mask),
+                jnp.sum(jnp.sum(counts > 0) > ladder),
+                jnp.sum(jnp.sum(plan.counts > 0) > ladder))
+        return real_apply(opt, packed, layout, dim, row_ids, grads,
+                          pre_counts, plan=plan)
+    monkeypatch.setattr(sparse, "sparse_apply_packed_table", watched)
+
+    def init(tr):
+        state = tr.init(batches[0])
+        if holds == "placed":   # ids of the Zipf head: every batch holds them
+            head = np.unique(batches[0]["sparse"]["emb"])[:24]
+            state = tr.refresh_hot_rows(state, hot_ids={"emb": head[:8]})
+            state = tr.migrate_rows(state, moves={"emb": (
+                head[8:], ((head[8:] + 1) % shards).astype(np.int32))})
+        return state
+
+    def many(tr):
+        state = init(tr)
+        assert tr._packed_layouts(state), "expected a packable table"
+        return tr.jit_train_many(stacked, state)(state, stacked)
+
+    sm, metrics = many(trainer())
+    jax.effects_barrier()
+    assert seen, "no apply took the owner's plan"
+    assert all(same for same, *_ in seen)
+    assert all(apply_rung == plan_rung for _, _, apply_rung, plan_rung in seen)
+    if holds != "criteo":
+        # a shard a step that does not fit makes and takes no plan
+        full_steps = sum(int(v) for v in metrics["owner_full_steps"].values())
+        assert full_steps == (_MK if holds == "one_owner" else 0)
+        assert len(seen) == shards * _MK - full_steps
+        assert (max(n for _, n, *_ in seen) > 0) == (holds != "one_owner")
+
+    trainer2, others = trainer(), []
+    if wire != "int8":
+        state2 = init(trainer2)
+        step = trainer2.jit_train_step(batches[0], state2)
+        losses = []
+        for b in batches:
+            state2, m = step(state2, b)
+            losses.append(np.asarray(m["loss"]))
+        others.append((state2, np.stack(losses)))
+
+    if holds != "criteo":
+        real_serve, before = sharded._serve_rows, len(seen)
+        monkeypatch.setattr(
+            sharded, "_serve_rows",
+            lambda *a, packed=None, **kw: real_serve(*a, **kw))
+        plan_less, pm = many(trainer())
+        jax.effects_barrier()
+        assert len(seen) == before      # no plan made, none taken
+        monkeypatch.setattr(sharded, "_serve_rows", real_serve)
+        others.append((plan_less, np.asarray(pm["loss"])))
+
+    for other, their_losses in others:
+        np.testing.assert_array_equal(np.asarray(metrics["loss"]),
+                                      their_losses)
+        for a, b in zip(jax.tree_util.tree_leaves(sm.tables),
+                        jax.tree_util.tree_leaves(other.tables)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    (name, spec), = trainer2.model.ps_specs().items()
+    # split layout on exit, and a table that moved
     assert sm.tables[name].weights.shape[1] == spec.output_dim
-    np.testing.assert_array_equal(np.asarray(sm.tables[name].weights),
-                                  np.asarray(state2.tables[name].weights))
-    for k, v in state2.tables[name].slots.items():
-        np.testing.assert_array_equal(np.asarray(sm.tables[name].slots[k]),
-                                      np.asarray(v))
+    fresh = init(trainer()).tables[name]
+    assert not np.array_equal(np.asarray(sm.tables[name].weights),
+                              np.asarray(fresh.weights))
+    if holds == "placed":   # ... and so did the annex and the hot cache
+        for moved in ("mig", "hot"):
+            assert not np.array_equal(
+                np.asarray(getattr(sm.tables[name], moved).weights),
+                np.asarray(getattr(fresh, moved).weights))
 
 
 def test_mesh_train_many_packed_hash(tmp_path):

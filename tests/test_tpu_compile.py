@@ -157,33 +157,32 @@ def test_shared_plan_scan_gathers_the_table_once_a_step_and_copies_none(one_chip
                for n, path in gathers), gathers
 
 
-def test_four_chip_scan_has_no_per_slot_op_over_s_x_capacity_rows(topo):
-    """`deepfm9x4.train_zipf`'s program as traced for the 2x2 described
-    chips: 2^27 rows in four shards, 4096 examples a chip, exact mode, so
-    every bucket array of the wire has S x cap = 4 x 106,496 rows. PR 30's
-    scan scattered the ids and the gradient payload into them and gathered
-    the pulled rows out of them slot by slot (3.3 ms of a 28.3 ms step on the
-    chip, PERF.md); the only scatters and gathers of that length left are
-    the owner's, in a step whose received ids do not fit its working size."""
-    import numpy as np
+S4, PER_CHIP = 4, 4096     # `deepfm9x4.train_zipf`: four shards, N positions each
+
+
+def _four_chip_scan(topo, K=2, vocabulary=1 << 27, **kw):
+    """`deepfm9x4.train_zipf`'s program for the 2x2 described chips: 2^27
+    rows in four shards, 4096 examples a chip, exact mode, bf16 wire ->
+    (the jitted K-step scan, its state's and its batches' shapes). Tracing
+    only: the state's shapes come from the CPU mesh (nothing is made: 10.7 GB
+    of table). `kw`: `MeshTrainer`'s and `make_deepfm`'s, by name."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from openembedding_tpu.models import make_deepfm
     from openembedding_tpu.parallel import MeshTrainer, make_mesh
-    S, K, per_chip = 4, 2, 4096
-    B = S * per_chip
+    B = S4 * PER_CHIP
+    model_kw = {k: kw.pop(k) for k in ("hashed", "capacity") if k in kw}
 
     def trainer(devices):
         return MeshTrainer(
-            make_deepfm(vocabulary=1 << 27, dim=9, hidden=(400, 400, 400),
-                        compute_dtype=jnp.bfloat16),
+            make_deepfm(vocabulary=vocabulary, dim=9, hidden=(400, 400, 400),
+                        compute_dtype=jnp.bfloat16, **model_kw),
             embed.Adagrad(learning_rate=0.05), mesh=make_mesh(devices),
-            wire="bf16")
+            wire="bf16", **kw)
     one = {"sparse": {"categorical": np.zeros((B, 26), np.int32)},
            "dense": np.zeros((B, 13), np.float32),
            "label": np.zeros((B,), np.float32)}
-    # the state's shapes from the CPU mesh (nothing is made: 10.7 GB of table)
-    shapes = jax.eval_shape(trainer(jax.devices()[:S]).init, one)
+    shapes = jax.eval_shape(trainer(jax.devices()[:S4]).init, one)
     tr = trainer(topo.devices)
     state = jax.tree_util.tree_map(
         lambda s, p: jax.ShapeDtypeStruct(
@@ -195,7 +194,19 @@ def test_four_chip_scan_has_no_per_slot_op_over_s_x_capacity_rows(topo):
                                        sharding=feed), one)
     many = tr.jit_train_many(jax.tree_util.tree_map(
         lambda x: np.zeros((K,) + x.shape, x.dtype), one), state)
-    rows = S * per_chip * 26
+    return many, state, stacked
+
+
+def test_four_chip_scan_has_no_per_slot_op_over_s_x_capacity_rows(topo):
+    """`deepfm9x4.train_zipf`'s program as traced for the 2x2 described
+    chips: every bucket array of the wire has S x cap = 4 x 106,496 rows.
+    PR 30's scan scattered the ids and the gradient payload into them and
+    gathered the pulled rows out of them slot by slot (3.3 ms of a 28.3 ms
+    step on the chip, PERF.md); the only scatters and gathers of that length
+    left are the owner's, in a step whose received ids do not fit its working
+    size."""
+    many, state, stacked = _four_chip_scan(topo)
+    rows = S4 * N
     sites = guards.primitive_sites(
         many, ("scatter", "scatter-add", "gather"), state, stacked)
     long = [(name, stack) for name, stack, shp in sites
@@ -205,6 +216,79 @@ def test_four_chip_scan_has_no_per_slot_op_over_s_x_capacity_rows(topo):
     a2a = [c for c in guards.collective_sequence(many, state, stacked)
            if c[0] == "all_to_all"]
     assert len(a2a) == 3  # ids, rows, grads: one dim-group, as before
+
+
+def _shard_gathers(many, state, stacked):
+    """(rows gathered, name stack) of every gather the program traces whose
+    operand is two-dimensional and at least 2^20 rows long: a shard."""
+    return sorted((shp[-1][0], stack) for _, stack, shp in
+                  guards.primitive_sites(many, ("gather",), state, stacked)
+                  if len(shp[0]) == 2 and shp[0][0] >= 1 << 20)
+
+
+def test_four_chip_scan_gathers_the_shard_at_the_owners_serve_alone(
+        topo, monkeypatch):
+    """The owner plans once a step (`parallel/sharded.py`): in the cell's
+    scan the only gathers from the 2^25 x 20 packed shard outside
+    `exchange.full_size` are the serve's, one a rung of `apply_ladder(n)`
+    under `exchange.owner_serve/.../sparse.pull` (a step runs one), and none
+    once a received slot; the compact apply gathers nothing from the shard.
+    The full-size branches keep the parent's program: the serve once a slot
+    of S x cap, the apply over its own ladder. Three all-to-alls still, and
+    `exchange.owner_plans{path="shared"}` once for the table."""
+    from openembedding_tpu.utils import metrics
+    monkeypatch.setattr(metrics, "_REGISTRY", {})
+    many, state, stacked = _four_chip_scan(topo)
+    found = _shard_gathers(many, state, stacked)
+    rep = metrics.report()
+    assert rep['exchange.owner_plans{path="shared"}'] == 1
+    assert 'exchange.owner_plans{path="per_slot"}' not in rep
+    fits = [(n, stack) for n, stack in found
+            if "exchange.full_size" not in stack]
+    assert [n for n, _ in fits] == list(apply_ladder(N)), fits
+    assert all("exchange.owner_serve" in stack and "sparse.pull" in stack
+               and "exchange.owner_apply" not in stack
+               and ("sparse.full_size" in stack) == (n == N)
+               for n, stack in fits), fits
+    full = [(n, stack) for n, stack in found if "exchange.full_size" in stack]
+    assert sorted(n for n, stack in full if "owner_serve" in stack) == [S4 * N]
+    assert sorted(n for n, stack in full if "owner_apply" in stack) == \
+        list(apply_ladder(S4 * N))
+    assert len(full) == 1 + len(apply_ladder(S4 * N))
+    a2a = [c for c in guards.collective_sequence(many, state, stacked)
+           if c[0] == "all_to_all"]
+    assert len(a2a) == 3
+
+
+@pytest.mark.parametrize("kw", [
+    dict(pipeline_steps=True),
+    dict(vocabulary=-1, hashed=True, capacity=1 << 27)],
+    ids=["pipelined", "hash_table"])
+def test_four_chip_scan_with_no_apply_to_share_with_serves_per_slot(
+        topo, kw, monkeypatch):
+    """What cannot share a plan keeps the parent's program: a pipelined scan
+    serves step t + 1 before step t's apply writes the shard, a hash table
+    probes per slot. `exchange.owner_plans{path="per_slot"}`, no plan made
+    (`plan_packed_rows` is not traced on the mesh), and the shard gathered
+    from once a received slot (W = n) at the serve and over the apply's
+    ladder at the apply, never at one of its rungs at the serve."""
+    from openembedding_tpu.parallel import sharded
+    from openembedding_tpu.utils import metrics
+    monkeypatch.setattr(metrics, "_REGISTRY", {})
+    monkeypatch.setattr(sharded, "plan_packed_rows", None)   # a call raises
+    many, state, stacked = _four_chip_scan(topo, **kw)
+    found = _shard_gathers(many, state, stacked)
+    rep = metrics.report()
+    assert 'exchange.owner_plans{path="shared"}' not in rep
+    assert rep['exchange.owner_plans{path="per_slot"}'] >= 1
+    fits = [(n, stack) for n, stack in found
+            if "exchange.full_size" not in stack]
+    # (the pipelined scan traces prologue, body and epilogue, and its
+    # conflict patch reads the S x cap received slots again)
+    serve = {n for n, stack in fits if "owner_serve" in stack}
+    assert N in serve and serve <= {N, S4 * N}, fits
+    assert {n for n, stack in fits if "owner_apply" in stack} == \
+        set(apply_ladder(N)), fits
 
 
 # the language-model cells' cores: (B, S, Hq, Hkv, D, Dv); 8,192 at 128 / 128

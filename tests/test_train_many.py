@@ -262,31 +262,40 @@ def test_mesh_window_carries_the_apply_load_beside_owner_fill(
         pytest.approx(float(vec.max()))
 
 
-def test_sparse_pulls_counts_each_table_once_a_trace(fresh_metrics):
-    """`sparse.pulls{path=}`, counted where the pull is traced: the packed
-    scan's array table shares one plan with its apply ("shared"), its hash
-    table probes per position, and so does every table of the un-packed
-    step."""
-    class Tower(nn.Module):
-        @nn.compact
-        def __call__(self, embedded, dense_inputs):
-            x = jnp.concatenate([embedded[k].reshape(embedded[k].shape[0], -1)
-                                 for k in sorted(embedded)], axis=-1)
-            return nn.Dense(1)(x)[:, 0]
+class _ConcatTower(nn.Module):
+    """A dense layer over every table's rows, flattened and concatenated."""
 
-    model = embed.EmbeddingModel(Tower(), [
-        embed.Embedding(VOCAB, 8, name="rows"),
-        embed.Embedding(-1, 8, name="keys", capacity=512)])
-    tr = Trainer(model, embed.Adagrad(learning_rate=0.05), seed=1)
+    @nn.compact
+    def __call__(self, embedded, dense_inputs):
+        x = jnp.concatenate([embedded[k].reshape(embedded[k].shape[0], -1)
+                             for k in sorted(embedded)], axis=-1)
+        return nn.Dense(1)(x)[:, 0]
+
+
+def _two_table_batches():
+    """Two batches for an array table "rows" and a hash table "keys", and
+    the two stacked."""
     rng = np.random.default_rng(0)
     batches = [{"sparse": {"rows": rng.integers(0, VOCAB, (16, 4)).astype(np.int32),
                            "keys": rng.integers(0, 10_000, (16, 4)).astype(np.int64)},
                 "dense": None,
                 "label": rng.integers(0, 2, (16,)).astype(np.float32)}
                for _ in range(2)]
-    stacked = jax.tree_util.tree_map(
+    return batches, jax.tree_util.tree_map(
         lambda *xs: np.stack(xs) if xs[0] is not None else None, *batches,
         is_leaf=lambda x: x is None)
+
+
+def test_sparse_pulls_counts_each_table_once_a_trace(fresh_metrics):
+    """`sparse.pulls{path=}`, counted where the pull is traced: the packed
+    scan's array table shares one plan with its apply ("shared"), its hash
+    table probes per position, and so does every table of the un-packed
+    step."""
+    model = embed.EmbeddingModel(_ConcatTower(), [
+        embed.Embedding(VOCAB, 8, name="rows"),
+        embed.Embedding(-1, 8, name="keys", capacity=512)])
+    tr = Trainer(model, embed.Adagrad(learning_rate=0.05), seed=1)
+    batches, stacked = _two_table_batches()
     state = tr.init(batches[0])
     assert set(tr._packed_layouts(state)) == {"rows", "keys"}
     shared, each = 'sparse.pulls{path="shared"}', 'sparse.pulls{path="per_position"}'
@@ -297,6 +306,62 @@ def test_sparse_pulls_counts_each_table_once_a_trace(fresh_metrics):
     assert (fresh_metrics.report()[shared], fresh_metrics.report()[each]) == (2, 2)
     tr.jit_train_step().lower(state, batches[0])    # the split layout: no plan
     assert (fresh_metrics.report()[shared], fresh_metrics.report()[each]) == (2, 4)
+
+
+def test_owner_plans_counts_each_table_once_a_trace_on_the_mesh(
+        fresh_metrics, monkeypatch):
+    """`exchange.owner_plans{path=}`, counted where the owner's serve decides
+    (`parallel/sharded.py` "THE OWNER PLANS ONCE A STEP"): the packed scan's
+    array table plans at the serve and its apply takes the plan ("shared");
+    its hash table probes per slot; so does every table of the un-packed
+    step, of a pipelined scan (its rows are served a step early: a plan would
+    be stale) and of a read-only pull. No plan is made but on the shared
+    path."""
+    from openembedding_tpu.ops import sparse
+    from openembedding_tpu.parallel import sharded
+
+    def trainer(**kw):
+        model = embed.EmbeddingModel(_ConcatTower(), [
+            embed.Embedding(VOCAB, 8, name="rows"),
+            embed.Embedding(-1, 8, name="keys", capacity=2048)])
+        return MeshTrainer(model, embed.Adagrad(learning_rate=0.05), seed=1,
+                           mesh=make_mesh(jax.devices()[:4]), **kw)
+    batches, stacked = _two_table_batches()
+    plans, taken = [], []
+    real_plan, real_apply = sharded.plan_packed_rows, \
+        sparse.sparse_apply_packed_table
+    monkeypatch.setattr(sharded, "plan_packed_rows", lambda *a: (
+        plans.append(1), real_plan(*a))[1])
+    monkeypatch.setattr(sparse, "sparse_apply_packed_table", lambda *a, **kw: (
+        taken.append(kw.get("plan") is not None), real_apply(*a, **kw))[1])
+
+    def counts():
+        rep = fresh_metrics.report()
+        return tuple(rep.get('exchange.owner_plans{path="%s"}' % path, 0)
+                     for path in ("shared", "per_slot"))
+
+    tr = trainer()
+    state = tr.init(batches[0])
+    assert set(tr._packed_layouts(state)) == {"rows", "keys"}
+    assert counts() == (0, 0)                       # `init` serves nothing
+    tr.jit_train_many(stacked, state).lower(state, stacked)
+    assert counts() == (1, 1)
+    # "rows": its compact apply took the plan, its full-size one and the
+    # hash table's two dedup for themselves
+    assert plans and sorted(taken) == [False, False, False, True]
+    trainer().jit_train_many(stacked, state).lower(state, stacked)
+    assert counts() == (2, 2)                       # a second trace counts again
+    made = len(plans)
+    tr.jit_train_step(batches[0], state).lower(state, batches[0])
+    assert counts() == (2, 4)                       # the split layout: no plan
+    tr.jit_eval_step(batches[0], state).lower(state, batches[0])
+    assert counts() == (2, 6)                       # `_serve_rows(train=False)`
+    del taken[:]
+    piped = trainer(pipeline_steps=True)
+    piped.jit_train_many(stacked, state).lower(state, stacked)
+    shared, per_slot = counts()
+    assert shared == 2 and per_slot > 6             # prologue and body prefetch
+    assert len(plans) == made and taken and not any(taken)
 
 
 # -- the entry point accounts for itself: `jit_train_many()` returns the
