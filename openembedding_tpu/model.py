@@ -148,6 +148,25 @@ def attach_tables(embedded: Dict[str, Any], model: "EmbeddingModel",
     return embedded
 
 
+# Reserved key for modules that declare `takes_labels = True`: {"label": the
+# batch's labels, "weight": its per-sample weight where it carries one}. A
+# module whose loss needs more of its output than fits beside the state (the
+# per-token cross-entropy of several exits through one head, each exit's
+# logits made and dropped in turn: `models/ouro.py`) computes that part where
+# the output is, and its `loss_fn` finishes the loss from what comes out.
+TARGETS_KEY = "__targets__"
+
+
+def attach_targets(embedded: Dict[str, Any], model: "EmbeddingModel",
+                   batch) -> Dict[str, Any]:
+    """Add `embedded[TARGETS_KEY]` iff the module opted in via `takes_labels`."""
+    if getattr(model.module, "takes_labels", False):
+        embedded[TARGETS_KEY] = {
+            k: jnp.asarray(batch[k]) for k in ("label", "weight")
+            if batch.get(k) is not None}
+    return embedded
+
+
 def sad_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
     """Dense-mirrored ('Cache' mode) table gather through `lookup_rows` — the
     ONE implementation of the invalid-id contract (-1 pads and out-of-range
@@ -796,7 +815,7 @@ class Trainer:
             out[TABLES_KEY] = {
                 name: jnp.zeros((spec.input_dim, spec.output_dim), spec.dtype)
                 for name, spec in self.model.sad_specs().items()}
-        return out
+        return attach_targets(out, self.model, batch)
 
     # -- the per-device step (pure; shard_map-able) -------------------------
 
@@ -887,6 +906,7 @@ class Trainer:
             attach_ids(embedded, model, batch)
             attach_tables(embedded, model,
                           dense_params.get("__embeddings__", {}))
+            attach_targets(embedded, model, batch)
             fr_new, module_stats = None, {}
             if train_apply is not None:
                 logits, fr_new = train_apply({"params": dense_params},
@@ -1157,6 +1177,7 @@ class Trainer:
         attach_ids(embedded, model, batch)
         attach_tables(embedded, model,
                       state.dense_params.get("__embeddings__", {}))
+        attach_targets(embedded, model, batch)
         logits = model.module.apply({"params": state.dense_params}, embedded,
                                     batch.get("dense"))
         loss, _, logits = self._loss_terms(logits, batch)

@@ -3,7 +3,7 @@
 Reference coverage (`documents/en/benchmark.md:6-16`, `examples/`,
 `test/benchmark/criteo_deepctr.py`): WDL (Wide&Deep), DeepFM, xDeepFM at dims 9/64,
 the LR subclass example (`examples/criteo_lr_subclass.py`), plus DLRM (the reference's
-PMem paper workload) and a two-tower retrieval model. Four language-model towers
+PMem paper workload) and a two-tower retrieval model. Five language-model towers
 behind a token table ride the same train path: NemotronH (`nemotron_h.py`: Mamba-2,
 attention, routed experts), JoyAI-LLM-Flash (`joyai_flash.py`: latent attention,
 routed SwiGLU experts, a multi-token-prediction module), Solar-Open2
@@ -11,7 +11,9 @@ routed SwiGLU experts, a multi-token-prediction module), Solar-Open2
 without positions, routed SwiGLU experts; a share of the heads held) and ZAYA1
 (`zaya1.py`: compressed convolutional attention, a router MLP whose state
 travels up the stack, top-1 experts, the token table tied to the head and
-trained densely).
+trained densely) and Ouro (`ouro.py`: a dense stack walked several times over
+shared weights as one scanned body, an exit gate a pass, the expected loss over
+the exits through one head, computed inside the walk).
 
 TPU-first layout decision (differs deliberately from the reference's per-feature
 DeepCTR `Embedding` layers): all categorical fields share ONE row-sharded table, with
@@ -33,6 +35,7 @@ from .nemotron_h import NemotronH, make_nemotron_h, softmax_xent
 from .joyai_flash import JoyAIFlash, make_joyai_flash, mtp_xent
 from .solar_open2 import SolarOpen2, kda_chunked, make_solar_open2
 from .zaya1 import Zaya1, make_zaya1
+from .ouro import Ouro, expected_exit_loss, make_ouro
 
 _FAMILIES = {
     "lr": make_lr, "wdl": make_wdl, "deepfm": make_deepfm,
@@ -44,6 +47,7 @@ _FAMILIES = {
     "joyai_flash": make_joyai_flash,
     "solar_open2": make_solar_open2,
     "zaya1": make_zaya1,
+    "ouro": make_ouro,
 }
 
 
@@ -79,5 +83,6 @@ __all__ = [
     "JoyAIFlash", "make_joyai_flash", "mtp_xent",
     "SolarOpen2", "make_solar_open2", "kda_chunked",
     "Zaya1", "make_zaya1",
+    "Ouro", "make_ouro", "expected_exit_loss",
     "CRITEO_NUM_SPARSE", "CRITEO_NUM_DENSE",
 ]

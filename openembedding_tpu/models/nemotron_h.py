@@ -79,14 +79,24 @@ BLOCK_ROWS = 256  # rows of one expert's block in the routed layer's layout
 EXPERT_TILE = 8
 
 
-def xent(logits: jax.Array, labels: jax.Array, weight=None) -> jax.Array:
-    """Mean softmax cross-entropy of (B, S, V) f32 logits against (B, S) ids.
-    `weight` (B,) or (B, S) turns the mean into a weighted mean."""
+def token_xent(logits: jax.Array, labels: jax.Array) -> jax.Array:
+    """Per-token softmax cross-entropy (B, S) of (B, S, V) logits, in f32."""
     logits = logits.astype(jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(
         logits, labels.astype(jnp.int32)[..., None], axis=-1)[..., 0]
-    per = lse - picked
+    return lse - picked
+
+
+def xent(logits: jax.Array, labels: jax.Array, weight=None) -> jax.Array:
+    """Mean softmax cross-entropy of (B, S, V) f32 logits against (B, S) ids.
+    `weight` (B,) or (B, S) turns the mean into a weighted mean."""
+    return weighted_mean(token_xent(logits, labels), weight)
+
+
+def weighted_mean(per: jax.Array, weight=None) -> jax.Array:
+    """The mean of a per-token (B, S) quantity; with `weight` (B,) or (B, S),
+    the weighted mean."""
     if weight is None:
         return jnp.mean(per)
     w = jnp.asarray(weight, per.dtype)

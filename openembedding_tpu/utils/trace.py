@@ -71,7 +71,7 @@ own (`models/nemotron_h.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
 core,out}`, `mlp.dense`, `mtp.{merge,layer,head,loss}`; (`models/
 solar_open2.py`): `kda.{qkv,conv,gates,scan,gate_norm,out}`, `attn.gate`;
 (`models/zaya1.py`): `cca.{project,conv,mean_norm,out}`, `router.mlp` (inside
-`moe.route`).
+`moe.route`); (`models/ouro.py`): `loop.{norm,gate}`.
 """
 
 from __future__ import annotations

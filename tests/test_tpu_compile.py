@@ -29,6 +29,13 @@ GiB of state) fits the chip only because its routed layer GATHERS a block's
 weights: the one-hot pick makes the compiler keep the experts' weights,
 gradients and accumulators a second time, padded from 10 experts to 16
 (20.7 of 15.75 GiB). The whole scan is compiled here at the cell's real sizes.
+
+The Ouro cell's walk (`models/ouro.py`) is ONE scanned body with the head and
+its cross-entropy inside: compiled here at the cell's widths with 2 of its 8
+layers (the whole cell takes 61-108 s in the sandbox, over a tier-1 case's
+room; PERF.md 4 has its numbers, made by hand): three loops whatever the
+passes, the fused core in the body, and no exit's logits stacked over the
+passes.
 """
 
 import os
@@ -386,3 +393,47 @@ def test_solar_open2_scan_fits_the_chip_at_ten_experts(one_chip):
         (state.dense_params, state.tables["token"].weights))) == 966_701_720
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
     assert not re.search(r"f32\[10,(4096,1280|1280,4096)\]\{2,0,1", text)
+
+
+def test_ouro_walk_is_one_scanned_body_that_stacks_no_logits(one_chip):
+    """`benchmark`'s `ouro.train_4k` at its real widths, 2 of its 8 layers
+    (22 s of compile): the 4-step scan holds three loops (the steps, the walk
+    over the four passes, its transpose: an unrolled walk would hold one),
+    the fused core inside the pass, what a pass leaves for the backward pass
+    stacked over the passes as (4, 1, 4096, ...) arrays of the state's and
+    the core's shapes, and NO `f32[.., 4096, 49152]` stacked over them: one
+    exit's logits are alive at a time."""
+    import json
+
+    from openembedding_tpu import models
+    from openembedding_tpu.model import Trainer
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs",
+                        "ouro-2.6b-ut4.json")
+    with open(path) as f:
+        cfg = dict(json.load(f), num_hidden_layers=2)
+    model = models.make_ouro(
+        compute_dtype=jnp.dtype(cfg["tower_dtype"]),
+        **{kw: cfg[key] for key, kw in cfg["make_keywords"].items()})
+    tr = Trainer(model, embed.Adagrad(learning_rate=cfg["learning_rate"],
+                                      initial_accumulator_value=0.1,
+                                      epsilon=1e-7))
+    K, B, S, T = 4, 1, 4096, cfg["total_ut_steps"]
+    sample = {"sparse": {"token": np.zeros((B, S), np.int32)},
+              "label": np.zeros((B, S), np.int32)}
+    with jax.enable_x64(False):  # the cell's own setting; the suite's is on
+        state = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(tr.init, sample))
+        ids = jax.ShapeDtypeStruct((K, B, S), jnp.int32, sharding=one_chip)
+        compiled = tr.jit_train_many().lower(
+            state, {"sparse": {"token": ids}, "label": ids}).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 3
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    stacked = set(re.findall(r"[a-z0-9]+\[%d,[0-9,]+\]" % T, text))
+    assert f"bf16[{T},1,4096,2048]" in stacked, stacked
+    assert not [s for s in stacked if "49152" in s], stacked
+    assert "f32[1,4096,49152]" in text or "f32[4096,49152]" in text
+    # 2 layers' state 2.27 GiB + temporaries 4.96 here; two exits' logits
+    # and their gradients more would be 3 GiB over
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.5 * 2 ** 30
