@@ -22,13 +22,23 @@ predicated per-row on `counts > 0`, and callers guarantee `counts > 0` implies a
 globally-unique row (the dedup in `ops/sparse.py::sparse_apply_dense_table` provides
 uniqueness), so no write ever races another row's write.
 
-MEASURED (v5e-1, `tools/pallas_microbench.py`, 2026-07): XLA's native gather/scatter
-runs this workload at HBM bandwidth already — gather 1.9G rows/s @ dim 64 / 5.1G @ dim
-128, fused XLA apply 1.0G grads/s @ dim 64 (~1 TB/s effective) — while per-row-DMA
-Pallas is HBM-latency-bound (~16M rows/s): random single-row access has no locality
-for DMA to exploit, so **the XLA path IS the TPU-native fast path** and these kernels
-are DEFAULT OFF. They remain available (`OETPU_PALLAS=on`) for lane-aligned tables
-(dim % 128 == 0) and as the scaffold for a future batched-rows variant.
+MEASURED, and what the later records say. `tools/pallas_microbench.py`
+(2026-07, before any line of `PERF_LEDGER.jsonl`) read XLA's gather at 1.9G
+rows/s and per-row-DMA Pallas at about 16M rows/s, and concluded that the XLA
+path is the fast path in both directions. The ledger disagrees for the
+SCATTER: against the 2^22 x 128 packed table XLA's sorted gather issues a slot
+every 9 ns, its sorted, unique scatter a valid row every 93 ns (ledger, PR 40,
+`deepfm64.train_zipf`), and a store-only kernel with a block's row DMAs in
+flight writes one every 16 ns alone and every 9 in that cell's scan (my chip
+runs, PR 41): `ops/pallas_scatter.py`, which `ops/sparse.scatter_rows`
+chooses from the table's shape, no switch.
+The kernels HERE stay DEFAULT OFF behind `OETPU_PALLAS=on`: the ring of
+`SEM_RING` = 8 buys nothing over one semaphore (42.5 ns a row with a ring of 8
+and of 32 alike, my chip run, PR 41: the per-slot wait and branch on the scalar
+core bound it, not the DMAs in flight), Mosaic takes a one-row slice of an HBM
+array only at width exactly 128 of a 4-byte dtype (PERF.md section 6, PR 41),
+and `fused_sparse_apply` halted the core at 2^21 x (128 + 128) with 79,872
+slots (my chip run, PR 41, PERF.md section 7: Design 5's reading).
 
 Mode control: `set_mode("off"|"on"|"interpret")`, env `OETPU_PALLAS`.
 "interpret" runs the Pallas interpreter (CPU tests, `tests/test_pallas.py`).
@@ -37,13 +47,28 @@ Mode control: `set_mode("off"|"on"|"interpret")`, env `OETPU_PALLAS`.
 from __future__ import annotations
 
 import functools
+import importlib
 import os
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+
+class _AtFirstUse:
+    """A module imported when a kernel first reaches into it: Pallas is 0.5-0.9
+    s of import, and `ops/sparse.py` imports THIS module in every program that
+    gathers a row, for `maybe_gather_rows` to say "off"."""
+
+    def __init__(self, name):
+        self._name = name
+
+    def __getattr__(self, attr):
+        return getattr(importlib.import_module(self._name), attr)
+
+
+pl = _AtFirstUse("jax.experimental.pallas")
+pltpu = _AtFirstUse("jax.experimental.pallas.tpu")
 
 _VALID_MODES = ("auto", "on", "off", "interpret")
 

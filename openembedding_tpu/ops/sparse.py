@@ -19,6 +19,7 @@ from typing import Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..utils import metrics as _metrics
 from ..utils import trace as _trace
 from .dedup import UniqueResult, unique_with_counts
 
@@ -67,15 +68,48 @@ def scatter_rows(weights: jax.Array, rows: jax.Array, values: jax.Array,
     `valid=None` means `rows` is already fully routed (invalid entries already
     carry out-of-bounds indices). `sorted_unique`: rows genuinely ascending and
     duplicate-free — TPU scatters serialize without these hints; this is the
-    difference between a vectorized update and a row loop over every slot."""
+    difference between a vectorized update and a row loop over every slot.
+
+    Under that promise a table whose rows are one lane line is written by
+    row DMAs kept in flight where the program is lowered for a TPU
+    (`ops/pallas_scatter.py`; `takes_row_dmas`: the choice is the shape's
+    alone); `sparse.scatters{path=}` counts, once a traced scatter under the
+    promise, 1 on the path the shape chose ("dma" / "xla") and 0 on the other."""
     n_rows = weights.shape[0]
     if valid is None:
         target = rows
     else:
         target = jnp.where(valid, rows, n_rows)  # out of bounds -> dropped
-    return weights.at[target].set(values, mode="drop",
-                                  indices_are_sorted=sorted_unique,
-                                  unique_indices=sorted_unique)
+
+    def xla(weights, target, values):
+        return weights.at[target].set(values, mode="drop",
+                                      indices_are_sorted=sorted_unique,
+                                      unique_indices=sorted_unique)
+
+    if sorted_unique:
+        dma = takes_row_dmas(weights)
+        for path, chosen in (("dma", dma), ("xla", not dma)):
+            _metrics.observe("sparse.scatters", int(chosen), "sum",
+                             labels={"path": path})
+        if dma:
+            # Pallas is half a second of import and more (PERF.md section
+            # 7): paid where such a table first traces its scatter
+            from . import pallas_scatter
+            return jax.lax.platform_dependent(
+                weights, target, values, tpu=pallas_scatter.scatter_rows,
+                default=xla)
+    return xla(weights, target, values)
+
+
+def takes_row_dmas(table: jax.Array) -> bool:
+    """A row of `table` is ONE 128-lane line of 4-byte elements, 512 bytes
+    that lie together in the tiled HBM array: the one-row slice Mosaic takes
+    as a DMA's end. A row of two lines and more, or of 2-byte elements, it
+    refuses ("slice shape along dimension 0 must be aligned to tiling (8)":
+    compiles for the described v5e at widths 256, 512, 8192 and bf16 x 128,
+    PR 41), so the language models' token tables keep XLA's scatter."""
+    return (table.ndim == 2 and table.dtype.itemsize == 4
+            and table.shape[1] == 128)
 
 
 # ---------------------------------------------------------------------------
@@ -89,7 +123,9 @@ def scatter_rows(weights: jax.Array, rows: jax.Array, values: jax.Array,
 # / 1.4 ms = 28 ns a slot, padding slots routed out of bounds included. At
 # packed width 128 (2^22 rows) the scatter moves 6.5x the bytes in 0.6x the
 # time and pays per VALID row: 6.8 ms over 106,496 slots, 6.6 over 79,872, the
-# same ~72k rows in both; gather and row math still pay per slot. Storing
+# same ~72k rows in both (93 ns a row; since PR 41 that table is written by row
+# DMAs kept in flight, 0.67 ms: `scatter_rows`, `ops/pallas_scatter.py`);
+# gather and row math still pay per slot. Storing
 # weights and slots separately pays one gather/scatter pair PER ARRAY;
 # concatenating them column-wise into one (rows, dim+Σslot) array pays ONE
 # pair. The packed form
@@ -401,6 +437,13 @@ def sparse_apply_packed_table(
                     counts = jnp.where(counts > 0,
                                        plan.uniq.segment_reduce(pre_counts), 0)
 
+        n = idx.shape[0]
+        # row DMAs take any number of slots at one price (padding starts
+        # nothing), and a kernel a rung is four to trace and lower: the rungs
+        # hand their new rows on, padded to n as the plan's gather pads its
+        # own, and ONE scatter follows the switch
+        after = takes_row_dmas(packed)
+
         def tail(W, settle):
             rows = (plan.rows[:W] if plan is not None else _gather_rows(
                 packed, idx[:W], sorted_unique=True))  # (W, width) f32
@@ -411,12 +454,18 @@ def sparse_apply_packed_table(
                 off += w
             new_w, new_s = optimizer.apply(rows[:, :dim], s_rows,
                                            g[:W].astype(jnp.float32), counts[:W])
-            table, new_rows = settle((packed, jnp.concatenate(
-                [new_w] + [new_s[name] for name, _ in layout], axis=1)))
-            return scatter_rows(table, idx[:W], new_rows.astype(packed.dtype),
-                                sorted_unique=True)
+            new_rows = jnp.concatenate(
+                [new_w] + [new_s[name] for name, _ in layout],
+                axis=1).astype(packed.dtype)
+            if after:
+                return jnp.pad(new_rows, ((0, n - W), (0, 0)))
+            table, new_rows = settle((packed, new_rows))
+            return scatter_rows(table, idx[:W], new_rows, sorted_unique=True)
 
-        return _over_unique_prefix(counts, packed, tail)
+        out, load = _over_unique_prefix(counts, packed, tail)
+        if after:
+            out = scatter_rows(packed, idx, out, sorted_unique=True)
+        return out, load
 
 
 def sparse_apply_dense_table(

@@ -79,6 +79,14 @@ def one_chip(topo):
     cc.reset_cache()
 
 
+def _row_dma_calls(text, table):
+    """The stage paths of the custom calls that hand back an array of the
+    shape `table` (a regex): `ops/pallas_scatter.py`'s kernel, in place."""
+    return [re.search(r'op_name="([^"]*)"', line).group(1) for line in
+            re.findall(rf"= {table}\S* custom-call\(([^\n]*)", text)
+            if "tpu_custom_call" in line]
+
+
 @pytest.mark.parametrize("rows,dim", [(1 << 25, 10), (1 << 22, 64), (1 << 22, 1)],
                          ids=["dim9_2e25x20", "dim64_2e22x128",
                               "dim64_first_order_2e22x2"])
@@ -87,7 +95,13 @@ def test_apply_ladder_costs_no_table_copy_on_the_tpu(one_chip, rows, dim):
     one conditional, the table updated in place in EVERY branch. The 32 MiB
     first-order table is under `FAST_MEMORY_BYTES`: no conditional, and the
     compiler keeps it in fast memory (`S(1)`) as it did (on the chip that
-    scatter read 4.2 ms there and 6.1 ms through a conditional, PR 29)."""
+    scatter read 4.2 ms there and 6.1 ms through a conditional, PR 29).
+    What writes the rows is the shape's choice (`ops.sparse.scatter_rows`):
+    the 2^22 x 128 table, a lane line a row, takes the row-DMA kernel, ONE
+    custom call under `sparse.apply` after the switch (the rungs hand it
+    their new rows padded to n) and no scatter of XLA's; the tables of width
+    20 and 2 compile to XLA's scatter and to no custom call, the program they
+    had."""
     opt = embed.Adagrad(learning_rate=0.05)
     layout = (("accum", dim),)
 
@@ -104,15 +118,46 @@ def test_apply_ladder_costs_no_table_copy_on_the_tpu(one_chip, rows, dim):
     text = compiled.as_text()
     table = rf"f32\[{rows},{2 * dim}\]"
     scatters = re.findall(rf"= {table}(\S*) fusion\([^\n]*/scatter", text)
+    kernels = _row_dma_calls(text, table)
     if rows * 2 * dim * 4 < FAST_MEMORY_BYTES:
-        assert " conditional(" not in text
+        assert " conditional(" not in text and "tpu_custom_call" not in text
         assert len(scatters) == 1 and "S(1)" in scatters[0]
         return
     assert len(apply_ladder(N)) == 4 and text.count(" conditional(") == 1
-    assert len(scatters) == 4
+    if 2 * dim % 128:
+        assert len(scatters) == 4 and "tpu_custom_call" not in text
+    else:  # ONE kernel, after the switch: the rungs hand it their rows
+        assert not scatters and len(kernels) == 1, (scatters, kernels)
+        # one `cond` in its path, `platform_dependent`'s own: not the switch's
+        assert "sparse.apply/" in kernels[0] and kernels[0].count("cond/") == 1
     copies = re.findall(rf"= {table}\S* (?:copy|copy-start)\(", text)
     assert not copies, f"{len(copies)} table-sized copies in the program"
     assert compiled.memory_analysis().temp_size_in_bytes < rows * 2 * dim * 4 // 8
+
+
+@pytest.mark.parametrize("width,dtype,taken", [
+    (128, jnp.float32, True), (128, jnp.int32, True), (256, jnp.float32, False),
+    (8192, jnp.float32, False), (128, jnp.bfloat16, False)])
+def test_row_dma_kernel_compiles_where_the_rule_sends_it_and_nowhere_else(
+        one_chip, width, dtype, taken):
+    """`ops.sparse.takes_row_dmas` is what Mosaic takes: a one-row slice of
+    the tiled HBM array is a DMA's end at one lane line of 4-byte elements
+    alone; a wider row or a 2-byte one is refused (should a later compiler
+    take them, this fails and the rule can widen)."""
+    from openembedding_tpu.ops import pallas_scatter
+    from openembedding_tpu.ops.sparse import takes_row_dmas
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    assert takes_row_dmas(arg((4096, width), dtype)) == taken
+    lowered = jax.jit(pallas_scatter.scatter_rows, donate_argnums=(0,)).lower(
+        arg((4096, width), dtype), arg((2048,), jnp.int32),
+        arg((2048, width), dtype))
+    if taken:
+        assert "tpu_custom_call" in lowered.compile().as_text()
+    else:
+        with pytest.raises(Exception, match="aligned to tiling"):
+            lowered.compile()
 
 
 def _table_gathers(text, table):
@@ -129,32 +174,38 @@ def _table_gathers(text, table):
     return found
 
 
-def test_shared_plan_scan_gathers_the_table_once_a_step_and_copies_none(one_chip):
-    """`deepfm9.train_zipf`'s scan at its real sizes (2 steps): two
-    conditionals over the four rungs (the pull's gather, the apply's row math
-    and scatter), no table-sized copy in any of the 8 branches, the scatter in
-    place in each of the apply's, and the 2.7 GB table gathered from in the
-    pull's four branches alone (a step runs one): W slots each, n only on the
-    full-size rung, and nowhere once a position."""
+def _deepfm_scan_text(one_chip, rows, dim, K=2, B=4096):
+    """The optimised HLO of a DeepFM cell's K-step scan at its real sizes."""
     from openembedding_tpu.model import Trainer
     from openembedding_tpu.models import make_deepfm
-    rows, B, K = 1 << 25, 4096, 2
-    tr = Trainer(make_deepfm(vocabulary=rows, dim=9, hidden=(400, 400, 400),
+    tr = Trainer(make_deepfm(vocabulary=rows, dim=dim, hidden=(400, 400, 400),
                              compute_dtype=jnp.bfloat16),
                  embed.Adagrad(learning_rate=0.05))
     sample = {"sparse": {"categorical": np.zeros((B, 26), np.int32)},
               "dense": np.zeros((B, 13), np.float32),
               "label": np.zeros((B,), np.float32)}
-    with jax.enable_x64(False):  # the cell's own setting
+    with jax.enable_x64(False):  # the cells' own setting
         state = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
             jax.eval_shape(tr.init, sample))
         stacked = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct((K,) + x.shape, x.dtype,
                                            sharding=one_chip), sample)
-        text = tr.jit_train_many().lower(state, stacked).compile().as_text()
+        return tr.jit_train_many().lower(state, stacked).compile().as_text()
+
+
+def test_shared_plan_scan_gathers_the_table_once_a_step_and_copies_none(one_chip):
+    """`deepfm9.train_zipf`'s scan at its real sizes (2 steps): two
+    conditionals over the four rungs (the pull's gather, the apply's row math
+    and scatter), no table-sized copy in any of the 8 branches, the scatter in
+    place in each of the apply's, and the 2.7 GB table gathered from in the
+    pull's four branches alone (a step runs one): W slots each, n only on the
+    full-size rung, and nowhere once a position. A row of 20 columns is no
+    lane line: XLA's scatter, and no custom call in the program."""
+    rows = 1 << 25
+    text = _deepfm_scan_text(one_chip, rows, 9)
     table = rf"f32\[{rows},20\]"
-    assert text.count(" conditional(") == 2
+    assert text.count(" conditional(") == 2 and "tpu_custom_call" not in text
     assert not re.findall(rf"= {table}\S* (?:copy|copy-start)\(", text)
     assert len(re.findall(rf"= {table}(\S*) fusion\([^\n]*/scatter", text)) == 4
     gathers = sorted(_table_gathers(text, table))
@@ -162,6 +213,29 @@ def test_shared_plan_scan_gathers_the_table_once_a_step_and_copies_none(one_chip
     assert all("sparse.pull/" in path and "sparse.apply" not in path
                and ("sparse.full_size" in path) == (n == N)
                for n, path in gathers), gathers
+
+
+def test_dim64_scan_writes_its_lane_aligned_table_by_row_dmas_in_place(one_chip):
+    """`deepfm64.train_zipf`'s scan at its real sizes (2 steps): the 2^22 x
+    128 packed table is written by `ops/pallas_scatter.py`'s kernel, ONE
+    custom call under `sparse.apply` after the apply's switch and no scatter
+    of XLA's into it, with NO copy of the 2 GiB table inside a step (the
+    custom call is in place as the scatter was); the 2^22 x 2 first-order
+    table keeps XLA's scatter in fast memory."""
+    rows = 1 << 22
+    text = _deepfm_scan_text(one_chip, rows, 64)
+    table, first_order = rf"f32\[{rows},128\]", rf"f32\[{rows},2\]"
+    kernels = _row_dma_calls(text, table)
+    assert len(kernels) == 1 == text.count("tpu_custom_call"), kernels
+    assert "sparse.apply/" in kernels[0], kernels
+    assert not re.findall(rf"= {table}\S* fusion\([^\n]*/scatter", text)
+    # one relayout of the table a DISPATCH at the scan's exit, as the parent's
+    # (the unpack; ROADMAP Speed 8), and none inside a step
+    copies = re.findall(rf"= {table}\S* (?:copy|copy-start)\([^\n]*?"
+                        r'op_name="([^"]*)"', text)
+    assert copies == ["jit(train_many)/while"], copies
+    scatters = re.findall(rf"= {first_order}(\S*) fusion\([^\n]*/scatter", text)
+    assert len(scatters) == 1 and "S(1)" in scatters[0], scatters
 
 
 S4, PER_CHIP = 4, 4096     # `deepfm9x4.train_zipf`: four shards, N positions each
