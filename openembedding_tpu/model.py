@@ -734,7 +734,13 @@ class Trainer:
             sample_batch = self.model.batch_transform(sample_batch)
         embedded = self._fake_embedded(sample_batch)
         dense_inputs = sample_batch.get("dense")
-        variables = self.module_init(key, embedded, dense_inputs)
+        # ONE traced program: called eagerly a tower's init is a dispatch an
+        # operation (66 for a DeepFM, each a compile or a cache read, all of
+        # it under the interpreter's lock: 2.1 of `trainer.init_s`' 2.3 s on
+        # the chip's host, PERF.md section 6, PR 42). The state is the eager
+        # init's to a few roundings (the compiler contracts an initializer's
+        # multiply-add inside a program: `tests/test_compile_account.py`).
+        variables = jax.jit(self.module_init)(key, embedded, dense_inputs)
         params = variables["params"]
         # sparse_as_dense tables live inside dense params under a reserved scope
         sad = {}
@@ -755,7 +761,8 @@ class Trainer:
         return TrainState(
             step=jnp.zeros((), jnp.int32),
             dense_params=params,
-            dense_slots=init_dense_slots(self.optimizer, slots_over),
+            dense_slots=jax.jit(partial(init_dense_slots, self.optimizer))(
+                slots_over),
             tables=tables,
             model_version=jnp.zeros((), jnp.int32),
         )
@@ -792,9 +799,10 @@ class Trainer:
                                       stage_depth=self.offload_stage_depth)
                 self.offload[name] = ot
                 tables[name] = ot.state
-            else:
-                tables[name] = init_table_state(spec, self.opt_for(spec),
-                                                seed=self.seed)
+            else:  # one program a table, as the tower's (`_init_state`)
+                tables[name] = jax.jit(partial(
+                    init_table_state, spec, self.opt_for(spec),
+                    seed=self.seed))()
         return tables
 
     def module_init(self, key, embedded, dense_inputs):
@@ -978,9 +986,9 @@ class Trainer:
         pulled_tables, pulled, stats, plans = {}, {}, {}, {}
         for name, spec in ps_specs.items():
             ids = jnp.asarray(batch["sparse"][spec.feature_name])
-            pull = self._packed_pull if name in packed else self.table_pull
-            pulled_tables[name], pulled[name], pull_stats, plans[name] = \
-                pull(spec, tables[name], ids)
+            pulled_tables[name], pulled[name], pull_stats, plans[name] = (
+                self._packed_pull(spec, tables[name], ids, packed[name])
+                if name in packed else self.table_pull(spec, tables[name], ids))
             for k, v in pull_stats.items():
                 stats[f"{name}/{k}"] = v
         return pulled_tables, pulled, stats, plans
@@ -1207,7 +1215,7 @@ class Trainer:
                 out[name] = lay
         return out
 
-    def _packed_pull(self, spec, table, ids):
+    def _packed_pull(self, spec, table, ids, layout):
         """Pull from the packed layout -> (table, rows, stats, plan). An
         array table plans its step first (`ops/sparse.plan_packed_rows`: the
         apply's dedup, and ONE gather of the unique packed rows at the
@@ -1227,10 +1235,14 @@ class Trainer:
             if spec.use_hash_table:
                 from .tables.hash_table import hash_lookup_train
                 table, rows = hash_lookup_train(table, flat,
-                                                out_dim=spec.output_dim)
+                                                out_dim=spec.output_dim,
+                                                layout=layout)
             else:
-                from .ops.sparse import lookup_rows, plan_packed_rows
-                plan = plan_packed_rows(table.weights, flat)
+                from .ops.sparse import (lookup_rows, packed_width,
+                                         plan_packed_rows)
+                plan = plan_packed_rows(
+                    table.weights, flat,
+                    width=packed_width(spec.output_dim, layout))
                 rows = lookup_rows(plan.rows[:, :spec.output_dim],
                                    plan.uniq.inverse)
             rows = rows.astype(spec.dtype).reshape(out_shape + (spec.output_dim,))

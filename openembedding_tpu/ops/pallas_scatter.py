@@ -26,7 +26,15 @@ and the packed apply calls ONE instance after its switch.
 
 Which tables take it is `ops/sparse.scatter_rows`'s choice, from the shape
 (`takes_row_dmas`): a row has to be one 128-lane line of 4-byte elements, or
-a one-row slice of the tiled HBM array is no DMA Mosaic can describe.
+a one-row slice of the tiled HBM array is no DMA Mosaic can describe. Since
+PR 42 that is dim 64's 2^22 x 128 table AND the narrow tables the scan holds
+four rows a lane line (`ops/sparse.py` "FOUR ROWS A LANE LINE": the dim-9
+cells' 2^25 x 20 as 2^23 x 128), whose apply hands over each slot's merged
+LINE: the targets then never decrease but may repeat, every slot of a run of
+equal targets carrying the same 512 bytes. Nothing here reads more of the
+order than a block's two ends, and two copies of equal bytes to one line
+leave those bytes whichever lands last (`scatter_rows(runs=True)` is the
+promise; XLA's scatter, on the other lowerings, is told another truth there).
 """
 
 from __future__ import annotations
@@ -112,9 +120,10 @@ def _kernel(idx, new_rows, table_in, table, sem, started, *, block, n_rows):
 
 def scatter_rows(table: jax.Array, idx: jax.Array, new_rows: jax.Array, *,
                  block: int = BLOCK, interpret: bool = False) -> jax.Array:
-    """`table.at[idx].set(new_rows, mode="drop")` for `idx` ascending and
-    duplicate-free, in place: slots whose target is out of `[0, R)` (the
-    routed apply's invalid and padding slots) write nothing."""
+    """`table.at[idx].set(new_rows, mode="drop")` for `idx` ascending, in
+    place: duplicate-free, or with equal rows wherever a target repeats;
+    slots whose target is out of `[0, R)` (the routed apply's invalid and
+    padding slots) write nothing."""
     n_rows, n = table.shape[0], idx.shape[0]
     assert block % UNROLL == 0, block
     if n == 0:

@@ -233,6 +233,7 @@ collective, no extra wire bytes, identical bucket shapes.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, NamedTuple, Optional
 
 import jax
@@ -242,7 +243,8 @@ from ..embedding import EmbeddingSpec, EmbeddingTableState, HotRows, MigRows
 from ..ops.dedup import (RoutedBuckets, UniqueResult, bucket_validity,
                          carry_to_unique, compact_blocks, expand_blocks,
                          unique_and_route, unique_with_counts)
-from ..ops.sparse import (PackedPlan, lookup_rows, plan_packed_rows,
+from ..ops.sparse import (PackedPlan, gather_packed_rows, lookup_rows,
+                          packed_rows, packed_width, plan_packed_rows,
                           sparse_apply_dense_table)
 from ..utils import metrics as _metrics
 from ..utils import trace as _trace
@@ -690,7 +692,7 @@ def exchange_load_stats(plan: ExchangePlan, *, axis: str = DATA_AXIS
 def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
                 plan: ExchangePlan, *, train: bool, axis: str,
                 fmt: str = "fp32", return_stash: bool = False,
-                packed=None):
+                packed=None, share: bool = True):
     """Server side of a pull: gather this shard's rows for the received ids
     -> (state, (S, cap, width) rows in `fmt`, stash, owner plan).
     With a migration directory, received MIGRATED ids (the indirection routed
@@ -714,12 +716,14 @@ def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
     no EF ran) — `grouped_conflict_patch` replays it against the post-apply
     weights to reproduce exactly what a serial serve would have shipped.
 
-    `packed`: the column layout of an array table whose shard is in the scan's
-    packed form, given by a caller whose apply of the SAME step follows with
-    nothing written to the shard between (`grouped_lookup_train`). The serve
-    then plans the step (module doc "THE OWNER PLANS ONCE A STEP") and the
-    fourth value is the `PackedPlan` for `_owner_apply`; None otherwise, and
-    for a hash table, which probes per slot either way.
+    `packed`: the column layout of a table whose shard is in the scan's
+    packed form (by it a shard held four rows a lane line is known,
+    `ops/sparse.in_lines`). `share`: the caller's apply of the SAME step
+    follows with nothing written to the shard between
+    (`grouped_lookup_train`): the serve of a packed array table then plans
+    the step (module doc "THE OWNER PLANS ONCE A STEP") and the fourth value
+    is the `PackedPlan` for `_owner_apply`; None otherwise (the pipelined
+    prefetch), and for a hash table, which probes per slot either way.
     `exchange.owner_plans{path=}` counts which, once a table a trace.
 
     Where the plan holds an `OwnerView` the work (`_serve_flat`) runs over its
@@ -727,7 +731,7 @@ def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
     layout by S masked block copies (`_expand`); in a step whose received ids
     do not fit, the same function runs over all S * cap slots, per slot and
     with no plan (module doc "WHAT THE OWNER WORKS OVER")."""
-    share = packed is not None and not spec.use_hash_table
+    share = share and packed is not None and not spec.use_hash_table
     _metrics.observe("exchange.owner_plans", 1, "sum",
                      labels={"path": "shared" if share else "per_slot"})
     with _trace.scope("exchange", "owner_serve"):
@@ -737,7 +741,7 @@ def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
         def serve(ids, valid, share):
             return _serve_flat(spec, state, ids, valid, S, train=train,
                                fmt=fmt, return_stash=return_stash,
-                               share=share)
+                               packed=packed, share=share)
 
         if view is None:
             writes, rows, stash, owner_plan = serve(*_flat_recv(plan), share)
@@ -756,7 +760,9 @@ def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
                 # the parent's program; both conditionals branch on the same
                 # `view.fits`, so the apply never reads this step's plan
                 return serve(*_flat_recv(plan), False)[:3] + (
-                    _no_plan(state.weights, view) if share else None,)
+                    _no_plan(state.weights, view,
+                             _width(spec, state.weights, packed))
+                    if share else None,)
             writes, rows, stash, owner_plan = jax.lax.cond(
                 view.fits, compact, _full_size_scope(full_size))
         if spec.use_hash_table and train:
@@ -771,13 +777,28 @@ def _serve_rows(spec: EmbeddingSpec, state: EmbeddingTableState,
         return state, rows, stash, owner_plan
 
 
-def _no_plan(packed: jax.Array, view: OwnerView) -> PackedPlan:
+def _width(spec: EmbeddingSpec, weights: jax.Array, packed) -> Optional[int]:
+    """Columns of a row of the shard `weights` in the scan's packed form
+    under the layout `packed`; None where the shard is not packed. A packed
+    shard read with no layout raises: its form is known by the layout alone
+    (`ops/sparse.in_lines`), and read as split it would be wrong rows."""
+    if packed is not None:
+        return packed_width(spec.output_dim, packed)
+    if weights.shape[1] != spec.output_dim:
+        raise ValueError(
+            f"table {spec.name!r}: a shard of {weights.shape[1]} columns for "
+            f"dim {spec.output_dim} is packed and is read with its layout "
+            "(packed= / packed_list=)")
+    return None
+
+
+def _no_plan(packed: jax.Array, view: OwnerView, width: int) -> PackedPlan:
     """Zeros in the shapes of the plan that the compact serve makes of
     `view`: what the full-size branch of the serve's conditional returns in
     its place (a conditional's branches return one shape) and the apply's
     full-size branch never reads."""
     like = jax.ShapeDtypeStruct
-    shapes = jax.eval_shape(plan_packed_rows,
+    shapes = jax.eval_shape(functools.partial(plan_packed_rows, width=width),
                             like(packed.shape, packed.dtype),
                             like(view.ids.shape, view.ids.dtype))
     return jax.tree_util.tree_map(lambda x: jnp.zeros(x.shape, x.dtype),
@@ -796,7 +817,8 @@ def _full_size_scope(fn):
 
 def _serve_flat(spec: EmbeddingSpec, state: EmbeddingTableState,
                 flat_recv: jax.Array, flat_valid: jax.Array, S: int, *,
-                train: bool, fmt: str, return_stash: bool, share: bool):
+                train: bool, fmt: str, return_stash: bool, packed,
+                share: bool):
     """`_serve_rows` over one flat run of received slots, whatever its length
     (the compacted view or the whole receive buffer); no collective. ->
     (the state fields the serve wrote: `keys`/`overflow` on a hash insert,
@@ -823,7 +845,8 @@ def _serve_flat(spec: EmbeddingSpec, state: EmbeddingTableState,
         if train:
             from ..tables.hash_table import hash_lookup_train
             inserted, rows = hash_lookup_train(state, probe,
-                                               out_dim=spec.output_dim)
+                                               out_dim=spec.output_dim,
+                                               layout=packed)
             writes.update(keys=inserted.keys, overflow=inserted.overflow)
             if need_ef:
                 # post-insert probe: the residual lives at the row's slot
@@ -845,17 +868,17 @@ def _serve_flat(spec: EmbeddingSpec, state: EmbeddingTableState,
             # the apply zeroes their counts) are the rows at -1: no mask to
             # sum, the plan's counts are the dedup's own
             with _trace.scope("sparse", "pull"):
-                owner_plan = plan_packed_rows(state.weights, local_rows)
+                owner_plan = plan_packed_rows(
+                    state.weights, local_rows,
+                    width=_width(spec, state.weights, packed))
                 rows = lookup_rows(owner_plan.rows[:, :spec.output_dim],
                                    owner_plan.uniq.inverse)
         else:
-            rows = lookup_rows(state.weights, local_rows)
-            if rows.shape[1] != spec.output_dim:
-                # packed weights+slots layout inside train_many's scan
-                # (`ops/sparse.packed_layout`), served with no apply of the
-                # same step to share with (the pipelined prefetch): the full
-                # packed row once a slot, the weight columns sliced out
-                rows = rows[:, :spec.output_dim]
+            # in the packed weights+slots layout inside train_many's scan
+            # (`ops/sparse.packed_layout`), served with no apply of the same
+            # step to share with (the pipelined prefetch): the full packed
+            # row once a slot, the weight columns sliced out
+            rows = _weight_rows(spec, state.weights, local_rows, packed)
         if need_ef:
             ef_idx = jnp.where(main_valid, flat_recv // S,
                                state.ef.shape[0]).astype(jnp.int32)
@@ -1140,7 +1163,8 @@ def _apply_unique(spec: EmbeddingSpec, state: EmbeddingTableState, optimizer,
             pre_counts = jnp.where((slot < capacity) & (rc > 0), rc, 0)
             rows, counts = jnp.clip(slot, 0, capacity), pre_counts
         else:
-            rows = jnp.where(rc > 0, rids // S, state.weights.shape[0])
+            rows = jnp.where(rc > 0, rids // S,
+                             _shard_rows(spec, state.weights, packed))
             counts = rc
         if packed is not None:
             from ..ops.sparse import sparse_apply_packed_table
@@ -1426,6 +1450,7 @@ def grouped_prefetch(
     capacity_factor: float = 0.0,
     wire: Optional[str] = None,
     load_stats: bool = True,
+    packed_list=None,
 ):
     """Id plane + speculative weight plane of a fused training pull for one
     dim-group, WITHOUT the client tail (`grouped_finalize_pull` runs that at
@@ -1445,9 +1470,12 @@ def grouped_prefetch(
     Returns (new_states, plans, uniq_rows_list, stats_list):
     `uniq_rows_list` holds each table's decoded per-UNIQUE-slot rows
     (n, dim) float32 — speculative until patched, hot slots zero until the
-    finalize overlay."""
+    finalize overlay. `packed_list`: as `grouped_lookup_train`'s, the
+    column layout of each table whose shard is in the scan's packed form."""
     from ..ops import wire as wire_mod
     S = jax.lax.axis_size(axis)
+    if packed_list is None:
+        packed_list = [None] * len(specs)
     if S == 1:
         raise ValueError(
             "grouped_prefetch needs S >= 2: the pipelined loop has nothing "
@@ -1462,12 +1490,13 @@ def grouped_prefetch(
                                migs=[state.mig for state in states])
     fmt = wire_mod.wire_format(wire)
     new_states, rows_list, stashed_plans = [], [], []
-    for spec, state, plan in zip(specs, states, plans):
+    for spec, state, plan, packed in zip(specs, states, plans, packed_list):
         # served a step BEFORE the apply that precedes its use writes the
         # shard: a plan made here would be stale, so per slot and no plan
         state, rows, stash, _ = _serve_rows(spec, state, plan, train=True,
                                             axis=axis, fmt=fmt,
-                                            return_stash=True)
+                                            return_stash=True, packed=packed,
+                                            share=False)
         new_states.append(state)
         rows_list.append(rows)
         # the pre-serve EF residuals ride the plan to the conflict patch
@@ -1521,13 +1550,33 @@ def grouped_finalize_pull(specs, states, ids_list, plans, uniq_rows_list):
         return outs
 
 
+def _shard_rows(spec: EmbeddingSpec, weights: jax.Array, packed) -> int:
+    """Rows of a shard's main table, packed under the layout `packed` (in
+    either form, `ops/sparse.packed_rows`) or not (None)."""
+    width = _width(spec, weights, packed)
+    return weights.shape[0] if width is None else packed_rows(weights, width)
+
+
+def _weight_rows(spec: EmbeddingSpec, weights: jax.Array, idx: jax.Array,
+                 packed) -> jax.Array:
+    """`lookup_rows` of a shard's main table once a slot -> (m, dim): of a
+    table the scan holds packed (`packed`: its layout; in either form) the
+    whole packed row is read and the weight columns sliced out."""
+    width = _width(spec, weights, packed)
+    if width is None:
+        return lookup_rows(weights, idx)
+    with _trace.scope("sparse", "pull"):
+        rows = gather_packed_rows(weights, width, idx)
+    return rows[:, :spec.output_dim]
+
+
 def _gather_rows_readonly(spec: EmbeddingSpec, state: EmbeddingTableState,
                           flat_recv: jax.Array, flat_valid: jax.Array,
-                          S: int, *, want_ef_idx: bool = False):
+                          S: int, *, want_ef_idx: bool = False, packed=None):
     """Row gather for ids this shard serves, strictly read-only: no hash
     insert (the prefetch already inserted every patched id), no
     error-feedback side effects. Mig-annex-aware exactly like `_serve_rows`;
-    packed train_many layouts slice the weight columns out. -> (n, dim) in
+    packed train_many layouts (`packed`) slice the weight columns out. -> (n, dim) in
     the table's storage dtype, plus (with `want_ef_idx`) each row's index
     into `state.ef` — the SAME index `_serve_rows` computes (OOB for
     invalid/annex rows), so the conflict patch's replay writes exactly the
@@ -1551,20 +1600,17 @@ def _gather_rows_readonly(spec: EmbeddingSpec, state: EmbeddingTableState,
             capacity = state.keys.shape[0]
             slot = hash_find(state.keys, probe)
             idx = jnp.where((slot < capacity) & main_valid, slot, capacity)
-            rows = lookup_rows(state.weights, idx)
+            rows = _weight_rows(spec, state.weights, idx, packed)
             if want_ef_idx:
                 ef_idx = idx
         else:
             idx = jnp.where(main_valid, flat_recv // S, -1)
-            rows = lookup_rows(state.weights, idx)
+            rows = _weight_rows(spec, state.weights, idx, packed)
             if want_ef_idx:
                 N = state.ef.shape[0] if state.ef is not None \
-                    else state.weights.shape[0]
+                    else _shard_rows(spec, state.weights, packed)
                 ef_idx = jnp.where(main_valid, flat_recv // S,
                                    N).astype(jnp.int32)
-        if rows.shape[1] != spec.output_dim:
-            # packed weights+slots layout inside train_many's scan
-            rows = rows[:, :spec.output_dim]
         if m_found is not None:
             M = mig.weights.shape[0]
             arows = lookup_rows(mig.weights, jnp.where(m_found, m_rank, M))
@@ -1583,6 +1629,7 @@ def grouped_conflict_patch(
     axis: str = DATA_AXIS,
     conflict_factor: float = 0.0,
     wire: Optional[str] = None,
+    packed_list=None,
 ):
     """Repair a dim-group's speculatively prefetched rows after the previous
     batch's push. Every row that push touched on this shard is exactly a
@@ -1604,14 +1651,18 @@ def grouped_conflict_patch(
     `conflict_rows` (this source's compacted patch rows — psum to the step
     total) and `conflict_overflow` (members dropped by the pcap budget;
     those rows keep their one-step-stale value); `new_states` carries the
-    replayed EF residuals (the input states unchanged otherwise)."""
+    replayed EF residuals (the input states unchanged otherwise).
+    `packed_list`: as `grouped_prefetch`'s."""
     from ..ops import wire as wire_mod
     from ..ops.dedup import compact_member_slots, member_mask
     S = jax.lax.axis_size(axis)
     dim = specs[0].output_dim
     fmt = wire_mod.wire_format(wire)
+    if packed_list is None:
+        packed_list = [None] * len(specs)
     payloads, metas, new_states = [], [], []
-    for spec, state, pplan, plan in zip(specs, states, prev_plans, plans):
+    for spec, state, pplan, plan, packed in zip(specs, states, prev_plans,
+                                                plans, packed_list):
         cap = plan.cap
         pcap = conflict_patch_cap(cap, conflict_factor)
         pair = plan.recv_ids.ndim == 3
@@ -1631,7 +1682,8 @@ def grouped_conflict_patch(
                    and plan.ef_stash is not None)
         if want_ef:
             rows, ef_idx = _gather_rows_readonly(
-                spec, state, flat_ids, live, S, want_ef_idx=True)
+                spec, state, flat_ids, live, S, want_ef_idx=True,
+                packed=packed)
             # x' = post-apply weights + the residual the speculative serve
             # consumed (stash zeros for annex rows — no EF there, like the
             # serve); non-live compaction padding masks to zero and its
@@ -1649,7 +1701,8 @@ def grouped_conflict_patch(
                     (slots + 1).reshape(-1).astype(jnp.int32), fmt)],
                 axis=1)
         else:
-            rows = _gather_rows_readonly(spec, state, flat_ids, live, S)
+            rows = _gather_rows_readonly(spec, state, flat_ids, live, S,
+                                         packed=packed)
             payload = wire_mod.encode_grads(
                 rows.astype(jnp.float32),
                 (slots + 1).reshape(-1).astype(jnp.int32), fmt)

@@ -1486,7 +1486,7 @@ class MeshTrainer(Trainer):
         return self.pipeline_steps and self.num_shards > 1
 
     # oelint: hot-path device_get=0
-    def _pipeline_prefetch(self, tables, batch, ps_specs):
+    def _pipeline_prefetch(self, tables, batch, ps_specs, packed):
         """Issue a batch's exchange a FULL STEP ahead: id plane (dedup/sort/
         route + id a2a) and the speculative row gather
         (`sharded.grouped_prefetch`). Returns (new_tables, plans, rows,
@@ -1504,7 +1504,8 @@ class MeshTrainer(Trainer):
                     specs, [tables[n] for n in names], ids_list,
                     axis=self.axis, capacity_factor=self.capacity_factor,
                     wire=self.wire_for(names[0]),
-                    load_stats=self.shard_stats)
+                    load_stats=self.shard_stats,
+                    packed_list=[packed.get(n) for n in names])
                 for n, ts, pl, rw, st in zip(names, states, plan_list,
                                              rows_list, stats_list):
                     new_tables[n], plans[n], rows[n] = ts, pl, rw
@@ -1531,7 +1532,8 @@ class MeshTrainer(Trainer):
         return pulled
 
     # oelint: hot-path device_get=0
-    def _pipeline_patch(self, ps_specs, tables, prev_plans, plans, rows):
+    def _pipeline_patch(self, ps_specs, tables, prev_plans, plans, rows,
+                        packed):
         """Repair the next batch's speculative rows against what this batch's
         apply just wrote (`sharded.grouped_conflict_patch`). Returns
         (patched_rows, new_tables, {name: conflict_rows psum},
@@ -1551,7 +1553,8 @@ class MeshTrainer(Trainer):
                     [plans[n] for n in names],
                     [rows[n] for n in names], axis=self.axis,
                     conflict_factor=self.conflict_factor,
-                    wire=self.wire_for(names[0]))
+                    wire=self.wire_for(names[0]),
+                    packed_list=[packed.get(n) for n in names])
                 for n, out, st, ts in zip(names, outs, stats_list, states):
                     patched[n] = out
                     new_tables[n] = ts
@@ -1660,7 +1663,7 @@ class MeshTrainer(Trainer):
         # it under yet); its pull stats contribute only overflow
         b0 = transform(batch_at(0))
         tables, plans0, rows0, pf_stats = self._pipeline_prefetch(
-            state.tables, b0, ps_specs)
+            state.tables, b0, ps_specs, layouts)
         state = state.replace(tables=tables)
         # ... and what its owners counted, on the same psum
         total_oflow, owner0 = jax.lax.psum(
@@ -1682,7 +1685,7 @@ class MeshTrainer(Trainer):
             # (1) batch t+1's exchange FIRST: no data dependency on batch
             # t's grads, so its collectives are free to overlap the compute
             tables, plans_n, rows_n, pf_stats = self._pipeline_prefetch(
-                state.tables, bn, ps_specs)
+                state.tables, bn, ps_specs, layouts)
             state = state.replace(tables=tables)
             # (2) consume the carried prefetch as batch t's pull
             plans_t = {n: plan_from_carry(pre[n]["plan"], *statics[n])
@@ -1697,7 +1700,7 @@ class MeshTrainer(Trainer):
             # (4) repair batch t+1's speculative rows post-apply; narrow
             # wire also rewrites the replayed error-feedback residuals
             patched, patch_tables, conflict, coflow = self._pipeline_patch(
-                ps_specs, state.tables, plans_t, plans_n, rows_n)
+                ps_specs, state.tables, plans_t, plans_n, rows_n, layouts)
             state = state.replace(tables=patch_tables)
             oflow = stats_overflow(metrics.get("stats", {}))
             pre_n = {n: {"plan": plan_carry(plans_n[n]), "rows": patched[n]}
@@ -1922,7 +1925,8 @@ class MeshTrainer(Trainer):
 
         metrics_spec = {"loss": P(), "overflow": P(),
                         "owner_fill": P(), "owner_full_steps": P(),
-                        "apply_fill": P(), "apply_full_steps": P()}
+                        "apply_fill": P(), "apply_full_steps": P(),
+                        "line_mates": P()}
         if self._pipeline_on():
             # the pipelined window reports two extra replicated counters;
             # the serial branch keeps EXACTLY the round-17 spec dict (the

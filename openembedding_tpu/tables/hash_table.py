@@ -342,7 +342,8 @@ def hash_lookup(state, ids: jax.Array) -> jax.Array:
         return jnp.where(hit[:, None], rows, jnp.zeros_like(rows))
 
 
-def hash_lookup_train(state, ids: jax.Array, out_dim: int = None):
+def hash_lookup_train(state, ids: jax.Array, out_dim: int = None,
+                      layout=None):
     """Training pull: inserts unseen ids (their slots already carry initializer values)
     and returns (new_state, rows). Mirrors the reference's lazy-init pull
     (`EmbeddingOptimizerVariable.h:242-266`).
@@ -350,7 +351,9 @@ def hash_lookup_train(state, ids: jax.Array, out_dim: int = None):
     `out_dim`: when the state holds the PACKED weights+slots layout
     (`ops/sparse.packed_layout`, inside `Trainer.train_many`'s scan), slice
     the weight columns out of the gathered packed rows — the gather is
-    latency-bound, the slot bytes ride free."""
+    latency-bound, the slot bytes ride free. `layout`: that packed form's
+    column layout, owed with it: by it a table held four rows a lane line is
+    known (`ops/sparse.in_lines`)."""
     with _trace.scope("sparse", "pull"):
         from ..ops.dedup import unique_with_counts
 
@@ -366,7 +369,19 @@ def hash_lookup_train(state, ids: jax.Array, out_dim: int = None):
         slot = uslot[uniq.inverse]
         capacity = state.keys.shape[0]
         hit = slot < capacity
-        rows = jnp.take(state.weights, jnp.clip(slot, 0, capacity - 1), axis=0)
+        at = jnp.clip(slot, 0, capacity - 1)
+        if layout is None:
+            if out_dim is not None and state.weights.shape[1] != out_dim:
+                raise ValueError(
+                    "hash_lookup_train: the state's weights have "
+                    f"{state.weights.shape[1]} columns for out_dim {out_dim}: "
+                    "a packed table is read with its layout= (its form is "
+                    "known by it alone)")
+            rows = jnp.take(state.weights, at, axis=0)
+        else:
+            from ..ops.sparse import gather_packed_rows, packed_width
+            rows = gather_packed_rows(
+                state.weights, packed_width(out_dim, layout), at)
         if out_dim is not None and rows.shape[1] != out_dim:
             rows = rows[:, :out_dim]
         rows = jnp.where(hit[:, None], rows, jnp.zeros_like(rows))

@@ -427,11 +427,12 @@ _SHARD_STATS = ("shard_rows", "shard_positions", "bucket_fill")
 # over the apply's unique buffer and whether the apply ran its last rung
 # (`ops/sparse.py` "WHAT THE APPLY WORKS OVER"; a scalar on one device)
 OWNER_STATS = ("owner_fill", "owner_full_steps")
-APPLY_STATS = ("apply_fill", "apply_full_steps")
+APPLY_STATS = ("apply_fill", "apply_full_steps", "line_mates")
 _TABLE_SERIES = {"owner_fill": ("exchange.owner_fill", "gauge"),
                  "owner_full_steps": ("exchange.owner_full_steps", "sum"),
                  "apply_fill": ("sparse.apply_fill", "gauge"),
-                 "apply_full_steps": ("sparse.apply_full_steps", "sum")}
+                 "apply_full_steps": ("sparse.apply_full_steps", "sum"),
+                 "line_mates": ("sparse.line_mates", "gauge")}
 
 
 def _fold_shard_stat(var: str, stat: str, vec) -> None:

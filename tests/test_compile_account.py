@@ -113,6 +113,38 @@ def test_a_second_signature_is_a_second_executable_of_one_trace():
     assert rep["trainer.dispatch.ms"] > 0 and rep["trainer.dispatch.ms.p50"] > 0
 
 
+@pytest.mark.parametrize("dim", [9, 64])
+def test_init_is_a_program_a_part_and_the_op_by_op_inits_state(dim):
+    """`Trainer.init` runs the tower's init, each table's init and the dense
+    slots as ONE jitted program each (PR 42: a DeepFM's op-by-op init was 66
+    dispatches, 2.1 s of set-up on the chip's host), so few executables; the
+    state is the op-by-op init's (`jax.disable_jit`): every integer leaf
+    equal, every float within a few roundings of the leaf's largest value (the
+    compiler may contract an initializer's multiply-adds inside a program:
+    on the CPU about half of a kernel's weights differ by 1e-8..1e-7)."""
+    B = 8
+    one = {"sparse": {"categorical": np.zeros((B, 26), np.int32)},
+           "dense": np.zeros((B, 13), np.float32),
+           "label": np.zeros((B,), np.float32)}
+    tr = embed.Trainer(make_deepfm(vocabulary=512, dim=dim, hidden=(8, 8)),
+                       embed.Adagrad(learning_rate=0.05))
+    state = tr.init(one)
+    # the tower, each table, the dense slots and a few conversions around them
+    # (8 and 9 here; op by op the same init compiles 60 and more)
+    assert 0 < _series("init")["executables"] <= 12, _series("init")
+    with jax.disable_jit():
+        eager = tr.init(one)
+    for a, b in zip(jax.tree_util.tree_leaves(state),
+                    jax.tree_util.tree_leaves(eager)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape and a.dtype == b.dtype
+        if a.dtype.kind == "f" and a.size:
+            np.testing.assert_allclose(
+                a, b, rtol=0, atol=4 * np.finfo(a.dtype).eps * np.abs(b).max())
+        else:
+            np.testing.assert_array_equal(a, b)
+
+
 def test_an_event_outside_any_entry_point_is_jaxs_function_name_or_other():
     def lonely_function(x):
         return x * 5 - 1

@@ -310,7 +310,7 @@ def test_mesh_train_many_packed_matches_step_loop(case, monkeypatch):
         real_serve, before = sharded._serve_rows, len(seen)
         monkeypatch.setattr(
             sharded, "_serve_rows",
-            lambda *a, packed=None, **kw: real_serve(*a, **kw))
+            lambda *a, **kw: real_serve(*a, **{**kw, "share": False}))
         plan_less, pm = many(trainer())
         jax.effects_barrier()
         assert len(seen) == before      # no plan made, none taken
@@ -453,16 +453,19 @@ def test_packed_scan_compiles_one_scatter_per_table(ladder):
 
     txt = compiled.as_text()
     packed = _count_table_scatters(txt, f"{V},20")
+    lines = _count_table_scatters(txt, f"{V // 4},128")
     split = _count_table_scatters(txt, f"{V},10")
     assert ladder in (1, 4)
-    assert packed == ladder, \
-        f"expected {ladder} packed-table scatter(s), found {packed}"
+    # a table that takes the ladder is held four rows a lane line
+    # (`ops.sparse.takes_lines`) and written ONCE, after the switch
+    assert (packed, lines) == ((1, 0) if ladder == 1 else (0, 1)), \
+        f"packed-table scatters: {packed} of rows, {lines} of lines"
     assert (" conditional(" in txt) == (ladder > 1)
     assert split == 0, f"split-layout scatters reappeared: {split}"
 
     ma = compiled.memory_analysis()
     if ma is not None:  # backend-dependent
-        packed_bytes = V * 20 * 4
+        packed_bytes = V * (20 if ladder == 1 else 32) * 4
         assert ma.temp_size_in_bytes < 3 * packed_bytes, (
             f"temps {ma.temp_size_in_bytes} suggest an extra table copy "
             f"inside the scan (packed table is {packed_bytes})")
@@ -728,10 +731,13 @@ class _PerPositionTrainer(Trainer):
     """The packed pull as it was: the full packed row once a position, no
     plan, so `_packed_apply` calls `sparse_apply_packed_table` without one."""
 
-    def _packed_pull(self, spec, table, ids):
-        from openembedding_tpu.ops.sparse import lookup_rows
-        rows = lookup_rows(table.weights, ids.reshape(-1))[:, :spec.output_dim]
-        return table, rows.astype(spec.dtype).reshape(
+    def _packed_pull(self, spec, table, ids, layout):
+        from openembedding_tpu.ops.sparse import (gather_packed_rows,
+                                                  packed_width)
+        rows = gather_packed_rows(
+            table.weights, packed_width(spec.output_dim, layout),
+            ids.reshape(-1))
+        return table, rows[:, :spec.output_dim].astype(spec.dtype).reshape(
             ids.shape + (spec.output_dim,)), {}, None
 
 
@@ -829,7 +835,8 @@ def test_plan_and_apply_take_positions_to_leave_out_and_multiplicities():
     g = jnp.asarray(rng.standard_normal((n, _PDIM)), jnp.float32)
 
     def planned(p):
-        plan = plan_packed_rows(p, ids, (pre > 0).astype(jnp.int32))
+        plan = plan_packed_rows(p, ids, (pre > 0).astype(jnp.int32),
+                                width=packed.shape[1])
         return sparse_apply_packed_table(opt, p, lay, _PDIM, ids, g, pre,
                                          plan=plan)
     want, load = jax.jit(lambda p: sparse_apply_packed_table(

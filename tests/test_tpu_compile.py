@@ -87,21 +87,27 @@ def _row_dma_calls(text, table):
             if "tpu_custom_call" in line]
 
 
-@pytest.mark.parametrize("rows,dim", [(1 << 25, 10), (1 << 22, 64), (1 << 22, 1)],
-                         ids=["dim9_2e25x20", "dim64_2e22x128",
-                              "dim64_first_order_2e22x2"])
-def test_apply_ladder_costs_no_table_copy_on_the_tpu(one_chip, rows, dim):
+@pytest.mark.parametrize("rows,dim,form,n", [
+    (1 << 25, 10, "rows", N), (1 << 25, 10, "lines", N), (1 << 22, 64, "rows", N),
+    (1 << 22, 1, "rows", N), (16384, 2688, "rows", 8192)],
+    ids=["dim9_2e25x20_as_rows", "dim9_2e23x128_lines", "dim64_2e22x128",
+         "dim64_first_order_2e22x2", "token_table_16384x5376"])
+def test_apply_ladder_costs_no_table_copy_on_the_tpu(one_chip, rows, dim, form, n):
     """A 2-step scan of the packed apply at the benchmark's table shapes:
     one conditional, the table updated in place in EVERY branch. The 32 MiB
     first-order table is under `FAST_MEMORY_BYTES`: no conditional, and the
     compiler keeps it in fast memory (`S(1)`) as it did (on the chip that
     scatter read 4.2 ms there and 6.1 ms through a conditional, PR 29).
     What writes the rows is the shape's choice (`ops.sparse.scatter_rows`):
-    the 2^22 x 128 table, a lane line a row, takes the row-DMA kernel, ONE
-    custom call under `sparse.apply` after the switch (the rungs hand it
-    their new rows padded to n) and no scatter of XLA's; the tables of width
-    20 and 2 compile to XLA's scatter and to no custom call, the program they
-    had."""
+    a table of one lane line a row takes the row-DMA kernel, ONE custom call
+    under `sparse.apply` after the switch (the rungs hand it their new rows
+    padded to n) and no scatter of XLA's: the 2^22 x 128 table, and the dim-9
+    table in the form its scan holds it in (`ops.sparse.takes_lines`: 2^23
+    lines of four rows, the apply gathering, merging and writing LINES). The
+    same dim-9 table handed over as 2^25 rows of 20, the first-order table
+    and a language model's token table (a row of 42 lane lines) compile to
+    XLA's scatter and to no custom call, the program they had."""
+    from openembedding_tpu.ops.sparse import pack_table, takes_row_dmas
     opt = embed.Adagrad(learning_rate=0.05)
     layout = (("accum", dim),)
 
@@ -112,19 +118,24 @@ def test_apply_ladder_costs_no_table_copy_on_the_tpu(one_chip, rows, dim):
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    like = jax.ShapeDtypeStruct((rows, dim), jnp.float32)
+    lines = jax.eval_shape(lambda w, a: pack_table(w, {"accum": a}, layout),
+                           like, like).shape
+    assert lines == ((rows // 4, 128) if dim == 10 else (rows, 2 * dim))
+    shape = lines if form == "lines" else (rows, 2 * dim)
     compiled = jax.jit(many, donate_argnums=(0,)).lower(
-        arg((rows, 2 * dim), jnp.float32), arg((2, N), jnp.int32),
-        arg((2, N, dim), jnp.float32)).compile()
+        arg(shape, jnp.float32), arg((2, n), jnp.int32),
+        arg((2, n, dim), jnp.float32)).compile()
     text = compiled.as_text()
-    table = rf"f32\[{rows},{2 * dim}\]"
+    table = rf"f32\[{shape[0]},{shape[1]}\]"
     scatters = re.findall(rf"= {table}(\S*) fusion\([^\n]*/scatter", text)
     kernels = _row_dma_calls(text, table)
     if rows * 2 * dim * 4 < FAST_MEMORY_BYTES:
         assert " conditional(" not in text and "tpu_custom_call" not in text
         assert len(scatters) == 1 and "S(1)" in scatters[0]
         return
-    assert len(apply_ladder(N)) == 4 and text.count(" conditional(") == 1
-    if 2 * dim % 128:
+    assert len(apply_ladder(n)) == 4 and text.count(" conditional(") == 1
+    if not takes_row_dmas(arg(shape, jnp.float32)):
         assert len(scatters) == 4 and "tpu_custom_call" not in text
     else:  # ONE kernel, after the switch: the rungs hand it their rows
         assert not scatters and len(kernels) == 1, (scatters, kernels)
@@ -132,7 +143,9 @@ def test_apply_ladder_costs_no_table_copy_on_the_tpu(one_chip, rows, dim):
         assert "sparse.apply/" in kernels[0] and kernels[0].count("cond/") == 1
     copies = re.findall(rf"= {table}\S* (?:copy|copy-start)\(", text)
     assert not copies, f"{len(copies)} table-sized copies in the program"
-    assert compiled.memory_analysis().temp_size_in_bytes < rows * 2 * dim * 4 // 8
+    if n == N:
+        assert compiled.memory_analysis().temp_size_in_bytes < \
+            shape[0] * shape[1] * 4 // 8
 
 
 @pytest.mark.parametrize("width,dtype,taken", [
@@ -160,6 +173,30 @@ def test_row_dma_kernel_compiles_where_the_rule_sends_it_and_nowhere_else(
             lowered.compile()
 
 
+@pytest.mark.parametrize("columns", [(10, 10), (16, 16), (9, 8), (8, 8, 8)])
+def test_line_kernels_compile_for_the_tpu(one_chip, columns):
+    """`ops/pallas_lines.py` at the dim-9 cells' 2^23 lines: the pack (every
+    array's four quarter blocks of (columns, 2048) stacked in VMEM, 32
+    sublanes a quarter, one transpose) and the unpack compile for the
+    described chip, whatever sublane an array starts at, with no temporary
+    beside what they return."""
+    from openembedding_tpu.ops import pallas_lines
+    lines = 1 << 23
+    offsets = tuple(int(x) for x in np.cumsum((0,) + columns[:-1]))
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    pack = jax.jit(lambda *a: pallas_lines.pack_lines(
+        *a, offsets=offsets)).lower(
+        *[arg((c, 4 * lines)) for c in columns]).compile()
+    unpack = jax.jit(lambda p: pallas_lines.unpack_lines(
+        p, columns=columns, offsets=offsets)).lower(
+        arg((lines, 128))).compile()
+    for compiled in (pack, unpack):
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def _table_gathers(text, table):
     """(rows gathered, the op's stage path) of every gather in the optimised
     HLO whose OPERAND has the shape `table` (a regex)."""
@@ -174,8 +211,8 @@ def _table_gathers(text, table):
     return found
 
 
-def _deepfm_scan_text(one_chip, rows, dim, K=2, B=4096):
-    """The optimised HLO of a DeepFM cell's K-step scan at its real sizes."""
+def _deepfm_scan(one_chip, rows, dim, K=2, B=4096):
+    """A DeepFM cell's K-step scan at its real sizes, compiled."""
     from openembedding_tpu.model import Trainer
     from openembedding_tpu.models import make_deepfm
     tr = Trainer(make_deepfm(vocabulary=rows, dim=dim, hidden=(400, 400, 400),
@@ -191,23 +228,45 @@ def _deepfm_scan_text(one_chip, rows, dim, K=2, B=4096):
         stacked = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct((K,) + x.shape, x.dtype,
                                            sharding=one_chip), sample)
-        return tr.jit_train_many().lower(state, stacked).compile().as_text()
+        return tr.jit_train_many().lower(state, stacked).compile()
+
+
+def _deepfm_scan_text(one_chip, rows, dim, K=2, B=4096):
+    """The optimised HLO of that scan."""
+    return _deepfm_scan(one_chip, rows, dim, K, B).as_text()
 
 
 def test_shared_plan_scan_gathers_the_table_once_a_step_and_copies_none(one_chip):
-    """`deepfm9.train_zipf`'s scan at its real sizes (2 steps): two
-    conditionals over the four rungs (the pull's gather, the apply's row math
-    and scatter), no table-sized copy in any of the 8 branches, the scatter in
-    place in each of the apply's, and the 2.7 GB table gathered from in the
-    pull's four branches alone (a step runs one): W slots each, n only on the
-    full-size rung, and nowhere once a position. A row of 20 columns is no
-    lane line: XLA's scatter, and no custom call in the program."""
+    """`deepfm9.train_zipf`'s scan at its real sizes (2 steps): the 2^25 x
+    (10 + 10) table is held as 2^23 lines of four rows (`ops/sparse.py` "FOUR
+    ROWS A LANE LINE"; 4 GiB, and the program fits the chip's 15.75 GiB with
+    both forms alive at its two ends). Two conditionals over the four rungs
+    (the pull's gather, the apply's row math and merge); the table is
+    gathered from in the pull's four branches alone (a step runs one): LINES
+    `f32[W,128]`, W slots each, n only on the full-size rung, nowhere once a
+    position and nowhere 20 columns wide; it is written by ONE
+    `scatter_rows_dma` custom call under `sparse.apply` after the apply's
+    switch, by no scatter of XLA's, and copied nowhere at all: the pack and
+    the unpack at the scan's two ends are one custom call each
+    (`ops/pallas_lines.py`), the program's only other two."""
     rows = 1 << 25
-    text = _deepfm_scan_text(one_chip, rows, 9)
-    table = rf"f32\[{rows},20\]"
-    assert text.count(" conditional(") == 2 and "tpu_custom_call" not in text
+    compiled = _deepfm_scan(one_chip, rows, 9)
+    text = compiled.as_text()
+    table = rf"f32\[{rows // 4},128\]"
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.75 * 2**30
+    assert text.count(" conditional(") == 2
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*?'
+                       r'op_name="([^"]*)"', text)
+    assert sorted(c.split("/")[-2] for c in calls) == [
+        "pack_lines", "scatter_rows_dma", "unpack_lines"], calls
+    kernel, = [c for c in calls if "scatter_rows_dma" in c]
+    assert "while/body" in kernel and "sparse.apply/" in kernel
+    assert not any("while/body" in c for c in calls if c != kernel), calls
+    assert not re.findall(rf"= {table}\S* fusion\([^\n]*/scatter", text)
+    assert not re.findall(rf"f32\[{rows},(?:20|32)\]", text)  # no row form
     assert not re.findall(rf"= {table}\S* (?:copy|copy-start)\(", text)
-    assert len(re.findall(rf"= {table}(\S*) fusion\([^\n]*/scatter", text)) == 4
+    assert not re.findall(r"= f32\[\d+,20\]\S* gather\(", text)
     gathers = sorted(_table_gathers(text, table))
     assert [g[0] for g in gathers] == list(apply_ladder(N)), gathers
     assert all("sparse.pull/" in path and "sparse.apply" not in path
@@ -310,7 +369,8 @@ def _shard_gathers(many, state, stacked):
 def test_four_chip_scan_gathers_the_shard_at_the_owners_serve_alone(
         topo, monkeypatch):
     """The owner plans once a step (`parallel/sharded.py`): in the cell's
-    scan the only gathers from the 2^25 x 20 packed shard outside
+    scan the only gathers from the packed shard (2^25 x 20, held as 2^23
+    lines of four rows: `ops/sparse.py` "FOUR ROWS A LANE LINE") outside
     `exchange.full_size` are the serve's, one a rung of `apply_ladder(n)`
     under `exchange.owner_serve/.../sparse.pull` (a step runs one), and none
     once a received slot; the compact apply gathers nothing from the shard.
@@ -339,6 +399,22 @@ def test_four_chip_scan_gathers_the_shard_at_the_owners_serve_alone(
     a2a = [c for c in guards.collective_sequence(many, state, stacked)
            if c[0] == "all_to_all"]
     assert len(a2a) == 3
+    # the shard is in the line form (2^23 lines of four rows): every gather
+    # above reads LINES, and what writes it is the row-DMA kernel, one
+    # instance in the compact apply and one in the full-size apply, each
+    # after its switch (beside it, for the lowerings that are no TPU's, XLA's
+    # scatter: `jax.lax.platform_dependent` traces both)
+    shard = (1 << 23, 128)
+    assert all(shp[0] == shard for _, _, shp in guards.primitive_sites(
+        many, ("gather",), state, stacked)
+        if len(shp[0]) == 2 and shp[0][0] >= 1 << 20)
+    kernels = [stack for _, stack, shp in guards.primitive_sites(
+        many, ("pallas_call",), state, stacked)
+        if shard in shp and "sparse.apply" in stack]  # not pack / unpack
+    assert len(kernels) == 2, kernels
+    assert sorted("exchange.full_size" in k for k in kernels) == [False, True]
+    assert all("exchange.owner_apply" in k and "sparse.apply" in k
+               for k in kernels), kernels
 
 
 @pytest.mark.parametrize("kw", [

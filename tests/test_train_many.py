@@ -330,8 +330,8 @@ def test_owner_plans_counts_each_table_once_a_trace_on_the_mesh(
     plans, taken = [], []
     real_plan, real_apply = sharded.plan_packed_rows, \
         sparse.sparse_apply_packed_table
-    monkeypatch.setattr(sharded, "plan_packed_rows", lambda *a: (
-        plans.append(1), real_plan(*a))[1])
+    monkeypatch.setattr(sharded, "plan_packed_rows", lambda *a, **kw: (
+        plans.append(1), real_plan(*a, **kw))[1])
     monkeypatch.setattr(sparse, "sparse_apply_packed_table", lambda *a, **kw: (
         taken.append(kw.get("plan") is not None), real_apply(*a, **kw))[1])
 

@@ -75,6 +75,8 @@ KNOWN_LABELS = {
     "component",  # memory ledger component (utils/memwatch.py)
     "fn",         # traced trainer entry point (bounded enum: train_step /
                   # train_many — `trainer.traces`)
+    "form",       # how a scan holds a packed table (bounded enum: lines /
+                  # rows — `sparse.packed_tables`)
     "hop",        # sync lineage hop (bounded enum: commit/publish/fetch/
                   # apply/swap/serve — sync/lineage.py HOP_ORDER)
     "instance",   # fleet-merge node id (metrics.merge_prometheus)
