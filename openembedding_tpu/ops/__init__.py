@@ -1,2 +1,2 @@
-from .dedup import unique_with_counts, bucket_by_owner, unbucket
+from .dedup import unique_with_counts
 from .sparse import lookup_rows, scatter_rows, sparse_apply_dense_table

@@ -4,7 +4,9 @@ Counterpart of the reference's server-side hot path on one shard:
 `EmbeddingOptimizerVariable::pull_weights` (table read, `EmbeddingOptimizerVariable.h:
 242-266`) and `update_weights` (commit + reduce + per-unique-row optimizer update,
 `:273-297`). Here a "shard" is just the rows of the table a device owns; the ops are
-plain XLA (Pallas variants live in `ops/pallas_*.py`).
+plain XLA, but for two movements whose kernel the shape chooses where the program
+is lowered for a TPU: `ops/pallas_scatter.py` (`takes_row_dmas`) and
+`ops/pallas_lines.py` (`takes_lines`).
 
 Scatter correctness under static shapes: padding slots of the unique-id buffer are
 scattered with out-of-bounds indices and `mode='drop'`, so they can never corrupt row 0.
@@ -13,7 +15,6 @@ scattered with out-of-bounds indices and `mode='drop'`, so they can never corrup
 from __future__ import annotations
 
 import functools
-import os
 from typing import Dict, NamedTuple, Tuple
 
 import jax
@@ -43,11 +44,6 @@ def _gather_rows(weights, rows, valid=None, *, sorted_unique=False,
     they update through this, so that read counts under `sparse.apply`.
     `ascending`: `rows` never decreases but may repeat (the LINES of sorted
     unique rows, "FOUR ROWS A LANE LINE" below)."""
-    if weights.ndim == 2 and rows.ndim == 1:
-        from .pallas_sparse import maybe_gather_rows
-        out = maybe_gather_rows(weights, rows, valid)
-        if out is not None:
-            return out
     n_rows = weights.shape[0]
     in_range = (rows >= 0) & (rows < n_rows)
     if valid is not None:
@@ -224,10 +220,8 @@ def takes_row_dmas(table: jax.Array) -> bool:
 PACKED_MAX_SUBLANE_WIDTH = 32
 # pack/unpack at the scan boundary transiently holds BOTH layouts (~2x the
 # packed bytes); tables whose packed form exceeds this skip packing so the
-# boundary cannot OOM a chip whose steady state fits. Override (bytes, per
-# shard) via OETPU_PACKED_MAX_BYTES for bigger-HBM parts.
-PACKED_MAX_BYTES = int(os.environ.get("OETPU_PACKED_MAX_BYTES",
-                                      str(4 << 30)))
+# boundary cannot OOM a chip whose steady state fits (bytes, per shard).
+PACKED_MAX_BYTES = 4 << 30
 LINE_LANES = 128                        # a lane line of 4-byte elements
 LINE_ROWS = 4                           # rows of a narrow packed table a line
 LINE_STRIDE = LINE_LANES // LINE_ROWS   # lanes a row
@@ -780,15 +774,8 @@ def sparse_apply_dense_table(
     with _trace.scope("sparse", "apply"):
         g, counts, idx = _dedup_routed(weights.shape[0], row_ids, grads, pre_counts)
 
-        from .pallas_sparse import maybe_fused_apply
-
         def tail(W, settle):
             idx_w, g_w, counts_w = idx[:W], g[:W], counts[:W]
-            fused = maybe_fused_apply(optimizer, weights, slots, idx_w, g_w,
-                                      counts_w)
-            if fused is not None:
-                return fused
-
             # Optimizer math always runs in float32, whatever the table dtype: in bf16,
             # beta_2^t rounds to 1.0 (killing Adam's lr_t) and g^2 accumulators lose most of
             # their mantissa. Slots are stored f32 (`SparseOptimizer.init_slots`); weights are

@@ -17,7 +17,7 @@ that PS-trained models equal GPU-trained ones; see `test/optimizer_test.py`):
   row is touched (reference: `EmbeddingOptimizer.h:156-181,199-220` keeps them in the
   row's state block).
 
-On TPU the update runs as one fused XLA/Pallas kernel over the block of unique rows
+On TPU the update runs as one XLA fusion over the block of unique rows
 gathered from the owning shard: `apply(weights, slots, grads, counts)` where rows with
 `counts == 0` (padding of the static-capacity unique buffer) are left bit-identical.
 

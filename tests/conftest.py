@@ -23,6 +23,25 @@ import jax
 # 63-bit hashed id spaces need int64 ids (`meta.HASH_VOCABULARY_THRESHOLD`)
 jax.config.update("jax_enable_x64", True)
 
+# ONE persistent compile cache a test process (an xdist worker, or the one
+# process of a serial run), in a directory of its own that goes with it: a
+# program whose HLO the process compiled before is loaded, not compiled again.
+# New trainers, `MeshTrainer.init`'s programs (made anew at every call) and a
+# path beside its reference compile the same HLO many times a file; tracing is
+# as it was. The key leaves metadata out (scope names, source lines), so a
+# loaded executable's TEXT carries the names of the trace that wrote it: a
+# test that changes nothing but those, or counts backend compiles, asks for
+# `no_compile_cache`. `JAX_COMPILATION_CACHE_DIR`, where set, is left alone.
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    import atexit
+    import shutil
+    import tempfile
+    _programs = tempfile.mkdtemp(prefix="oetpu-tests-programs-")
+    atexit.register(shutil.rmtree, _programs, ignore_errors=True)
+    jax.config.update("jax_compilation_cache_dir", _programs)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
 
 def pytest_configure(config):
     # tier-1 (ROADMAP.md) runs `-m 'not slow'` under a hard wall-clock
@@ -44,3 +63,16 @@ def _windows_fold_in_their_own_test():
     yield
     from openembedding_tpu.utils import metrics
     metrics.report()
+
+
+@pytest.fixture
+def no_compile_cache():
+    """Every backend compile of this test is a compile (the process's cache
+    is put back afterwards)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keep = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_compilation_cache_dir", keep)
+    cc.reset_cache()

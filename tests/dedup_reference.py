@@ -4,7 +4,13 @@ per-position passes (`ids[order]`, the run heads' sorted scatter, the segment
 sum of ones, `zeros.at[order].set(seg)`). `tests/test_dedup.py` holds every
 field of the package's results equal to these; `patch_reference_dedup` puts them
 into the package for an end-to-end comparison (`tests/test_packed_layout.py`).
+Below them the split routing the exchange had before `unique_and_route`
+(`bucket_by_owner`, `unbucket`: a sort, a searchsorted and per-slot scatters),
+which no code of the package has called since PR 31 and the tests hold the
+fused routing and the block copies against.
 Not collected: no test lives here."""
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +118,82 @@ def unique_and_route(ids: jax.Array, valid: jax.Array, num_shards: int,
                                        fill=empty)
         return uniq, RoutedBuckets(bucket_ids, start, count, positions,
                                    overflow)
+
+
+class BucketResult(NamedTuple):
+    bucket_ids: jax.Array    # (num_shards, capacity) — ids grouped by owner shard
+    bucket_valid: jax.Array  # (num_shards, capacity) bool
+    # position of input element i inside its bucket: (owner[i], slot[i])
+    owner: jax.Array         # (n,) int32
+    slot: jax.Array          # (n,) int32
+    overflow: jax.Array      # () int32 — elements dropped because a bucket was full
+
+
+def bucket_by_owner(ids: jax.Array, valid: jax.Array, num_shards: int,
+                    capacity: int) -> BucketResult:
+    """Group ids into per-owner-shard buckets of static capacity. The split,
+    per-slot form, `ops/dedup.py`'s until PR 43: the exchange routes through
+    `unique_and_route`, and this is its independent reference
+    (`tests/test_dedup.py`).
+
+    Owner layout matches the reference: `owner = id % num_shards`, row-within-shard
+    `id // num_shards` (`EmbeddingPullOperator.cpp:74-84`). Elements beyond a bucket's
+    capacity are counted in `overflow` and dropped (the reference's dynamic buffers
+    can't overflow; static XLA shapes can — callers size capacity via config and tests
+    use capacity == n for exactness).
+
+    NOTE: empty bucket slots are ZERO-filled here with `bucket_valid` as the
+    mask; `unique_and_route` (the fused hot path) instead sentinel-fills so
+    validity is derivable from the ids alone — do not apply `bucket_validity`
+    to THIS function's output.
+    """
+    with _trace.scope("exchange", "route"):
+        n = ids.shape[0]
+        if ids.ndim == 2:  # split-pair layout: owner via modular pair arithmetic
+            from openembedding_tpu.ops.id64 import pair_mod
+            owner = jnp.where(valid, pair_mod(ids, num_shards).astype(jnp.int32),
+                              num_shards)
+        else:
+            owner = jnp.where(valid, (ids % num_shards).astype(jnp.int32),
+                              num_shards)
+        # stable sort by owner so each bucket preserves input order
+        order = jnp.argsort(owner, stable=True)
+        sorted_owner = owner[order]
+        # index within the owner group = position - start of that owner's run
+        group_start = jnp.searchsorted(sorted_owner, sorted_owner, side="left")
+        idx_in_group = jnp.arange(n, dtype=jnp.int32) - group_start.astype(jnp.int32)
+        slot_sorted = idx_in_group
+        in_cap = (slot_sorted < capacity) & (sorted_owner < num_shards)
+        overflow = jnp.sum((~in_cap) & (sorted_owner < num_shards)).astype(jnp.int32)
+        # scatter (owner, slot) -> id; out-of-capacity and invalid entries drop
+        flat_pos = jnp.where(in_cap, sorted_owner * capacity + slot_sorted,
+                             num_shards * capacity)
+        lanes = ids.shape[1:]  # () single-lane, (2,) split-pair
+        bucket_ids = jnp.zeros((num_shards * capacity,) + lanes,
+                               ids.dtype).at[flat_pos].set(
+            ids[order], mode="drop").reshape((num_shards, capacity) + lanes)
+        bucket_valid = jnp.zeros((num_shards * capacity,), bool).at[flat_pos].set(
+            True, mode="drop").reshape(num_shards, capacity)
+        # per-input-element position (for unbucketing responses)
+        owner_out = jnp.zeros((n,), jnp.int32).at[order].set(sorted_owner)
+        slot_out = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.where(in_cap, slot_sorted, capacity))
+        return BucketResult(bucket_ids, bucket_valid, owner_out, slot_out, overflow)
+
+
+def unbucket(bucket_rows: jax.Array, owner: jax.Array, slot: jax.Array) -> jax.Array:
+    """Inverse of bucket_by_owner for per-id payloads: read back each input element's
+    row from its (owner, slot) position. bucket_rows: (num_shards, capacity, ...).
+    A gather per slot: the reference the exchange's block copies
+    (`compact_blocks`) are tested against, not called by it."""
+    with _trace.scope("exchange", "reassemble"):
+        num_shards, capacity = bucket_rows.shape[:2]
+        flat = bucket_rows.reshape((num_shards * capacity,) + bucket_rows.shape[2:])
+        pos = jnp.clip(owner * capacity + slot, 0, num_shards * capacity - 1)
+        oob = (owner >= num_shards) | (slot >= capacity)
+        out = flat[pos]
+        return jnp.where(oob.reshape((-1,) + (1,) * (out.ndim - 1)),
+                         jnp.zeros_like(out), out)
 
 
 def patch_reference_dedup(monkeypatch):

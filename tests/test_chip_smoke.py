@@ -1,6 +1,5 @@
-"""Bring-up guards: where the compile cache goes, that the chip entry points
-refuse to run off-chip unless the caller asked for the CPU by name, and that
-`OETPU_PALLAS=on` never silently runs XLA.
+"""Bring-up guards: where the compile cache goes, and that the chip entry
+points refuse to run off-chip unless the caller asked for the CPU by name.
 
 Cheap by design (tier-1 runs against a wall-clock budget): the entry-point
 tests exit at the device check, before a model is built. The full three-stage
@@ -11,11 +10,8 @@ import os
 import subprocess
 import sys
 
-import jax.numpy as jnp
 import pytest
 
-from openembedding_tpu.ops import pallas_sparse
-from openembedding_tpu.ops.sparse import lookup_rows
 from openembedding_tpu.utils import compile_cache
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -73,15 +69,6 @@ def test_chip_smoke_cpu_needs_explicit_sizes():
     request: without explicit sizes the script still refuses."""
     p = _run(["chip_smoke.py"], JAX_PLATFORMS="cpu")
     assert p.returncode != 0 and p.stdout.strip() == "", (p.stdout, p.stderr)
-
-
-def test_pallas_on_unaligned_width_raises():
-    pallas_sparse.set_mode("on")
-    try:
-        with pytest.raises(ValueError, match=r"64-row table.*\[10\]"):
-            lookup_rows(jnp.zeros((64, 10)), jnp.zeros((4,), jnp.int32))
-    finally:
-        pallas_sparse.set_mode("off")
 
 
 @pytest.mark.slow

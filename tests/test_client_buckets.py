@@ -16,7 +16,7 @@ holds no scatter or gather over S x capacity rows on the client's side.
 The per-slot reference is the parent's code, kept HERE and patched over the
 three seams (`sharded.unique_and_route`, `_to_buckets`, `_from_buckets`): the
 (owner, slot) of every unique slot, a scatter into a filled S x cap array,
-and `ops/dedup.unbucket`'s gather back.
+and `tests/dedup_reference.unbucket`'s gather back.
 """
 
 from typing import NamedTuple
@@ -27,6 +27,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import dedup_reference
 import openembedding_tpu as embed
 from openembedding_tpu.models import make_deepfm
 from openembedding_tpu.ops import dedup
@@ -211,7 +212,7 @@ def _slot_to_buckets(payload, plan):
 
 
 def _slot_from_buckets(x, plan):
-    return dedup.unbucket(x, plan.buckets.owner, plan.buckets.slot)
+    return dedup_reference.unbucket(x, plan.buckets.owner, plan.buckets.slot)
 
 
 @pytest.fixture

@@ -84,7 +84,8 @@ def test_the_same_program_after_clear_caches_is_a_hit_with_load_seconds(
     assert got["trace_s"] > first["trace_s"]       # but it is traced again
 
 
-def test_a_second_signature_is_a_second_executable_of_one_trace():
+def test_a_second_signature_is_a_second_executable_of_one_trace(
+        no_compile_cache):
     """An uncommitted first state and a committed later one (PERF.md section
     7 c): `trainer.traces` cannot see the second compile, the account can."""
     rng = np.random.default_rng(0)

@@ -6,7 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from openembedding_tpu.ops.dedup import bucket_by_owner, unbucket, unique_with_counts
+from dedup_reference import bucket_by_owner, unbucket
+from openembedding_tpu.ops.dedup import unique_with_counts
 
 
 @pytest.mark.parametrize("n,vocab", [(16, 5), (128, 1000), (64, 2)])

@@ -15,6 +15,8 @@ import pytest
 import openembedding_tpu as embed
 from openembedding_tpu.ops.sparse import sparse_apply_dense_table
 
+from apply_reference import np_adagrad
+
 DIM = 8
 ROWS = 6
 
@@ -32,11 +34,6 @@ def np_sgd(w, g, s, lr=0.01, momentum=0.0, nesterov=False):
     m = s["moment"] * momentum + lr * g
     w = w - (m * momentum + lr * g) if nesterov else w - m
     return w, {"moment": m}
-
-
-def np_adagrad(w, g, s, lr=0.001, eps=1e-7):
-    a = s["accum"] + g * g
-    return w - lr * g / (np.sqrt(a) + eps), {"accum": a}
 
 
 def np_adadelta(w, g, s, lr=0.001, rho=0.95, eps=1e-7):

@@ -28,6 +28,8 @@ from openembedding_tpu.models import make_deepfm
 from openembedding_tpu.parallel import MeshTrainer, make_mesh, sharded
 from openembedding_tpu.utils import metrics
 
+from hlo_hash import strip
+
 K = 3
 PER_CHIP = 4            # examples a device: n = 4 x 26 = 104 positions
 N = PER_CHIP * 26       # the working size W
@@ -65,24 +67,56 @@ def _batches(S, *, vocab=VOCAB, seed=0, pool=None, stride=1):
             "label": rng.integers(0, 2, (K, B)).astype(np.float32)}
 
 
-def _train(S, stacked, *, full_size=False, many=True, dim=9, vocab=VOCAB,
-           hash_capacity=0, hot=0, mig=0, **kw):
+_trainers = {}
+_OWNER_VIEW = sharded._owner_view
+
+
+def _no_view(*a):
+    """In `sharded._owner_view`'s place: the full-size path, the program as
+    it was before the owner compacted."""
+    return None
+
+
+def _trainer(S, *, dim=9, vocab=VOCAB, hash_capacity=0, hot=0, mig=0, **kw):
+    """A tiny DeepFM's `MeshTrainer` on S devices. A `MeshTrainer` keeps its
+    jitted step and scan, so two cases that drive the same program compile it
+    once: ONE trainer a process for a configuration AND what stands in
+    `sharded._owner_view`'s place while it traces (the mechanism or
+    `_no_view`; `FAST_MEMORY_BYTES` is this module's 0 throughout). A case's
+    own spy, or rows placed by hand, get a trainer of their own: a program
+    traced before the spy would never call it. A case's state is its own
+    (`init`)."""
+    from openembedding_tpu.ops import sparse
+    view = sharded._owner_view
+    key = (S, view, sparse.FAST_MEMORY_BYTES, dim, vocab, hash_capacity,
+           tuple(sorted(kw.items())))
+    shared = not (hot or mig) and view in (_OWNER_VIEW, _no_view)
+    if shared and key in _trainers:
+        return _trainers[key]
+    if hash_capacity:
+        model = make_deepfm(vocabulary=-1, dim=dim, hidden=(8,),
+                            hashed=True, capacity=hash_capacity)
+    else:
+        model = make_deepfm(vocabulary=vocab, dim=dim, hidden=(8,))
+    tr = MeshTrainer(model, embed.Adagrad(learning_rate=0.05), seed=1,
+                     mesh=make_mesh(jax.devices()[:S]), hot_rows=hot,
+                     mig_rows=mig, **kw)
+    if shared:
+        _trainers[key] = tr
+    return tr
+
+
+def _train(S, stacked, *, full_size=False, many=True, hot=0, mig=0, **kw):
     """K steps of a tiny DeepFM on S devices -> (trainer, state on the host,
     metrics as the call returned them: `record_window_stats` knows a window
     by its arrays). `full_size` nulls the mechanism for the run's traces."""
     one = jax.tree_util.tree_map(lambda x: x[0], stacked)
+    kw.setdefault("wire", "fp32")  # the suite's default (tests/conftest.py)
     orig = sharded._owner_view
     if full_size:
-        sharded._owner_view = lambda *a: None
+        sharded._owner_view = _no_view
     try:
-        if hash_capacity:
-            model = make_deepfm(vocabulary=-1, dim=dim, hidden=(8,),
-                                hashed=True, capacity=hash_capacity)
-        else:
-            model = make_deepfm(vocabulary=vocab, dim=dim, hidden=(8,))
-        tr = MeshTrainer(model, embed.Adagrad(learning_rate=0.05), seed=1,
-                         mesh=make_mesh(jax.devices()[:S]), hot_rows=hot,
-                         mig_rows=mig, **kw)
+        tr = _trainer(S, hot=hot, mig=mig, **kw)
         state = tr.init(one)
         if hot:
             state = tr.refresh_hot_rows(
@@ -236,9 +270,7 @@ def test_mesh_entry_point_publishes_the_owner_counters_unasked(S):
     vocab = 1 << 16
     stacked = _batches(S, vocab=vocab, stride=S)
     one = jax.tree_util.tree_map(lambda x: x[0], stacked)
-    tr = MeshTrainer(make_deepfm(vocabulary=vocab, dim=9, hidden=(8,)),
-                     embed.Adagrad(learning_rate=0.05), seed=1,
-                     mesh=make_mesh(jax.devices()[:S]))
+    tr = _trainer(S, vocab=vocab, wire="fp32")  # the crowded scan's program
     state = tr.init(one)
     many = tr.jit_train_many(stacked, state)
     assert tr.jit_train_many() is many  # ONE dispatch object a trainer
@@ -269,12 +301,6 @@ def test_mesh_entry_point_publishes_the_owner_counters_unasked(S):
 # -- (d) no compaction where the receive side is no larger than W -------------
 
 
-def _strip(text):
-    blocks = [b for b in text.split("\n\n") if b.split("\n", 1)[0] not in
-              ("FileNames", "FunctionNames", "FileLocations", "StackFrames")]
-    return re.sub(r",? ?metadata=\{[^}]*\}", "", "\n\n".join(blocks))
-
-
 @functools.lru_cache(maxsize=None)
 def _scan_text(kind, full_size=False):
     """The compiled `train_many` of a tiny DeepFM: `Trainer`, or `MeshTrainer`
@@ -286,7 +312,7 @@ def _scan_text(kind, full_size=False):
     opt = embed.Adagrad(learning_rate=0.05)
     orig = sharded._owner_view
     if full_size:
-        sharded._owner_view = lambda *a: None
+        sharded._owner_view = _no_view
     try:
         if kind == "single":
             tr = embed.Trainer(model, opt)
@@ -335,7 +361,7 @@ def test_no_compaction_where_the_receive_side_is_small(kind):
     assert not _owner_conditionals(text)
     # and it is the program with the mechanism nulled, instruction for
     # instruction (`Trainer` never enters parallel/sharded.py)
-    assert _strip(text) == _strip(_scan_text(kind, full_size=True))
+    assert strip(text) == strip(_scan_text(kind, full_size=True))
 
 
 def test_exact_mode_on_four_devices_compacts():

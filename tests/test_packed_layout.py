@@ -6,6 +6,8 @@ numeric parity against the split-layout step path, (b) the width gate, and
 (checkpoints/serving/offload never see packed arrays).
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -17,11 +19,16 @@ import openembedding_tpu as embed
 from openembedding_tpu.data import synthetic_criteo
 from openembedding_tpu.model import Trainer
 from openembedding_tpu.models import make_deepfm
+from openembedding_tpu.ops import sparse
 from openembedding_tpu.ops.sparse import (apply_ladder, packed_layout,
                                           pack_table,
                                           sparse_apply_dense_table,
                                           sparse_apply_packed_table,
                                           unpack_table)
+from openembedding_tpu.parallel import MeshTrainer, make_mesh, sharded
+
+from apply_reference import (ROUNDS_UNDER_JIT, SLOTTED_OPTS,
+                             assert_same_table, warm_table)
 
 
 def test_packed_layout_gate():
@@ -51,30 +58,33 @@ def test_pack_unpack_roundtrip():
         np.testing.assert_array_equal(np.asarray(slots[k]), np.asarray(s2[k]))
 
 
-@pytest.mark.parametrize("opt_name", ["adagrad", "adam", "ftrl"])
-def test_packed_apply_matches_split(opt_name):
-    """One fused update through both layouts: bit-identical tables."""
-    opt = {"adagrad": embed.Adagrad(learning_rate=0.1),
-           "adam": embed.Adam(learning_rate=0.01),
-           "ftrl": embed.Ftrl(learning_rate=0.1)}[opt_name]
+@pytest.mark.parametrize("opt", SLOTTED_OPTS, ids=lambda o: o.category)
+def test_packed_apply_matches_split(opt):
+    """One fused update through both layouts, at a dim at which every
+    optimizer's weights and slots pack (width <= 32): two compiled programs
+    leave bit-identical tables (for the two optimizers of `ROUNDS_UNDER_JIT`,
+    tables a rounding apart), and so do the two applies run op by op, where
+    no kernel holds two operations and what is compared is where the layouts
+    put a row's columns, for all eight."""
     dim, rows, n = 6, 64, 40
-    rng = np.random.default_rng(1)
-    w = jnp.asarray(rng.standard_normal((rows, dim)), jnp.float32)
-    slots = opt.init_slots(rows, dim)
+    w, slots, ids, g = warm_table(opt, rows, dim, n, np.random.default_rng(1))
     lay = packed_layout(dim, slots)
-    if lay is None:
-        pytest.skip(f"{opt_name}: not packable at dim {dim}")
-    ids = jnp.asarray(rng.integers(-1, rows, n), jnp.int32)  # incl. invalid
-    g = jnp.asarray(rng.standard_normal((n, dim)), jnp.float32)
+    assert lay is not None and dim + sum(k for _, k in lay) <= 32
 
-    sw, ss = jax.jit(lambda w, s: sparse_apply_dense_table(opt, w, s, ids, g))(
-        w, slots)
-    packed, _ = jax.jit(lambda w, s: sparse_apply_packed_table(
-        opt, pack_table(w, s, lay), lay, dim, ids, g))(w, slots)
-    pw, ps = unpack_table(packed, lay, dim, w.dtype)
-    np.testing.assert_array_equal(np.asarray(sw), np.asarray(pw))
-    for k in ss:
-        np.testing.assert_array_equal(np.asarray(ss[k]), np.asarray(ps[k]))
+    def split(w, s):
+        return sparse_apply_dense_table(opt, w, s, ids, g)
+
+    def packed(w, s):
+        out, _ = sparse_apply_packed_table(opt, pack_table(w, s, lay), lay,
+                                           dim, ids, g)
+        return unpack_table(out, lay, dim, w.dtype)
+
+    want = jax.jit(split)(w, slots)
+    assert (np.asarray(want[0]) != np.asarray(w)).any()
+    assert_same_table(want, jax.jit(packed)(w, slots),
+                      exact=opt.category not in ROUNDS_UNDER_JIT)
+    with jax.disable_jit():
+        assert_same_table(split(w, slots), packed(w, slots))
 
 
 def test_train_many_packed_matches_step_loop():
@@ -181,6 +191,44 @@ _MESH_CASES = {
     "one_shard": (1, "fp32", "spread"),
 }
 _MB, _MF, _MV, _MK = 256, 8, 4096, 3     # 2,048 positions a step, 512 a device
+_mesh_trainers, _mesh_seen = {}, []
+
+
+def _watched_apply(opt, packed, layout, dim, row_ids, grads, pre_counts=None,
+                   *, plan=None):
+    """`sparse_apply_packed_table` that leaves in `_mesh_seen` what the
+    owner's apply is handed beside a plan, a record a shard a step. ONE
+    function and one list a process: a trainer the family shares runs the
+    callback it was traced with."""
+    if plan is not None:
+        mask = plan.counts[plan.uniq.inverse] > 0   # the slots it kept
+        ladder = jnp.asarray(apply_ladder(row_ids.shape[0])[:-1])
+        counts = jnp.where(plan.counts > 0,
+                           plan.uniq.segment_reduce(pre_counts), 0)
+        jax.debug.callback(
+            lambda *r: _mesh_seen.append([np.asarray(x) for x in r]),
+            jnp.all((pre_counts > 0) == mask), jnp.sum(mask),
+            jnp.sum(jnp.sum(counts > 0) > ladder),
+            jnp.sum(jnp.sum(plan.counts > 0) > ladder))
+    return sparse_apply_packed_table(opt, packed, layout, dim, row_ids, grads,
+                                     pre_counts, plan=plan)
+
+
+_SERVE_ROWS = sharded._serve_rows
+
+
+def _serve_per_slot(*a, **kw):
+    """In `sharded._serve_rows`' place: the owner makes no plan (the program
+    as it was before PR 39)."""
+    return _SERVE_ROWS(*a, **{**kw, "share": False})
+
+
+def _patched():
+    """Everything this family puts in the package's place while a program
+    traces: part of a shared trainer's key, so a case that patches anything
+    else (or another spy) never gets a program traced without it."""
+    return (sparse.sparse_apply_packed_table, sharded._serve_rows,
+            sparse.FAST_MEMORY_BYTES)
 
 
 def _mesh_batches(holds):
@@ -222,15 +270,12 @@ def test_mesh_train_many_packed_matches_step_loop(case, monkeypatch):
     in one that does not, and watch what the apply is handed beside a plan:
     counts positive exactly on the slots the plan kept (the others it routed
     to its sentinel), and a rung that is the plan's."""
-    from openembedding_tpu.ops import sparse
-    from openembedding_tpu.parallel import MeshTrainer, make_mesh, sharded
-
     shards, wire, holds = _MESH_CASES[case]
     mesh = make_mesh(jax.devices()[:shards])
     if holds == "criteo":
         batches = list(synthetic_criteo(64, id_space=4096, steps=4, seed=13))
 
-        def trainer():
+        def trainer(role=""):
             return MeshTrainer(make_deepfm(vocabulary=4096, dim=8),
                                embed.Adagrad(learning_rate=0.05), mesh=mesh)
     else:
@@ -240,35 +285,28 @@ def test_mesh_train_many_packed_matches_step_loop(case, monkeypatch):
 
         placed = 16 if holds == "placed" else 0
 
-        def trainer():
+        def trainer(role=""):
+            # where nothing is placed by hand, ONE trainer a (shards, wire,
+            # role, what is patched) a process: a `MeshTrainer` keeps its
+            # jitted scan and step, so two cases that differ in their ids
+            # alone (compact and full-size step; sound and bad ids) share
+            # the three programs
+            key = (shards, wire, role, _patched())
+            if not placed and key in _mesh_trainers:
+                return _mesh_trainers[key]
             layer = embed.Embedding(_MV, _PDIM, name="emb")
-            return MeshTrainer(embed.EmbeddingModel(_BagTower(), [layer]),
-                               embed.Adagrad(learning_rate=0.1), seed=2,
-                               mesh=mesh, wire=wire, hot_rows=placed,
-                               mig_rows=placed)
+            tr = MeshTrainer(embed.EmbeddingModel(_BagTower(), [layer]),
+                             embed.Adagrad(learning_rate=0.1), seed=2,
+                             mesh=mesh, wire=wire, hot_rows=placed,
+                             mig_rows=placed)
+            return tr if placed else _mesh_trainers.setdefault(key, tr)
     stacked = jax.tree_util.tree_map(
         lambda *xs: np.stack(xs) if xs[0] is not None else None, *batches,
         is_leaf=lambda x: x is None)
 
-    # what the owner's apply is handed beside a plan, a record a shard a step
-    seen = []
-    real_apply = sparse.sparse_apply_packed_table
-
-    def watched(opt, packed, layout, dim, row_ids, grads, pre_counts=None, *,
-                plan=None):
-        if plan is not None:
-            mask = plan.counts[plan.uniq.inverse] > 0   # the slots it kept
-            ladder = jnp.asarray(apply_ladder(row_ids.shape[0])[:-1])
-            counts = jnp.where(plan.counts > 0,
-                               plan.uniq.segment_reduce(pre_counts), 0)
-            jax.debug.callback(
-                lambda *r: seen.append([np.asarray(x) for x in r]),
-                jnp.all((pre_counts > 0) == mask), jnp.sum(mask),
-                jnp.sum(jnp.sum(counts > 0) > ladder),
-                jnp.sum(jnp.sum(plan.counts > 0) > ladder))
-        return real_apply(opt, packed, layout, dim, row_ids, grads,
-                          pre_counts, plan=plan)
-    monkeypatch.setattr(sparse, "sparse_apply_packed_table", watched)
+    seen = _mesh_seen
+    del seen[:]
+    monkeypatch.setattr(sparse, "sparse_apply_packed_table", _watched_apply)
 
     def init(tr):
         state = tr.init(batches[0])
@@ -284,7 +322,7 @@ def test_mesh_train_many_packed_matches_step_loop(case, monkeypatch):
         assert tr._packed_layouts(state), "expected a packable table"
         return tr.jit_train_many(stacked, state)(state, stacked)
 
-    sm, metrics = many(trainer())
+    sm, metrics = many(trainer("planned"))
     jax.effects_barrier()
     assert seen, "no apply took the owner's plan"
     assert all(same for same, *_ in seen)
@@ -296,7 +334,7 @@ def test_mesh_train_many_packed_matches_step_loop(case, monkeypatch):
         assert len(seen) == shards * _MK - full_steps
         assert (max(n for _, n, *_ in seen) > 0) == (holds != "one_owner")
 
-    trainer2, others = trainer(), []
+    trainer2, others = trainer("step_loop"), []
     if wire != "int8":
         state2 = init(trainer2)
         step = trainer2.jit_train_step(batches[0], state2)
@@ -307,14 +345,12 @@ def test_mesh_train_many_packed_matches_step_loop(case, monkeypatch):
         others.append((state2, np.stack(losses)))
 
     if holds != "criteo":
-        real_serve, before = sharded._serve_rows, len(seen)
-        monkeypatch.setattr(
-            sharded, "_serve_rows",
-            lambda *a, **kw: real_serve(*a, **{**kw, "share": False}))
-        plan_less, pm = many(trainer())
+        before = len(seen)
+        with monkeypatch.context() as m:
+            m.setattr(sharded, "_serve_rows", _serve_per_slot)
+            plan_less, pm = many(trainer("plan_less"))
         jax.effects_barrier()
         assert len(seen) == before      # no plan made, none taken
-        monkeypatch.setattr(sharded, "_serve_rows", real_serve)
         others.append((plan_less, np.asarray(pm["loss"])))
 
     for other, their_losses in others:
@@ -327,7 +363,7 @@ def test_mesh_train_many_packed_matches_step_loop(case, monkeypatch):
     (name, spec), = trainer2.model.ps_specs().items()
     # split layout on exit, and a table that moved
     assert sm.tables[name].weights.shape[1] == spec.output_dim
-    fresh = init(trainer()).tables[name]
+    fresh = init(trainer2).tables[name]
     assert not np.array_equal(np.asarray(sm.tables[name].weights),
                               np.asarray(fresh.weights))
     if holds == "placed":   # ... and so did the annex and the hot cache
@@ -343,7 +379,6 @@ def test_mesh_train_many_packed_hash(tmp_path):
     from openembedding_tpu.embedding import Embedding
     from openembedding_tpu.model import EmbeddingModel
     from openembedding_tpu.models.ctr import LogisticRegression
-    from openembedding_tpu.parallel import MeshTrainer, make_mesh
 
     steps = 3
     model = EmbeddingModel(
@@ -424,7 +459,6 @@ def ladder(request, monkeypatch):
     """"small_table": a table under `FAST_MEMORY_BYTES` keeps the program as
     it was (no switch); "ladder": the gate lifted, as for a table of 128 MiB
     and more. -> the scatters a table then compiles to."""
-    from openembedding_tpu.ops import sparse
     if request.param == "ladder":
         monkeypatch.setattr(sparse, "FAST_MEMORY_BYTES", 0)
         return len(apply_ladder(256 * 26))
@@ -566,15 +600,63 @@ _KINDS = ("array_packed", "array_split", "hash_packed", "hash_split",
           "bf16_split")
 
 
-def _prefix_case(kind, case):
-    """-> (run() -> (tables, load), the host's count of valid unique rows).
-    `invalid_mixed` plants negative ids and, on array tables, `pre_counts`
-    of 0 (on hash tables: ids the pull never inserted, which the apply gives
-    count 0), all outside the `_ID_CASES[case]` rows that stay valid."""
-    from openembedding_tpu.embedding import (EmbeddingSpec, init_table_state,
-                                             lookup_train)
+def _prefix_fn(kind):
+    """The apply of a table of `kind` over (the table, ids, g, pre) -> (what
+    it leaves, the step's load). Every case of a kind has the same shapes."""
     from openembedding_tpu.tables.hash_table import (
         hash_apply_gradients, hash_apply_gradients_packed)
+    opt = embed.Adagrad(learning_rate=0.1)
+    if kind == "hash_split":
+        def fn(st, ids, g, pre):
+            st, load = hash_apply_gradients(st, opt, ids, g, with_load=True)
+            return (st.keys, st.weights, st.slots), load
+    elif kind == "hash_packed":
+        def fn(st, ids, g, pre):
+            lay = packed_layout(_DIM, st.slots)
+            st = st.replace(weights=pack_table(st.weights, st.slots, lay),
+                            slots={})
+            st, load = hash_apply_gradients_packed(st, opt, ids, g, lay, _DIM)
+            return (st.keys, st.weights), load
+    elif kind == "array_packed":
+        def fn(table, ids, g, pre):
+            w, s = table
+            lay = packed_layout(_DIM, s)
+            return sparse_apply_packed_table(
+                opt, pack_table(w, s, lay), lay, _DIM, ids, g, pre)
+    else:
+        def fn(table, ids, g, pre):
+            w, s, load = sparse_apply_dense_table(opt, *table, ids, g, pre,
+                                                  with_load=True)
+            return (w, s), load
+    return fn
+
+
+@functools.lru_cache(maxsize=None)
+def _prefix_program(kind, laddered):
+    """ONE jitted apply a (kind, with the ladder or with it patched to
+    `(n,)`): the ids, gradients and counts are arguments, so the cases of a
+    kind share the two programs (as `tests/test_packed_lines.py::
+    _apply_program` does)."""
+    return jax.jit(_prefix_fn(kind))
+
+
+def _prefix_run(kind, laddered, args, monkeypatch):
+    """The patches are on during every call: the first one traces."""
+    with monkeypatch.context() as m:
+        m.setattr(sparse, "FAST_MEMORY_BYTES", 0)  # these are KiB
+        if not laddered:
+            m.setattr(sparse, "apply_ladder", lambda n: (n,))
+        return jax.device_get(_prefix_program(kind, laddered)(*args))
+
+
+def _prefix_case(kind, case):
+    """-> ((the table, ids, g, pre_counts), the host's count of valid unique
+    rows). `invalid_mixed` plants negative ids and, on array tables,
+    `pre_counts` of 0 (on hash tables: ids the pull never inserted, which the
+    apply gives count 0), all outside the `_ID_CASES[case]` rows that stay
+    valid."""
+    from openembedding_tpu.embedding import (EmbeddingSpec, init_table_state,
+                                             lookup_train)
     rng = np.random.default_rng(sorted(_ID_CASES).index(case))
     u = _ID_CASES[case]
     hashed = kind.startswith("hash")
@@ -605,50 +687,20 @@ def _prefix_case(kind, case):
         state, _ = lookup_train(spec, init_table_state(spec, opt),
                                 jnp.asarray(inserted, jnp.int64))
         assert int(state.overflow) == 0
-        if kind == "hash_split":
-            def fn(st):
-                st, load = hash_apply_gradients(st, opt, ids, g, with_load=True)
-                return (st.keys, st.weights, st.slots), load
-        else:
-            lay = packed_layout(_DIM, state.slots)
-
-            def fn(st):
-                st = st.replace(weights=pack_table(st.weights, st.slots, lay),
-                                slots={})
-                st, load = hash_apply_gradients_packed(st, opt, ids, g, lay,
-                                                       _DIM)
-                return (st.keys, st.weights), load
-        # a fresh function a run: a patched ladder must be traced, not cached
-        return (lambda: jax.device_get(jax.jit(lambda st: fn(st))(state))), u
+        return (state, ids, g, None), u
     dtype = jnp.bfloat16 if kind == "bf16_split" else jnp.float32
     w = jnp.asarray(rng.standard_normal((_ROWS, _DIM)), dtype)
-    slots = opt.init_slots(_ROWS, _DIM)
-    pre = jnp.asarray(pre)
-    if kind == "array_packed":
-        lay = packed_layout(_DIM, slots)
-
-        def fn(w, s):
-            return sparse_apply_packed_table(
-                opt, pack_table(w, s, lay), lay, _DIM, ids, g, pre)
-    else:
-        def fn(w, s):
-            w, s, load = sparse_apply_dense_table(opt, w, s, ids, g, pre,
-                                                  with_load=True)
-            return (w, s), load
-    return (lambda: jax.device_get(jax.jit(lambda w, s: fn(w, s))(w, slots))), u
+    return ((w, opt.init_slots(_ROWS, _DIM)), ids, g, jnp.asarray(pre)), u
 
 
 @pytest.mark.parametrize("case", sorted(_ID_CASES))
 @pytest.mark.parametrize("kind", _KINDS)
 def test_apply_over_the_unique_prefix_is_the_full_size_apply(
         kind, case, monkeypatch):
-    from openembedding_tpu.ops import sparse
     assert apply_ladder(_N) == (128, 256, 384, _N)
-    monkeypatch.setattr(sparse, "FAST_MEMORY_BYTES", 0)  # these are KiB
-    run, n_valid = _prefix_case(kind, case)
-    got, load = run()
-    monkeypatch.setattr(sparse, "apply_ladder", lambda n: (n,))
-    want, full = run()
+    args, n_valid = _prefix_case(kind, case)
+    got, load = _prefix_run(kind, True, args, monkeypatch)
+    want, full = _prefix_run(kind, False, args, monkeypatch)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         assert a.dtype == b.dtype
@@ -682,7 +734,6 @@ def test_a_small_table_traces_no_switch(gate, switched, monkeypatch):
     """A table the compiler can keep in fast memory (under
     `FAST_MEMORY_BYTES`) gets the program as it was; a buffer too short to
     split (n <= 128) likewise, whatever the table."""
-    from openembedding_tpu.ops import sparse
     if gate is not None:
         monkeypatch.setattr(sparse, "FAST_MEMORY_BYTES", gate)
     opt = embed.Adagrad(learning_rate=0.1)
@@ -766,6 +817,34 @@ def _plan_batches(case):
     return out
 
 
+_plan_programs = {}
+
+
+def _plan_program(gate, combiner, sample, stacked):
+    """The three programs of a (gate, combiner), traced while the caller's
+    gate is patched in: the scan with the shared plan, the step, the scan as
+    it was. Every case of the pair has the same shapes, so each compiles once
+    a process; a case brings its own batches and fresh states (which is why
+    the key is not the arguments: no `lru_cache`)."""
+    if (gate, combiner) in _plan_programs:
+        return _plan_programs[gate, combiner]
+
+    def trainer(cls):
+        layer = embed.Embedding(_PROWS, _PDIM, name="emb", combiner=combiner)
+        return cls(embed.EmbeddingModel(_BagTower(), [layer]),
+                   embed.Adagrad(learning_rate=0.1), seed=2)
+
+    tr, old = trainer(Trainer), trainer(_PerPositionTrainer)
+    assert "emb" in tr._packed_layouts(tr.init(sample))
+    many = tr.jit_train_many()
+    text = many.lower(tr.init(sample), stacked).as_text()
+    # the pull's conditional and the apply's, or neither
+    assert text.count("stablehlo.case") == (2 if gate == 0 else 0)
+    _plan_programs[gate, combiner] = (tr, many, tr.jit_train_step(), old,
+                                      old.jit_train_many())
+    return _plan_programs[gate, combiner]
+
+
 @pytest.mark.parametrize("case,gate", [(c, 0) for c in sorted(_PLAN_CASES)] + [
     ("duplicates_rung_0", None), ("negative_ids", None)])
 def test_scan_with_the_shared_plan_is_the_step_loop_and_the_plan_less_scan(
@@ -773,7 +852,6 @@ def test_scan_with_the_shared_plan_is_the_step_loop_and_the_plan_less_scan(
     """gate 0: the ladder engaged, as for a table of `FAST_MEMORY_BYTES` and
     more (both conditionals traced, the case's rung taken); None: a table
     under it (no conditional, the gather at n)."""
-    from openembedding_tpu.ops import sparse
     if gate is not None:
         monkeypatch.setattr(sparse, "FAST_MEMORY_BYTES", gate)
     valid, _, combiner = _PLAN_CASES[case]
@@ -782,28 +860,17 @@ def test_scan_with_the_shared_plan_is_the_step_loop_and_the_plan_less_scan(
     stacked = jax.tree_util.tree_map(
         lambda *xs: np.stack(xs) if xs[0] is not None else None, *batches,
         is_leaf=lambda x: x is None)
-
-    def trainer(cls):
-        layer = embed.Embedding(_PROWS, _PDIM, name="emb", combiner=combiner)
-        return cls(embed.EmbeddingModel(_BagTower(), [layer]),
-                   embed.Adagrad(learning_rate=0.1), seed=2)
-
-    tr = trainer(Trainer)
-    assert "emb" in tr._packed_layouts(tr.init(batches[0]))
-    text = tr.jit_train_many().lower(tr.init(batches[0]), stacked).as_text()
-    # the pull's conditional and the apply's, or neither
-    assert text.count("stablehlo.case") == (2 if gate == 0 else 0)
-    planned, m_planned = tr.jit_train_many()(tr.init(batches[0]), stacked)
+    tr, many, step, old, old_many = _plan_program(gate, combiner, batches[0],
+                                                  stacked)
+    planned, m_planned = many(tr.init(batches[0]), stacked)
     assert int(m_planned["apply_full_steps"]["emb"]) == \
         (_PK if gate == 0 and valid > 384 else 0)
 
     stepped, losses = tr.init(batches[0]), []
-    step = tr.jit_train_step()
     for b in batches:
         stepped, m = step(stepped, b)
         losses.append(np.asarray(m["loss"]))
-    old = trainer(_PerPositionTrainer)
-    plan_less, m_plan_less = old.jit_train_many()(old.init(batches[0]), stacked)
+    plan_less, m_plan_less = old_many(old.init(batches[0]), stacked)
 
     for other, their_losses in ((stepped, np.stack(losses)),
                                 (plan_less, np.asarray(m_plan_less["loss"]))):
@@ -856,7 +923,6 @@ def test_k_step_scan_leaves_the_tables_of_the_scatter_based_dedup(
     tables bit for bit, one chip's path and the 8-device exchange's (client
     route + owner dedup)."""
     import dedup_reference
-    from openembedding_tpu.parallel import MeshTrainer, make_mesh
 
     V, steps = 4096, 4
     batches = list(synthetic_criteo(64, id_space=V, steps=steps, seed=21))

@@ -27,6 +27,8 @@ from openembedding_tpu.ops.sparse import (apply_ladder, in_lines, pack_table,
                                           takes_lines, unpack_table)
 
 import dedup_reference
+from apply_reference import (ROUNDS_UNDER_JIT, SLOTTED_OPTS,
+                             assert_same_table, warm_table)
 
 DIM = 10                       # Adagrad: 10 + 10 = the benchmark's width 20
 
@@ -278,6 +280,55 @@ def test_apply_over_lines_leaves_the_row_forms_table(case, planned,
     mates = {"one_a_line": 0.0, "two_a_line": 1.0, "four_a_line": 1.0}
     if case in mates:
         assert load_l["line_mates"] == mates[case]
+
+
+# every optimizer that has slots, each at a dim that puts its packed width in
+# the rule's range, both ends of it included (17 and 32 columns)
+_OPT_DIMS = {"sgd": 9, "adagrad": 10, "adadelta": 8, "adam": 10, "adamax": 10,
+             "ftrl": 10, "rmsprop": 8, "test": 16}
+
+
+@pytest.mark.parametrize("opt", SLOTTED_OPTS, ids=lambda o: o.category)
+def test_every_optimizers_slots_go_through_the_line_form(
+        opt, narrow_tables_take_lines, monkeypatch):
+    """One fused update of a table held four rows a line against the same
+    table in the row form and in the split layout: every slot of every
+    optimizer is packed, picked, merged and unpacked at the columns its
+    layout gives it (the cases above are Adagrad's one `accum`). As compiled
+    programs the two packed forms agree bit for bit and the split layout as
+    `test_packed_apply_matches_split` says; op by op all three do."""
+    dim, rows, n = _OPT_DIMS[opt.category], 1024, 300
+    assert apply_ladder(n) == (128, 256, 300)
+    w, slots, ids, g = warm_table(opt, rows, dim, n,
+                                  np.random.default_rng(dim))
+    lay = packed_layout(dim, slots)
+    width = packed_width(dim, lay)
+    assert 16 < width <= 32 and takes_lines(rows, width)
+
+    def split(w, s):
+        return sparse.sparse_apply_dense_table(opt, w, s, ids, g)
+
+    def through(lined, run):
+        def apply(w, s):
+            packed = pack_table(w, s, lay)
+            assert in_lines(packed, width) == lined
+            out, load = sparse_apply_packed_table(opt, packed, lay, dim, ids, g)
+            assert ("line_mates" in load) == lined
+            return unpack_table(out, lay, dim, jnp.float32)
+        with monkeypatch.context() as m:
+            if not lined:
+                m.setattr(sparse, "takes_lines", lambda r, w: False)
+            return run(apply)(w, slots)
+
+    want = jax.jit(split)(w, slots)
+    assert (np.asarray(want[0]) != np.asarray(w)).any()
+    lines, row_form = through(True, jax.jit), through(False, jax.jit)
+    assert_same_table(row_form, lines)
+    assert_same_table(want, lines, exact=opt.category not in ROUNDS_UNDER_JIT)
+    with jax.disable_jit():
+        want = split(w, slots)
+        for lined in (True, False):
+            assert_same_table(want, through(lined, lambda f: f))
 
 
 # ---------------------------------------------------------------------------

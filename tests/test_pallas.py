@@ -1,237 +1,15 @@
-"""Pallas kernel parity vs the XLA path (interpreter mode on CPU).
-
-The reference validates its server hot path with self-checking expected-value tests
-(`entry/c_api_test.h:32-154`); here the XLA implementation in `ops/sparse.py` is the
-checked-elsewhere oracle and the Pallas kernels must match it bit-for-bit (same f32
-math, same masking contract)."""
+"""The row-DMA scatter kernel (`ops/pallas_scatter.py`) against XLA's scatter,
+under the interpreter on the CPU: bit for bit on what `ops.sparse.scatter_rows`
+hands it, the rule that chooses it from the table's shape (`takes_row_dmas`)
+and its counter, and a `train_many` scan through it. The line form's kernels
+(`ops/pallas_lines.py`) are `tests/test_packed_lines.py`'s, the attention
+kernel `tests/test_attention_kernel.py`'s."""
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-
-from openembedding_tpu.ops import pallas_sparse
-from openembedding_tpu.ops.sparse import lookup_rows, sparse_apply_dense_table
-from openembedding_tpu import optimizers
-
-
-@pytest.fixture(autouse=True)
-def _pallas_off_by_default():
-    """Each test drives the mode explicitly; never leak state across tests."""
-    pallas_sparse.set_mode("off")
-    yield
-    pallas_sparse.set_mode("off")
-
-
-def _rand_table(rng, n_rows, dim, dtype=jnp.float32):
-    return jnp.asarray(rng.standard_normal((n_rows, dim)), dtype)
-
-
-def test_gather_rows_matches_xla():
-    rng = np.random.default_rng(0)
-    w = _rand_table(rng, 64, 12)
-    rows = jnp.asarray(rng.integers(-5, 80, size=50), jnp.int32)  # incl. OOB both ends
-    ref = lookup_rows(w, rows)
-    got = pallas_sparse.gather_rows(w, rows, interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
-
-
-def test_gather_rows_valid_mask():
-    rng = np.random.default_rng(1)
-    w = _rand_table(rng, 32, 8)
-    rows = jnp.asarray(rng.integers(0, 32, size=20), jnp.int32)
-    valid = jnp.asarray(rng.integers(0, 2, size=20).astype(bool))
-    ref = lookup_rows(w, rows, valid)
-    got = pallas_sparse.gather_rows(w, rows, valid, interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
-
-
-def test_gather_rows_non_divisible_block():
-    rng = np.random.default_rng(2)
-    w = _rand_table(rng, 300, 9)  # dim 9: the reference benchmark dim, unaligned
-    rows = jnp.asarray(rng.integers(0, 300, size=37), jnp.int32)
-    ref = lookup_rows(w, rows)
-    got = pallas_sparse.gather_rows(w, rows, block=16, interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
-
-
-ALL_OPTS = [
-    optimizers.Default(learning_rate=0.1),
-    optimizers.SGD(learning_rate=0.05, momentum=0.9, nesterov=True),
-    optimizers.Adagrad(learning_rate=0.1),
-    optimizers.Adadelta(learning_rate=0.5),
-    optimizers.Adam(learning_rate=0.01),
-    optimizers.Adamax(learning_rate=0.01),
-    optimizers.Ftrl(learning_rate=0.05, l1_regularization_strength=0.01,
-                    l2_regularization_strength=0.01),
-    optimizers.RMSprop(learning_rate=0.05, momentum=0.5),
-    optimizers.TestOptimizer(),
-]
-
-
-@pytest.mark.parametrize("opt", ALL_OPTS, ids=lambda o: o.category)
-def test_fused_apply_matches_xla(opt):
-    rng = np.random.default_rng(3)
-    n_rows, dim, n = 64, 12, 40
-    w = _rand_table(rng, n_rows, dim)
-    slots = opt.init_slots(n_rows, dim)
-    # warm the slots so non-trivial state paths are exercised
-    ids0 = jnp.asarray(rng.integers(0, n_rows, size=n))
-    g0 = jnp.asarray(rng.standard_normal((n, dim)), jnp.float32)
-    w, slots = sparse_apply_dense_table(opt, w, slots, ids0, g0)
-
-    ids = jnp.asarray(rng.integers(0, n_rows, size=n))  # duplicates likely
-    g = jnp.asarray(rng.standard_normal((n, dim)), jnp.float32)
-
-    ref_w, ref_s = sparse_apply_dense_table(opt, w, slots, ids, g)
-    pallas_sparse.set_mode("interpret")
-    got_w, got_s = sparse_apply_dense_table(opt, w, slots, ids, g)
-
-    # rtol covers ftrl's slightly different operation order in the kernel (~1e-7 rel)
-    np.testing.assert_allclose(np.asarray(ref_w), np.asarray(got_w),
-                               rtol=2e-6, atol=1e-6)
-    for k in ref_s:
-        np.testing.assert_allclose(np.asarray(ref_s[k]), np.asarray(got_s[k]),
-                                   rtol=2e-6, atol=1e-6, err_msg=k)
-
-
-def test_fused_apply_padding_rows_untouched():
-    """counts == 0 and out-of-range rows must leave the table bit-identical."""
-    rng = np.random.default_rng(4)
-    opt = optimizers.Adagrad(learning_rate=0.1)
-    n_rows, dim = 32, 8
-    w = _rand_table(rng, n_rows, dim)
-    slots = opt.init_slots(n_rows, dim)
-    rows = jnp.asarray([3, 7, n_rows, -1, 3 + n_rows * 10], jnp.int32)
-    counts = jnp.asarray([1, 2, 1, 1, 1], jnp.int32)
-    grads = jnp.asarray(rng.standard_normal((5, dim)), jnp.float32)
-    new_w, new_s = pallas_sparse.fused_sparse_apply(
-        opt, w, slots, rows, grads, counts, interpret=True)
-    touched = {3, 7}
-    for r in range(n_rows):
-        if r in touched:
-            assert not np.allclose(np.asarray(new_w[r]), np.asarray(w[r]))
-        else:
-            np.testing.assert_array_equal(np.asarray(new_w[r]), np.asarray(w[r]))
-            np.testing.assert_array_equal(np.asarray(new_s["accum"][r]),
-                                          np.asarray(slots["accum"][r]))
-
-
-def test_fused_apply_bf16_table():
-    """bf16 weights: f32 update math, bf16 store (slots stay f32)."""
-    rng = np.random.default_rng(5)
-    opt = optimizers.Adam(learning_rate=0.05)
-    n_rows, dim, n = 48, 16, 24
-    w = _rand_table(rng, n_rows, dim, jnp.bfloat16)
-    slots = opt.init_slots(n_rows, dim)
-    ids = jnp.asarray(rng.integers(0, n_rows, size=n))
-    g = jnp.asarray(rng.standard_normal((n, dim)), jnp.float32)
-    ref_w, ref_s = sparse_apply_dense_table(opt, w, slots, ids, g)
-    pallas_sparse.set_mode("interpret")
-    got_w, got_s = sparse_apply_dense_table(opt, w, slots, ids, g)
-    np.testing.assert_array_equal(np.asarray(ref_w, np.float32),
-                                  np.asarray(got_w, np.float32))
-    for k in ref_s:
-        np.testing.assert_allclose(np.asarray(ref_s[k]), np.asarray(got_s[k]),
-                                   atol=1e-6)
-
-
-def test_hash_table_apply_via_pallas():
-    """The hash push path routes slots through the same fused apply."""
-    from openembedding_tpu.embedding import (EmbeddingSpec, apply_gradients,
-                                             init_table_state, lookup_train)
-    rng = np.random.default_rng(6)
-    spec = EmbeddingSpec(name="h", input_dim=-1, output_dim=8, capacity=128,
-                         variable_id=0)
-    opt = optimizers.Adagrad(learning_rate=0.1)
-    state = init_table_state(spec, opt)
-    ids = jnp.asarray(rng.integers(0, 1 << 40, size=30).astype(np.int64))
-    state, _ = lookup_train(spec, state, ids)
-    grads = jnp.asarray(rng.standard_normal((30, 8)), jnp.float32)
-
-    ref = apply_gradients(spec, state, opt, ids, grads)
-    pallas_sparse.set_mode("interpret")
-    got = apply_gradients(spec, state, opt, ids, grads)
-    np.testing.assert_allclose(np.asarray(ref.weights), np.asarray(got.weights),
-                               atol=1e-6)
-
-
-def test_single_device_train_step_with_pallas():
-    """Whole Trainer step under interpret mode stays numerically on the XLA path."""
-    import openembedding_tpu as embed
-    from openembedding_tpu.model import Trainer
-    from openembedding_tpu.models import make_deepfm
-    from openembedding_tpu.data import synthetic_criteo
-
-    model = make_deepfm(vocabulary=1 << 12, dim=8)
-    batch = next(synthetic_criteo(64, id_space=1 << 12, steps=1, seed=0))
-
-    def run():
-        trainer = Trainer(model, embed.Adagrad(learning_rate=0.05), seed=1)
-        state = trainer.init(batch)
-        state, metrics = trainer.jit_train_step()(state, batch)
-        return float(metrics["loss"]), state
-
-    loss_ref, state_ref = run()
-    pallas_sparse.set_mode("interpret")
-    loss_got, state_got = run()
-    assert np.isfinite(loss_got)
-    np.testing.assert_allclose(loss_got, loss_ref, atol=1e-6)
-    for name in state_ref.tables:
-        np.testing.assert_allclose(
-            np.asarray(state_ref.tables[name].weights),
-            np.asarray(state_got.tables[name].weights), atol=1e-6)
-
-
-def test_env_mode_validated(monkeypatch):
-    """Round-1 advisor: OETPU_PALLAS=garbage must not silently enable Pallas."""
-    monkeypatch.setenv("OETPU_PALLAS", "TRUE")
-    with pytest.warns(RuntimeWarning, match="OETPU_PALLAS"):
-        assert pallas_sparse._env_mode() == "off"
-    monkeypatch.setenv("OETPU_PALLAS", "interpret")
-    assert pallas_sparse._env_mode() == "interpret"
-
-
-def test_gather_rows_windows_matches_xla():
-    """Window-batched gather (PERF lever #1): sorted, clustered, uniform, and
-    OOB ids all match the XLA oracle."""
-    rng = np.random.default_rng(3)
-    w = _rand_table(rng, 1000, 12)
-    # clustered (frequency-relabeled shape): many ids in the hot low range
-    hot = np.sort(rng.integers(0, 64, size=40))
-    cold = np.sort(rng.integers(64, 1000, size=24))
-    for rows_np in (
-        np.concatenate([hot, cold]),                      # sorted, clustered
-        rng.integers(0, 1000, size=77),                   # unsorted uniform
-        np.asarray([0, 1, 2, 998, 999]),                  # table-edge windows
-        np.asarray([-3, 5, 1005]),                        # OOB both ends
-    ):
-        rows = jnp.asarray(rows_np, jnp.int32)
-        ref = lookup_rows(w, rows)
-        got = pallas_sparse.gather_rows_windows(w, rows, window=16,
-                                                interpret=True)
-        np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
-
-
-def test_gather_rows_windows_small_table_falls_back():
-    rng = np.random.default_rng(4)
-    w = _rand_table(rng, 8, 4)  # table smaller than the window
-    rows = jnp.asarray([0, 3, 7, 9, -1], jnp.int32)
-    ref = lookup_rows(w, rows)
-    got = pallas_sparse.gather_rows_windows(w, rows, window=16,
-                                            interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
-
-
-def test_gather_rows_windows_multiblock():
-    rng = np.random.default_rng(5)
-    w = _rand_table(rng, 4096, 8)
-    rows = jnp.asarray(np.sort(rng.integers(0, 4096, size=700)), jnp.int32)
-    ref = lookup_rows(w, rows)
-    got = pallas_sparse.gather_rows_windows(w, rows, window=32, block=256,
-                                            interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
 
 
 # ---------------------------------------------------------------------------

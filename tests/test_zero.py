@@ -15,6 +15,7 @@ Acceptance (ISSUE 10):
   enforced at conversion time.
 """
 
+import functools
 import os
 
 import numpy as np
@@ -185,6 +186,15 @@ def _run_training(tmp_path, tag, *, dense_shard, dense_wire=None):
     return losses
 
 
+@pytest.fixture(scope="module")
+def control_run(tmp_path_factory):
+    """-> (root, losses) of the replicated fp32 run every artifact test
+    compares against: the same six steps and the same three writers each
+    time, so it is made once a process."""
+    root = tmp_path_factory.mktemp("zero_control")
+    return root / "c", _run_training(root, "c", dense_shard=False)
+
+
 def _assert_trees_equal(off_root, on_root, skip=("model_meta",)):
     found = 0
     for root, _dirs, files in os.walk(off_root):
@@ -199,23 +209,22 @@ def _assert_trees_equal(off_root, on_root, skip=("model_meta",)):
     assert found > 0
 
 
-def test_zero_checkpoint_export_delta_byte_identical(tmp_path):
+def test_zero_checkpoint_export_delta_byte_identical(tmp_path, control_run):
     """A dense_shard run's on-disk artifacts — sharded checkpoint,
     standalone export, incremental sync deltas — equal a ZeRO-off control
     run's byte for byte (every writer goes through `externalize`)."""
-    l_off = _run_training(tmp_path, "off", dense_shard=False)
+    off, l_off = control_run
     l_on = _run_training(tmp_path, "on", dense_shard=True)
     assert l_off == l_on
-    _assert_trees_equal(tmp_path / "off" / "ckpt", tmp_path / "on" / "ckpt")
-    _assert_trees_equal(tmp_path / "off" / "export",
-                        tmp_path / "on" / "export",
+    _assert_trees_equal(off / "ckpt", tmp_path / "on" / "ckpt")
+    _assert_trees_equal(off / "export", tmp_path / "on" / "export",
                         skip=("model_meta", "model_meta.json"))
     import glob
-    offs = sorted(glob.glob(str(tmp_path / "off" / "persist" / "**" /
-                                "table_*.npz"), recursive=True))
+    offs = sorted(glob.glob(str(off / "persist" / "**" / "table_*.npz"),
+                            recursive=True))
     assert offs
     for p_off in offs:
-        p_on = p_off.replace(str(tmp_path / "off"), str(tmp_path / "on"))
+        p_on = p_off.replace(str(off), str(tmp_path / "on"))
         a, b = np.load(p_off), np.load(p_on)
         assert sorted(a.files) == sorted(b.files), p_off
         for k in a.files:
@@ -228,20 +237,26 @@ def test_zero_checkpoint_cross_compatible(tmp_path):
     continued training stays bit-exact — the serialized form is ONE layout
     (replicated), conversion happens at the load/save boundary."""
     batches = _batches(5, seed=11)
+    # one trainer, so ONE compiled step, a `dense_shard` value: what a combo
+    # is about is the state that crosses the dump. The state that trains is
+    # made anew by each `init`; the one `load` fills is a template (`load`
+    # reads its shapes and shardings and returns arrays of its own), made
+    # once a destination
+    trainers = {shard: MeshTrainer(_model(), embed.Adagrad(learning_rate=0.1),
+                                   mesh=make_mesh(), dense_shard=shard)
+                for shard in (False, True)}
+    templates = {shard: tr.init(batches[0]) for shard, tr in trainers.items()}
 
     def run(save_shard, load_shard):
-        tr = MeshTrainer(_model(), embed.Adagrad(learning_rate=0.1),
-                         mesh=make_mesh(), dense_shard=save_shard)
+        tr = trainers[save_shard]
         state = tr.init(batches[0])
         step = tr.jit_train_step(batches[0], state)
         for b in batches[:2]:
             state, _ = step(state, b)
         path = str(tmp_path / f"ckpt_{save_shard}_{load_shard}")
         tr.save(state, path, model_sign="x")
-        tr2 = MeshTrainer(_model(), embed.Adagrad(learning_rate=0.1),
-                          mesh=make_mesh(), dense_shard=load_shard)
-        st2 = tr2.init(batches[0])
-        st2 = tr2.load(st2, path)
+        tr2 = trainers[load_shard]
+        st2 = tr2.load(templates[load_shard], path)
         if load_shard:
             assert zero.is_sharded_slots(st2.dense_slots)
         step2 = tr2.jit_train_step(batches[0], st2)
@@ -312,6 +327,21 @@ def test_zero_rejects_wide_dtypes():
 # ---------------------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
+def _four_steps_of_zero(dense_wire):
+    batches = _batches(4, seed=3)
+    tr = MeshTrainer(_model(), embed.Adagrad(learning_rate=0.1),
+                     mesh=make_mesh(), wire="fp32", dense_shard=True,
+                     dense_wire=dense_wire)
+    state = tr.init(batches[0])
+    step = tr.jit_train_step(batches[0], state)
+    losses = []
+    for b in batches:
+        state, m = step(state, b)
+        losses.append(float(m["loss"]))
+    return tr, state, losses
+
+
 @pytest.mark.parametrize("fmt", ["bf16", "int8", "sparse_topk"])
 def test_dense_wire_trains_close_to_fp32(fmt):
     """`dense_wire` swaps the fp32 psum_scatter for the in-band-encoded
@@ -322,21 +352,8 @@ def test_dense_wire_trains_close_to_fp32(fmt):
     steps stay within format tolerance of the lossless round-14 path."""
     from openembedding_tpu.ops import wire as wire_mod
 
-    def run(dense_wire):
-        batches = _batches(4, seed=3)
-        tr = MeshTrainer(_model(), embed.Adagrad(learning_rate=0.1),
-                         mesh=make_mesh(), wire="fp32", dense_shard=True,
-                         dense_wire=dense_wire)
-        state = tr.init(batches[0])
-        step = tr.jit_train_step(batches[0], state)
-        losses = []
-        for b in batches:
-            state, m = step(state, b)
-            losses.append(float(m["loss"]))
-        return tr, state, losses
-
-    tr_f, st_f, l_f = run(None)
-    tr_q, st_q, l_q = run(fmt)
+    tr_f, st_f, l_f = _four_steps_of_zero(None)  # the same run in every case
+    tr_q, st_q, l_q = _four_steps_of_zero(fmt)
     plan = tr_q._zero_plan
     assert plan.chunk % wire_mod.INBAND_BLOCK == 0
     flat = st_q.dense_slots[zero.ZERO_KEY]
@@ -383,13 +400,21 @@ def test_dense_wire_checkpoint_cross_compatible(tmp_path):
         "zero_sparse": {"dense_shard": True, "dense_wire": "sparse_topk"},
     }
 
-    def make(cfg):
-        return MeshTrainer(_model(), embed.Adagrad(learning_rate=0.1),
-                           mesh=make_mesh(), wire="fp32", **configs[cfg])
+    # one trainer, so ONE compiled step, a configuration, as source and as
+    # destination alike: every pair is about the state that crosses the dump,
+    # and the program a destination runs depends on its configuration alone.
+    # A source trains a state of its own `init`; what `load` fills is a
+    # template (it reads shapes and shardings and returns arrays of its
+    # own: the step after it donates them, never the template's), made once
+    # a destination
+    trainers = {cfg: MeshTrainer(_model(), embed.Adagrad(learning_rate=0.1),
+                                 mesh=make_mesh(), wire="fp32", **kw)
+                for cfg, kw in configs.items()}
+    templates = {cfg: tr.init(batches[0]) for cfg, tr in trainers.items()}
 
     saved = {}
     for cfg in ("replicated", "zero_int8", "zero_sparse"):
-        tr = make(cfg)
+        tr = trainers[cfg]
         state = tr.init(batches[0])
         step = tr.jit_train_step(batches[0], state)
         for b in batches[:2]:
@@ -400,9 +425,8 @@ def test_dense_wire_checkpoint_cross_compatible(tmp_path):
 
     for src, (path, ext_src) in saved.items():
         for dst in configs:
-            tr2 = make(dst)
-            st2 = tr2.init(batches[0])
-            st2 = tr2.load(st2, path)
+            tr2 = trainers[dst]
+            st2 = tr2.load(templates[dst], path)
             if dst != "replicated":
                 assert zero.is_sharded_slots(st2.dense_slots)
                 flat = st2.dense_slots[zero.ZERO_KEY]
@@ -417,7 +441,8 @@ def test_dense_wire_checkpoint_cross_compatible(tmp_path):
 
 
 @pytest.mark.parametrize("fmt", ["int8", "sparse_topk"])
-def test_dense_wire_artifacts_schema_oblivious_and_reload(tmp_path, fmt):
+def test_dense_wire_artifacts_schema_oblivious_and_reload(tmp_path, fmt,
+                                                          control_run):
     """A narrow-wire run (int8 or sparse_topk) writes artifacts — sharded
     checkpoint, standalone export, incremental sync deltas — with EXACTLY
     the file set and array schema of a replicated fp32 control run (masters
@@ -425,7 +450,6 @@ def test_dense_wire_artifacts_schema_oblivious_and_reload(tmp_path, fmt):
     disk), and its checkpoint reloads into a fresh dense_wire trainer which
     keeps training."""
     l_q = _run_training(tmp_path, "q", dense_shard=True, dense_wire=fmt)
-    _run_training(tmp_path, "c", dense_shard=False)
     assert np.all(np.isfinite(l_q))
 
     def listing(root):
@@ -436,7 +460,7 @@ def test_dense_wire_artifacts_schema_oblivious_and_reload(tmp_path, fmt):
                 out[os.path.relpath(p, root)] = p
         return out
 
-    q, c = listing(tmp_path / "q"), listing(tmp_path / "c")
+    q, c = listing(tmp_path / "q"), listing(control_run[0])
     assert sorted(q) == sorted(c)
     checked = 0
     for rel, p in q.items():
