@@ -3,7 +3,7 @@
 Reference coverage (`documents/en/benchmark.md:6-16`, `examples/`,
 `test/benchmark/criteo_deepctr.py`): WDL (Wide&Deep), DeepFM, xDeepFM at dims 9/64,
 the LR subclass example (`examples/criteo_lr_subclass.py`), plus DLRM (the reference's
-PMem paper workload) and a two-tower retrieval model. Five language-model towers
+PMem paper workload) and a two-tower retrieval model. Six language-model towers
 behind a token table ride the same train path: NemotronH (`nemotron_h.py`: Mamba-2,
 attention, routed experts), JoyAI-LLM-Flash (`joyai_flash.py`: latent attention,
 routed SwiGLU experts, a multi-token-prediction module), Solar-Open2
@@ -13,7 +13,10 @@ without positions, routed SwiGLU experts; a share of the heads held) and ZAYA1
 travels up the stack, top-1 experts, the token table tied to the head and
 trained densely) and Ouro (`ouro.py`: a dense stack walked several times over
 shared weights as one scanned body, an exit gate a pass, the expected loss over
-the exits through one head, computed inside the walk).
+the exits through one head, computed inside the walk) and Granite 4.0-H
+(`granite_hybrid.py`: nine Mamba-2 layers in ten, a SwiGLU after every mixer,
+four muP scalars, a tied head; documents packed into a sequence, the state, the
+convolution and the attention mask reset at every document start).
 
 TPU-first layout decision (differs deliberately from the reference's per-feature
 DeepCTR `Embedding` layers): all categorical fields share ONE row-sharded table, with
@@ -36,6 +39,7 @@ from .joyai_flash import JoyAIFlash, make_joyai_flash, mtp_xent
 from .solar_open2 import SolarOpen2, kda_chunked, make_solar_open2
 from .zaya1 import Zaya1, make_zaya1
 from .ouro import Ouro, expected_exit_loss, make_ouro
+from .granite_hybrid import GraniteHybrid, make_granite_hybrid
 
 _FAMILIES = {
     "lr": make_lr, "wdl": make_wdl, "deepfm": make_deepfm,
@@ -48,6 +52,7 @@ _FAMILIES = {
     "solar_open2": make_solar_open2,
     "zaya1": make_zaya1,
     "ouro": make_ouro,
+    "granite_hybrid": make_granite_hybrid,
 }
 
 
@@ -84,5 +89,6 @@ __all__ = [
     "SolarOpen2", "make_solar_open2", "kda_chunked",
     "Zaya1", "make_zaya1",
     "Ouro", "make_ouro", "expected_exit_loss",
+    "GraniteHybrid", "make_granite_hybrid",
     "CRITEO_NUM_SPARSE", "CRITEO_NUM_DENSE",
 ]

@@ -44,6 +44,13 @@ Stage names (`utils/trace.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
 module hands `Trainer` per-step `moe.*` stats (`apply_with_stats`,
 `window_stats`); `attn.cores{path=}` counts the traced attention cores by
 what their shape allows (`blockwise_causal_attention`).
+
+Documents packed into a sequence (`granite_hybrid.py`): `ssd_chunked`,
+`causal_conv` and `blockwise_causal_attention` take `starts` (B, S), nonzero
+where a document begins, and reset the state, the taps and the mask there;
+`segment_ids` (scope `pack.segments`) makes the document ids every mask is a
+pair of; `pack.resets{site=}` counts the traced call sites given starts.
+With none given each traces the program it traced before it could.
 """
 
 from __future__ import annotations
@@ -126,14 +133,36 @@ def _mm(spec, a, b, dtype):
                       preferred_element_type=jnp.float32)
 
 
-def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32):
+def segment_ids(starts):
+    """starts (B, S), nonzero where a document begins -> n (B, S) int32, the
+    document a position belongs to (the running count of starts: 1.. where
+    position 0 starts one). Two positions see each other where their n is
+    equal; every mask of a packed sequence is made of such pairs."""
+    with _trace.scope("pack", "segments"):
+        return jnp.cumsum((starts != 0).astype(jnp.int32), axis=1)
+
+
+def _count_reset(site: str):
+    """`pack.resets{site=}`: one a traced call site that was given starts."""
+    _metrics.observe("pack.resets", 1, "sum", labels={"site": site})
+
+
+def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32, starts=None):
     """The Mamba-2 recurrence h_t = exp(dt_t A) h_{t-1} + dt_t B_t (x) x_t,
     y_t = C_t . h_t, chunk by chunk (state-space duality: inside a chunk a
     masked matrix product, between chunks the recurrence on chunk states).
     x (Bt, L, H, P); dt (Bt, L, H) f32, already positive; A (H,) f32 negative;
     B, C (Bt, L, G, N), H % G == 0. -> (Bt, L, H, P) f32. L need not be a
     multiple of `chunk`: the tail is padded with dt = 0, which neither decays
-    nor feeds the state. Decays are f32; matrix products take `dtype` inputs."""
+    nor feeds the state. Decays are f32; matrix products take `dtype` inputs.
+    `starts` (Bt, L), nonzero where a document begins: the state is zero
+    before each (h_t = dt_t B_t (x) x_t there). The reset is a mask of
+    PAIRS, never a -inf added to a cumulated exponent (two positions after a
+    start would then differ by the rounding of 1e30): with n the document of
+    a position (`segment_ids`), inside a chunk the pair (l, s) counts where
+    n_l = n_s; what a chunk leaves keeps the positions of its last
+    document; chunk c's state enters chunk z where nothing starts between
+    their ends, and a position reads it where it is still in that document."""
     Bt, L, H, P = x.shape
     G, N = B.shape[2:]
     r = H // G
@@ -142,6 +171,14 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32):
         x, dt, B, C = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
                        for t in (x, dt, B, C))
     c = (L + pad) // chunk
+    if starts is not None:
+        _count_reset("ssd")
+        n = jnp.pad(segment_ids(starts), ((0, 0), (0, pad)), mode="edge")
+        n = n.reshape(Bt, c, chunk)
+        n_end = n[:, :, -1]                                  # (Bt,c)
+        # the document the state ENTERING chunk z belongs to: chunk z-1's
+        # last (nothing enters chunk 0, whatever this says there)
+        n_in = jnp.pad(n_end[:, :-1], ((0, 0), (1, 0)))
     dt = dt.astype(jnp.float32).reshape(Bt, c, chunk, G, r)
     xd = (x.astype(jnp.float32).reshape(Bt, c, chunk, G, r, P) * dt[..., None])
     Bc = B.reshape(Bt, c, chunk, G, N)
@@ -151,11 +188,15 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32):
     # inside a chunk: y_l += sum_{s<=l} (C_l . B_s) exp(acs_l - acs_s) dt_s x_s
     seg = acs[:, :, :, None] - acs[:, :, None, :]            # (Bt,c,l,s,G,r)
     tri = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None, None]
+    if starts is not None:
+        tri = tri & (n[:, :, :, None] == n[:, :, None, :])[..., None, None]
     decay = jnp.exp(jnp.where(tri, seg, -jnp.inf))
     cb = _mm("bclgn,bcsgn->bclsg", Cc, Bc, dtype)
     y = _mm("bclsgr,bcsgrp->bclgrp", cb[..., None] * decay, xd, dtype)
     # what a chunk leaves behind: sum_s exp(acs_end - acs_s) dt_s B_s (x) x_s
     left = jnp.exp(acs[:, :, -1:] - acs)                     # (Bt,c,Q,G,r)
+    if starts is not None:
+        left = jnp.where((n == n_end[:, :, None])[..., None, None], left, 0.0)
     states = _mm("bcsgn,bcsgrp->bcgrpn", Bc, xd * left[..., None], dtype)
     # between chunks: the state entering chunk z is sum_{c<z} exp(sum of the
     # chunk totals strictly between) states_c (a (c, c) lower-triangular product)
@@ -163,18 +204,26 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32):
     run = jnp.cumsum(tot, axis=1)
     between = (run - tot)[:, :, None] - run[:, None, :]      # (Bt,z,c,G,r)
     low = jnp.tril(jnp.ones((c, c), bool), -1)[:, :, None, None]
+    if starts is not None:
+        low = low & (n_in[:, :, None] == n_end[:, None, :])[..., None, None]
     carry = jnp.exp(jnp.where(low, between, -jnp.inf))
     entering = _mm("bzcgr,bcgrpn->bzgrpn", carry, states, dtype)
-    y = y + _mm("bclgn,bcgrpn->bclgrp", Cc, entering, dtype) * \
-        jnp.exp(acs)[..., None]
+    read = _mm("bclgn,bcgrpn->bclgrp", Cc, entering, dtype)
+    reach = jnp.exp(acs)                                     # (Bt,c,Q,G,r)
+    if starts is not None:
+        reach = jnp.where((n == n_in[:, :, None])[..., None, None], reach, 0.0)
+    y = y + read * reach[..., None]
     return y.reshape(Bt, c * chunk, H, P)[:, :L]
 
 
-def blockwise_causal_attention(q, k, v, *, block: int = 512):
+def blockwise_causal_attention(q, k, v, *, block: int = 512, scale=None,
+                               starts=None):
     """Causal softmax attention: q (B, S, Hq, D), k (B, S, Hkv, D), v
     (B, S, Hkv, Dv), Hq % Hkv == 0 (grouped key/value heads; Dv need not be
-    D) -> (B, S, Hq, Dv); scale D^-1/2; softmax in f32; nothing of size
-    S x S is ever kept. The entry to two bodies that share no logic: at a
+    D) -> (B, S, Hq, Dv); scores times `scale` (None: D^-1/2); softmax in
+    f32; nothing of size S x S is ever kept. `starts` (B, S), nonzero where
+    a document begins: a query sees the keys of its own document alone
+    (`segment_ids`). The entry to two bodies that share no logic: at a
     shape the fused kernel's tiling takes (`ops/flash_attention.tiling`) a
     TPU lowering runs the kernel (its own blocks, scores in VMEM only) and
     every other platform the plain body; at any other shape (`block` queries
@@ -182,14 +231,22 @@ def blockwise_causal_attention(q, k, v, *, block: int = 512):
     settled when the program is lowered, so `attn.cores{path=}`, counted
     here once a traced call site, speaks for the SHAPE alone: "fused" = the
     kernel would take it, "blockwise" = it refuses it. That the kernel ran
-    is the device trace's to say (a custom call under `attn.core`)."""
+    is the device trace's to say (a custom call under `attn.core`). The
+    kernel has one scale and no document mask: a call that gives either
+    counts as "blockwise" and runs the plain body. Both series exist once
+    one does (the other reads 0)."""
     (_, S, Hq, D), Hkv, Dv = q.shape, k.shape[2], v.shape[3]
-    fused = _flash().tiling(S, D, Dv, Hq, Hkv) is not None
-    _metrics.observe("attn.cores", 1, "sum",
-                     labels={"path": "fused" if fused else "blockwise"})
+    fused = (scale is None and starts is None
+             and _flash().tiling(S, D, Dv, Hq, Hkv) is not None)
+    for path in ("fused", "blockwise"):
+        _metrics.observe("attn.cores", int(fused == (path == "fused")), "sum",
+                         labels={"path": path})
     plain = functools.partial(_blockwise_causal_attention, block=block)
     if not fused:
-        return plain(q, k, v)
+        if starts is None:
+            return plain(q, k, v, scale=scale)
+        _count_reset("attn")
+        return plain(q, k, v, scale=scale, n=segment_ids(starts))
     return jax.lax.platform_dependent(q, k, v, tpu=_flash().causal_attention,
                                       default=plain)
 
@@ -202,40 +259,60 @@ def _flash():
     return flash_attention
 
 
-def _blockwise_causal_attention(q, k, v, *, block):
+def _blockwise_causal_attention(q, k, v, *, block, scale=None, n=None):
     """The plain body: one block of queries at a time against the keys it
     can see (keys [0, block end)), each block rematerialised in the backward
     pass, the blocks above the diagonal never computed; the f32 scores of a
-    block are an array of the program."""
+    block are an array of the program. `n` (B, S): the document of each
+    position; a (query, key) pair of two documents is masked like one above
+    the diagonal."""
     B, S, Hq, D = q.shape
     Hkv, Dv = k.shape[2], v.shape[3]
     qg = q.reshape(B, S, Hkv, Hq // Hkv, D)
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
 
     @jax.checkpoint
-    def one(qb, kb, vb, lo):
+    def one(qb, kb, vb, lo, *docs):
         s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kb,
                        preferred_element_type=jnp.float32) * scale
         qpos = lo + jnp.arange(qb.shape[1])[:, None]
-        s = jnp.where(qpos >= jnp.arange(kb.shape[1])[None, :], s, NEG_INF)
+        seen = qpos >= jnp.arange(kb.shape[1])[None, :]
+        if docs:
+            nq, nk = docs
+            seen = seen & (nq[:, :, None] == nk[:, None, :])[:, None, None]
+        s = jnp.where(seen, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1).astype(vb.dtype)
         return jnp.einsum("bgrqk,bkgd->bqgrd", p, vb,
                           preferred_element_type=jnp.float32).astype(qb.dtype)
 
-    out = [one(qg[:, lo:lo + block], k[:, :lo + block], v[:, :lo + block], lo)
+    def docs(lo):
+        return () if n is None else (n[:, lo:lo + block], n[:, :lo + block])
+
+    out = [one(qg[:, lo:lo + block], k[:, :lo + block], v[:, :lo + block], lo,
+               *docs(lo))
            for lo in range(0, S, block)]
     return jnp.concatenate(out, axis=1).reshape(B, S, Hq, Dv)
 
 
-def causal_conv(x, w, bias=None):
+def causal_conv(x, w, bias=None, starts=None):
     """Depthwise causal convolution over time: x (B, S, C), w (K, C) ->
     (B, S, C) f32; tap j reads position t - (K - 1) + j, positions before
-    the sequence read 0. Shared with `solar_open2.py`'s linear layers."""
+    the sequence read 0. Shared with `solar_open2.py`'s linear layers.
+    `starts` (B, S), nonzero where a document begins: a tap that would read
+    another document's position reads 0 (`segment_ids`)."""
     K, S = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     acc = 0.0 if bias is None else bias.astype(jnp.float32)
+    if starts is not None:
+        _count_reset("conv")
+        n = segment_ids(starts)
+        before = jnp.pad(n, ((0, 0), (K - 1, 0)))
     for j in range(K):
-        acc = acc + padded[:, j:j + S].astype(jnp.float32) * w[j]
+        tap = padded[:, j:j + S].astype(jnp.float32)
+        if starts is not None and j < K - 1:
+            tap = jnp.where((before[:, j:j + S] == n)[..., None], tap, 0.0)
+        acc = acc + tap * w[j]
     return acc
 
 
@@ -263,7 +340,7 @@ class Mamba2Mixer(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, starts=None):
         H, P, G, N, K = (self.num_heads, self.head_dim, self.n_groups,
                          self.state, self.conv_kernel)
         inner, bc = H * P, G * N
@@ -276,7 +353,8 @@ class Mamba2Mixer(nn.Module):
             w = self.param("conv_kernel", nn.initializers.normal(K ** -0.5),
                            (K, conv_dim))
             b = self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
-            xbc = jax.nn.silu(causal_conv(xbc, w, b)).astype(self.dtype)
+            xbc = jax.nn.silu(causal_conv(xbc, w, b, starts)).astype(
+                self.dtype)
             xs, Bm, Cm = jnp.split(xbc, [inner, inner + bc], axis=-1)
         with _trace.scope("ssm", "scan"):
             dt_bias = self.param("dt_bias", _dt_bias_init, (H,))
@@ -287,7 +365,7 @@ class Mamba2Mixer(nn.Module):
             y = ssd_chunked(xh, dt, -jnp.exp(A_log.astype(jnp.float32)),
                             Bm.reshape(Bm.shape[:2] + (G, N)),
                             Cm.reshape(Cm.shape[:2] + (G, N)),
-                            self.chunk, self.dtype)
+                            self.chunk, self.dtype, starts)
             y = y + xh.astype(jnp.float32) * D[:, None]
             y = y.reshape(xs.shape)
         with _trace.scope("ssm", "gate_norm"):
@@ -309,9 +387,11 @@ class Attention(nn.Module):
     # an element-wise sigmoid gate as wide as the core's output, from the
     # layer's input, before the output projection (`solar_open2.py`)
     gate: bool = False
+    # what the scores are multiplied by; None: head_dim^-1/2
+    scale: Optional[float] = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, starts=None):
         B, S, _ = x.shape
         Hq, Hkv, D = self.num_heads, self.num_kv_heads, self.head_dim
 
@@ -323,7 +403,8 @@ class Attention(nn.Module):
         with _trace.scope("attn", "qkv"):
             q, k, v = proj("q_proj", Hq), proj("k_proj", Hkv), proj("v_proj", Hkv)
         with _trace.scope("attn", "core"):
-            o = blockwise_causal_attention(q, k, v, block=self.block)
+            o = blockwise_causal_attention(q, k, v, block=self.block,
+                                           scale=self.scale, starts=starts)
         o = o.reshape(B, S, Hq * D)
         if self.gate:
             with _trace.scope("attn", "gate"):

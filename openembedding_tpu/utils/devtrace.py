@@ -63,7 +63,7 @@ import re
 from typing import Dict, Iterable, List, Mapping, Optional, Tuple
 
 LAYERS = ("sparse", "exchange", "dense", "trainer", "ssm", "attn", "moe", "lm",
-          "mlp", "mtp", "kda", "cca", "router", "loop")
+          "mlp", "mtp", "kda", "cca", "router", "loop", "pack")
 CONTAINERS = {"while", "conditional", "call"}
 COLLECTIVES = ("all-to-all", "all-reduce", "all-gather", "reduce-scatter",
                "collective-permute", "collective-broadcast",

@@ -47,6 +47,8 @@ KNOWN_GROUPS = {
     "memory",     # device-memory ledger + preflight gate (utils/memwatch.py)
     "metrics",    # the metrics subsystem's own health (report_errors)
     "offload",    # host-cached table cache admission/flush/staging pipeline
+    "pack",       # documents packed into a sequence: traced reset sites,
+                  # documents a sequence, the longest one's share (PR 44)
     "persist",    # async/incremental persistence
     "placement",  # self-driving placement controller + cold-tail migration
     "serving",    # REST predict/pull/batching
@@ -89,6 +91,8 @@ KNOWN_LABELS = {
     "rank",       # hot-row popularity rank bucket (utils/sketch.py)
     "ring",       # feed-ring instance label (data/ingest.py)
     "shard",      # table shard ordinal (bounded by mesh size)
+    "site",       # which function of a packed sequence was given document
+                  # starts (bounded enum: ssd / conv / attn — `pack.resets`)
     "slo",        # SLO spec name (bounded by the spec file)
     "slot",       # optimizer slot name (bounded enum)
     "table",      # embedding table / variable name
