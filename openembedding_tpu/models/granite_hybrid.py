@@ -25,8 +25,9 @@ document a sequence. r is the residual stream (the compute dtype):
   `mamba_n_groups` groups, the gate norm over each group.
 - `"attention"`: `nemotron_h.Attention`, no positions, scores times
   `attention_multiplier` (NOT head_dim^-1/2), a query sees the keys k <= q
-  of its own document (`blockwise_causal_attention`; the fused kernel has
-  neither a scale nor a document mask, so the plain blockwise body runs).
+  of its own document (`blockwise_causal_attention`: on a TPU at a shape
+  the fused kernel's tiling takes, the kernel, which skips the key blocks
+  no query's document reaches).
 - Head: logits = RMSNorm(r_L) E^T / `logits_scaling`, f32; mean cross-entropy
   over every position (`softmax_xent`; no mask at document ends).
 
@@ -35,8 +36,11 @@ Stage names (`utils/trace.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
 -> n, wherever a mask needs it) and `pack.stats`. Counters:
 `pack.resets{site="ssd"|"conv"|"attn"}` (trace time, one a traced call site
 given starts: 19 a trace of ten layers), `attn.cores{path=}` as `nemotron_h`;
-`window_stats` `pack.documents` (documents a sequence) and
-`pack.longest_doc_share` (the longest document over S), means over a window.
+`window_stats` `pack.documents` (documents a sequence),
+`pack.longest_doc_share` (the longest document over S) and
+`attn.key_block_share` (the key blocks the fused kernel walks over the causal
+key blocks, at the block its tiling chose; 1.0 where it refuses the shape),
+means over a window.
 """
 
 from __future__ import annotations
@@ -52,8 +56,8 @@ from ..embedding import Embedding
 from ..initializers import Normal
 from ..model import TABLES_KEY, EmbeddingModel
 from ..utils import trace as _trace
-from .nemotron_h import (TOKEN, Attention, Mamba2Mixer, _keep_products,
-                         _swiglu, rms_norm, softmax_xent)
+from .nemotron_h import (TOKEN, Attention, Mamba2Mixer, _flash,
+                         _keep_products, _swiglu, rms_norm, softmax_xent)
 
 KINDS = ("mamba", "attention")
 
@@ -125,21 +129,27 @@ class DecoderLayer(nn.Module):
         return _scaled_add(r, y.reshape(B, S, D), c.residual_multiplier)
 
 
-def packing_stats(starts):
+def packing_stats(starts, block=None):
     """-> {`pack.documents`: documents a sequence, `pack.longest_doc_share`:
-    the longest document's share of the sequence}, means over the batch;
-    `starts` None: one document a sequence."""
+    the longest document's share of the sequence, `attn.key_block_share`:
+    the key blocks the fused attention kernel walks over the causal key
+    blocks at `block` (None: the kernel refuses the shape, and every causal
+    block is computed)}, means over the batch; `starts` None: one document a
+    sequence."""
+    one = jnp.ones((), jnp.float32)
     if starts is None:
-        one = jnp.ones((), jnp.float32)
-        return {"pack.documents": one, "pack.longest_doc_share": one}
+        return {"pack.documents": one, "pack.longest_doc_share": one,
+                "attn.key_block_share": one}
     with _trace.scope("pack", "stats"):
         seq = starts.shape[1]
         at = jnp.arange(seq, dtype=jnp.int32)
         began = jax.lax.cummax(jnp.where(starts != 0, at, 0), axis=1)
         longest = jnp.max(at - began + 1, axis=1).astype(jnp.float32)
         docs = jnp.sum(starts != 0, axis=1).astype(jnp.float32)
+        share = one if block is None else _flash().key_block_share(began, block)
         return {"pack.documents": jnp.mean(docs),
-                "pack.longest_doc_share": jnp.mean(longest) / seq}
+                "pack.longest_doc_share": jnp.mean(longest) / seq,
+                "attn.key_block_share": share}
 
 
 class GraniteHybrid(nn.Module):
@@ -153,7 +163,8 @@ class GraniteHybrid(nn.Module):
     # the module reads the token table whole: it is its head (`model.TABLES_KEY`)
     takes_tables = True
     # per-step stats -> how a `train_many` window folds them (`Trainer`)
-    window_stats = (("pack.documents", "avg"), ("pack.longest_doc_share", "avg"))
+    window_stats = (("pack.documents", "avg"), ("pack.longest_doc_share", "avg"),
+                    ("attn.key_block_share", "avg"))
 
     @nn.compact
     def __call__(self, embedded, dense_inputs=None, *, with_stats=False):
@@ -176,7 +187,9 @@ class GraniteHybrid(nn.Module):
             logits = logits / c.logits_scaling
         if not with_stats:
             return logits
-        return logits, packing_stats(starts)
+        block = _flash().tiling(r.shape[1], c.head_dim, c.head_dim,
+                                c.num_attention_heads, c.num_key_value_heads)
+        return logits, packing_stats(starts, block)
 
     def apply_with_stats(self, variables, embedded, dense_inputs=None):
         """-> (logits, {stat name: scalar}): the step's `window_stats`."""

@@ -230,32 +230,37 @@ def blockwise_causal_attention(q, k, v, *, block: int = 512, scale=None,
     D) -> (B, S, Hq, Dv); scores times `scale` (None: D^-1/2); softmax in
     f32; nothing of size S x S is ever kept. `starts` (B, S), nonzero where
     a document begins: a query sees the keys of its own document alone
-    (`segment_ids`). The entry to two bodies that share no logic: at a
-    shape the fused kernel's tiling takes (`ops/flash_attention.tiling`) a
-    TPU lowering runs the kernel (its own blocks, scores in VMEM only) and
-    every other platform the plain body; at any other shape (`block` queries
-    a block) the plain body everywhere. Both are traced and the platform is
-    settled when the program is lowered, so `attn.cores{path=}`, counted
-    here once a traced call site, speaks for the SHAPE alone: "fused" = the
-    kernel would take it, "blockwise" = it refuses it. That the kernel ran
-    is the device trace's to say (a custom call under `attn.core`). The
-    kernel has one scale and no document mask: a call that gives either
-    counts as "blockwise" and runs the plain body. Both series exist once
-    one does (the other reads 0)."""
+    (`segment_ids`, made once a call for either body). The entry to two
+    bodies that share no logic: at a shape the fused kernel's tiling takes
+    (`ops/flash_attention.tiling`) a TPU lowering runs the kernel (its own
+    blocks, scores in VMEM only, the key blocks no query's document reaches
+    skipped) and every other platform the plain body; at any other shape
+    (`block` queries a block) the plain body everywhere. Both are traced and
+    the platform is settled when the program is lowered, so
+    `attn.cores{path=}`, counted here once a traced call site, speaks for the
+    SHAPE alone: "fused" = the kernel would take it, "blockwise" = it
+    refuses it. That the kernel ran is the device trace's to say (a custom
+    call under `attn.core`). Both series exist once one does (the other
+    reads 0)."""
     (_, S, Hq, D), Hkv, Dv = q.shape, k.shape[2], v.shape[3]
-    fused = (scale is None and starts is None
-             and _flash().tiling(S, D, Dv, Hq, Hkv) is not None)
+    fused = _flash().tiling(S, D, Dv, Hq, Hkv) is not None
     for path in ("fused", "blockwise"):
         _metrics.observe("attn.cores", int(fused == (path == "fused")), "sum",
                          labels={"path": path})
-    plain = functools.partial(_blockwise_causal_attention, block=block)
-    if not fused:
-        if starts is None:
-            return plain(q, k, v, scale=scale)
+    n = None
+    if starts is not None:
         _count_reset("attn")
-        return plain(q, k, v, scale=scale, n=segment_ids(starts))
-    return jax.lax.platform_dependent(q, k, v, tpu=_flash().causal_attention,
-                                      default=plain)
+        n = segment_ids(starts)
+
+    def plain(q, k, v, n):
+        return _blockwise_causal_attention(q, k, v, block=block, scale=scale,
+                                           n=n)
+    if not fused:
+        return plain(q, k, v, n)
+
+    def kernel(q, k, v, n):
+        return _flash().causal_attention(q, k, v, scale=scale, segment_ids=n)
+    return jax.lax.platform_dependent(q, k, v, n, tpu=kernel, default=plain)
 
 
 def _flash():

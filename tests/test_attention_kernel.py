@@ -1,9 +1,12 @@
 """The fused causal-attention kernel's mathematics (`ops/flash_attention.py`)
 under the Pallas interpreter, reached through the private head-major core
 (`_flash(..., True)`; the program has no switch for it), against full-softmax
-attention in f32: output and the three gradients at the two shapes the
+attention in f32: output and the three gradients at the three shapes the
 benchmark's cells send (keys wider than values with a key/value head a query
-head; grouped heads), f32 and bf16 operands. And the entry's choice
+head; grouped heads; 64-wide grouped heads), f32 and bf16 operands; a score
+scale of the caller's; documents packed into a sequence (segment ids) against
+the plain body's document mask, with the table of first key blocks each query
+block's walk starts from. And the entry's choice
 (`models/nemotron_h.blockwise_causal_attention`): what a shape counts as, and
 that a CPU lowering holds the plain body at every shape.
 
@@ -25,6 +28,8 @@ from openembedding_tpu.utils import metrics
 LATENT = (192, 128, 4, 4)
 GROUPED = (128, 128, 8, 2)
 GROUPS_OF_16 = (128, 128, 32, 2)
+NARROW = (64, 64, 4, 2)
+SHAPES = {"latent": LATENT, "grouped": GROUPED, "narrow": NARROW}
 
 
 def _operands(seq, d, dv, hq, hkv, dtype, seed=0):
@@ -36,14 +41,14 @@ def _operands(seq, d, dv, hq, hkv, dtype, seed=0):
             draw(1, hq, seq, dv))
 
 
-def _oracle(q, k, v):
+def _oracle(q, k, v, scale=None):
     """Full-softmax causal attention in f32 at `highest`, head-major."""
     q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
     group, seq = q.shape[1] // k.shape[1], q.shape[2]
     k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest")
-    s = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)),
-                  s / np.sqrt(q.shape[-1]), -jnp.inf)
+    scale = 1 / np.sqrt(q.shape[-1]) if scale is None else scale
+    s = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), s * scale, -jnp.inf)
     return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v,
                       precision="highest")
 
@@ -58,11 +63,11 @@ def _with_grads(core, q, k, v, w):
     return tuple(np.asarray(t, np.float32) for t in (o,) + grads)
 
 
-def _plain(q, k, v):
+def _plain(q, k, v, scale=None, n=None):
     """The plain blockwise body on head-major operands."""
     major = lambda x: jnp.transpose(x, (0, 2, 1, 3))
     return major(nh._blockwise_causal_attention(
-        major(q), major(k), major(v), block=512))
+        major(q), major(k), major(v), block=512, scale=scale, n=n))
 
 
 def _worst(got, want):
@@ -79,7 +84,7 @@ def _norm_gap(got, want):
 
 
 @pytest.mark.parametrize("seq", [512, 1024])
-@pytest.mark.parametrize("shape", [LATENT, GROUPED], ids=["latent", "grouped"])
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
 def test_kernel_equals_full_softmax_in_f32(seq, shape):
     q, k, v, w = _operands(seq, *shape, jnp.float32)
     want = _with_grads(_oracle, q, k, v, w)
@@ -89,7 +94,7 @@ def test_kernel_equals_full_softmax_in_f32(seq, shape):
 
 
 @pytest.mark.parametrize("seq", [512, 1024])
-@pytest.mark.parametrize("shape", [LATENT, GROUPED], ids=["latent", "grouped"])
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
 def test_kernel_in_bf16_is_as_close_as_the_plain_body(seq, shape):
     """bf16 operands: the kernel rounds P before it normalises and dS once;
     by the norm of its error against the f32 oracle it stays within a tenth
@@ -131,6 +136,73 @@ def test_first_block_is_fully_visible_and_the_diagonal_is_masked():
     np.testing.assert_allclose(base[:, :, 0], np.asarray(v)[:, :, 0], atol=1e-6)
 
 
+def test_a_given_scale_is_the_kernels_scale_forward_and_backward():
+    """Granite's 1/64 in place of D^-1/2 = 1/8: the output and dq, dk, dv
+    follow the oracle at that scale, and differ from the default's."""
+    q, k, v, w = _operands(512, *NARROW, jnp.float32)
+    want = _with_grads(lambda *t: _oracle(*t, scale=1 / 64), q, k, v, w)
+    got = _with_grads(lambda *t: fa._flash(*t, True, 1 / 64), q, k, v, w)
+    assert max(_worst(got, want)) < 2e-5, _worst(got, want)
+    plain = _with_grads(lambda *t: _plain(*t, scale=1 / 64), q, k, v, w)
+    assert max(_worst(got, plain)) < 2e-5
+    default = _with_grads(lambda *t: fa._flash(*t, True), q, k, v, w)
+    assert min(_worst(default, want)) > 1e-2
+
+
+# where documents begin in a sequence of 2,048 (blocks of 512): mid-block
+# starts, a document over two blocks, one starting ON a block edge, and the
+# last block's first query in a document that began in block 2 while its
+# later queries start three more (from 1,600: block 2 is visited and wholly
+# masked for them), one of them a single position
+STARTS = (0, 300, 1024, 1100, 1600, 1601, 1900)
+
+
+def _packed(seq=2048, at=STARTS):
+    starts = np.zeros((1, seq), np.int32)
+    starts[0, list(at)] = 1
+    n = nh.segment_ids(jnp.asarray(starts))
+    began = fa.document_starts(n)
+    return n, (fa.first_key_blocks(began, 512), began)
+
+
+def test_first_key_blocks_and_their_share_by_hand():
+    n, (first, began) = _packed()
+    np.testing.assert_array_equal(began[0, [0, 299, 300, 1023, 1024, 1099, 1100,
+                                            1599, 1600, 1601, 1602, 2047]],
+                                  [0, 0, 300, 300, 1024, 1024, 1100, 1100,
+                                   1600, 1601, 1601, 1900])
+    # block 0 from 0; block 1's first query (512) is in the document of 300;
+    # block 2 begins a document; block 3's first query (1536) is in the
+    # document of 1100
+    np.testing.assert_array_equal(first, [[0, 0, 2, 2]])
+    assert float(fa.key_block_share(began, 512)) == pytest.approx(6 / 10)
+    # one document: every causal block; at blocks of 256 the same starts skip more
+    one = fa.document_starts(jnp.ones((2, 2048), jnp.int32))
+    assert float(fa.key_block_share(one, 512)) == 1.0
+    np.testing.assert_array_equal(fa.first_key_blocks(began, 256),
+                                  [[0, 0, 1, 1, 4, 4, 4, 6]])
+    assert float(fa.key_block_share(began, 256)) == pytest.approx(
+        (1 + 2 + 2 + 3 + 1 + 2 + 3 + 2) / 36)
+
+
+@pytest.mark.parametrize("shape,scale", [(NARROW, 1 / 64), (GROUPED, None)],
+                         ids=["narrow_scaled", "grouped"])
+def test_documents_in_the_kernel_equal_the_plain_bodys_mask(shape, scale):
+    """Output and dq, dk, dv of the kernel given segment ids (its walk from
+    the first key blocks, the document mask in every visited block) against
+    the plain body given the same documents, f32: the rows of block 3 from
+    1,600 on, for which block 2 is wholly masked, included."""
+    q, k, v, w = _operands(2048, *shape, jnp.float32)
+    n, docs = _packed()
+    want = _with_grads(lambda *t: _plain(*t, scale=scale, n=n), q, k, v, w)
+    got = _with_grads(lambda *t: fa._flash(*t, True, scale, docs), q, k, v, w)
+    assert max(_worst(got, want)) < 2e-5, _worst(got, want)
+    # the masked row is no accident of the comparison: one document a
+    # sequence reads otherwise there
+    whole = _with_grads(lambda *t: fa._flash(*t, True, scale), q, k, v, w)
+    assert np.abs(whole[0][:, :, 1700] - got[0][:, :, 1700]).max() > 1e-2
+
+
 def test_kernel_behind_the_entry_layout_equals_the_plain_body():
     """`causal_attention` is (B, S, H, D)-major like the plain body; here
     with the interpreter in the kernel's place."""
@@ -144,9 +216,12 @@ def test_kernel_behind_the_entry_layout_equals_the_plain_body():
 @pytest.mark.parametrize("seq,d,dv,hq,hkv,block", [
     (512, 192, 128, 32, 32, 512), (4096, 128, 128, 32, 2, 512),
     (768, 128, 128, 4, 4, 256), (384, 128, 256, 4, 1, 128),
-    (21, 128, 128, 4, 4, None), (512, 64, 64, 4, 4, None),
+    (21, 128, 128, 4, 4, None), (512, 64, 64, 4, 4, 512),
     (512, 128, 64, 4, 4, None), (512, 200, 128, 4, 4, None),
-    (512, 128, 128, 6, 4, None), (1 << 16, 128, 128, 4, 4, None)])
+    (512, 128, 128, 6, 4, None), (1 << 16, 128, 128, 4, 4, None),
+    (8192, 64, 64, 32, 8, 512), (8192, 128, 128, 8, 1, 512),
+    (512, 64, 128, 4, 4, None), (512, 32, 32, 4, 4, None),
+    (1 << 14, 64, 64, 4, 4, None), (768, 64, 64, 6, 3, 256)])
 def test_tiling_takes_what_it_can_tile(seq, d, dv, hq, hkv, block):
     assert fa.tiling(seq, d, dv, hq, hkv) == block
 
@@ -167,6 +242,44 @@ def test_entry_counts_a_call_site_by_its_shape(seq, width, path):
     f = jax.jit(lambda q: nh.blockwise_causal_attention(q, q, q, block=8))
     f(q), f(q)
     assert (_count(path), _count(other)) == (before[0] + 1, before[1])
+
+
+def test_entry_counts_a_packed_scaled_call_as_fused_and_as_a_reset():
+    """Starts and a scale no longer send a call the kernel's tiling takes to
+    the plain body's count; the call is still a reset site."""
+    resets = lambda: metrics.report().get('pack.resets{site="attn"}', 0.0)
+    before = _count("fused"), _count("blockwise"), resets()
+    q = jnp.ones((1, 512, 2, 64), jnp.float32)
+    starts = jnp.zeros((1, 512), jnp.int32).at[0, jnp.array([0, 200])].set(1)
+    jax.jit(lambda q, st: nh.blockwise_causal_attention(
+        q, q, q, block=8, scale=1 / 64, starts=st))(q, starts)
+    assert (_count("fused"), _count("blockwise"), resets()) == \
+        (before[0] + 1, before[1], before[2] + 1)
+
+
+def test_cpu_lowering_of_a_packed_scaled_call_holds_the_plain_body():
+    """The same on the CPU with starts and a scale: no custom call, the plain
+    body's result with the documents' mask to the bit, forward and
+    backward."""
+    rng = np.random.default_rng(7)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 512, h, 64)), jnp.float32)
+               for h in (4, 2, 2))
+    starts = np.zeros((1, 512), np.int32)
+    starts[0, [0, 130, 131, 400]] = 1
+
+    def grads(core):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v, st: jnp.sum(jnp.square(core(q, k, v, st))),
+            argnums=(0, 1, 2)))
+    entry = grads(lambda q, k, v, st: nh.blockwise_causal_attention(
+        q, k, v, block=128, scale=1 / 64, starts=st))
+    text = entry.lower(q, k, v, starts).compile().as_text()
+    assert "custom-call" not in text and "custom_call" not in text
+    plain = grads(lambda q, k, v, st: nh._blockwise_causal_attention(
+        q, k, v, block=128, scale=1 / 64, n=nh.segment_ids(st)))
+    for got, want in zip(jax.tree_util.tree_leaves(entry(q, k, v, starts)),
+                         jax.tree_util.tree_leaves(plain(q, k, v, starts))):
+        np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("seq,d,dv,hq,hkv", [(512, 192, 128, 2, 2),

@@ -380,6 +380,8 @@ def test_k_steps_of_train_many_follow_the_reference_and_count_the_packing():
     assert report['pack.resets{site="ssd"}'] == report['pack.resets{site="conv"}'] == 3
     assert report['pack.resets{site="attn"}'] == 1
     assert report['attn.cores{path="blockwise"}'] == 1 and report['attn.cores{path="fused"}'] == 0
+    # the kernel refuses 16-wide heads: every causal key block is computed
+    assert report["attn.key_block_share"] == 1.0
 
 
 def test_untied_head_is_another_training_step(first_step):
@@ -408,6 +410,39 @@ def test_packing_stats_count_documents_and_the_longest():
     none = gh.packing_stats(None)
     assert float(none["pack.documents"]) == float(none["pack.longest_doc_share"]) == 1.0
     np.testing.assert_array_equal(nh.segment_ids(st)[1], [1, 2, 3, 3, 3, 3, 3, 3, 3, 4])
+
+
+def test_key_block_share_is_one_for_one_document_and_a_hand_count_packed():
+    """At blocks of 8 over S = 40 (five blocks, 15 causal block pairs),
+    documents from 0, 3, 13, 30: the query blocks from 0, 8, 16, 24, 32 sit
+    in documents begun at 0, 3, 13, 13, 30, so their walks start at key
+    blocks 0, 0, 1, 1, 3 and visit 1 + 2 + 2 + 3 + 2 = 10 of 15."""
+    st = np.concatenate([starts_of(PACKINGS["off_chunk_edges"]), starts_of((0,))])
+    got = gh.packing_stats(jnp.asarray(st), block=8)
+    assert float(got["attn.key_block_share"]) == pytest.approx((10 / 15 + 1) / 2)
+    assert float(gh.packing_stats(jnp.asarray(st[1:]), block=8)[
+        "attn.key_block_share"]) == 1.0
+    # no block (the kernel refuses the shape) and no starts: nothing skipped
+    assert float(gh.packing_stats(jnp.asarray(st))["attn.key_block_share"]) == 1.0
+    assert float(gh.packing_stats(None, block=8)["attn.key_block_share"]) == 1.0
+
+
+def test_module_reports_the_share_at_the_block_the_kernels_tiling_takes():
+    """64-wide heads at S = 1,024: the kernel's tiling takes blocks of 512;
+    a document from 512 on starts the second block's walk there (2 of 3
+    causal block pairs)."""
+    model = make(hidden_size=256, num_hidden_layers=1, layer_types=["attention"],
+                 num_attention_heads=4, num_key_value_heads=2)
+    seq = 1024
+    starts = np.zeros((1, seq), np.int32)
+    starts[0, [0, 512]] = 1
+    rows = jnp.zeros((1, seq, 256), jnp.float32)
+    embedded = {"token": rows, TABLES_KEY: {"token": jnp.zeros((64, 256))}}
+    module = model.module
+    variables = module.init(jax.random.PRNGKey(0), embedded, starts)
+    _, stats = jax.jit(module.apply_with_stats)(variables, embedded, starts)
+    assert module.dims.head_dim == 64
+    assert float(stats["attn.key_block_share"]) == pytest.approx(2 / 3)
 
 
 def test_make_refuses_layer_types_it_does_not_know():

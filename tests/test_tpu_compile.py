@@ -21,8 +21,9 @@ The attention core is one fused kernel on a TPU lowering
 (`ops/flash_attention.py`, behind `models/nemotron_h.blockwise_causal_
 attention`): at the language-model cells' shapes `value_and_grad` through the
 entry compiles to custom calls under the `attn.core` stage and to no f32
-array of a score block's size; a shape the tiling refuses compiles to the
-plain body.
+array of a score block's size, Granite's packed call too (64-wide heads, a
+score scale of 1/64, document starts); a shape the tiling refuses compiles to
+the plain body.
 
 The Solar-Open2 cell's scan (`models/solar_open2.py`: 966.7M parameters, 7.2
 GiB of state) fits the chip only because its routed layer GATHERS a block's
@@ -449,29 +450,39 @@ def test_four_chip_scan_with_no_apply_to_share_with_serves_per_slot(
 
 
 # the language-model cells' cores: (B, S, Hq, Hkv, D, Dv); 8,192 at 128 / 128
-# is the longest sequence the kernel's tiling takes
+# is the longest sequence the kernel's tiling takes; Granite's call is given
+# a scale and document starts (`_core_text`)
 CORES = {"joyai_keys192_values128": (2, 4096, 32, 32, 192, 128),
          "nemotron_32_heads_over_2": (2, 4096, 32, 2, 128, 128),
-         "solar_8k_8_heads_over_1": (1, 8192, 8, 1, 128, 128)}
+         "solar_8k_8_heads_over_1": (1, 8192, 8, 1, 128, 128),
+         "granite_8k_64_wide_packed": (1, 8192, 32, 8, 64, 64)}
 SCORE_BLOCK = 2 * 32 * 512 * 512   # elements of one block's scores
 
 
 def _core_text(one_chip, shape, dtype=jnp.bfloat16):
     """Optimised HLO and temporaries of value_and_grad through the entry,
-    under the stage name the models give it."""
+    under the stage name the models give it; at Granite's 64-wide heads
+    with its scale (1/64) and document starts, as its attention layer calls
+    it."""
     from openembedding_tpu.models import nemotron_h as nh
     from openembedding_tpu.utils import trace
     B, S, Hq, Hkv, D, Dv = shape
+    packed = D == 64
+    kw = dict(scale=1 / 64) if packed else {}
 
-    def loss(q, k, v):
+    def loss(q, k, v, *starts):
         with trace.scope("attn", "core"):
-            o = nh.blockwise_causal_attention(q, k, v, block=512)
+            o = nh.blockwise_causal_attention(q, k, v, block=512, **kw,
+                                              starts=starts[0] if starts else None)
         return jnp.sum(o.astype(jnp.float32))
 
-    def arg(*dims):
+    def arg(*dims, dtype=dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    args = [arg(B, S, Hq, D), arg(B, S, Hkv, D), arg(B, S, Hkv, Dv)]
+    if packed:
+        args.append(arg(B, S, dtype=jnp.int32))
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        arg(B, S, Hq, D), arg(B, S, Hkv, D), arg(B, S, Hkv, Dv)).compile()
+        *args).compile()
     return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
 
 
@@ -491,7 +502,11 @@ def test_attention_core_lowers_to_the_fused_kernel_on_the_tpu(one_chip, shape):
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 2                      # forward, backward
     assert all("attn.core" in line for line in calls), calls
-    assert max(_score_blocks(text), default=0) < SCORE_BLOCK
+    assert {re.search(r"%(flash_attention_\w+?)\.", line).group(1)
+            for line in calls} == {"flash_attention_fwd", "flash_attention_bwd"}
+    B, Hq = shape[0], shape[2]
+    assert max(_score_blocks(text), default=0) < min(SCORE_BLOCK,
+                                                     B * Hq * 512 * 512)
     assert temp < 1 << 30
 
 
