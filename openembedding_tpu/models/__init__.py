@@ -3,7 +3,7 @@
 Reference coverage (`documents/en/benchmark.md:6-16`, `examples/`,
 `test/benchmark/criteo_deepctr.py`): WDL (Wide&Deep), DeepFM, xDeepFM at dims 9/64,
 the LR subclass example (`examples/criteo_lr_subclass.py`), plus DLRM (the reference's
-PMem paper workload) and a two-tower retrieval model. Six language-model towers
+PMem paper workload) and a two-tower retrieval model. Seven language-model towers
 behind a token table ride the same train path: NemotronH (`nemotron_h.py`: Mamba-2,
 attention, routed experts), JoyAI-LLM-Flash (`joyai_flash.py`: latent attention,
 routed SwiGLU experts, a multi-token-prediction module), Solar-Open2
@@ -16,7 +16,10 @@ shared weights as one scanned body, an exit gate a pass, the expected loss over
 the exits through one head, computed inside the walk) and Granite 4.0-H
 (`granite_hybrid.py`: nine Mamba-2 layers in ten, a SwiGLU after every mixer,
 four muP scalars, a tied head; documents packed into a sequence, the state, the
-convolution and the attention mask reset at every document start).
+convolution and the attention mask reset at every document start) and SDAR
+(`sdar_moe.py`: the Qwen3-MoE decoder trained by block diffusion over a noised
+and a clean copy of every sequence, the block mask in the fused attention
+kernel, per-head q / k norms, softmax top-k routing).
 
 TPU-first layout decision (differs deliberately from the reference's per-feature
 DeepCTR `Embedding` layers): all categorical fields share ONE row-sharded table, with
@@ -40,6 +43,7 @@ from .solar_open2 import SolarOpen2, kda_chunked, make_solar_open2
 from .zaya1 import Zaya1, make_zaya1
 from .ouro import Ouro, expected_exit_loss, make_ouro
 from .granite_hybrid import GraniteHybrid, make_granite_hybrid
+from .sdar_moe import SdarMoE, corrupt_blocks, diffusion_xent, make_sdar_moe
 
 _FAMILIES = {
     "lr": make_lr, "wdl": make_wdl, "deepfm": make_deepfm,
@@ -53,6 +57,7 @@ _FAMILIES = {
     "zaya1": make_zaya1,
     "ouro": make_ouro,
     "granite_hybrid": make_granite_hybrid,
+    "sdar_moe": make_sdar_moe,
 }
 
 
@@ -90,5 +95,6 @@ __all__ = [
     "Zaya1", "make_zaya1",
     "Ouro", "make_ouro", "expected_exit_loss",
     "GraniteHybrid", "make_granite_hybrid",
+    "SdarMoE", "make_sdar_moe", "diffusion_xent", "corrupt_blocks",
     "CRITEO_NUM_SPARSE", "CRITEO_NUM_DENSE",
 ]

@@ -66,6 +66,7 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..embedding import Embedding
 from ..initializers import Normal
@@ -224,7 +225,7 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32, starts=None):
 
 
 def blockwise_causal_attention(q, k, v, *, block: int = 512, scale=None,
-                               starts=None):
+                               starts=None, denoise=None):
     """Causal softmax attention: q (B, S, Hq, D), k (B, S, Hkv, D), v
     (B, S, Hkv, Dv), Hq % Hkv == 0 (grouped key/value heads; Dv need not be
     D) -> (B, S, Hq, Dv); scores times `scale` (None: D^-1/2); softmax in
@@ -241,8 +242,17 @@ def blockwise_causal_attention(q, k, v, *, block: int = 512, scale=None,
     SHAPE alone: "fused" = the kernel would take it, "blockwise" = it
     refuses it. That the kernel ran is the device trace's to say (a custom
     call under `attn.core`). Both series exist once one does (the other
-    reads 0)."""
+    reads 0).
+
+    `denoise` (block diffusion, `sdar_moe.py`): the sequence is a noised copy
+    and the clean one, S / 2 each, and pairs are seen by denoising blocks of
+    `denoise` positions (`diffusion_pairs`; no `starts` with it). Counted as
+    `attn.cores{path="block_diffusion"}` where the kernel's block-diffusion
+    walk takes the shape (`flash_attention.diffusion_block`), else as
+    "blockwise"."""
     (_, S, Hq, D), Hkv, Dv = q.shape, k.shape[2], v.shape[3]
+    if denoise is not None:
+        return _diffusion_entry(q, k, v, block, scale, starts, denoise)
     fused = _flash().tiling(S, D, Dv, Hq, Hkv) is not None
     for path in ("fused", "blockwise"):
         _metrics.observe("attn.cores", int(fused == (path == "fused")), "sum",
@@ -261,6 +271,81 @@ def blockwise_causal_attention(q, k, v, *, block: int = 512, scale=None,
     def kernel(q, k, v, n):
         return _flash().causal_attention(q, k, v, scale=scale, segment_ids=n)
     return jax.lax.platform_dependent(q, k, v, n, tpu=kernel, default=plain)
+
+
+def _diffusion_entry(q, k, v, block, scale, starts, denoise):
+    """`blockwise_causal_attention`'s block-diffusion call."""
+    if starts is not None:
+        raise ValueError("a block-diffusion call takes no document starts")
+    (_, S, Hq, D), Hkv, Dv = q.shape, k.shape[2], v.shape[3]
+    if S % 2:
+        raise ValueError(f"a noised and a clean half of one length: S = {S}")
+    took = "block_diffusion" if _flash().diffusion_block(
+        S, D, Dv, Hq, Hkv, denoise) is not None else "blockwise"
+    for path in ("fused", "blockwise", "block_diffusion"):
+        _metrics.observe("attn.cores", int(path == took), "sum",
+                         labels={"path": path})
+
+    def plain(q, k, v):
+        return _diffusion_attention(q, k, v, block=block, scale=scale,
+                                    denoise=denoise)
+    if took == "blockwise":
+        return plain(q, k, v)
+
+    def kernel(q, k, v):
+        return _flash().causal_attention(q, k, v, scale=scale, denoise=denoise)
+    return jax.lax.platform_dependent(q, k, v, tpu=kernel, default=plain)
+
+
+def diffusion_pairs(qpos, kpos, half: int, denoise: int):
+    """Which (query, key) pairs block diffusion sees, positions in a sequence
+    of a noised half [0, half) then a clean one [half, 2 half) (broadcasting
+    integer arrays): the denoising block of a position is `pos // denoise`
+    within its half; a noisy query sees the noisy keys of its own block and
+    the clean keys of earlier blocks, a clean query the clean keys of its own
+    block and earlier ones."""
+    q_noisy, k_noisy = qpos < half, kpos < half
+    qb, kb = (qpos % half) // denoise, (kpos % half) // denoise
+    noisy_query = (k_noisy & (kb == qb)) | (~k_noisy & (kb < qb))
+    return (q_noisy & noisy_query) | (~q_noisy & ~k_noisy & (kb <= qb))
+
+
+def _diffusion_attention(q, k, v, *, block, scale=None, denoise):
+    """The plain body of a block-diffusion call: each half's queries a block
+    of `block` at a time against the keys the block can see (the clean
+    positions up to the end of its last denoising block, and for noisy
+    queries the noisy positions of their own denoising blocks), masked by
+    `diffusion_pairs`, each block rematerialised in the backward pass."""
+    B, S, Hq, D = q.shape
+    Hkv, Dv, half = k.shape[2], v.shape[3], S // 2
+    qg = q.reshape(B, S, Hkv, Hq // Hkv, D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
+
+    @jax.checkpoint
+    def one(qb, kb, vb, seen):
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kb,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(seen, s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(vb.dtype)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", p, vb,
+                          preferred_element_type=jnp.float32).astype(qb.dtype)
+
+    out = []
+    for start in (0, half):
+        for lo in range(start, start + half, block):
+            hi = min(lo + block, start + half)
+            reach = min(half, -(-(hi - start) // denoise) * denoise)
+            spans = [(half, half + reach)]
+            if start == 0:
+                spans.append(((lo // denoise) * denoise, reach))
+            kpos = np.concatenate([np.arange(a, b) for a, b in spans])
+            seen = diffusion_pairs(np.arange(lo, hi)[:, None], kpos[None, :],
+                                   half, denoise)
+            keys = [jnp.concatenate([t[:, a:b] for a, b in spans], axis=1)
+                    for t in (k, v)]
+            out.append(one(qg[:, lo:hi], *keys, jnp.asarray(seen)))
+    return jnp.concatenate(out, axis=1).reshape(B, S, Hq, Dv)
 
 
 def _flash():

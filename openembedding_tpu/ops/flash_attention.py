@@ -31,6 +31,20 @@ the running maximum's MASKED; the first real score makes its alpha 0. With
 no `segment_ids` the kernels are traced as they were before they took any
 (a static branch, not a mask of ones).
 
+Block diffusion (`denoise` = the denoising block B_k; `models/sdar_moe.py`):
+the sequence is two halves of L = S / 2, a noised copy then the clean one,
+and a pair is seen by denoising block (`pos // B_k` within its half): a
+noisy query sees the noisy keys of its own denoising block and the clean keys
+of earlier ones, a clean query the clean keys of its own and earlier ones
+(`models/nemotron_h.diffusion_pairs`). Each query block's walk
+(`diffusion_key_blocks`) visits the whole clean blocks before its position,
+the clean block AT its position (masked by denoising block), and for a noisy
+query block its own noisy block (masked to its own denoising blocks): n^2 + 2n
+of the n(2n + 1) blocks a causal walk over 2L visits, n = L / block. No
+block above that walk is touched, in the forward pass, in dq or in dk / dv's
+sums. The block is `tiling`'s, halved until it divides L (`diffusion_block`).
+Without `denoise` the kernels are traced as they were before it existed.
+
 Precision: the operands of every product keep the dtype they came in (bf16 in
 the benchmark's cells), every product accumulates in f32; scale, mask,
 maximum, exponent, sum, log-sum-exp, delta = rowsum(dO * O) and
@@ -117,6 +131,35 @@ def key_block_share(began, block: int):
     return jnp.mean(walked.astype(jnp.float32)) / (n * (n + 1) / 2)
 
 
+def diffusion_block(S: int, D: int, Dv: int, Hq: int, Hkv: int, denoise: int):
+    """The block of a block-diffusion call (module docstring): `tiling`'s,
+    halved until it divides the half L = S / 2; None where there is none of
+    128 rows or more, or where it is no multiple of the denoising block (a
+    power of two)."""
+    block = tiling(S, D, Dv, Hq, Hkv)
+    if block is None or S % 2 or denoise < 1 or denoise & (denoise - 1):
+        return None
+    while block >= 128 and (S // 2) % block:
+        block //= 2
+    return block if block >= 128 and block % denoise == 0 else None
+
+
+def diffusion_key_blocks(i: int, n: int):
+    """The key blocks query block `i` of a block-diffusion call walks, `n`
+    blocks a half: the clean blocks up to the one at its position, then, for
+    a noisy query block, its own."""
+    clean = list(range(n, n + i % n + 1))
+    return clean + [i] if i < n else clean
+
+
+def diffusion_key_block_share(S: int, block: int) -> float:
+    """Key blocks a block-diffusion call's walk visits over those a causal
+    walk over all S positions would: (n + 2) / (2n + 1), n = S / 2 / block."""
+    n = S // 2 // block
+    walked = sum(len(diffusion_key_blocks(i, n)) for i in range(2 * n))
+    return walked / (2 * n * (2 * n + 1) / 2)
+
+
 def _causal(shape, query_dim: int):
     """Within a diagonal block: key position <= query position."""
     qpos = jax.lax.broadcasted_iota(jnp.int32, shape, query_dim)
@@ -131,7 +174,34 @@ def _in_document(shape, query_dim: int, lo, began):
     return kpos >= began
 
 
-def _fwd_kernel(*refs, block, scale, docs):
+def _by_denoise_block(op, shift):
+    """Within a visited block of a block-diffusion call: op(key's denoising
+    block, query's), the blocks by `pos >> shift` from the block's start
+    (its two sides begin on one denoising block boundary of their halves)."""
+    def seen(shape, query_dim):
+        qb = jax.lax.broadcasted_iota(jnp.int32, shape, query_dim) >> shift
+        kb = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - query_dim) >> shift
+        return op(kb, qb)
+    return seen
+
+
+def _diffusion_walk(i, n, shift, step, carry):
+    """Query block `i`'s walk of a block-diffusion call (`diffusion_key_blocks`),
+    `n` blocks a half: `step(j, carry, diagonal, seen)` a visited key block."""
+    c = jnp.where(i < n, i, i - n)
+    carry = jax.lax.fori_loop(n, n + c, functools.partial(step, diagonal=False),
+                              carry)
+
+    def noisy(carry):
+        carry = step(n + c, carry, False, _by_denoise_block(jnp.less, shift))
+        return step(i, carry, False, _by_denoise_block(jnp.equal, shift))
+
+    def clean(carry):
+        return step(i, carry, False, _by_denoise_block(jnp.less_equal, shift))
+    return jax.lax.cond(i < n, noisy, clean, carry)
+
+
+def _fwd_kernel(*refs, block, scale, docs, denoise=None):
     if docs:
         first_ref, q_ref, k_ref, v_ref, began_ref, o_ref, lse_ref = refs
     else:
@@ -139,12 +209,14 @@ def _fwd_kernel(*refs, block, scale, docs):
     i = pl.program_id(3)
     q = q_ref[...]
 
-    def step(j, carry, diagonal):
+    def step(j, carry, diagonal, seen=None):
         m, l, acc = carry
         rows = pl.ds(pl.multiple_of(j * block, block), block)
         s = jax.lax.dot_general(q, k_ref[rows, :], _NT,
                                 preferred_element_type=jnp.float32) * scale
-        if docs:
+        if seen is not None:
+            s = jnp.where(seen(s.shape, 0), s, MASKED)
+        elif docs:
             seen = _in_document(s.shape, 0, j * block, began_ref[...])
             if diagonal:
                 seen = seen & _causal(s.shape, 0)
@@ -162,17 +234,22 @@ def _fwd_kernel(*refs, block, scale, docs):
     carry = (jnp.full((block, 1), MASKED, jnp.float32),
              jnp.zeros((block, 1), jnp.float32),
              jnp.zeros((block, v_ref.shape[-1]), jnp.float32))
-    start = first_ref[pl.program_id(0), i] if docs else 0
-    carry = jax.lax.fori_loop(start, i, functools.partial(step, diagonal=False),
-                              carry)
-    m, l, acc = step(i, carry, True)
+    if denoise:
+        m, l, acc = _diffusion_walk(i, np.int32(k_ref.shape[0] // 2 // block),
+                                    np.int32(denoise.bit_length() - 1), step,
+                                    carry)
+    else:
+        start = first_ref[pl.program_id(0), i] if docs else 0
+        carry = jax.lax.fori_loop(start, i,
+                                  functools.partial(step, diagonal=False), carry)
+        m, l, acc = step(i, carry, True)
     o_ref[...] = (acc / l).astype(o_ref.dtype)
     # the backward pass reads the log-sum-exp along lanes (a row a block)
     lse = jnp.broadcast_to(m + jnp.log(l), (block, 128))
     lse_ref[...] = lse.T[:1]
 
 
-def _bwd_kernel(*refs, block, scale, docs):
+def _bwd_kernel(*refs, block, scale, docs, denoise=None):
     if docs:
         (first_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, began_ref,
          dq_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -191,12 +268,14 @@ def _bwd_kernel(*refs, block, scale, docs):
 
     # scores are made transposed, (keys, queries): the row statistics then
     # broadcast along sublanes and only dq needs a transposed operand
-    def step(j, dq, diagonal):
+    def step(j, dq, diagonal, seen=None):
         rows = pl.ds(pl.multiple_of(j * block, block), block)
         k, v = k_ref[rows, :], v_ref[rows, :]
         s = jax.lax.dot_general(k, q, _NT,
                                 preferred_element_type=jnp.float32) * scale
-        if docs:
+        if seen is not None:
+            s = jnp.where(seen(s.shape, 1), s, MASKED)
+        elif docs:
             seen = _in_document(s.shape, 1, j * block, began_ref[...])
             if diagonal:
                 seen = seen & _causal(s.shape, 1)
@@ -213,10 +292,16 @@ def _bwd_kernel(*refs, block, scale, docs):
         return dq + jax.lax.dot_general(ds, k, _TN,
                                         preferred_element_type=jnp.float32)
 
-    start = first_ref[pl.program_id(0), i] if docs else 0
-    dq = jax.lax.fori_loop(start, i, functools.partial(step, diagonal=False),
-                           jnp.zeros(q.shape, jnp.float32))
-    dq_ref[...] = (step(i, dq, True) * scale).astype(dq_ref.dtype)
+    if denoise:
+        dq = _diffusion_walk(i, np.int32(k_ref.shape[0] // 2 // block),
+                             np.int32(denoise.bit_length() - 1), step,
+                             jnp.zeros(q.shape, jnp.float32))
+    else:
+        start = first_ref[pl.program_id(0), i] if docs else 0
+        dq = jax.lax.fori_loop(start, i, functools.partial(step, diagonal=False),
+                               jnp.zeros(q.shape, jnp.float32))
+        dq = step(i, dq, True)
+    dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
 
     @pl.when((r == pl.num_programs(2) - 1) & (i == pl.num_programs(3) - 1))
     def _():
@@ -233,17 +318,20 @@ def _params(*semantics):
                                 vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
-def _specs(q, k, v):
+def _specs(q, k, v, denoise=None):
     """Grid (batch, key/value head, query head of its group, query block) and
     the three kinds of block: a block of queries of one query head, a row of
     per-query statistics of that block, the whole sequence of the key/value
     head (fetched once a head: its index does not change within a group); the
     kernels see them without the two leading dimensions. An index map takes
     and ignores the prefetched table of first key blocks, where there is
-    one."""
+    one. `denoise`: a block-diffusion call's denoising block (its blocks are
+    `diffusion_block`'s)."""
     B, Hq, S, D = q.shape
     Hkv, Dv = k.shape[1], v.shape[3]
-    block, group = tiling(S, D, Dv, Hq, Hkv), Hq // Hkv
+    block = (diffusion_block(S, D, Dv, Hq, Hkv, denoise) if denoise
+             else tiling(S, D, Dv, Hq, Hkv))
+    group = Hq // Hkv
     zero = np.int32(0)          # (a Python 0 is an i64 under jax_enable_x64)
     per_q = lambda width: pl.BlockSpec(
         (None, None, block, width),
@@ -266,12 +354,13 @@ def _grid(grid, in_specs, out_specs, docs, scratch_shapes=()):
         out_specs=out_specs, scratch_shapes=scratch_shapes))
 
 
-def _forward(q, k, v, interpret, scale=None, docs=None):
+def _forward(q, k, v, interpret, scale=None, docs=None, denoise=None):
     """Head-major q (B, Hq, S, D), k (B, Hkv, S, D), v (B, Hkv, S, Dv) ->
     o (B, Hq, S, Dv), log-sum-exp (B, Hq, S) f32. `docs`: None, or (first key
-    blocks (B, S / block), document starts (B, S)) int32."""
+    blocks (B, S / block), document starts (B, S)) int32. `denoise`: None, or
+    a block-diffusion call's denoising block (module docstring)."""
     (B, Hq, S, D), Dv = q.shape, v.shape[3]
-    block, grid, per_q, row, whole = _specs(q, k, v)
+    block, grid, per_q, row, whole = _specs(q, k, v, denoise)
     in_specs, operands = [per_q(D), whole(D), whole(Dv)], (q, k, v)
     if docs is not None:
         first, began = docs
@@ -282,7 +371,7 @@ def _forward(q, k, v, interpret, scale=None, docs=None):
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block=block,
                           scale=_scale(D, scale),
-                          docs=docs is not None),
+                          docs=docs is not None, denoise=denoise),
         **_grid(grid, in_specs, [per_q(Dv), row], docs),
         out_shape=[jax.ShapeDtypeStruct((B, Hq, S, Dv), q.dtype),
                    jax.ShapeDtypeStruct((B, Hq, 1, S), jnp.float32)],
@@ -292,9 +381,10 @@ def _forward(q, k, v, interpret, scale=None, docs=None):
     return o, lse.reshape(B, Hq, S)
 
 
-def _backward(q, k, v, o, lse, do, interpret, scale=None, docs=None):
+def _backward(q, k, v, o, lse, do, interpret, scale=None, docs=None,
+              denoise=None):
     (B, Hq, S, D), Dv = q.shape, v.shape[3]
-    block, grid, per_q, row, whole = _specs(q, k, v)
+    block, grid, per_q, row, whole = _specs(q, k, v, denoise)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     rows = lambda x: x.reshape(B, Hq, 1, S)      # a row a block of queries
     in_specs = [per_q(D), whole(D), whole(Dv), per_q(Dv), row, row]
@@ -308,7 +398,7 @@ def _backward(q, k, v, o, lse, do, interpret, scale=None, docs=None):
     return pl.pallas_call(
         functools.partial(_bwd_kernel, block=block,
                           scale=_scale(D, scale),
-                          docs=docs is not None),
+                          docs=docs is not None, denoise=denoise),
         **_grid(grid, in_specs, [per_q(D), whole(D), whole(Dv)], docs,
                 [pltpu.VMEM((S, D), jnp.float32),
                  pltpu.VMEM((S, Dv), jnp.float32)]),
@@ -320,31 +410,38 @@ def _backward(q, k, v, o, lse, do, interpret, scale=None, docs=None):
         name="flash_attention_bwd", interpret=interpret)(*operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, interpret=False, scale=None, docs=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 6))
+def _flash(q, k, v, interpret=False, scale=None, docs=None, denoise=None):
     """The head-major core (shapes: `_forward`)."""
-    return _forward(q, k, v, interpret, scale, docs)[0]
+    return _forward(q, k, v, interpret, scale, docs, denoise)[0]
 
 
-def _flash_fwd(q, k, v, interpret, scale, docs):
-    o, lse = _forward(q, k, v, interpret, scale, docs)
+def _flash_fwd(q, k, v, interpret, scale, docs, denoise):
+    o, lse = _forward(q, k, v, interpret, scale, docs, denoise)
     o, lse = checkpoint_name(o, OUT_NAME), checkpoint_name(lse, LSE_NAME)
     return o, (q, k, v, o, lse, docs)
 
 
-def _flash_bwd(interpret, scale, residuals, do):
+def _flash_bwd(interpret, scale, denoise, residuals, do):
     *residuals, docs = residuals
-    return _backward(*residuals, do, interpret, scale, docs) + (None,)
+    return _backward(*residuals, do, interpret, scale, docs, denoise) + (None,)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def causal_attention(q, k, v, *, scale=None, segment_ids=None):
+def causal_attention(q, k, v, *, scale=None, segment_ids=None, denoise=None):
     """(B, S, H, D)-major in and out (module docstring); the shapes must be
     ones `tiling` takes. `scale`: a number (None: D^-1/2); `segment_ids`
-    (B, S): each document one run of equal ids."""
+    (B, S): each document one run of equal ids; `denoise`: the denoising
+    block of a block-diffusion call (a shape `diffusion_block` takes; no
+    `segment_ids` with it)."""
     major = lambda x: jnp.transpose(x, (0, 2, 1, 3))
+    if denoise:
+        if segment_ids is not None:
+            raise ValueError("a block-diffusion call takes no segment ids")
+        return major(_flash(major(q), major(k), major(v), False, scale, None,
+                            denoise))
     docs = None
     if segment_ids is not None:
         (B, S, Hq, D), Hkv, Dv = q.shape, k.shape[2], v.shape[3]

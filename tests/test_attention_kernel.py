@@ -203,6 +203,82 @@ def test_documents_in_the_kernel_equal_the_plain_bodys_mask(shape, scale):
     assert np.abs(whole[0][:, :, 1700] - got[0][:, :, 1700]).max() > 1e-2
 
 
+def _major(x):
+    return jnp.transpose(x, (0, 2, 1, 3))
+
+
+def _diffusion_oracle(q, k, v, denoise):
+    """Full softmax in f32 at `highest` under the block-diffusion pair rule
+    (`nh.diffusion_pairs`) over the doubled sequence, head-major."""
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    group, seq = q.shape[1] // k.shape[1], q.shape[2]
+    k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+    pos = np.arange(seq)
+    seen = nh.diffusion_pairs(pos[:, None], pos[None, :], seq // 2, denoise)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest")
+    s = jnp.where(seen, s / np.sqrt(q.shape[-1]), -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+# (2L, denoising block): one block of 128 a half, at the SDAR models' block
+# of 4 and at 64; two blocks of 512 a half (the walk's loop over whole clean
+# blocks, and a clean block at a noisy query block's position that masks the
+# rows of its first denoising block whole)
+@pytest.mark.parametrize("seq,denoise", [(256, 4), (256, 64), (2048, 4)])
+def test_block_diffusion_kernel_equals_the_plain_masked_body(seq, denoise):
+    """Output and dq, dk, dv of the kernel's block-diffusion walk against the
+    plain body (`nh._diffusion_attention`), both against full softmax under
+    the pair rule, f32."""
+    q, k, v, w = _operands(seq, *GROUPED, jnp.float32)
+    want = _with_grads(lambda *t: _diffusion_oracle(*t, denoise), q, k, v, w)
+    plain = _with_grads(lambda *t: _major(nh._diffusion_attention(
+        *map(_major, t), block=128, denoise=denoise)), q, k, v, w)
+    got = _with_grads(lambda *t: fa._flash(*t, True, None, None, denoise),
+                      q, k, v, w)
+    assert max(_worst(plain, want)) < 2e-5, _worst(plain, want)
+    assert max(_worst(got, plain)) < 2e-5, _worst(got, plain)
+    # the rule is no causal mask over 2L: that one reads otherwise
+    causal = _with_grads(_oracle, q, k, v, w)
+    assert max(_worst(causal, want)) > 1e-2
+
+
+def test_entry_counts_a_block_diffusion_call_by_its_shape():
+    """A shape the kernel's block-diffusion walk takes counts as
+    "block_diffusion"; one it refuses as "blockwise"; never as "fused"."""
+    paths = ("fused", "blockwise", "block_diffusion")
+    for width, took in ((128, "block_diffusion"), (8, "blockwise")):
+        before = [_count(p) for p in paths]
+        q = jnp.ones((1, 512, 2, width), jnp.float32)
+        jax.jit(lambda q: nh.blockwise_causal_attention(
+            q, q, q, block=8, denoise=4))(q)
+        assert [_count(p) - b for p, b in zip(paths, before)] == \
+            [int(p == took) for p in paths]
+
+
+def test_cpu_lowering_of_a_block_diffusion_call_holds_the_plain_body():
+    """Through the entry lowered for the CPU at a shape the kernel takes: no
+    custom call, the plain body's result to the bit, forward and backward."""
+    rng = np.random.default_rng(11)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 512, h, 128)), jnp.float32)
+               for h in (4, 2, 2))
+
+    def grads(core):
+        return jax.jit(jax.value_and_grad(
+            lambda *t: jnp.sum(jnp.square(core(*t, block=128, denoise=4))),
+            argnums=(0, 1, 2)))
+    entry = grads(nh.blockwise_causal_attention)
+    text = entry.lower(q, k, v).compile().as_text()
+    assert "custom-call" not in text and "custom_call" not in text
+    for got, want in zip(jax.tree_util.tree_leaves(entry(q, k, v)),
+                         jax.tree_util.tree_leaves(
+                             grads(nh._diffusion_attention)(q, k, v))):
+        np.testing.assert_array_equal(got, want)
+    with pytest.raises(ValueError, match="no document starts"):
+        nh.blockwise_causal_attention(q, k, v, denoise=4,
+                                      starts=jnp.ones((1, 512), jnp.int32))
+
+
 def test_kernel_behind_the_entry_layout_equals_the_plain_body():
     """`causal_attention` is (B, S, H, D)-major like the plain body; here
     with the interpreter in the kernel's place."""

@@ -22,8 +22,10 @@ The attention core is one fused kernel on a TPU lowering
 attention`): at the language-model cells' shapes `value_and_grad` through the
 entry compiles to custom calls under the `attn.core` stage and to no f32
 array of a score block's size, Granite's packed call too (64-wide heads, a
-score scale of 1/64, document starts); a shape the tiling refuses compiles to
-the plain body.
+score scale of 1/64, document starts), SDAR's block-diffusion call too; a
+shape the tiling refuses compiles to the plain body. Every call without the
+block-diffusion walk lowers to the kernels and program the tree had before the
+walk existed (their hashes, source locations and stack frames taken out).
 
 The Solar-Open2 cell's scan (`models/solar_open2.py`: 966.7M parameters, 7.2
 GiB of state) fits the chip only because its routed layer GATHERS a block's
@@ -455,20 +457,24 @@ def test_four_chip_scan_with_no_apply_to_share_with_serves_per_slot(
 CORES = {"joyai_keys192_values128": (2, 4096, 32, 32, 192, 128),
          "nemotron_32_heads_over_2": (2, 4096, 32, 2, 128, 128),
          "solar_8k_8_heads_over_1": (1, 8192, 8, 1, 128, 128),
-         "granite_8k_64_wide_packed": (1, 8192, 32, 8, 64, 64)}
+         "granite_8k_64_wide_packed": (1, 8192, 32, 8, 64, 64),
+         "zaya_8k_8_heads_over_2": (1, 8192, 8, 2, 128, 128),
+         "ouro_32_heads_over_32": (1, 4096, 32, 32, 128, 128)}
 SCORE_BLOCK = 2 * 32 * 512 * 512   # elements of one block's scores
 
 
-def _core_text(one_chip, shape, dtype=jnp.bfloat16):
+def _core_text(one_chip, shape, dtype=jnp.bfloat16, denoise=None):
     """Optimised HLO and temporaries of value_and_grad through the entry,
     under the stage name the models give it; at Granite's 64-wide heads
     with its scale (1/64) and document starts, as its attention layer calls
-    it."""
+    it; with `denoise`, a block-diffusion call (SDAR's)."""
     from openembedding_tpu.models import nemotron_h as nh
     from openembedding_tpu.utils import trace
     B, S, Hq, Hkv, D, Dv = shape
     packed = D == 64
     kw = dict(scale=1 / 64) if packed else {}
+    if denoise:
+        kw["denoise"] = denoise
 
     def loss(q, k, v, *starts):
         with trace.scope("attn", "core"):
@@ -508,6 +514,69 @@ def test_attention_core_lowers_to_the_fused_kernel_on_the_tpu(one_chip, shape):
     assert max(_score_blocks(text), default=0) < min(SCORE_BLOCK,
                                                      B * Hq * 512 * 512)
     assert temp < 1 << 30
+
+
+def test_block_diffusion_core_lowers_to_the_fused_kernel_on_the_tpu(one_chip):
+    """SDAR's cell: B 2 x 2L 8192, 32 query heads over 4 of 128, denoising
+    blocks of 4 -> the kernel's two calls under `attn.core` (blocks of 512),
+    no f32 array of a score block's size, under 1 GiB of temporaries."""
+    text, temp = _core_text(one_chip, (2, 8192, 32, 4, 128, 128), denoise=4)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2 and all("attn.core" in line for line in calls)
+    assert {re.search(r"%(flash_attention_\w+?)\.", line).group(1)
+            for line in calls} == {"flash_attention_fwd", "flash_attention_bwd"}
+    assert max(_score_blocks(text), default=0) < SCORE_BLOCK
+    assert temp < 1 << 30
+
+
+def _kernels_and_rest(text):
+    """(sha256 prefixes of each Mosaic body decoded WITHOUT its source
+    locations, of the rest of the text with the bodies, metadata, backend
+    configs and the stack-frame tables (file paths, line numbers) taken out)."""
+    import base64
+    import hashlib
+
+    from jax._src.lib import tpu as tpu_dialect
+    from jax._src.lib.mlir import ir
+    digest = lambda t: hashlib.sha256(t.encode()).hexdigest()[:10]
+    bodies = []
+
+    def decode(match):
+        ctx = ir.Context()
+        tpu_dialect.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            bodies.append(digest(module.operation.get_asm(enable_debug_info=False)))
+        return ""
+    text = re.sub(r'"custom_call_config":\{"body":"([^"]+)"', decode, text)
+    text = re.sub(r"metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"backend_config=\{.*?\}\s", "", text)
+    text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                  r"(?:[^\n]+\n)*", "\n", text)
+    return bodies, digest(text)
+
+
+# the kernels and program each language-model cell's core lowered to before
+# the kernel took its block-diffusion walk (forward, backward; rest), under
+# the suite's `jax_enable_x64` (Granite's starts make an i64 there)
+CORES_BEFORE_THE_WALK = {
+    "joyai_keys192_values128": (["8f59202fbf", "4b4d82735f"], "1a991d7cf6"),
+    "nemotron_32_heads_over_2": (["7a99853aef", "0c9635e503"], "4f09382c9a"),
+    "solar_8k_8_heads_over_1": (["407b51e9a3", "a30f11bef7"], "b3f3cb38b5"),
+    "granite_8k_64_wide_packed": (["ee3ce1eb61", "4df82503a0"], "dbb075f8df"),
+    "zaya_8k_8_heads_over_2": (["fc91947aae", "6e8117bc5e"], "4828ed774b"),
+    "ouro_32_heads_over_32": (["c2f82c3c49", "465cb7afe0"], "444bfb1f5e")}
+
+
+@pytest.mark.parametrize("shape", CORES.values(), ids=CORES.keys())
+def test_attention_cores_without_block_diffusion_compile_as_before(one_chip, shape):
+    """The block-diffusion walk is a static branch: every other call lowers to
+    the kernels and program it lowered to before the walk existed."""
+    text, _ = _core_text(one_chip, shape)
+    name = next(k for k, v in CORES.items() if v == shape)
+    assert _kernels_and_rest(text) == CORES_BEFORE_THE_WALK[name]
 
 
 def test_attention_core_the_tiling_refuses_lowers_to_the_plain_body(one_chip):
