@@ -14,7 +14,8 @@ Every block is `x + mixer(RMSNorm(x))`; the pattern's letters pick the mixer:
   and SiLU over xBC; x (heads x head_dim), B and C (groups x state);
   dt = softplus(dt + dt_bias); A = -exp(A_log);
   h_t = exp(dt A) h_{t-1} + dt B_t (x) x_t; y_t = C_t . h_t + D x_t, computed
-  chunk by chunk (`ssd_chunked`); y = grouped RMSNorm(y * SiLU(z)); `out_proj`.
+  chunk by chunk (`ssd_chunked`: on a TPU one fused kernel a direction,
+  `ops/ssd_scan.py`); y = grouped RMSNorm(y * SiLU(z)); `out_proj`.
 - `*`, causal attention with grouped key/value heads and no rotary embedding
   (`blockwise_causal_attention`: no S x S array; on a TPU one fused kernel,
   `ops/flash_attention.py`, elsewhere query block by query block over the
@@ -46,7 +47,9 @@ Stage names (`utils/trace.py`): `ssm.{in_proj,conv,scan,gate_norm,out_proj}`,
 module hands `Trainer` per-step `moe.*` stats (`apply_with_stats`,
 `window_stats`); `attn.cores{path=}` counts the traced attention cores by
 what their shape allows (`blockwise_causal_attention`); `moe.products{path=
-"grouped"|"picked"}` the traced runs of a routed layer by its held count.
+"grouped"|"picked"}` the traced runs of a routed layer by its held count;
+`ssm.scans{path="fused"|"chunked"}` the traced chunked scans by what their
+shape allows (`ssd_chunked`).
 
 Documents packed into a sequence (`granite_hybrid.py`): `ssd_chunked`,
 `causal_conv` and `blockwise_causal_attention` take `starts` (B, S), nonzero
@@ -170,19 +173,47 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32, starts=None):
     a position (`segment_ids`), inside a chunk the pair (l, s) counts where
     n_l = n_s; what a chunk leaves keeps the positions of its last
     document; chunk c's state enters chunk z where nothing starts between
-    their ends, and a position reads it where it is still in that document."""
+    their ends, and a position reads it where it is still in that document.
+
+    The entry to two bodies: at a shape the kernel takes
+    (`ops/ssd_scan.takes`) a process whose default backend is a TPU traces
+    the kernel (the whole chunk loop, a chunk's decays and the carried state
+    in VMEM only) and every other process the plain body; at any other shape
+    the plain body everywhere. The body is chosen when the scan is traced,
+    not when it is lowered: `jax.lax.platform_dependent` would trace and
+    differentiate both, 3-4 s more of every warm set-up at Granite's nine
+    layers. `ssm.scans{path=}`, counted here once a traced call site, speaks
+    for the SHAPE: "fused" = the kernel would take it, "chunked" = it
+    refuses it (both series exist once one does)."""
     Bt, L, H, P = x.shape
     G, N = B.shape[2:]
-    r = H // G
     pad = (-L) % chunk
     if pad:
         x, dt, B, C = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
                        for t in (x, dt, B, C))
     c = (L + pad) // chunk
+    n = None
     if starts is not None:
         _count_reset("ssd")
         n = jnp.pad(segment_ids(starts), ((0, 0), (0, pad)), mode="edge")
         n = n.reshape(Bt, c, chunk)
+    fused = _ssd_kernel().takes(chunk, H, G, P, N)
+    for path in ("fused", "chunked"):
+        _metrics.observe("ssm.scans", int(fused == (path == "fused")), "sum",
+                         labels={"path": path})
+    body = _ssd_fused if fused and _traces_for_tpu() else _ssd_plain
+    return body(x, dt, A, B, C, n, chunk, dtype)[:, :L]
+
+
+def _ssd_plain(x, dt, A, B, C, n, chunk, dtype):
+    """The plain body, (Bt, c Q, H, P) f32: every pair's decay an element
+    of an f32 (Bt, c, Q, Q, G, r) array, the chunks' states joined by a
+    (c x c) product."""
+    Bt, Lp, H, P = x.shape
+    G, N = B.shape[2:]
+    r = H // G
+    c = Lp // chunk
+    if n is not None:
         n_end = n[:, :, -1]                                  # (Bt,c)
         # the document the state ENTERING chunk z belongs to: chunk z-1's
         # last (nothing enters chunk 0, whatever this says there)
@@ -196,14 +227,14 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32, starts=None):
     # inside a chunk: y_l += sum_{s<=l} (C_l . B_s) exp(acs_l - acs_s) dt_s x_s
     seg = acs[:, :, :, None] - acs[:, :, None, :]            # (Bt,c,l,s,G,r)
     tri = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None, None]
-    if starts is not None:
+    if n is not None:
         tri = tri & (n[:, :, :, None] == n[:, :, None, :])[..., None, None]
     decay = jnp.exp(jnp.where(tri, seg, -jnp.inf))
     cb = _mm("bclgn,bcsgn->bclsg", Cc, Bc, dtype)
     y = _mm("bclsgr,bcsgrp->bclgrp", cb[..., None] * decay, xd, dtype)
     # what a chunk leaves behind: sum_s exp(acs_end - acs_s) dt_s B_s (x) x_s
     left = jnp.exp(acs[:, :, -1:] - acs)                     # (Bt,c,Q,G,r)
-    if starts is not None:
+    if n is not None:
         left = jnp.where((n == n_end[:, :, None])[..., None, None], left, 0.0)
     states = _mm("bcsgn,bcsgrp->bcgrpn", Bc, xd * left[..., None], dtype)
     # between chunks: the state entering chunk z is sum_{c<z} exp(sum of the
@@ -212,16 +243,33 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, dtype=jnp.float32, starts=None):
     run = jnp.cumsum(tot, axis=1)
     between = (run - tot)[:, :, None] - run[:, None, :]      # (Bt,z,c,G,r)
     low = jnp.tril(jnp.ones((c, c), bool), -1)[:, :, None, None]
-    if starts is not None:
+    if n is not None:
         low = low & (n_in[:, :, None] == n_end[:, None, :])[..., None, None]
     carry = jnp.exp(jnp.where(low, between, -jnp.inf))
     entering = _mm("bzcgr,bcgrpn->bzgrpn", carry, states, dtype)
     read = _mm("bclgn,bcgrpn->bclgrp", Cc, entering, dtype)
     reach = jnp.exp(acs)                                     # (Bt,c,Q,G,r)
-    if starts is not None:
+    if n is not None:
         reach = jnp.where((n == n_in[:, :, None])[..., None, None], reach, 0.0)
     y = y + read * reach[..., None]
-    return y.reshape(Bt, c * chunk, H, P)[:, :L]
+    return y.reshape(x.shape)
+
+
+def _ssd_fused(x, dt, A, B, C, n, chunk, dtype):
+    """The kernel's body, (Bt, c Q, H, P) f32: the cumulated decays as the
+    plain body makes them, every operand in the model's own layout but dt
+    and the decays, a row a head (the kernel makes xd = dt x)."""
+    Bt, Lp, H, P = x.shape
+    G, N = B.shape[2:]
+    c = Lp // chunk
+    dt = dt.astype(jnp.float32).reshape(Bt, c, chunk, H)
+    acs = jnp.cumsum(dt * A.astype(jnp.float32), axis=2)      # (Bt,c,Q,H)
+    Bc, Cc = (t.reshape(Bt, c, chunk, G * N).astype(dtype) for t in (B, C))
+    docs = None if n is None else n.astype(jnp.int32)
+    y = _ssd_kernel().scan(x.reshape(Bt, c, chunk, H * P),
+                           jnp.moveaxis(dt, 2, 3), jnp.moveaxis(acs, 2, 3),
+                           Bc, Cc, G, docs)
+    return y.reshape(x.shape)
 
 
 def blockwise_causal_attention(q, k, v, *, block: int = 512, scale=None,
@@ -354,6 +402,19 @@ def _flash():
     no attention (and their set-up times) are spared."""
     from ..ops import flash_attention
     return flash_attention
+
+
+def _traces_for_tpu() -> bool:
+    """Whether this process lowers for a TPU: its default backend (tests of
+    the kernel's path on the CPU patch this)."""
+    return jax.default_backend() == "tpu"
+
+
+def _ssd_kernel():
+    """`ops/ssd_scan.py`, imported when a model first traces a chunked scan
+    (Pallas, as `_flash`)."""
+    from ..ops import ssd_scan
+    return ssd_scan
 
 
 def _blockwise_causal_attention(q, k, v, *, block, scale=None, n=None):

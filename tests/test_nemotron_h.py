@@ -188,6 +188,77 @@ def test_chunked_ssd_equals_the_recurrence(length):
         assert np.max(np.abs(reset - want)) > 1e-2
 
 
+# the chunked scan's TPU kernel (`ops/ssd_scan.py`) traced as on a TPU, under
+# the Pallas interpreter, against the plain body: (chunk, groups, heads,
+# head width, length, where documents begin); a padded tail where the length
+# is no multiple of the chunk, starts on a chunk boundary and inside a chunk;
+# two 64-wide heads share a lane tile, a 128-wide head fills one
+KERNEL_CASES = {
+    "q128_one_group_padded_tail": (128, 1, 8, 64, 300, None),
+    "q256_two_groups": (256, 2, 16, 64, 512, None),
+    "q128_two_groups_starts_on_and_inside_chunks_padded_tail":
+        (128, 2, 16, 64, 300, (0, 50, 128, 200)),
+    "q256_one_group_starts_on_and_inside_a_chunk_padded_tail":
+        (256, 1, 8, 64, 600, (0, 100, 256, 300)),
+    "q128_one_group_128_wide_heads_starts_inside_a_chunk":
+        (128, 1, 8, 128, 384, (0, 70, 300)),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES.values(), ids=KERNEL_CASES.keys())
+def test_scan_kernel_equals_the_plain_body(monkeypatch, case):
+    from openembedding_tpu.ops import ssd_scan
+    from openembedding_tpu.utils import metrics
+    chunk, groups, heads, width, length, at = case
+    rng = np.random.default_rng(chunk + length)
+    x = jnp.asarray(rng.normal(size=(1, length, heads, width)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.001, 0.1, size=(1, length, heads)), jnp.float32)
+    a = -jnp.exp(jnp.asarray(rng.normal(size=(heads,)), jnp.float32))
+    b, c = (jnp.asarray(rng.normal(size=(1, length, groups, 128)) / 8,
+                        jnp.float32) for _ in range(2))
+    starts = None
+    if at is not None:
+        starts = np.zeros((1, length), np.int32)
+        starts[0, list(at)] = 1
+
+    def run():
+        def loss(*t):
+            y = nh.ssd_chunked(*t, chunk=chunk, starts=starts)
+            return jnp.sum(jnp.sin(y)), y
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3, 4), has_aux=True))(
+            x, dt, a, b, c)
+    (_, want), gw = run()
+    kernel = ssd_scan.scan
+    monkeypatch.setattr(ssd_scan, "scan",
+                        lambda *args: kernel(*args, interpret=True))
+    monkeypatch.setattr(nh, "_traces_for_tpu", lambda: True)
+    before = metrics.report().get('ssm.scans{path="fused"}', 0)
+    (_, got), gg = run()
+    assert metrics.report()['ssm.scans{path="fused"}'] == before + 1
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    for u, v in zip(gg, gw):
+        np.testing.assert_allclose(u, v, atol=1e-4 * float(np.max(np.abs(v))),
+                                   rtol=1e-5)
+
+
+def test_chunks_the_kernel_refuses_count_as_chunked():
+    from openembedding_tpu.ops import ssd_scan
+    from openembedding_tpu.utils import metrics
+    assert ssd_scan.takes(256, 64, 1, 64, 128)          # Granite's layer
+    assert ssd_scan.takes(128, 64, 8, 64, 128)          # Nemotron's
+    assert not ssd_scan.takes(8, 4, 2, 8, 16)           # this file's
+    assert not ssd_scan.takes(128, 12, 1, 64, 128)      # 12 heads a group
+    assert not ssd_scan.takes(128, 8, 1, 32, 128)       # 32-wide heads
+    assert not ssd_scan.takes(128, 8, 1, 64, 64)        # a state of 64
+    assert not ssd_scan.takes(128, 8, 1, 64, 256)       # a state of 256
+    x = jnp.ones((1, 16, 4, 8), jnp.float32)
+    before = metrics.report().get('ssm.scans{path="chunked"}', 0)
+    jax.eval_shape(lambda *t: nh.ssd_chunked(*t, chunk=8), x,
+                   jnp.ones((1, 16, 4)), -jnp.ones((4,)),
+                   jnp.ones((1, 16, 2, 16)), jnp.ones((1, 16, 2, 16)))
+    assert metrics.report()['ssm.scans{path="chunked"}'] == before + 1
+
+
 @pytest.mark.parametrize("seq,block", [(32, 8), (21, 8), (16, 16), (7, 16)])
 def test_blockwise_attention_equals_reference_attention(seq, block):
     rng = np.random.default_rng(seq)

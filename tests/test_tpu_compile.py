@@ -587,6 +587,72 @@ def test_attention_core_the_tiling_refuses_lowers_to_the_plain_body(one_chip):
     assert max(_score_blocks(text)) >= SCORE_BLOCK
 
 
+# one Mamba-2 layer's chunked scan: (batch, length, chunk, groups, heads, head
+# width, state, document starts); Granite's with starts, Nemotron's without
+SCANS = {"granite_8k_chunk256_one_group_packed": (1, 8192, 256, 1, 64, 64, 128, True),
+         "nemotron_4k_chunk128_eight_groups": (2, 4096, 128, 8, 64, 64, 128, False)}
+
+
+def _scan_text(one_chip, shape, monkeypatch):
+    """Optimised HLO of value_and_grad through one `ssd_chunked` call under
+    the stage name the models give it, in bf16 and laid out as the cells run
+    it (x, B and C split from the convolution's channels, y back into them),
+    traced as a TPU process traces it (this one's default backend is the
+    CPU)."""
+    from openembedding_tpu.models import nemotron_h as nh
+    from openembedding_tpu.utils import trace
+    monkeypatch.setattr(nh, "_traces_for_tpu", lambda: True)
+    Bt, L, Q, G, H, P, N, packed = shape
+
+    def loss(xs, dt, A, B, C, *starts):
+        with trace.scope("ssm", "scan"):
+            y = nh.ssd_chunked(xs.reshape(Bt, L, H, P), dt, A,
+                               B.reshape(Bt, L, G, N), C.reshape(Bt, L, G, N),
+                               Q, jnp.bfloat16, starts[0] if starts else None)
+        return jnp.sum(jnp.sin(y.reshape(xs.shape)))
+
+    def arg(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    args = [arg(Bt, L, H * P), arg(Bt, L, H, dtype=jnp.float32),
+            arg(H, dtype=jnp.float32), arg(Bt, L, G * N), arg(Bt, L, G * N)]
+    if packed:
+        args.append(arg(Bt, L, dtype=jnp.int32))
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+
+
+@pytest.mark.parametrize("shape", SCANS.values(), ids=SCANS.keys())
+def test_chunked_scan_lowers_to_the_scan_kernel_on_the_tpu(one_chip, shape,
+                                                          monkeypatch):
+    """The scan is `ops/ssd_scan.py`'s two kernels under `ssm.scan`, and no
+    f32 array of a chunk's pairs over its heads (Q x Q x H a chunk: the
+    plain body's decays) nor any copy of an f32 array of the scan's size (x,
+    xd or y moved to another layout) is left."""
+    text = _scan_text(one_chip, shape, monkeypatch)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2 and all("ssm.scan" in line for line in calls), calls
+    assert {re.search(r"%(ssd_scan_\w+?)\.", line).group(1)
+            for line in calls} == {"ssd_scan_fwd", "ssd_scan_bwd"}
+    Bt, L, Q, G, H, P = shape[:6]
+    f32 = [int(np.prod([int(d) for d in dims.split(",")])) for dims in
+           set(re.findall(r"f32\[([0-9,]+)\]", text))]
+    assert max(f32) < Bt * L * Q * H
+    copies = re.findall(r"= f32\[([0-9,]+)\]\{[^}]*\} copy\(", text)
+    assert all(np.prod([int(d) for d in c.split(",")]) < Bt * L * H * P
+               for c in copies), copies
+
+
+def test_chunked_scan_the_kernel_refuses_lowers_to_the_plain_body(one_chip,
+                                                                  monkeypatch):
+    """A chunk of 64 is no lane tile: the plain body, its pairs an f32 array."""
+    text = _scan_text(one_chip, (1, 1024, 64, 1, 16, 64, 128, False),
+                      monkeypatch)
+    assert "tpu_custom_call" not in text
+    assert re.search(r"f32\[1,16,64,64,1,16\]", text), \
+        sorted(set(re.findall(r"f32\[1,16,64,64[0-9,]*\]", text)))
+
+
 def test_solar_open2_scan_fits_the_chip_at_ten_experts(one_chip):
     """`benchmark`'s `solar-open2.train_8k` at its real sizes: the 4-step scan
     compiles for the described v5e (the compiler raises where it does not
